@@ -26,13 +26,6 @@ Layout (mirrors SURVEY.md §7's proposed layout):
 
 __version__ = "0.1.0"
 
-# Publish ``jax.shard_map`` on old jax pins (< 0.7) before anything —
-# package-internal or user code written against the modern spelling —
-# touches it.  A real ``jax.shard_map`` is never overwritten.
-from distlearn_tpu.utils import compat as _compat
-
-_compat.install()
-
 from distlearn_tpu.parallel.mesh import MeshTree, all_reduce, broadcast_from, node_index
 from distlearn_tpu.parallel.allreduce_sgd import AllReduceSGD
 from distlearn_tpu.parallel.allreduce_ea import AllReduceEA

@@ -1,4 +1,4 @@
-"""Transport error taxonomy shared by the Python and native IO paths.
+"""Transport error classes shared by the Python and native IO paths.
 
 Lives in its own module (rather than comm/transport.py) because the
 native ctypes shim (comm/native.py) must raise the same types while

@@ -5,10 +5,8 @@ batch directly in GPU memory (examples/Data.lua:27, consumed by the EASGD
 trio).  The TPU-native upgrade goes further: upload the WHOLE dataset to
 device memory once, then each step transfers only the batch's int32 index
 vector (a few hundred bytes) and gathers the batch with an on-device
-``jnp.take``.  On a remote-attached chip this removes the per-step
-megabytes-over-the-wire that otherwise dominate small-model step time
-(measured on the CIFAR-10 example: per-step host batch upload capped it at
-~8 steps/s while the compute-bound rate is ~300).
+``jnp.take``.  This removes the per-step host-to-device batch upload,
+which for a small model can cost more than the step itself.
 
 Fits-in-HBM datasets only (MNIST/CIFAR-scale: tens to hundreds of MB);
 streaming sets keep using the host prefetch pipeline (data/prefetch.py).

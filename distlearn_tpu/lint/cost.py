@@ -15,8 +15,8 @@ post-fusion HLO module is walked to attribute
 * **post-fusion collective op counts** — what fusion actually left in the
   module, which is what the wire sees (``ops/fused_update.py`` degrading
   to per-tensor reduces shows up here long before a profile would);
-* **compiled peak/temp memory** via
-  :func:`distlearn_tpu.utils.compat.compiled_memory_stats`.
+* **compiled peak/temp memory** from ``compiled.memory_analysis()``
+  (:func:`compiled_memory_stats`).
 
 The numbers are *per device per step*: the module XLA emits under SPMD
 partitioning is the one program every device runs, with local (sharded)
@@ -83,7 +83,6 @@ from typing import Sequence
 import numpy as np
 
 from distlearn_tpu.lint.core import Finding
-from distlearn_tpu.utils import compat
 
 __all__ = ["CollectiveOp", "CostReport", "analyze_step", "audit_compiles",
            "count_entry_relayouts", "lint_tick_loop", "parse_collectives",
@@ -269,9 +268,9 @@ class CostReport:
     ``bytes_by_kind`` / ``ops_by_kind`` aggregate over mesh axes;
     ``bytes_by_axis`` keeps the per-axis split (keys like
     ``"all-reduce@data"``).  ``memory`` is the
-    :func:`~distlearn_tpu.utils.compat.compiled_memory_stats` dict (or
-    None where the backend reports nothing); ``flops`` comes from the
-    compiler's own cost analysis when available.
+    :func:`compiled_memory_stats` dict (or None where the backend
+    reports nothing); ``flops`` comes from the compiler's own cost
+    analysis when available.
     """
 
     name: str
@@ -368,7 +367,7 @@ def _count_explicit_gathers(fn, args) -> int:
     """Author-requested all-gathers: ``all_gather``/``pgather`` equations
     anywhere in the traced jaxpr (the baseline DL201 subtracts)."""
     import jax
-    from jax import core as jcore
+    from jax.extend import core as jcore
     try:
         closed = jax.make_jaxpr(fn)(*args)
     except Exception:
@@ -411,7 +410,7 @@ def _spec_is_sharded(spec) -> bool:
     return False
 
 
-def _check_replicated_params(lowered, compiled, args, in_specs,
+def _audit_replicated_params(lowered, compiled, args, in_specs,
                              name: str) -> list[Finding]:
     """DL202: declared-sharded large arguments compiled fully replicated."""
     import jax
@@ -683,6 +682,27 @@ def lint_tick_loop(sources=None) -> list[Finding]:
     return findings
 
 
+def compiled_memory_stats(compiled) -> dict | None:
+    """Byte-level memory stats of a compiled executable, or None where
+    the backend reports nothing.
+
+    ``compiled.memory_analysis()`` as a plain dict with ``argument``,
+    ``output``, ``temp``, ``alias``, ``generated_code`` byte counts plus
+    a derived ``peak`` (arguments + outputs + temporaries, minus donated
+    aliases — the live-at-once footprint the budget lockfiles gate)."""
+    ma = compiled.memory_analysis()
+    if ma is None:
+        return None
+    stats = {k: int(getattr(ma, k + "_size_in_bytes", 0) or 0)
+             for k in ("argument", "output", "temp", "alias",
+                       "generated_code")}
+    if not any(stats.values()):
+        return None
+    stats["peak"] = max(0, stats["argument"] + stats["output"]
+                        + stats["temp"] - stats["alias"])
+    return stats
+
+
 def analyze_step(fn, args: Sequence, *, mesh=None, name: str = "step",
                  in_specs=None,
                  gather_threshold: int = GATHER_BYTES_THRESHOLD,
@@ -701,15 +721,18 @@ def analyze_step(fn, args: Sequence, *, mesh=None, name: str = "step",
     measured ``compile_s``, and entry ``relayout_ops`` for the DL207/
     DL208 budget gates.
     """
+    import jax
     t0 = time.perf_counter()
-    lowered, compiled = compat.lower_compiled(fn, args)
+    # ``args`` may be abstract (ShapeDtypeStruct) — nothing is executed
+    lowered = (fn if hasattr(fn, "lower") else jax.jit(fn)).lower(*args)
+    compiled = lowered.compile()
     compile_s = time.perf_counter() - t0
     hlo = compiled.as_text()
     report = CostReport(
         name=name,
         collectives=parse_collectives(hlo, mesh),
-        memory=compat.compiled_memory_stats(compiled),
-        flops=compat.compiled_cost_analysis(compiled).get("flops"),
+        memory=compiled_memory_stats(compiled),
+        flops=(compiled.cost_analysis() or {}).get("flops"),
         signature=_arg_signature(args),
         compile_s=compile_s,
         relayout_ops=count_entry_relayouts(hlo),
@@ -730,7 +753,7 @@ def analyze_step(fn, args: Sequence, *, mesh=None, name: str = "step",
             "with_sharding_constraint",
             where=name))
     if in_specs is not None:
-        findings += _check_replicated_params(lowered, compiled, args,
+        findings += _audit_replicated_params(lowered, compiled, args,
                                              in_specs, name)
     if donation:
         findings += _check_donation(lowered, hlo, name)

@@ -204,7 +204,7 @@ def _ep_family():
     from jax import lax
     from jax.sharding import Mesh, PartitionSpec as P
     from distlearn_tpu.parallel.ep import moe_ffn
-    from distlearn_tpu.utils.compat import shard_map
+    from jax import shard_map
     E, N, D = MIN_DEVICES, 16, 32
     mesh = Mesh(np.array(jax.devices()[:E]), ("expert",))
 
@@ -253,7 +253,7 @@ def _seq_family():
     from jax.sharding import Mesh, PartitionSpec as P
     from distlearn_tpu.parallel.sequence import (alltoall_attention,
                                                  ring_attention)
-    from distlearn_tpu.utils.compat import shard_map
+    from jax import shard_map
     n = MIN_DEVICES
     mesh = Mesh(np.array(jax.devices()[:n]), ("seq",))
     B, L, H, D = 2, 16 * n, n, 16     # H divisible by n (ulysses), L/n even
@@ -324,7 +324,8 @@ def _wirek_family():
     import jax
     from distlearn_tpu.ops import wire_kernels as wk
     from distlearn_tpu.ops.flatten import LANE
-    rows = 4 * wk._BLOCK_ROWS           # 4 grid steps of the block spec
+    from distlearn_tpu.ops.fused_update import _BLOCK_ROWS
+    rows = 4 * _BLOCK_ROWS              # 4 grid steps of the block spec
     x = jax.ShapeDtypeStruct((rows, LANE), "float32")
     q = jax.ShapeDtypeStruct((rows, LANE), "int8")
     st = jax.ShapeDtypeStruct((1, 1), "float32")

@@ -15,7 +15,7 @@ state thread through the walk:
 
 * per-value **taint** — the set of bound axes across which a value may
   differ between devices.  Sources: ``axis_index`` output and
-  ``shard_map`` inputs sharded along an axis (``in_names``).  A reducing
+  ``shard_map`` inputs sharded along an axis (``in_specs``).  A reducing
   collective over axes ``A`` makes its result identical along ``A`` and
   subtracts ``A`` from the taint; everything else unions its operands.
   Taint is what lets DL002 stay quiet on the repo's
@@ -34,7 +34,7 @@ from __future__ import annotations
 from typing import NamedTuple, Sequence
 
 import jax
-from jax import core
+from jax.extend import core
 
 from distlearn_tpu.lint.core import Finding, filter_suppressed
 
@@ -114,10 +114,11 @@ def _walk(jaxpr: core.Jaxpr, in_taints, bound: frozenset, path: str) -> _WalkRes
             mesh_axes = frozenset(str(a) for a in eqn.params["mesh"].axis_names)
             inner_bound = bound | mesh_axes
             body_in = []
-            for t, names in zip(in_ts, eqn.params["in_names"]):
+            for t, spec in zip(in_ts, eqn.params["in_specs"]):
+                # a PartitionSpec entry is None, an axis name, or a tuple
                 sharded = frozenset(
-                    str(a) for axes in dict(names).values()
-                    for a in (axes if isinstance(axes, (tuple, list)) else (axes,)))
+                    str(a) for part in spec if part is not None
+                    for a in (part if isinstance(part, tuple) else (part,)))
                 body_in.append(t | sharded)
             sub = _walk_closed(eqn.params["jaxpr"], body_in, inner_bound,
                                f"{here}")
@@ -313,17 +314,17 @@ def lint_donation(fn, args, *, name: str = "step") -> list[Finding]:
     findings = []
     outs = [(tuple(o.shape), jax.numpy.dtype(o.dtype)) for o in out_info]
     for a in args_info:
-        if not getattr(a, "donated", False):
+        if not a.donated:
             continue
-        aval = getattr(a, "aval", None) or a._aval  # private on old jax
-        key = (tuple(aval.shape), jax.numpy.dtype(aval.dtype))
+        key = (tuple(a.shape), jax.numpy.dtype(a.dtype))
         if key in outs:
             outs.remove(key)  # each output aliases at most one input
         else:
             findings.append(Finding(
                 "DL005",
-                f"donated input {aval.str_short()} has no matching output "
-                "to alias; the buffer is invalidated without being reused",
+                f"donated input {key[1].name}{list(key[0])} has no matching "
+                "output to alias; the buffer is invalidated without being "
+                "reused",
                 where=name))
     return findings
 

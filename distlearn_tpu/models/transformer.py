@@ -31,7 +31,6 @@ from jax import lax, random
 from jax.sharding import PartitionSpec as P
 
 from distlearn_tpu.models.core import Model
-from distlearn_tpu.utils import compat
 from distlearn_tpu.parallel.sequence import (alltoall_attention,
                                              local_attention, ring_attention)
 from distlearn_tpu.parallel.tp import tp_enter, tp_reduce
@@ -200,9 +199,8 @@ def transformer_lm(vocab: int = 256, dim: int = 128, depth: int = 2,
 
     ``scan_blocks=True`` stores the per-block parameters STACKED on a
     leading ``[depth]`` axis (``params["blocks"]``) and runs the depth
-    loop as one ``lax.scan`` — the program no longer grows with depth
-    (the unrolled loop's ~depth-fold program size is what made very deep
-    / very long configs exceed this environment's compile limits).
+    loop as one ``lax.scan`` — the program (and its compile time) no
+    longer grows with depth.
     Identical math to the unrolled layout (tested); convert between
     layouts with :func:`stack_block_params` / :func:`unstack_block_params`.
     Requires a homogeneous dense stack (no MoE blocks — their routed
@@ -322,7 +320,7 @@ def transformer_lm(vocab: int = 256, dim: int = 128, depth: int = 2,
             my = lax.axis_index(seq_axis)
             if seq_layout == "zigzag":
                 # local shard = early stripe my ++ late stripe 2n-1-my
-                n_sh = compat.axis_size(seq_axis)
+                n_sh = lax.axis_size(seq_axis)
                 s_len = L // 2
                 pa = lax.dynamic_slice_in_dim(params["pos"], my * s_len,
                                               s_len)
@@ -648,7 +646,7 @@ def lm_loss(model: Model, params, tokens, seq_axis=None, tp_axis=None,
         nll = -jnp.take_along_axis(lp, targets[..., None], -1)[..., 0]
         loss = nll.mean()
         return loss + bal if bal is not None else loss
-    n = compat.axis_size(seq_axis)
+    n = lax.axis_size(seq_axis)
     my = lax.axis_index(seq_axis)
     L = tokens.shape[1]
     if seq_layout == "zigzag":

@@ -26,7 +26,14 @@ Backends:
   :func:`dequant_add_jax`), so a device-resident delta quantizes on the
   VPU and only int8 crosses D2H (4x fewer staging bytes).  On non-TPU
   backends the same kernels run in Pallas interpret mode — that is how
-  the CPU test mesh proves them against the numpy reference.
+  the CPU test mesh proves them against the numpy reference.  Compiled
+  by Mosaic on a v5e (chip_smoke.py): ``scale`` and the apply are
+  bitwise the reference's, but the chip's f32 divide is not correctly
+  rounded, so ``q`` differs by one step where ``d/scale`` lands within
+  rounding of a .5 tie (measured: 1 element in 2**20).  Error feedback
+  absorbs it — ``q*scale + r == d`` holds to one ulp either way — so
+  the device route is self-consistent but NOT bitwise-interchangeable
+  with the host routes.
 * **host native (CPU)** — a tiny single-pass SIMD C kernel
   (:mod:`wire_native`), compiled by the system compiler at first use and
   silently absent when there is no compiler.  This is the CPU production
@@ -72,6 +79,7 @@ from jax.experimental import pallas as pl
 
 from distlearn_tpu.ops import wire_native
 from distlearn_tpu.ops.flatten import LANE
+from distlearn_tpu.ops.fused_update import _grid_for, _interpret
 from distlearn_tpu.utils import flags
 
 __all__ = [
@@ -237,22 +245,9 @@ def fp16_add(t: np.ndarray, wirebuf: np.ndarray,
 # ---------------------------------------------------------------------------
 
 #: int8 min tile is (32, 128) — pad flats to 32*128 elements so one grid
-#: covers f32 and int8 refs alike (fused_update pads to the f32 tile only).
+#: covers f32 and int8 refs alike (fused_update pads to the f32 tile only;
+#: its 256-row block is a multiple of 32 too, so its grid serves both).
 _TILE_Q = 32 * LANE
-
-_BLOCK_ROWS = 256      # rows of 128 lanes per grid step, % 32 == 0
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def _grid_for(n: int) -> tuple[int, tuple[int, int]]:
-    rows = n // LANE
-    block_rows = min(_BLOCK_ROWS, rows)
-    while rows % block_rows:
-        block_rows -= 32            # rows % 32 == 0 by _TILE_Q padding
-    return rows // block_rows, (block_rows, LANE)
 
 
 def _quant_ef_kernel(x_ref, s_ref, q_ref, r_ref):

@@ -1679,8 +1679,7 @@ class AsyncEAServerConcurrent(AsyncEAServer):
     ``pin_device`` pins the center on a jax device with a jitted donated
     ``center += delta`` apply (the BASELINE.json north-star "one-sided
     update against a pinned center replica"); host numpy otherwise.
-    Note: worth it when the accelerator is locally attached — on a
-    remote-tunneled chip the per-sync device round trip dominates.
+    Not measured on a chip yet (ROADMAP S7).
     """
 
     def __init__(self, host: str, port: int, num_nodes: int,

@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from distlearn_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from distlearn_tpu.utils import compat
 
 
 def _block_attn(q, k, v, scale, mask):
@@ -89,7 +88,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if layout not in ("contig", "zigzag"):
         raise ValueError(f"layout must be 'contig' or 'zigzag', "
                          f"got {layout!r}")
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if layout == "zigzag" and causal and n > 1:
         if q.shape[1] % 2:
             raise ValueError(
@@ -257,7 +256,7 @@ def alltoall_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     q/k/v: local shards ``[B, L_local, H, D]`` (global sequence = rank-order
     concatenation over the axis).  Returns ``[B, L_local, H, D]``.
     """
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return local_attention(q, k, v, causal=causal, impl=impl)
     H = q.shape[2]
@@ -391,10 +390,16 @@ def local_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ``impl`` picks the kernel: ``"xla"`` (naive fused, full [B,H,L,L]
     scores), ``"flash"`` (Pallas blockwise online softmax, no score
     materialization), or ``"chunked"`` (:func:`chunked_causal_attention`
-    — causal FLOP skip with saved softmax weights; falls back to xla for
-    non-causal or short/ragged L).  Default resolution: the ``flash``
-    arg (back-compat), then the ``DISTLEARN_TPU_ATTN`` env var, then
-    ``DISTLEARN_TPU_FLASH``, then xla."""
+    — causal FLOP skip with saved softmax weights).  Default resolution:
+    the ``flash`` arg (back-compat), then the ``DISTLEARN_TPU_ATTN`` env
+    var, then ``DISTLEARN_TPU_FLASH``, then xla.
+
+    On the TPU backend a requested kernel that cannot run at this shape
+    RAISES, however it was requested: a row labelled "flash" or
+    "chunked" must have run that kernel.  Off-TPU (tests, CPU examples)
+    an env-requested flash and a non-engaging chunked fall back to xla
+    so one setting can cover mixed configs; an explicit flash argument
+    raises everywhere."""
     B, L, H, D = q.shape
     explicit_flash = flash is True or impl == "flash"
     if impl is None:
@@ -407,16 +412,21 @@ def local_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if impl not in ("xla", "flash", "chunked"):
         raise ValueError(f"attention impl must be 'xla', 'flash', or "
                          f"'chunked', got {impl!r}")
+    on_tpu = jax.default_backend() == "tpu"
     if impl == "chunked":
         chunk = resolve_chunk(L)
         if causal and chunked_engages(L, chunk):
             return chunked_causal_attention(q, k, v, chunk=chunk)
+        if on_tpu:
+            raise ValueError(
+                f"chunked attention cannot run here (causal={causal}, "
+                f"L={L}, chunk={chunk}): it needs causal attention with "
+                "L > chunk and L % chunk == 0")
         impl = "xla"     # chunking only pays off via the causal FLOP skip
     if impl == "flash":
         # the Pallas kernel's default blocking needs L to be a multiple of
         # its 128-wide blocks
-        supported = jax.default_backend() == "tpu" and L >= 128 and L % 128 == 0
-        if supported:
+        if on_tpu and L >= 128 and L % 128 == 0:
             from jax.experimental.pallas.ops.tpu.flash_attention import \
                 flash_attention
             out = flash_attention(
@@ -424,17 +434,15 @@ def local_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 v.transpose(0, 2, 1, 3), causal=causal,
                 sm_scale=1.0 / (D ** 0.5))
             return out.transpose(0, 2, 1, 3).astype(q.dtype)
-        if explicit_flash:
-            # explicitly requested (flash=True or impl="flash" argument) —
+        if explicit_flash or on_tpu:
             # refusing loudly beats silently materializing the O(L^2)
-            # buffer the caller asked to avoid; env-driven requests fall
-            # back quietly so one flag can cover mixed configs
+            # buffer the caller asked to avoid
             raise ValueError(
                 "flash attention needs the TPU backend and seq len a "
                 f"multiple of 128; got backend={jax.default_backend()}, "
                 f"L={L}. Drop the explicit flash request to use the "
                 "portable path.")
-        # env-enabled but unsupported here: portable fallback
+        # env-enabled off-TPU: portable fallback
     scale = 1.0 / (D ** 0.5)
     # native-dtype inputs + f32 ACCUMULATION: on bf16 configs the MXU runs
     # bf16 matmuls accumulating in f32 (upcasting the operands instead

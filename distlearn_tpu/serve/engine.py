@@ -125,8 +125,6 @@ class DecodeEngine:
                  num_pages: int | None = None):
         import jax
         import jax.numpy as jnp
-        from distlearn_tpu.utils.compile_cache import enable_compile_cache
-        enable_compile_cache()   # warm starts skip the first-tick compile
         self._jax, self._jnp = jax, jnp
         params, self.depth = generate_params(params)
         self.params = params
@@ -200,9 +198,8 @@ class DecodeEngine:
         ``_build_tick``): the builders compose it around this."""
         if self.mesh is None:
             return body
-        from distlearn_tpu.utils.compat import shard_map
-        return shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+        return self._jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
+                                   out_specs=out_specs, check_vma=False)
 
     def _wrap(self, body, in_specs, out_specs, donate):
         """jit(shard_map(body)) under TP, plain jit otherwise."""

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from distlearn_tpu.utils.compat import shard_map
+from jax import shard_map
 
 from distlearn_tpu.models.core import Model
 from distlearn_tpu.models.transformer import (_rmsnorm, block_apply, lm_loss,

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax import lax, random
 from jax.sharding import PartitionSpec as P
 
-from distlearn_tpu.utils.compat import shard_map
+from jax import shard_map
 
 from distlearn_tpu.models.core import Model, loss_fn
 from distlearn_tpu.ops import flatten as flatten_lib

@@ -1,63 +1,44 @@
-"""Env-gated persistent XLA compilation cache.
+"""Persistent XLA compilation cache, placed from outside.
 
-The serve path's steady-state dispatch overhead is budgeted statically
-(DL207, docs/LINT.md), but a fresh process still pays the full XLA
-compile of every tick/prefill/train program on its FIRST dispatch —
-tens of seconds of single-core work that dwarfs any per-dispatch win.
-Pointing ``DISTLEARN_TPU_COMPILE_CACHE`` at a directory persists the
-compiled executables across process restarts: a warm start deserializes
-instead of recompiling, cutting the first-dispatch tail to load time
-(measured numbers next to the DL207 estimate in docs/LINT.md).
+A fresh process pays the full XLA compile of every train / tick / prefill
+program on its first dispatch — tens of seconds on the chip.  The
+persistent cache turns a warm start into a deserialize.  One rule, for
+every entry point:
 
-Opt-in by environment variable rather than default-on because the cache
-directory is a shared mutable resource: concurrent first-runs race
-benignly (last write wins) but tests that assert compile counts, and
-sandboxes with read-only checkouts, must be able to leave it off.
+* ``JAX_COMPILATION_CACHE_DIR`` set in the environment — JAX reads it
+  itself; this module sets NO directory in code, so whoever launches the
+  program (a runner that keeps a cache between calls, a CI job) decides
+  where it lives.
+* unset — the fixed ``<checkout>/.jax_cache`` (git-ignored).  Never a
+  temp name, pid or timestamp: the path is part of the cache key, so a
+  directory that moves never hits.
+
+Only ENTRY POINTS call :func:`enable_compile_cache` (the examples'
+``setup_platform``, ``bench.py``, ``chip_smoke.py``).  Library code never
+does: a constructor must not reconfigure process-global JAX state, and
+tests that count compiles must not depend on a cache on disk.
 """
 
 from __future__ import annotations
 
 import os
 
-ENV_VAR = "DISTLEARN_TPU_COMPILE_CACHE"
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 
-_enabled: str | None = None
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
-def enable_compile_cache(path: str | None = None) -> str | None:
-    """Turn on jax's persistent compile cache when ``path`` (or the
-    ``DISTLEARN_TPU_COMPILE_CACHE`` env var) names a directory.
-
-    Returns the cache directory in effect, or ``None`` when unset or
-    when jax refuses the config (the cache is an optimization only —
-    never an error).  Idempotent: repeat calls with the same resolved
-    path are no-ops, so every entry point (examples ``setup_platform``,
-    ``DecodeEngine``) can call it unconditionally.
-    """
-    global _enabled
-    path = path or os.environ.get(ENV_VAR)
-    if not path:
-        return None
-    path = os.path.abspath(path)
-    if _enabled == path:
-        return path
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", path)
-        # persist everything, however fast the compile: the CPU test
-        # programs compile in <1s yet still dominate a cold example run
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        # 1, not 0: the cache treats 0 as "unset" and substitutes its
-        # own (larger) default at initialization
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 1)
-        # the cache module latches enabled/disabled at the FIRST
-        # compile; if anything already compiled (model init before the
-        # engine ctor), the config update alone is inert — reset back
-        # to pristine so the next compile re-initializes with the dir
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
-        _cc.reset_cache()
-    except Exception:  # noqa: BLE001 — optimization only
-        return None
-    _enabled = path
-    return path
+def enable_compile_cache() -> str:
+    """Turn on jax's persistent compile cache; returns the directory in
+    effect.  Call before the first compile (the cache latches on/off
+    there)."""
+    import jax
+    if not os.environ.get(ENV_VAR):
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
+    # persist every program, however fast the compile or small the entry
+    # (1, not 0: the cache reads 0 as "unset" and substitutes its default)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 1)
+    return jax.config.jax_compilation_cache_dir

@@ -3,6 +3,12 @@
 # worker clients on localhost.  The reference kills stale ports with fuser
 # and derives the server IP from ifconfig; localhost + fresh port suffices
 # here (multi-host: pass --host/--port to each role).
+#
+# One process per chip: every role here runs on the CPU (no --tpu, so
+# setup_platform pins the CPU explicitly).  On a TPU host the server and
+# the tester STAY on the CPU; launch by hand the client(s) that should
+# compute on a chip, one --tpu client per chip — a second process that
+# goes for a chip another one holds fails or hangs.
 cd "$(dirname "$0")"
 # --join-after S / --leave-after S: elastic membership drills
 # (docs/ELASTIC.md).  Either flag switches the server to

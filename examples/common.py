@@ -19,40 +19,41 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 def setup_platform(num_nodes: int, tpu: bool):
     """Pick the backend BEFORE any device query.
 
-    --tpu: use the real TPU backend (devices as-is).  Otherwise: CPU with
-    ``num_nodes`` virtual host devices (the reference's LocalhostTree
-    analogue, SURVEY.md §4).
+    ``--tpu`` means "use the TPU": this process takes the chip(s) — one
+    process per chip, so in a multi-process launcher only the roles that
+    compute pass it — and exits non-zero when JAX finds none, never
+    training on the CPU under a TPU label.  Otherwise: CPU, explicitly,
+    with ``num_nodes`` virtual host devices (the reference's
+    LocalhostTree analogue, SURVEY.md §4).
     """
     from distlearn_tpu.utils.compile_cache import enable_compile_cache
-    enable_compile_cache()   # DISTLEARN_TPU_COMPILE_CACHE warm starts
-    if tpu:
+    enable_compile_cache()
+    if not tpu:
+        from distlearn_tpu.utils.platform import force_cpu
+        force_cpu(num_nodes)
         return
-    from distlearn_tpu.utils.platform import force_cpu
-    force_cpu(num_nodes)
+    import jax
+    platform = jax.devices()[0].platform
+    if platform != "tpu":
+        raise SystemExit(f"--tpu: JAX found no TPU (platform={platform!r}, "
+                         f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r})")
 
 
 def resolve_num_nodes(requested: int, tpu: bool) -> int:
-    """Clamp ``--numNodes`` to what the attached backend offers.
-
-    The reference oversubscribes by time-slicing N processes on one GPU
-    (examples/cifar10-cuda.sh); an SPMD mesh has exactly one program per
-    device, so on a 1-chip TPU a 4-node request becomes a 1-node run with a
-    loud warning instead of a crash (VERDICT r1 weak #5).  On CPU the
-    requested count is virtualized by :func:`setup_platform`, so it always
-    fits.
+    """``--numNodes`` against the attached backend.  On CPU the requested
+    count is virtualized by :func:`setup_platform`, so it always fits.
+    On the TPU an SPMD mesh has exactly one program per device (the
+    reference instead time-slices N processes on one GPU,
+    examples/cifar10-cuda.sh), so a request for more nodes than chips is
+    an error — a quietly narrower mesh is a different run.
     """
-    if not tpu:
-        return requested
-    import sys
-
-    import jax
-    avail = len(jax.devices())
-    if requested > avail:
-        print(f"[distlearn_tpu] --numNodes {requested} exceeds the "
-              f"{avail} attached TPU chip(s); running {avail} node(s). "
-              "(The reference time-slices processes per GPU; an SPMD mesh "
-              "needs one device per node.)", file=sys.stderr)
-        return avail
+    if tpu:
+        import jax
+        avail = len(jax.devices())
+        if requested > avail:
+            raise SystemExit(f"--numNodes {requested} exceeds the {avail} "
+                             "attached TPU chip(s): an SPMD mesh needs one "
+                             "device per node")
     return requested
 
 

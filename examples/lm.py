@@ -13,7 +13,7 @@ single jitted step (distlearn_tpu/train/lm.py).
 Run (8 virtual CPU devices):
     python examples/lm.py --dp 2 --sp 2 --tp 2
     python examples/lm.py --dp 4 --sp 2 --tp 1 --moeExperts 4
-On the attached TPU chip:
+On a TPU (this process takes the chips; exits non-zero if JAX finds none):
     python examples/lm.py --tpu --dp 1 --sp 1 --tp 1 --dim 1024 --depth 8
 Train then serve with continuous batching (docs/SERVING.md; tp>1
 shards the decode tick too; drive with examples/lm_client.py):

@@ -29,8 +29,8 @@ def main():
         "numExamples": (4096, "synthetic dataset size"),
         "reportEvery": (100, "steps between reports"),
         "scanCycle": (False, "run each tau-step EASGD cycle as ONE XLA "
-                             "program (build_ea_cycle) — amortizes host "
-                             "dispatch on remote-attached chips"),
+                             "program (build_ea_cycle) — one host "
+                             "dispatch per round instead of per step"),
         "momentum": (0.0, "local heavy-ball momentum — EAMSGD "
                           "(arXiv:1412.6651 §3); 0 = plain EASGD "
                           "(the reference)"),

@@ -4,6 +4,9 @@
 # fire CONCURRENCY parallel requests at it, then SIGTERM the server and
 # let the drain finish the in-flight requests.
 #   PORT=9123 CONCURRENCY=8 ./serve_lm.sh
+# One process per chip: both roles run on the CPU here.  On a TPU host
+# only lm.py may take --tpu (it then owns the chip); lm_client.py never
+# computes and stays chip-free.
 cd "$(dirname "$0")"
 PORT=${PORT:-9123}
 SLOTS=${SLOTS:-4}

@@ -22,6 +22,7 @@ HybridBackend host leg as through a raw Tree.
 
 from __future__ import annotations
 
+import os
 import threading
 
 import numpy as np
@@ -439,34 +440,47 @@ def test_async_ea_slice_client_one_leg_for_l_rows():
 
 # ------------------------------------------------------------ compile cache
 
-def test_compile_cache_env_gate(tmp_path, monkeypatch):
-    """DISTLEARN_TPU_COMPILE_CACHE points jax's persistent compile cache
-    at a directory — even when enabled AFTER earlier compiles latched
-    the cache off (the DecodeEngine-ctor ordering)."""
-    import jax
-    import jax.numpy as jnp
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-    from distlearn_tpu.utils import compile_cache as cc
 
-    monkeypatch.delenv(cc.ENV_VAR, raising=False)
-    monkeypatch.setattr(cc, "_enabled", None)
-    assert cc.enable_compile_cache() is None     # unset -> off
+def _compile_cache_probe(env_dir):
+    """enable_compile_cache() in a fresh interpreter (it reconfigures
+    process-global jax state — never in the suite's own process):
+    which config keys it set in code, and the directory in effect."""
+    import json
+    import subprocess
+    import sys
 
-    cache_dir = tmp_path / "xla"
-    monkeypatch.setenv(cc.ENV_VAR, str(cache_dir))
-    try:
-        assert cc.enable_compile_cache() == str(cache_dir)
-        # idempotent re-enable is a no-op, not a cache reset
-        assert cc.enable_compile_cache() == str(cache_dir)
-        jax.jit(lambda x: x * 2.0 + 1.0)(jnp.ones((32, 32)))
-        assert cache_dir.is_dir() and any(cache_dir.iterdir())
-    finally:
-        # un-latch: later tests must not write into the deleted tmp dir
-        from jax.experimental.compilation_cache import (
-            compilation_cache as jcc)
-        monkeypatch.setattr(cc, "_enabled", None)
-        jax.config.update("jax_compilation_cache_dir", None)
-        jcc.reset_cache()
+    code = (
+        "import json, jax\n"
+        "from unittest import mock\n"
+        "from distlearn_tpu.utils import compile_cache as cc\n"
+        "with mock.patch.object(jax.config, 'update',\n"
+        "                       wraps=jax.config.update) as up:\n"
+        "    d = cc.enable_compile_cache()\n"
+        "print(json.dumps({'set': [c.args[0] for c in up.call_args_list],\n"
+        "                  'dir': d, 'default': cc.DEFAULT_DIR}))\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=_ROOT)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    out = subprocess.run([sys.executable, "-c", code], env=env, cwd="/",
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_compile_cache_placed_from_outside(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: no code path names a directory
+    (jax reads the env itself).  Unset: the fixed <checkout>/.jax_cache,
+    whatever the working directory."""
+    rec = _compile_cache_probe(str(tmp_path / "xla"))
+    assert "jax_compilation_cache_dir" not in rec["set"], rec
+    assert rec["dir"] == str(tmp_path / "xla")
+
+    rec = _compile_cache_probe(None)
+    assert rec["set"].count("jax_compilation_cache_dir") == 1, rec
+    assert rec["dir"] == rec["default"] == os.path.join(_ROOT, ".jax_cache")
 
 
 # ------------------------------------------------------------ lint hooks

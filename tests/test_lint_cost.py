@@ -21,7 +21,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distlearn_tpu.lint import budget as budget_mod
 from distlearn_tpu.lint import cost as cost_mod
-from distlearn_tpu.utils.compat import shard_map
+from jax import shard_map
 
 pytestmark = pytest.mark.lint
 

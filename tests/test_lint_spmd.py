@@ -16,7 +16,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from distlearn_tpu.lint import Finding, lint_step
 from distlearn_tpu.lint.core import filter_suppressed, format_findings
-from distlearn_tpu.utils import compat
 
 
 @pytest.fixture
@@ -27,8 +26,8 @@ def mesh(devices):
 def _sm(mesh, f, in_specs, out_specs):
     # check_vma=False: several known-bad bodies are exactly the programs the
     # static replication checker refuses; the linter must catch them anyway.
-    return compat.shard_map(f, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_vma=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _rules(findings):

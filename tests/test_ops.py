@@ -51,9 +51,16 @@ def test_fused_elastic_matches_reference_math():
 
 
 def test_fused_ops_jit_under_vmap_free_shapes():
-    # padded length not a multiple of the default block: exercises the
-    # block-rows fallback in _grid_for
+    # shorter than one block: the block is the whole array
     n = 1024 * 7
+    x = jnp.arange(n, dtype=jnp.float32)
+    out = fused_sgd(x, jnp.ones(n, jnp.float32), 1.0)
+    np.testing.assert_allclose(np.asarray(out), np.arange(n) - 1.0, rtol=1e-6)
+    # rows not a multiple of the block: the trailing partial block is
+    # masked, and the grid stays short however awkward the row count
+    from distlearn_tpu.ops.fused_update import _grid_for
+    n = 1024 * 301                      # 2408 rows = 9 blocks of 256 + 104
+    assert _grid_for(n) == (10, (256, 128))
     x = jnp.arange(n, dtype=jnp.float32)
     out = fused_sgd(x, jnp.ones(n, jnp.float32), 1.0)
     np.testing.assert_allclose(np.asarray(out), np.arange(n) - 1.0, rtol=1e-6)

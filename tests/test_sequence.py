@@ -335,10 +335,7 @@ def test_zigzag_halves_causal_flops():
             out_specs=P(None, "seq"), check_vma=False))
 
     def flops(layout):
-        ca = build(layout).lower(q, k, v).compile().cost_analysis()
-        if isinstance(ca, (list, tuple)):  # pre-0.5 jax: one dict per device
-            ca = ca[0]
-        return ca["flops"]
+        return build(layout).lower(q, k, v).compile().cost_analysis()["flops"]
 
     fz = flops("zigzag")
     fc = flops("contig")

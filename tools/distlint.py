@@ -44,10 +44,6 @@ jax.config.update("jax_platforms", "cpu")
 # exact same programs or the two contexts would disagree on the budgets.
 jax.config.update("jax_enable_x64", True)
 
-from distlearn_tpu.utils import compat  # noqa: E402
-
-compat.install()
-
 from distlearn_tpu.lint.core import RULES, format_findings  # noqa: E402
 from distlearn_tpu.lint import budget as budget_mod  # noqa: E402
 from distlearn_tpu.lint import registry  # noqa: E402
