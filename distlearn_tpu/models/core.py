@@ -19,6 +19,15 @@ from distlearn_tpu.models import nn
 
 PyTree = Any
 
+#: The ``jax.named_scope`` names that model and train-step code give the
+#: device work (docs/OBSERVABILITY.md "device time by model part").  Flat
+#: siblings, never nested: an instruction's ``op_name`` carries at most
+#: one of them.  Forward / backward / recomputation are NOT scopes — JAX
+#: marks those itself (``jvp(``, ``transpose(``, ``rematted_computation``).
+#: The one list: trace readers import it, nothing else spells the names.
+SCOPES = ("embed", "norm", "attn_proj", "attn_core", "mlp", "head_loss",
+          "grad_reduce", "update")
+
 
 class Model(NamedTuple):
     """``init(key) -> (params, state)``;

@@ -43,9 +43,11 @@ def _norm_init(shape, dtype):
 
 
 def _rmsnorm(params, x, eps=1e-6):
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    y = x * lax.rsqrt(var + eps).astype(x.dtype)
-    return y * params["scale"].astype(x.dtype)
+    with jax.named_scope("norm"):
+        var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
+                       keepdims=True)
+        y = x * lax.rsqrt(var + eps).astype(x.dtype)
+        return y * params["scale"].astype(x.dtype)
 
 
 def attn_qkv(blk: PyTree, x: jax.Array, cd, tp_axis: str | None = None):
@@ -55,11 +57,12 @@ def attn_qkv(blk: PyTree, x: jax.Array, cd, tp_axis: str | None = None):
     change (bias terms, RoPE, QK-norm) cannot silently diverge between
     training and generation."""
     h = _rmsnorm(blk["ln1"], x)
-    if tp_axis is not None:   # enter column-parallel region ("f")
-        h = tp_enter(h, tp_axis)
-    q = jnp.einsum("ble,ehd->blhd", h, blk["wq"].astype(cd))
-    k = jnp.einsum("ble,ehd->blhd", h, blk["wk"].astype(cd))
-    v = jnp.einsum("ble,ehd->blhd", h, blk["wv"].astype(cd))
+    with jax.named_scope("attn_proj"):
+        if tp_axis is not None:   # enter column-parallel region ("f")
+            h = tp_enter(h, tp_axis)
+        q = jnp.einsum("ble,ehd->blhd", h, blk["wq"].astype(cd))
+        k = jnp.einsum("ble,ehd->blhd", h, blk["wk"].astype(cd))
+        v = jnp.einsum("ble,ehd->blhd", h, blk["wv"].astype(cd))
     return q, k, v
 
 
@@ -67,10 +70,11 @@ def attn_out(blk: PyTree, x: jax.Array, att: jax.Array, cd,
              tp_axis: str | None = None) -> jax.Array:
     """Output projection + residual (the other half shared with the
     decoder)."""
-    proj = jnp.einsum("blhd,hde->ble", att, blk["wo"].astype(cd))
-    if tp_axis is not None:   # heads were sharded: reduce ("g")
-        proj = tp_reduce(proj, tp_axis)
-    return x + proj
+    with jax.named_scope("attn_proj"):
+        proj = jnp.einsum("blhd,hde->ble", att, blk["wo"].astype(cd))
+        if tp_axis is not None:   # heads were sharded: reduce ("g")
+            proj = tp_reduce(proj, tp_axis)
+        return x + proj
 
 
 def attn_apply(blk: PyTree, x: jax.Array, cd, *, seq_attn=None,
@@ -82,10 +86,11 @@ def attn_apply(blk: PyTree, x: jax.Array, cd, *, seq_attn=None,
     attention output and the flash kernel's softmax residuals instead of
     re-running the attention forward in the backward pass)."""
     q, k, v = attn_qkv(blk, x, cd, tp_axis)
-    if seq_axis is not None:
-        att = seq_attn(q, k, v, seq_axis, causal=True, impl=attn_impl)
-    else:
-        att = local_attention(q, k, v, causal=True, impl=attn_impl)
+    with jax.named_scope("attn_core"):
+        if seq_axis is not None:
+            att = seq_attn(q, k, v, seq_axis, causal=True, impl=attn_impl)
+        else:
+            att = local_attention(q, k, v, causal=True, impl=attn_impl)
     return attn_out(blk, x, att, cd, tp_axis)
 
 
@@ -95,47 +100,50 @@ def ffn_apply(blk: PyTree, x: jax.Array, cd, *, tp_axis: str | None = None,
               return_moe_aux: bool = False):
     """FFN/MoE half of a transformer block (see :func:`attn_apply`)."""
     h = _rmsnorm(blk["ln2"], x)
-    if "router" in blk:       # routed MoE FFN (parallel/ep.py)
-        from distlearn_tpu.parallel.ep import moe_ffn, moe_ffn_local
+    with jax.named_scope("mlp"):
+        if "router" in blk:       # routed MoE FFN (parallel/ep.py)
+            from distlearn_tpu.parallel.ep import moe_ffn, moe_ffn_local
 
-        Bq, Lq, Dq = h.shape
-        flat = h.reshape(Bq * Lq, Dq)
+            Bq, Lq, Dq = h.shape
+            flat = h.reshape(Bq * Lq, Dq)
 
-        def expert(p, t):
-            u = jax.nn.gelu(t @ p["we1"].astype(cd)
-                            + p["wb1"].astype(cd))
-            return u @ p["we2"].astype(cd)
+            def expert(p, t):
+                u = jax.nn.gelu(t @ p["we1"].astype(cd)
+                                + p["wb1"].astype(cd))
+                return u @ p["we2"].astype(cd)
 
-        eparams = {k2: blk[k2] for k2 in ("we1", "wb1", "we2")}
-        if ep_axis is None:
-            y = moe_ffn_local(expert, eparams, blk["router"], flat,
-                              moe_capacity_factor, top_k=moe_top_k,
-                              return_aux=return_moe_aux)
-        else:                 # one expert per device on ep_axis
-            n_local = blk["we1"].shape[0]
-            if n_local != 1:
-                raise ValueError(
-                    f"stacked expert leaves hold {n_local} shards on this "
-                    "device; expected exactly one per device on ep_axis")
-            local = jax.tree_util.tree_map(
-                lambda a: jnp.squeeze(a, 0), eparams)
-            y = moe_ffn(expert, local, blk["router"], flat,
-                        moe_capacity_factor, axis_name=ep_axis,
-                        top_k=moe_top_k, return_aux=return_moe_aux)
+            eparams = {k2: blk[k2] for k2 in ("we1", "wb1", "we2")}
+            if ep_axis is None:
+                y = moe_ffn_local(expert, eparams, blk["router"], flat,
+                                  moe_capacity_factor, top_k=moe_top_k,
+                                  return_aux=return_moe_aux)
+            else:                 # one expert per device on ep_axis
+                n_local = blk["we1"].shape[0]
+                if n_local != 1:
+                    raise ValueError(
+                        f"stacked expert leaves hold {n_local} shards on "
+                        "this device; expected exactly one per device on "
+                        "ep_axis")
+                local = jax.tree_util.tree_map(
+                    lambda a: jnp.squeeze(a, 0), eparams)
+                y = moe_ffn(expert, local, blk["router"], flat,
+                            moe_capacity_factor, axis_name=ep_axis,
+                            top_k=moe_top_k, return_aux=return_moe_aux)
+            if return_moe_aux:
+                y, aux = y
+                return x + y.reshape(Bq, Lq, Dq).astype(x.dtype), aux
+            return x + y.reshape(Bq, Lq, Dq).astype(x.dtype)
         if return_moe_aux:
-            y, aux = y
-            return x + y.reshape(Bq, Lq, Dq).astype(x.dtype), aux
-        return x + y.reshape(Bq, Lq, Dq).astype(x.dtype)
-    if return_moe_aux:
-        raise ValueError("return_moe_aux=True on a dense block (no router)")
-    if tp_axis is not None:
-        h = tp_enter(h, tp_axis)
-    h = h @ blk["w1"].astype(cd) + blk["b1"].astype(cd)
-    h = jax.nn.gelu(h)
-    h = h @ blk["w2"].astype(cd)
-    if tp_axis is not None:   # hidden was sharded: reduce ("g")
-        h = tp_reduce(h, tp_axis)
-    return x + h + blk["b2"].astype(cd)
+            raise ValueError(
+                "return_moe_aux=True on a dense block (no router)")
+        if tp_axis is not None:
+            h = tp_enter(h, tp_axis)
+        h = h @ blk["w1"].astype(cd) + blk["b1"].astype(cd)
+        h = jax.nn.gelu(h)
+        h = h @ blk["w2"].astype(cd)
+        if tp_axis is not None:   # hidden was sharded: reduce ("g")
+            h = tp_reduce(h, tp_axis)
+        return x + h + blk["b2"].astype(cd)
 
 
 def block_apply(blk: PyTree, x: jax.Array, cd, *, seq_attn=None,
@@ -159,6 +167,21 @@ def block_apply(blk: PyTree, x: jax.Array, cd, *, seq_attn=None,
     return ffn_apply(blk, x, cd, tp_axis=tp_axis, ep_axis=ep_axis,
                      moe_capacity_factor=moe_capacity_factor,
                      moe_top_k=moe_top_k, return_moe_aux=return_moe_aux)
+
+
+def _pos_rows(pos, L: int, seq_axis: str | None, seq_layout: str):
+    """The ``L`` rows of the position table THIS sequence shard holds."""
+    if seq_axis is None:
+        return lax.dynamic_slice_in_dim(pos, 0, L)
+    my = lax.axis_index(seq_axis)
+    if seq_layout != "zigzag":
+        return lax.dynamic_slice_in_dim(pos, my * L, L)
+    # local shard = early stripe my ++ late stripe 2n-1-my
+    n_sh = lax.axis_size(seq_axis)
+    s_len = L // 2
+    pa = lax.dynamic_slice_in_dim(pos, my * s_len, s_len)
+    pb = lax.dynamic_slice_in_dim(pos, (2 * n_sh - 1 - my) * s_len, s_len)
+    return jnp.concatenate([pa, pb], axis=0)
 
 
 def transformer_lm(vocab: int = 256, dim: int = 128, depth: int = 2,
@@ -316,23 +339,10 @@ def transformer_lm(vocab: int = 256, dim: int = 128, depth: int = 2,
                     "order)")
             import functools
             sa = functools.partial(seq_attn, layout="zigzag")
-        if seq_axis is not None:
-            my = lax.axis_index(seq_axis)
-            if seq_layout == "zigzag":
-                # local shard = early stripe my ++ late stripe 2n-1-my
-                n_sh = lax.axis_size(seq_axis)
-                s_len = L // 2
-                pa = lax.dynamic_slice_in_dim(params["pos"], my * s_len,
-                                              s_len)
-                pb = lax.dynamic_slice_in_dim(
-                    params["pos"], (2 * n_sh - 1 - my) * s_len, s_len)
-                pos_emb = jnp.concatenate([pa, pb], axis=0)
-            else:
-                pos_emb = lax.dynamic_slice_in_dim(params["pos"], my * L, L)
-        else:
-            pos_emb = lax.dynamic_slice_in_dim(params["pos"], 0, L)
-        x = params["embed"][tokens].astype(cd)
-        x = x + pos_emb.astype(cd)[None]
+        with jax.named_scope("embed"):
+            pos_emb = _pos_rows(params["pos"], L, seq_axis, seq_layout)
+            x = params["embed"][tokens].astype(cd)
+            x = x + pos_emb.astype(cd)[None]
 
         def make_block(is_moe):
             if remat == "mlp":
@@ -387,8 +397,9 @@ def transformer_lm(vocab: int = 256, dim: int = 128, depth: int = 2,
                          moe_dropped_frac=dropped / n_moe)
 
         x = _rmsnorm(params["out_norm"], x)
-        logits = x @ params["embed"].T.astype(cd)
-        return logits.astype(dtype), state
+        with jax.named_scope("head_loss"):
+            logits = (x @ params["embed"].T.astype(cd)).astype(dtype)
+        return logits, state
 
     return Model(init=init, apply=apply, name="transformer_lm",
                  input_shape=(max_len,), num_classes=vocab)
@@ -404,12 +415,13 @@ def decode_attend(q: jax.Array, ck: jax.Array, cv: jax.Array,
     token parity between the two is a tested invariant, so the math must
     not fork."""
     D = q.shape[-1]
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, ck,
-                   preferred_element_type=jnp.float32)
-    s = s * (1.0 / (D ** 0.5))
-    s = jnp.where(live, s, -jnp.inf)
-    w = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", w.astype(cd), cv)
+    with jax.named_scope("attn_core"):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, ck,
+                       preferred_element_type=jnp.float32)
+        s = s * (1.0 / (D ** 0.5))
+        s = jnp.where(live, s, -jnp.inf)
+        w = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", w.astype(cd), cv)
 
 
 def generate_params(params: PyTree) -> tuple[PyTree, int]:
@@ -642,9 +654,10 @@ def lm_loss(model: Model, params, tokens, seq_axis=None, tp_axis=None,
            and "moe_balance_loss" in st else None)
     if seq_axis is None:
         targets = tokens[:, 1:]
-        lp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32))
-        nll = -jnp.take_along_axis(lp, targets[..., None], -1)[..., 0]
-        loss = nll.mean()
+        with jax.named_scope("head_loss"):
+            lp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32))
+            nll = -jnp.take_along_axis(lp, targets[..., None], -1)[..., 0]
+            loss = nll.mean()
         return loss + bal if bal is not None else loss
     n = lax.axis_size(seq_axis)
     my = lax.axis_index(seq_axis)
@@ -677,12 +690,13 @@ def lm_loss(model: Model, params, tokens, seq_axis=None, tp_axis=None,
         targets = jnp.concatenate([tokens[:, 1:], nxt_first], axis=1)
         pos = my * L + jnp.arange(L)
         w = (pos < n * L - 1).astype(jnp.float32)
-    lp = jax.nn.log_softmax(logits.astype(jnp.float32))
-    nll = -jnp.take_along_axis(lp, targets[..., None], -1)[..., 0]
-    # mask the target-less global last position; normalize by the GLOBAL
-    # token count (a constant — no gradient flows through it)
-    count = lax.psum(jnp.sum(w) * tokens.shape[0], seq_axis)
-    local = jnp.sum(nll * w[None, :]) / jnp.maximum(count, 1.0)
+    with jax.named_scope("head_loss"):
+        lp = jax.nn.log_softmax(logits.astype(jnp.float32))
+        nll = -jnp.take_along_axis(lp, targets[..., None], -1)[..., 0]
+        # mask the target-less global last position; normalize by the
+        # GLOBAL token count (a constant — no gradient flows through it)
+        count = lax.psum(jnp.sum(w) * tokens.shape[0], seq_axis)
+        local = jnp.sum(nll * w[None, :]) / jnp.maximum(count, 1.0)
     if bal is not None:
         # each shard routes its own tokens: 1/n of the balance term per
         # shard makes the psum'd total the cross-shard mean

@@ -2,6 +2,14 @@
 paths (handshakes, step dispatch, rejoin cycles) — plus the
 cross-process trace context those spans can ride.
 
+A record is ``{"type": "span", "name", "ts", "t0", "dur"[, "labels",
+"err", "trace", "span", "parent", "proc"]}``: ``t0`` is the span's START
+on ``time.perf_counter()`` — monotonic, this process's clock only: it
+orders spans, nests them (a child's ``[t0, t0 + dur]`` lies inside its
+parent's) and cuts the ring to a window a caller timed on the same clock
+— ``dur`` its length on that clock, and ``ts`` the wall-clock time at
+its END, which is what lines trails of different processes up.
+
 A span is one timed region: ``with obs.span("async_ea.handshake",
 cid=3):`` or ``@obs.traced("data.load")``.  Completed spans land in an
 in-memory ring buffer (bounded; the newest ``ring_size`` survive; ring
@@ -271,7 +279,7 @@ class _Span:
             except Exception:
                 pass
         rec = {"type": "span", "name": self.name, "ts": time.time(),
-               "dur": dur}
+               "t0": self._t0, "dur": dur}
         if self.labels:
             rec["labels"] = self.labels
         if exc_type is not None:
@@ -324,7 +332,7 @@ def record_span(name: str, dur: float, **labels):
     if not core.enabled():
         return
     rec = {"type": "span", "name": name, "ts": time.time(),
-           "dur": float(dur)}
+           "t0": time.perf_counter() - float(dur), "dur": float(dur)}
     if labels:
         rec["labels"] = labels
     cur = current()

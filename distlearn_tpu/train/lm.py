@@ -21,6 +21,8 @@ from distlearn_tpu.models.transformer import (_rmsnorm, block_apply, lm_loss,
                                               stack_block_params,
                                               unstack_block_params)
 from distlearn_tpu.parallel.pp import pipeline_apply
+from distlearn_tpu.train.trainer import (_timed, apply_elastic_round,
+                                         local_update)
 
 
 def lm_local_grads(model: Model, params, tokens, *, seq_axis, tp_axis,
@@ -147,18 +149,21 @@ def build_lm_step(model: Model, mesh: Mesh, params_template, lr: float,
                 g = lax.psum(g, gaxes)
             return g / jnp.asarray(dp, g.dtype)
 
-        grads = jax.tree_util.tree_map(reduce_grad, grads, is_ep_leaf)
+        with jax.named_scope("grad_reduce"):
+            grads = jax.tree_util.tree_map(reduce_grad, grads, is_ep_leaf)
         gl = jax.tree_util.tree_leaves(grads)
         pl = jax.tree_util.tree_leaves(params)
-        if use_fused and all(g.dtype == p.dtype for g, p in zip(gl, pl)):
-            spec = flatten_lib.make_bucket_spec(grads, max_bucket_bytes)
-            g_flats = flatten_lib.pack_buckets(spec, grads)
-            new_params = fused_update.sgd_update_buckets(spec, params,
-                                                         g_flats, lr)
-        else:
-            new_params = jax.tree_util.tree_map(
-                lambda p, g: p - jnp.asarray(lr, p.dtype) * g.astype(p.dtype),
-                params, grads)
+        with jax.named_scope("update"):
+            if use_fused and all(g.dtype == p.dtype
+                                 for g, p in zip(gl, pl)):
+                spec = flatten_lib.make_bucket_spec(grads, max_bucket_bytes)
+                g_flats = flatten_lib.pack_buckets(spec, grads)
+                new_params = fused_update.sgd_update_buckets(
+                    spec, params, g_flats, lr)
+            else:
+                new_params = jax.tree_util.tree_map(
+                    lambda p, g: p - jnp.asarray(lr, p.dtype)
+                    * g.astype(p.dtype), params, grads)
         return new_params, lax.pmean(loss, data_axis)
 
     tok_spec = P(data_axis, seq_axis) if seq_axis else P(data_axis)
@@ -166,7 +171,8 @@ def build_lm_step(model: Model, mesh: Mesh, params_template, lr: float,
                            in_specs=(pspecs, tok_spec),
                            out_specs=(pspecs, P()),
                            check_vma=False)
-    return jax.jit(mapped, donate_argnums=(0,) if donate else ())
+    return _timed(jax.jit(mapped, donate_argnums=(0,) if donate else ()),
+                  "lm")
 
 
 def build_lm_moe_metrics(model: Model, mesh: Mesh, params_template,
@@ -332,7 +338,8 @@ def build_lm_pp_step(mesh: Mesh, shared_template, stacked_template,
         in_specs=(P(), P(pipe_axis), P(data_axis)),
         out_specs=(P(), P(pipe_axis), P()),
         check_vma=False)
-    return jax.jit(mapped, donate_argnums=(0, 1) if donate else ())
+    return _timed(jax.jit(mapped, donate_argnums=(0, 1) if donate else ()),
+                  "lm_pp")
 
 
 def build_lm_pp_1f1b_step(mesh: Mesh, shared_template, stacked_template,
@@ -436,7 +443,8 @@ def build_lm_pp_1f1b_step(mesh: Mesh, shared_template, stacked_template,
         in_specs=(P(), P(pipe_axis), P(data_axis)),
         out_specs=(P(), P(pipe_axis), P()),
         check_vma=False)
-    return jax.jit(mapped, donate_argnums=(0, 1) if donate else ())
+    return _timed(jax.jit(mapped, donate_argnums=(0, 1) if donate else ()),
+                  "lm_pp_1f1b")
 
 
 class LMMixedState(NamedTuple):
@@ -508,12 +516,14 @@ def build_lm_mixed_step(model: Model, mesh: Mesh, params_template, lr: float,
                 g = lax.psum(g, gaxes)
             return g / jnp.asarray(dp, g.dtype)
 
-        grads = jax.tree_util.tree_map(reduce_grad, grads, is_ep_leaf)
-        master = jax.tree_util.tree_map(
-            lambda m, g: m - jnp.asarray(lr, m.dtype) * g.astype(m.dtype),
-            st.master, grads)
-        params = jax.tree_util.tree_map(
-            lambda p, m: m.astype(p.dtype), st.params, master)
+        with jax.named_scope("grad_reduce"):
+            grads = jax.tree_util.tree_map(reduce_grad, grads, is_ep_leaf)
+        with jax.named_scope("update"):
+            master = jax.tree_util.tree_map(
+                lambda m, g: m - jnp.asarray(lr, m.dtype)
+                * g.astype(m.dtype), st.master, grads)
+            params = jax.tree_util.tree_map(
+                lambda p, m: m.astype(p.dtype), st.params, master)
         return (LMMixedState(params, master),
                 lax.pmean(loss, data_axis))
 
@@ -521,7 +531,8 @@ def build_lm_mixed_step(model: Model, mesh: Mesh, params_template, lr: float,
     spec = LMMixedState(params=pspecs, master=pspecs)
     mapped = shard_map(step, mesh=mesh, in_specs=(spec, tok_spec),
                            out_specs=(spec, P()), check_vma=False)
-    return jax.jit(mapped, donate_argnums=(0,) if donate else ())
+    return _timed(jax.jit(mapped, donate_argnums=(0,) if donate else ()),
+                  "lm_mixed")
 
 
 class LMEAState(NamedTuple):
@@ -563,8 +574,6 @@ def build_lm_ea_steps(model: Model, tree, lr: float, alpha: float,
     replica on its shard.  ``ea_round(state) -> state``.
     """
     from distlearn_tpu.parallel.mesh import expand_node, squeeze_node
-    from distlearn_tpu.train.trainer import (apply_elastic_round,
-                                             local_update)
     axis = tree.axis_name
 
     def local_step(st: LMEAState, tokens):
@@ -593,4 +602,4 @@ def build_lm_ea_steps(model: Model, tree, lr: float, alpha: float,
         shard_map(ea_round, mesh=tree.mesh, in_specs=(spec,),
                       out_specs=spec, check_vma=False),
         donate_argnums=(0,) if donate else ())
-    return local, rnd
+    return _timed(local, "lm_ea_local"), _timed(rnd, "lm_ea_round")

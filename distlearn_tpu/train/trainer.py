@@ -48,16 +48,56 @@ from distlearn_tpu.utils import metrics as metrics_lib
 PyTree = Any
 
 
+#: name -> the newest shim a builder returned under that name.  Strong
+#: references, bounded by the number of builder names: a caller reads a
+#: program's text after the code that built and ran the step has
+#: returned and dropped it (the benchmark's readers do).
+_programs: dict = {}
+
+
+def step_programs() -> dict:
+    """The step programs built in this process, by the name their builder
+    gave them (``sgd``, ``lm``, ...; the newest build per name).  What a
+    trace reader asks for a program's :meth:`_TimedStep.hlo_text`.
+    Empty with ``DISTLEARN_OBS=0``."""
+    return dict(_programs)
+
+
+def _signature(args):
+    """The abstract signature of a call's arguments — shape, dtype and
+    (where the array is committed to one) sharding of every array,
+    anything else (a Python scalar) as it is; no buffer is kept.  None for
+    a call made under a trace (``make_jaxpr``, an outer ``jit``): that is
+    no dispatch, and a tracer has no placement."""
+    if any(isinstance(x, jax.core.Tracer)
+           for x in jax.tree_util.tree_leaves(args)):
+        return None
+
+    def abstract(x):
+        if not (hasattr(x, "shape") and hasattr(x, "dtype")):
+            return x
+        placed = getattr(x, "committed", False)
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=x.sharding if placed else None)
+    return jax.tree_util.tree_map(abstract, args)
+
+
 class _TimedStep:
-    """Telemetry shim around a jitted step: times each host dispatch
-    (async — the wall time to ENQUEUE the program, which is what the
-    scan/cycle builders exist to amortize, not device compute) and counts
-    calls.  ``__getattr__`` forwards everything else to the jitted
-    callable so ``.lower()`` consumers — bench.py, the distcost budget
-    gate — see the unwrapped object and compiled HLO stays identical."""
+    """Telemetry shim around a jitted step: one ``train.dispatch`` span
+    per call, which times the host dispatch (async — the wall time to
+    ENQUEUE the program, which is what the scan/cycle builders exist to
+    amortize, not device compute), the same time into a histogram, and a
+    call count.  It keeps the abstract signature of its first call (no
+    buffer), so :meth:`hlo_text` can name the program the device ran.
+    ``__getattr__`` forwards everything else to the jitted callable so
+    ``.lower()`` consumers — bench.py, the distcost budget gate — see the
+    unwrapped object and compiled HLO stays identical."""
 
     def __init__(self, fn, name: str):
         self._fn = fn
+        self._name = name
+        self._sig = None
+        self._hlo = None
         lat = obs.histogram(
             "train_step_dispatch_seconds",
             "host-side dispatch wall time per jitted step call",
@@ -66,13 +106,52 @@ class _TimedStep:
                           labels=("step",))
         self._h = lat.labels(step=name)
         self._c = cnt.labels(step=name)
+        _programs[name] = self
 
     def __call__(self, *a, **kw):
+        if self._sig is None:
+            self._sig = _signature((a, kw))
         t0 = time.perf_counter()
-        out = self._fn(*a, **kw)
+        with obs.span("train.dispatch", step=self._name):
+            out = self._fn(*a, **kw)
         self._c.inc()
         self._h.observe(time.perf_counter() - t0)
         return out
+
+    def hlo_text(self) -> str:
+        """The optimized HLO of the program the first call ran, as text:
+        every instruction with the ``op_name`` that says which
+        ``jax.named_scope`` and which pass (forward, backward,
+        recomputation) it came from — see
+        :func:`distlearn_tpu.utils.profiling.scope_table`.  Lowered and
+        compiled again from the remembered signature on first use, so call
+        it outside any timed window.
+
+        JAX's persistent cache keys a program WITHOUT its ``op_name``s,
+        so the executable a call loads may be one that another checkout
+        compiled from the same mathematics under other scope names (or
+        none): same instructions, its names — and within a process JAX
+        answers this compile from memory with that very executable.  A
+        caller that needs THIS source's names whatever the cache held
+        drops JAX's in-memory programs first (``jax.clear_caches()``, as
+        ``benchmarks/scope_reduce.py`` does once its window is over); the
+        compile that then happens here keeps the metadata in its key, so
+        it can only hit an entry that an ``hlo_text()`` of this very
+        source wrote."""
+        if self._sig is None:
+            raise RuntimeError(
+                f"step {self._name!r} has not been called yet: there is no "
+                "signature to lower it with")
+        if self._hlo is None:
+            a, kw = self._sig
+            flag = "jax_compilation_cache_include_metadata_in_key"
+            before = getattr(jax.config, flag)
+            jax.config.update(flag, True)
+            try:
+                self._hlo = self._fn.lower(*a, **kw).compile().as_text()
+            finally:
+                jax.config.update(flag, before)
+        return self._hlo
 
     def __getattr__(self, name):
         return getattr(self._fn, name)

@@ -6,6 +6,7 @@ per-step wall-clock timers suitable for the bench harness.
 from __future__ import annotations
 
 import contextlib
+import re
 import time
 
 import jax
@@ -20,6 +21,33 @@ def trace(log_dir: str):
         yield
     finally:
         jax.profiler.stop_trace()
+
+
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_HLO_OP_NAME = re.compile(r'metadata=\{[^}]*?\bop_name="([^"]*)"')
+
+
+def scope_table(hlo_text: str) -> dict[str, str]:
+    """``{instruction name: op_name}`` of every instruction of an optimized
+    HLO module's text (``compiled.as_text()``, or ``hlo_text()`` of a
+    step a ``train/`` builder returned) that carries an ``op_name``.
+
+    The ``op_name`` is JAX's name stack at the point the operation was
+    traced: the ``jax.named_scope`` around it (``models.core.SCOPES``)
+    and JAX's own marks of the pass — ``jvp(`` alone on the forward pass,
+    ``transpose(jvp(`` on the backward pass, ``rematted_computation``
+    where ``jax.checkpoint`` computes the forward pass again.
+    Instruction names are unique in a module and are the names a device
+    trace gives its events, so this table says which part of the model a
+    traced operation belongs to."""
+    table = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m:
+            op = _HLO_OP_NAME.search(line)
+            if op:
+                table[m.group(1)] = op.group(1)
+    return table
 
 
 class StepTimer:

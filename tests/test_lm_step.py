@@ -1,8 +1,11 @@
 """3D-parallel LM train step: loss decreases; TP shards update consistently."""
 
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distlearn_tpu.models.transformer import transformer_lm
@@ -467,3 +470,202 @@ def test_lm_pp_1f1b_liveness_beats_gpipe():
     gpipe = temp_bytes(build_lm_pp_step)
     f1b = temp_bytes(build_lm_pp_1f1b_step)
     assert f1b < 0.6 * gpipe, (f1b, gpipe)
+
+
+# ---------------------------------- scopes, the dispatch shim, its catalog --
+
+def _scoped_lm(**kw):
+    """A toy scanned, fully rematerialised LM on a (2, 1, 1) mesh: the
+    benchmark's own shape of program."""
+    mesh = Mesh(np.array(jax.devices()[:2]).reshape(2, 1, 1),
+                ("data", "seq", "model"))
+    model = transformer_lm(vocab=97, dim=32, depth=3, heads=4, max_len=16,
+                           scan_blocks=True, remat="full")
+    params, _ = model.init(jax.random.PRNGKey(0))
+    tokens = jax.device_put(
+        jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 97, jnp.int32),
+        NamedSharding(mesh, P("data", "seq")))
+    return build_lm_step(model, mesh, params, lr=0.1, donate=False,
+                         **kw), params, tokens
+
+
+@pytest.fixture(scope="module")
+def scoped_op_names():
+    """``op_name`` of every instruction of the toy step's optimized HLO."""
+    from distlearn_tpu.utils.profiling import scope_table
+    step, params, tokens = _scoped_lm()
+    step(params, tokens)
+    table = scope_table(step.hlo_text())
+    assert table
+    return list(table.values())
+
+
+def _has_scope(op_name, scope):
+    return any(part in (scope, f"jvp({scope})", f"transpose(jvp({scope}))")
+               for part in op_name.split("/"))
+
+
+_IN_PHASE = {
+    "forward": lambda n: "jvp(" in n and "transpose(" not in n,
+    "recompute": lambda n: "rematted_computation" in n,
+    "backward": lambda n: "transpose(" in n
+    and "rematted_computation" not in n,
+}
+
+
+@pytest.mark.parametrize("phase", sorted(_IN_PHASE))
+def test_every_block_scope_shows_in_every_pass(scoped_op_names, phase):
+    """Forward / recompute / backward are JAX's own marks; the declared
+    scopes ride inside each of them."""
+    names = [n for n in scoped_op_names if _IN_PHASE[phase](n)]
+    wanted = {"norm", "attn_proj", "attn_core", "mlp"}
+    if phase != "recompute":        # embedding and head sit outside the scan
+        wanted |= {"embed", "head_loss"}
+    missing = {s for s in wanted if not any(_has_scope(n, s) for n in names)}
+    assert not missing, (phase, missing)
+
+
+def test_update_and_grad_reduce_sit_outside_the_passes(scoped_op_names):
+    from distlearn_tpu.models.core import SCOPES
+    for scope in ("update", "grad_reduce"):
+        names = [n for n in scoped_op_names if _has_scope(n, scope)]
+        assert names, scope
+        assert not any("jvp(" in n or "transpose(" in n for n in names), scope
+    # the declared list is what the program uses: nothing else, nothing less
+    used = {s for s in SCOPES
+            if any(_has_scope(n, s) for n in scoped_op_names)}
+    assert used == set(SCOPES)
+
+
+def test_scopes_change_no_number(monkeypatch):
+    """Scopes are metadata: the same build with ``jax.named_scope`` turned
+    into a null context lowers to the same program text (locations apart,
+    which is also how JAX keys its compile cache) and gives bitwise the
+    same losses and parameters."""
+    import contextlib
+
+    def two_steps():
+        step, params, tokens = _scoped_lm()
+        text = step.lower(params, tokens).as_text()
+        losses = []
+        for _ in range(2):
+            params, loss = step(params, tokens)
+            losses.append(np.asarray(loss))
+        return text, losses + jax.tree_util.tree_leaves(
+            jax.device_get(params))
+
+    text, numbers = two_steps()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare_text, bare_numbers = two_steps()
+    assert text == bare_text
+    for a, b in zip(numbers, bare_numbers):
+        np.testing.assert_array_equal(a, b)
+
+
+def _lm_builders():
+    """name -> a thunk building ``(step, args)`` for every LM builder."""
+    from distlearn_tpu.parallel.mesh import MeshTree
+    from distlearn_tpu.train import (build_lm_ea_steps, build_lm_mixed_step,
+                                     build_lm_pp_1f1b_step, build_lm_pp_step,
+                                     init_lm_ea_state, init_lm_mixed_state,
+                                     stack_blocks)
+
+    def plain():
+        return _scoped_lm()
+
+    def mixed():
+        _, params, tokens = _scoped_lm()
+        mesh = tokens.sharding.mesh
+        model = transformer_lm(vocab=97, dim=32, depth=3, heads=4,
+                               max_len=16, scan_blocks=True, remat="full")
+        return (build_lm_mixed_step(model, mesh, params, lr=0.1,
+                                    donate=False),
+                init_lm_mixed_state(params), tokens)
+
+    def pipelined(builder):
+        def build():
+            lm = transformer_lm(vocab=64, dim=32, depth=2, heads=2,
+                                max_len=16)
+            params, _ = lm.init(jax.random.PRNGKey(0))
+            mesh = Mesh(np.array(jax.devices()[:2]).reshape(1, 2),
+                        ("data", "pipe"))
+            shared, stacked = stack_blocks(params, 2)
+            return (builder(mesh, shared, stacked, lr=0.1,
+                            num_microbatches=2, donate=False),
+                    jax.device_put(shared, NamedSharding(mesh, P())),
+                    jax.device_put(stacked, NamedSharding(mesh, P("pipe"))),
+                    jax.device_put(np.zeros((4, 16), np.int32),
+                                   NamedSharding(mesh, P("data"))))
+        return build
+
+    def elastic(which):
+        def build():
+            tree = MeshTree(num_nodes=2)
+            lm = transformer_lm(vocab=32, dim=32, depth=2, heads=2,
+                                max_len=16)
+            st = init_lm_ea_state(lm, tree, jax.random.PRNGKey(0))
+            local, rnd = build_lm_ea_steps(lm, tree, lr=0.1, alpha=0.25,
+                                           donate=False)
+            toks = jax.device_put(np.zeros((4, 16), np.int32),
+                                  NamedSharding(tree.mesh, P("data")))
+            return (local, st, toks) if which == "local" else (rnd, st)
+        return build
+
+    return {"lm": plain, "lm_mixed": mixed,
+            "lm_pp": pipelined(build_lm_pp_step),
+            "lm_pp_1f1b": pipelined(build_lm_pp_1f1b_step),
+            "lm_ea_local": elastic("local"), "lm_ea_round": elastic("round")}
+
+
+@pytest.mark.parametrize("name", ["lm", "lm_mixed", "lm_pp", "lm_pp_1f1b",
+                                  "lm_ea_local", "lm_ea_round"])
+def test_every_lm_builder_reports_its_dispatch(name):
+    """Each LM builder returns the trainer's shim under its own name:
+    ``.lower()`` still works, one call = one ``train.dispatch`` span + one
+    histogram observation + one count, and the program can name its own
+    instructions afterwards."""
+    from distlearn_tpu import obs
+    from distlearn_tpu.obs import core, trace
+    from distlearn_tpu.train.trainer import _TimedStep, step_programs
+    from distlearn_tpu.utils.profiling import scope_table
+    core.configure(True)
+    try:
+        step, *args = _lm_builders()[name]()
+        assert isinstance(step, _TimedStep)
+        assert step_programs()[name] is step
+        assert step.lower(*args).compile() is not None
+        with pytest.raises(RuntimeError, match="not been called"):
+            step.hlo_text()
+        trace.clear()
+        seen = step._h.count
+        t_before = time.perf_counter()
+        jax.block_until_ready(step(*args))
+        t_after = time.perf_counter()
+        spans = [s for s in obs.spans() if s["name"] == "train.dispatch"]
+        assert len(spans) == 1 and spans[0]["labels"] == {"step": name}
+        assert t_before <= spans[0]["t0"] <= t_after
+        assert spans[0]["dur"] <= t_after - t_before
+        assert step._h.count == seen + 1
+        table = scope_table(step.hlo_text())
+        assert table and all(isinstance(v, str) for v in table.values())
+    finally:
+        core.configure(None)
+
+
+def test_obs_off_returns_the_bare_jit_and_records_nothing():
+    from distlearn_tpu import obs
+    from distlearn_tpu.obs import core, trace
+    from distlearn_tpu.train.trainer import _TimedStep, step_programs
+    before = step_programs()
+    core.configure(False)
+    try:
+        step, params, tokens = _scoped_lm()
+        assert not isinstance(step, _TimedStep)
+        assert hasattr(step, "lower") and not hasattr(step, "hlo_text")
+        trace.clear()
+        jax.block_until_ready(step(params, tokens))
+        assert obs.spans() == []
+        assert step_programs() == before
+    finally:
+        core.configure(None)

@@ -618,3 +618,37 @@ def test_spans_dropped_surfaced_in_diststat(clean_obs, tmp_path, capsys):
     diststat._print_summary(doc)
     out = capsys.readouterr().out
     assert "WARNING" in out and "dropped 6" in out
+
+
+# -- span start (t0) ---------------------------------------------------------
+
+def test_span_t0_is_monotonic_and_orders_nested_spans(clean_obs):
+    """``t0`` is the span's START on ``time.perf_counter()``: a caller who
+    timed a window on that clock can cut the ring to it, a child lies
+    inside its parent, and siblings sort by start even though the ring
+    holds them in order of their END."""
+    t_before = time.perf_counter()
+    with obs.span("t.outer"):
+        with obs.span("t.first"):
+            time.sleep(0.002)
+        with obs.span("t.second"):
+            pass
+    obs.record_span("t.measured", 0.25, req=7)
+    t_after = time.perf_counter()
+    by_name = {s["name"]: s for s in obs.spans()}
+    assert [s["name"] for s in obs.spans()] == [
+        "t.first", "t.second", "t.outer", "t.measured"]   # END order
+    outer, first, second = (by_name[n] for n in
+                            ("t.outer", "t.first", "t.second"))
+    assert t_before <= outer["t0"] <= first["t0"] < second["t0"] <= t_after
+    for child in (first, second):
+        assert child["t0"] + child["dur"] <= outer["t0"] + outer["dur"]
+    assert first["t0"] + first["dur"] <= second["t0"]
+    # self time (choosing-metrics section 4) is now computable
+    assert outer["dur"] - first["dur"] - second["dur"] >= 0
+    # a caller-measured span ends now and started ``dur`` ago
+    m = by_name["t.measured"]
+    assert m["t0"] + m["dur"] == pytest.approx(t_after, abs=0.05)
+    assert m["t0"] < t_before and m["labels"] == {"req": 7}
+    # the wall-clock fields every trail consumer reads are still there
+    assert all({"ts", "dur", "t0"} <= set(s) for s in obs.spans())
