@@ -1631,8 +1631,9 @@ def bench_transformer_lm(batch: int, seq: int, iters: int, windows: int,
     step (next-token loss, full backward, SGD) on one chip, bf16 compute.
     On a pod the same step shards over (data, seq, model) axes — see
     distlearn_tpu.train.lm; this measures the per-chip compute story.
-    ``attn`` picks the attention kernel ("xla"/"flash"/"chunked" — see
-    distlearn_tpu.parallel.sequence.local_attention); ``remat`` is the
+    ``attn`` forces the attention path ("xla"/"splash"; None = chosen from
+    the shape — see distlearn_tpu.parallel.sequence.local_attention);
+    ``remat`` is the
     transformer's mode (False / "full" / "mlp"); ``scan_blocks`` uses the
     scanned-depth layout (program size flat in depth — the recipe for
     configs whose unrolled program exceeds the compile limits).  MFU for
@@ -2485,7 +2486,7 @@ def main():
               "step (bubble excluded; real pods add (S-1)/(M+S-1))",
               file=sys.stderr)
 
-    # --- long-context LM (chunked causal attention + selective remat) -------
+    # --- long-context LM (blockwise causal attention + selective remat) -----
     if os.environ.get("BENCH_SKIP_LM_LONG") != "1":
         if ("BENCH_LM_LONG_BATCH" in os.environ
                 or "BENCH_LM_LONG_SEQ" in os.environ):
@@ -2505,17 +2506,17 @@ def main():
             cfg = cfg.strip()
             scanned = cfg.endswith("s")
             lcb, lcs = (int(v) for v in cfg.rstrip("s").split("x"))
-            # Long-context recipe (r4): CHUNKED causal attention (masked
-            # half of the scores never computed, softmax weights saved so
-            # backward re-runs no exp — measured faster than both the
-            # naive path and the Pallas flash kernel on v5e, which is
-            # exp/VPU-bound at this shape) + selective remat where the
-            # saved f32 weights fit HBM, full remat otherwise.  MFU uses
-            # model flops (no-remat program); HFU counts the recompute.
+            # Long-context recipe: the attention local_attention picks
+            # from the shape (blockwise, masked blocks skipped: PERF.md
+            # section 6, PR 27) + selective remat on the smaller rows, full
+            # remat on the larger — the r4 sizing rule (bytes of causal f32
+            # weights, which the r4 kernel saved), kept until these rows
+            # are measured again (ROADMAP S3).  MFU uses model flops
+            # (no-remat program); HFU counts the recompute.
             w_bytes = lcb * (lm_dim // 64) * lcs * lcs // 2 * 4 * lm_depth
             remat_mode = "mlp" if w_bytes < 9e9 else "full"
             rows.append(bench_transformer_lm(
-                lcb, lcs, lci, 3, peak, attn="chunked", remat=remat_mode,
+                lcb, lcs, lci, 3, peak, attn=None, remat=remat_mode,
                 scan_blocks=scanned))
         # Configs whose no-remat program does not fit HBM (or ran scanned)
         # have mfu=None; extrapolate model flops analytically, calibrated on a

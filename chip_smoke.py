@@ -12,7 +12,9 @@ models the repo supports, with seeded random weights and synthetic data:
   (build_ea_steps), tau=10.
 * ``lm`` — examples/lm.py's path: transformer_lm(vocab 32768, dim 1024,
   depth 8, heads 16, bf16) + build_lm_step at batch 8 x seq 1024, then one
-  step each with attn_impl "flash" and "chunked" at seq 4096.
+  step at seq 4096; at both lengths the attention ``local_attention`` picks
+  by default must be the blockwise kernel (the ``obs`` counter
+  ``attn_kernel_total`` says which path each traced call resolved to).
 * ``serve`` — examples/lm.py --serve's path at the same width: DecodeEngine
   (8 slots, max_len 1024) behind ServeServer, ServeClients on threads over
   the framed-TCP port: prompts in several prefill buckets, a prefix-cache
@@ -22,8 +24,8 @@ models the repo supports, with seeded random weights and synthetic data:
 
 What "right" means, per phase: finite losses that fall; parameters bitwise
 equal across nodes after sync; every mesh device holding its shard; the
-fused update and flash attention present in the lowered program as Mosaic
-custom calls (not interpreted loops); every stream complete.  The serve
+fused update and the blockwise attention present in the lowered program as
+Mosaic custom calls (not interpreted loops); every stream complete.  The serve
 check is made ON LOGITS, not on token equality: the engine runs as the
 example runs it (float32 params, the TPU's default matmul precision — bf16
 MXU passes), the reference is the training forward ``model.apply`` in
@@ -248,6 +250,7 @@ def phase_lm(*, mesh_shape: tuple[int, int, int] = (1, 1, 1),
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from distlearn_tpu.models.transformer import param_specs, transformer_lm
+    from distlearn_tpu.parallel.sequence import attention_paths_traced
     from distlearn_tpu.train.lm import build_lm_step
 
     dp, sp, tp = mesh_shape
@@ -271,44 +274,59 @@ def phase_lm(*, mesh_shape: tuple[int, int, int] = (1, 1, 1),
             .astype(np.int32), NamedSharding(mesh, P("data", "seq")))
         return step, placed, tokens
 
+    def lower_default(step, params, tokens, what):
+        """Lower a step built with no ``attn_impl`` and say which attention
+        ``local_attention`` resolved while it was traced (the ``obs``
+        counter).  On the TPU it must be the blockwise kernel alone, as a
+        Mosaic call."""
+        before = attention_paths_traced()
+        lowered = step.lower(params, tokens)
+        traced = {k: v - before.get(k, 0)
+                  for k, v in attention_paths_traced().items()
+                  if v - before.get(k, 0)}
+        if jax.default_backend() == "tpu":
+            _require(set(traced) == {"splash"},
+                     f"{what}: the blockwise attention did not engage by "
+                     f"default (attn_kernel_total moved by {traced})")
+            _require_mosaic(lowered, f"{what}: blockwise attention")
+        return lowered, traced
+
     step, params, tokens = build(seq, batch)
     _require(_distinct_devices(tokens) == n_dev,
              "tokens do not live on every mesh device")
     _require(_distinct_devices(params["block0"]["wq"]) == n_dev,
              "block0/wq does not live on every mesh device")
-    _require(n_dev == 1 or "all-reduce" in
-             step.lower(params, tokens).compile().as_text(),
+    lowered, traced = lower_default(step, params, tokens, f"seq {seq}")
+    out["attn_kernels"] = {str(seq): traced}
+    _require(n_dev == 1 or "all-reduce" in lowered.compile().as_text(),
              f"compiled LM step holds no all-reduce on mesh {mesh_shape}")
+    # same params, same tokens, the full-square path forced: the two
+    # attentions agree to bf16 on the loss before any update
+    xstep, xparams, _ = build(seq, batch, attn_impl="xla")
+    _, xloss = xstep(xparams, tokens)
+    del xparams
     losses = []
     for _ in range(steps):
         params, loss = step(params, tokens)
         losses.append(float(loss))
     _require(np.isfinite(losses).all(), f"non-finite LM loss: {losses}")
     _require(losses[-1] < losses[0], f"LM loss did not fall: {losses}")
-    out.update(batch=batch, seq=seq, losses=[round(l, 4) for l in losses])
+    _require(abs(float(xloss) - losses[0]) < 0.01 * abs(losses[0]),
+             f"default attention and impl='xla' disagree on the first loss: "
+             f"{losses[0]} against {float(xloss)}")
+    out.update(batch=batch, seq=seq, losses=[round(l, 4) for l in losses],
+               loss_xla=round(float(xloss), 4))
     del params
 
-    # long context: both kernels compiled by this libtpu, one step each
-    # (selective remat = bench.py's long-context recipe); the Pallas flash
-    # kernel exists on the TPU only
-    long_losses = {}
-    for impl in (("flash", "chunked") if jax.default_backend() == "tpu"
-                 else ("chunked",)):
-        lstep, lparams, ltokens = build(long_seq, dp, attn_impl=impl,
-                                        remat="mlp")
-        if impl == "flash":
-            out["mosaic_calls_flash_step"] = _require_mosaic(
-                lstep.lower(lparams, ltokens), "flash attention")
-        lparams, loss = lstep(lparams, ltokens)
-        _require(np.isfinite(float(loss)), f"non-finite {impl} loss")
-        long_losses[impl] = round(float(loss), 4)
-        del lparams
-    if len(long_losses) > 1:
-        # same params, same tokens, same math: the kernels agree to bf16
-        vals = list(long_losses.values())
-        _require(abs(vals[0] - vals[1]) < 0.01 * abs(vals[0]),
-                 f"long-context kernels disagree: {long_losses}")
-    out.update(long_seq=long_seq, long_losses=long_losses)
+    # long context, one step, default attention again (selective remat =
+    # bench.py's long-context recipe)
+    lstep, lparams, ltokens = build(long_seq, dp, remat="mlp")
+    _, traced = lower_default(lstep, lparams, ltokens, f"seq {long_seq}")
+    out["attn_kernels"][str(long_seq)] = traced
+    lparams, loss = lstep(lparams, ltokens)
+    _require(np.isfinite(float(loss)), "non-finite long-context loss")
+    out.update(long_seq=long_seq, long_loss=round(float(loss), 4))
+    del lparams
     return out
 
 

@@ -83,7 +83,7 @@ def attn_apply(blk: PyTree, x: jax.Array, cd, *, seq_attn=None,
     """Attention half of a transformer block (pre-norm attention residual)
     on a LOCAL param shard — split out of :func:`block_apply` so the
     selective-remat mode can checkpoint the FFN half alone (saving the
-    attention output and the flash kernel's softmax residuals instead of
+    attention output and the blockwise kernel's softmax residuals instead of
     re-running the attention forward in the backward pass)."""
     q, k, v = attn_qkv(blk, x, cd, tp_axis)
     with jax.named_scope("attn_core"):
@@ -200,15 +200,15 @@ def transformer_lm(vocab: int = 256, dim: int = 128, depth: int = 2,
     picks the sequence-parallel attention: ``"ring"`` (neighbor-hop K/V
     rotation, unbounded L) or ``"alltoall"`` (Ulysses head-scatter — needs
     heads divisible by the seq axis and the full score block in memory).
-    ``attn_impl`` picks the single-device attention kernel
-    (``"xla"``/``"flash"``/``"chunked"`` — see
-    :func:`distlearn_tpu.parallel.sequence.local_attention`; None = env
-    default).  It applies whenever the attention runs locally: no
-    ``seq_axis``, or a size-1 sequence axis.  With a real (>1) sequence
-    axis the ring/all-to-all blockwise math takes over and the knob is
-    inert — see :func:`distlearn_tpu.parallel.sequence.ring_attention`
-    for why (and for the zigzag layout that does the causal FLOP cut
-    there).
+    ``attn_impl`` forces the single-device attention path
+    (``"xla"``/``"splash"`` — see
+    :func:`distlearn_tpu.parallel.sequence.local_attention`; None = chosen
+    from the call's shape, dtype and backend).  It applies whenever the
+    attention runs locally: no ``seq_axis``, or a size-1 sequence axis.
+    With a real (>1) sequence axis the ring/all-to-all blockwise math takes
+    over and the argument is inert — see
+    :func:`distlearn_tpu.parallel.sequence.ring_attention` for why (and for
+    the zigzag layout that does the causal FLOP cut there).
 
     ``remat=True`` (= ``"full"``) wraps each block in ``jax.checkpoint``:
     activations are recomputed in the backward pass instead of saved — HBM
@@ -216,7 +216,7 @@ def transformer_lm(vocab: int = 256, dim: int = 128, depth: int = 2,
     standard trade for long-context/deep configs.  ``remat="mlp"`` is the
     selective middle ground (Megatron-style selective activation
     recomputation): only the FFN half of each block is checkpointed, so
-    the attention output AND the flash kernel's softmax residuals stay
+    the attention output AND the blockwise kernel's softmax residuals stay
     saved — the backward pass never re-runs the attention forward, at the
     cost of keeping O(L * dim) attention activations per block live.
 

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from distlearn_tpu import obs
 
 
 def _block_attn(q, k, v, scale, mask):
@@ -62,15 +63,15 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         (``layout="zigzag"``).
       axis_name: mesh axis carrying the sequence shards.
       causal: apply a causal mask over GLOBAL positions.
-      impl: single-device kernel choice, honored ONLY in the degenerate
-        n == 1 case (forwarded to :func:`local_attention`).  For n > 1
-        the inner kernel is always the portable blockwise
-        :func:`_block_attn` — the Pallas flash kernel in this jax
-        version returns no softmax residuals, so its per-block outputs
-        cannot be merged across ring hops; use the zigzag layout to
-        halve the causal block work, and note its per-block score
-        buffer is [B, H, L_loc/2, L_loc/2] (a quarter of the contiguous
-        ring's per-block buffer).
+      impl: forces the single-device path, honored ONLY in the degenerate
+        n == 1 case (forwarded to :func:`local_attention`, which picks
+        one from the shape when this is None).  For n > 1 the inner
+        kernel is always the portable blockwise :func:`_block_attn` —
+        its per-block partials carry the softmax statistics the ring
+        merge needs; use the zigzag layout to halve the causal block
+        work, and note its per-block score buffer is
+        [B, H, L_loc/2, L_loc/2] (a quarter of the contiguous ring's
+        per-block buffer).
       unroll: forwarded to the ring ``fori_loop`` — inlining the n-1
         hops lets XLA overlap each hop's ppermute with the next block's
         compute across iteration boundaries (the r3 GPipe lesson; use
@@ -98,10 +99,9 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                                    lax.axis_index(axis_name), unroll=unroll)
     if n == 1:
         # Degenerate ring: the whole sequence is local.  Delegate to the
-        # single-device kernel so the flash/chunked paths (no O(L^2)
-        # score buffer / causal FLOP skip) stay available — the blockwise
-        # fallback below would materialize the full [B,H,L,L] s_exp for
-        # its one block.
+        # single-device attention so its blockwise kernel (no O(L^2)
+        # score buffer, causal block skip) engages — the ring body below
+        # would materialize the full [B,H,L,L] s_exp for its one block.
         return local_attention(q, k, v, causal=causal, impl=impl)
     my = lax.axis_index(axis_name)
     B, Lq, H, D = q.shape
@@ -278,171 +278,142 @@ def alltoall_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                           tiled=True)
 
 
-def resolve_chunk(L: int) -> int:
-    """Effective chunked-attention chunk for local length ``L``:
-    ``DISTLEARN_TPU_CHUNK`` when set (must be a positive int — a
-    malformed override raises rather than silently benchmarking a config
-    the user did not ask for), else the measured default
-    ``max(128, L // 32)`` (see :func:`chunked_causal_attention`).
-    The ONE place the resolution rule lives — the example's advisory note
-    and the attention dispatch both call it, so they cannot drift."""
-    import os
-    env = os.environ.get("DISTLEARN_TPU_CHUNK")
-    if env:
-        try:
-            c = int(env)
-        except ValueError:
-            raise ValueError(
-                f"DISTLEARN_TPU_CHUNK={env!r} is not an integer") from None
-        if c <= 0:
-            raise ValueError(
-                f"DISTLEARN_TPU_CHUNK={env!r} must be positive")
-        return c
-    return max(128, L // 32)
+#: the implementations ``impl=`` may name (``None`` = :func:`select_attention`)
+ATTN_IMPLS = ("xla", "splash")
+
+#: block edges the blockwise kernel is run at, widest first.  Measured on the
+#: v5e at ``[8, 1024, 20, 64]`` bf16 inside the scanned, rematerialised LM
+#: step (PERF.md section 6, PR 27): 512 is the fastest, 256 still beats the
+#: full-square path, 128 loses to it — so the default engages only where one
+#: of the first two tiles the length, and 128 serves a forced
+#: ``impl="splash"`` alone.
+_SPLASH_BLOCKS = (512, 256, 128)
+_SPLASH_MIN_BLOCK = 256
+#: the shortest local length the blockwise kernel was measured to win at
+_SPLASH_MIN_LEN = 1024
 
 
-def chunked_engages(L: int, chunk: int | None = None) -> bool:
-    """Whether the chunked causal path actually runs at local length
-    ``L`` (it needs ``L > chunk`` and ``L % chunk == 0``; otherwise the
-    dispatch falls back to plain XLA attention)."""
-    c = chunk if chunk else resolve_chunk(L)
-    return L > c and L % c == 0
+def _splash_block(L: int) -> int | None:
+    """The widest block edge that tiles a length-``L`` sequence, if any."""
+    return next((b for b in _SPLASH_BLOCKS if L % b == 0), None)
 
 
-def chunked_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                             chunk: int | None = None) -> jax.Array:
-    """Causal attention with the masked half of the score matrix never
-    computed — a portable (pure-XLA) counterpart to flash attention tuned
-    for the opposite end of the memory/compute trade.
+def select_attention(causal: bool, L: int, D: int, dtype,
+                     backend: str) -> str:
+    """The single-device attention path of a call, decided from what the
+    call itself shows — causality, local length, head size, dtype, backend
+    — and from nothing else (no environment variable, no model name).
 
-    The query axis is split into static chunks; chunk ``i`` attends only
-    to keys ``[0, (i+1)*chunk)``, so the matmul and exp work is the causal
-    ~L^2/2 rather than the full L^2 the naive path computes-then-masks.
-    Unlike flash, the per-chunk softmax weights are left for XLA to save
-    as backward residuals: the backward pass re-runs NO exp.  On v5e the
-    lm_long config is exp/VPU-bound, where flash pays ~3x the exp count
-    (forward + two backward recomputes) of this path's 1x — measured
-    (docs/PERF.md): chunked beats both flash and the naive path at
-    seq 4096 while using O(L^2/2) f32 residual memory, which fits at the
-    batch sizes a 16 GB chip trains at this length anyway.  For long
-    sequences at larger batch, flash remains the memory-bound choice.
+    ``"splash"`` (causal attention blockwise, masked blocks skipped, no
+    ``[B, H, L, L]`` array in HBM) where it was measured to win: on the TPU,
+    causal, ``L`` at least 1024 and a multiple of a block at least 256 wide,
+    a head size the kernel's lanes take (a multiple of 64), bf16 or float32.
+    Everything else — non-causal, short or ragged lengths, other backends —
+    is ``"xla"``, the full-square path, bit for bit what it was."""
+    if (backend == "tpu" and causal and L >= _SPLASH_MIN_LEN
+            and (_splash_block(L) or 0) >= _SPLASH_MIN_BLOCK and D % 64 == 0
+            and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
+                                     jnp.dtype(jnp.float32))):
+        return "splash"
+    return "xla"
 
-    Only the diagonal sub-block gets a mask; the strict-past prefix is
-    computed unmasked — no [L, L] predicate materialization.
 
-    ``chunk=None`` resolves via :func:`resolve_chunk` (``DISTLEARN_TPU_
-    CHUNK`` override, else ``max(128, L // 32)``): the measured v5e sweep
-    at L=4096 improves monotonically down to 128 (5.6 -> 11.3 steps/s
-    on the full train step across 2048/1024/512/256/128), while capping
-    the chunk count at 32 keeps the unrolled per-block program bounded
-    for very long sequences (the compile-size failure mode the scanned
-    depth layout exists for).  Chunks must stay multiples of the
-    128-lane tile — 384 measured catastrophically (6.1 steps/s).
-    """
+def _attn_counter():
+    return obs.counter(
+        "attn_kernel_total",
+        "local_attention calls traced, by resolved implementation",
+        labels=("impl",))
+
+
+def attention_paths_traced() -> dict[str, int]:
+    """``{impl: local_attention calls traced so far}`` in this process (the
+    ``attn_kernel_total`` counter; empty with ``DISTLEARN_OBS=0``)."""
+    family = _attn_counter()
+    if family is obs.NULL:
+        return {}
+    return {s["labels"]["impl"]: s["value"] for s in family.sample()}
+
+
+def _backend() -> str:
+    """The platform the program being traced will run on.  Its own function
+    because a test that compiles for a described (not attached) TPU has to
+    answer for the chip here, and JAX's own lowering must not hear it."""
+    return jax.default_backend()
+
+
+def _splash_causal_attention(q, k, v, block: int, interpret: bool):
+    """Causal attention through JAX's Pallas ``splash_attention`` kernel
+    with a ``CausalMask``: blocks above the diagonal are never visited,
+    scores and softmax statistics are float32 and live in VMEM only, the
+    backward pass is the kernel's fused dK/dV/dQ call.  q/k/v:
+    ``[B, L, H, D]``; the kernel wants ``[H, L, D]`` per batch row and an
+    already scaled q."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
     B, L, H, D = q.shape
-    if chunk is None:
-        chunk = resolve_chunk(L)
-    if not chunked_engages(L, chunk):
-        return local_attention(q, k, v, causal=True, impl="xla")
-    scale = 1.0 / (D ** 0.5)
-    pos = jnp.arange(chunk)
-    diag_mask = pos[:, None] >= pos[None, :]          # [chunk, chunk]
-    outs = []
-    for i in range(L // chunk):
-        qs = q[:, i * chunk:(i + 1) * chunk]
-        parts = []
-        if i:  # strictly-past keys: fully visible, no mask at all
-            s_pre = jnp.einsum("bqhd,bkhd->bhqk", qs, k[:, :i * chunk],
-                               preferred_element_type=jnp.float32) * scale
-            parts.append(s_pre)
-        s_diag = jnp.einsum("bqhd,bkhd->bhqk", qs,
-                            k[:, i * chunk:(i + 1) * chunk],
-                            preferred_element_type=jnp.float32) * scale
-        parts.append(jnp.where(diag_mask[None, None], s_diag, -jnp.inf))
-        s = jnp.concatenate(parts, axis=-1) if len(parts) > 1 else parts[0]
-        w = jax.nn.softmax(s, axis=-1)                # f32, saved for bwd
-        outs.append(jnp.einsum("bhqk,bkhd->bqhd", w.astype(q.dtype),
-                               v[:, :(i + 1) * chunk],
-                               preferred_element_type=jnp.float32))
-    return jnp.concatenate(outs, axis=1).astype(q.dtype)
-
-
-def _flash_enabled(override: bool | None) -> bool:
-    """Opt-in Pallas flash-attention (TPU only).  Priority: explicit arg >
-    ``DISTLEARN_TPU_FLASH`` env > off.  Off by default because at moderate
-    lengths XLA's own fused attention is on par (measured on v5e: flash
-    wins ~10-12% at L >= 4096 and removes the O(L^2) score buffer — turn
-    it on for long-context configs)."""
-    if override is not None:
-        return bool(override)
-    from distlearn_tpu.utils.flags import env_truthy
-    return bool(env_truthy("DISTLEARN_TPU_FLASH"))
+    # A head narrower than the 128 lanes is padded to them wherever it is
+    # the minor dimension (``[.., L, 64]`` is stored as ``[.., L, 128]``);
+    # sequence-minor q/k/v are not.  Measured at D = 64: 22 ms of a 433 ms
+    # step, in the projections that write them (PERF.md section 6, PR 27).
+    layout = (sk.QKVLayout.HEAD_DIM_MINOR if D % 128 == 0
+              else sk.QKVLayout.SEQ_MINOR)
+    sizes = sk.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=block,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+        use_fused_bwd_kernel=True,
+        q_layout=layout, k_layout=layout, v_layout=layout)
+    kernel = sk.make_splash_mha(
+        sm.MultiHeadMask([sm.CausalMask((L, L))] * H), block_sizes=sizes,
+        head_shards=1, q_seq_shards=1, interpret=interpret)
+    heads_first = lambda a: a.transpose(0, 2, 1, 3)   # noqa: E731
+    # scaled in float32, rounded once (exact at D = 64: the scale is 1/8)
+    qs = (q.astype(jnp.float32) * (1.0 / (D ** 0.5))).astype(q.dtype)
+    out = jax.vmap(kernel)(heads_first(qs), heads_first(k), heads_first(v))
+    return heads_first(out).astype(q.dtype)
 
 
 def local_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False,
-                    flash: bool | None = None,
                     impl: str | None = None) -> jax.Array:
     """Single-device attention (same layout as the sharded variants), for
     non-sharded runs and as the per-shard kernel of
     :func:`alltoall_attention`.  q/k/v: [B, L, H, D].
 
-    ``impl`` picks the kernel: ``"xla"`` (naive fused, full [B,H,L,L]
-    scores), ``"flash"`` (Pallas blockwise online softmax, no score
-    materialization), or ``"chunked"`` (:func:`chunked_causal_attention`
-    — causal FLOP skip with saved softmax weights).  Default resolution:
-    the ``flash`` arg (back-compat), then the ``DISTLEARN_TPU_ATTN`` env
-    var, then ``DISTLEARN_TPU_FLASH``, then xla.
+    ``impl=None`` — what every caller in the package passes — resolves
+    through :func:`select_attention`.  Naming an implementation
+    (:data:`ATTN_IMPLS`) forces it, for tests and ``examples/lm.py
+    --attnImpl``: ``"xla"`` is the fused full-square path (float32
+    ``[B, H, L, L]`` scores and probabilities), ``"splash"`` the blockwise
+    Pallas kernel (:func:`_splash_causal_attention`).  A forced path that
+    cannot run at this shape RAISES on every backend — a row labelled
+    "splash" must have run that kernel; off the TPU a forced ``"splash"``
+    runs the kernel in Pallas interpret mode (slow; the CPU tests).
 
-    On the TPU backend a requested kernel that cannot run at this shape
-    RAISES, however it was requested: a row labelled "flash" or
-    "chunked" must have run that kernel.  Off-TPU (tests, CPU examples)
-    an env-requested flash and a non-engaging chunked fall back to xla
-    so one setting can cover mixed configs; an explicit flash argument
-    raises everywhere."""
+    The resolved path is counted in ``attn_kernel_total{impl=}`` (``obs``):
+    once per traced call, not per step — a jitted program is traced once."""
     B, L, H, D = q.shape
-    explicit_flash = flash is True or impl == "flash"
+    backend = _backend()
+    # with 64-bit types on, the kernel's loop counters trace as int64, which
+    # Mosaic refuses (the interpreter, off the TPU, takes them)
+    mosaic_refuses = backend == "tpu" and jax.config.jax_enable_x64
     if impl is None:
-        if flash is not None:
-            impl = "flash" if flash else "xla"
-        else:
-            import os
-            impl = os.environ.get("DISTLEARN_TPU_ATTN") \
-                or ("flash" if _flash_enabled(None) else "xla")
-    if impl not in ("xla", "flash", "chunked"):
-        raise ValueError(f"attention impl must be 'xla', 'flash', or "
-                         f"'chunked', got {impl!r}")
-    on_tpu = jax.default_backend() == "tpu"
-    if impl == "chunked":
-        chunk = resolve_chunk(L)
-        if causal and chunked_engages(L, chunk):
-            return chunked_causal_attention(q, k, v, chunk=chunk)
-        if on_tpu:
-            raise ValueError(
-                f"chunked attention cannot run here (causal={causal}, "
-                f"L={L}, chunk={chunk}): it needs causal attention with "
-                "L > chunk and L % chunk == 0")
-        impl = "xla"     # chunking only pays off via the causal FLOP skip
-    if impl == "flash":
-        # the Pallas kernel's default blocking needs L to be a multiple of
-        # its 128-wide blocks
-        if on_tpu and L >= 128 and L % 128 == 0:
-            from jax.experimental.pallas.ops.tpu.flash_attention import \
-                flash_attention
-            out = flash_attention(
-                q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                v.transpose(0, 2, 1, 3), causal=causal,
-                sm_scale=1.0 / (D ** 0.5))
-            return out.transpose(0, 2, 1, 3).astype(q.dtype)
-        if explicit_flash or on_tpu:
-            # refusing loudly beats silently materializing the O(L^2)
-            # buffer the caller asked to avoid
-            raise ValueError(
-                "flash attention needs the TPU backend and seq len a "
-                f"multiple of 128; got backend={jax.default_backend()}, "
-                f"L={L}. Drop the explicit flash request to use the "
-                "portable path.")
-        # env-enabled off-TPU: portable fallback
+        impl = select_attention(causal, L, D, q.dtype, backend)
+        if mosaic_refuses:
+            impl = "xla"
+    elif impl not in ATTN_IMPLS:
+        raise ValueError(f"attention impl must be one of {ATTN_IMPLS}, "
+                         f"got {impl!r}")
+    block = _splash_block(L)
+    if impl == "splash" and (not causal or block is None or mosaic_refuses):
+        raise ValueError(
+            f"splash attention cannot run here (causal={causal}, L={L}, "
+            f"jax_enable_x64={jax.config.jax_enable_x64}): it needs causal "
+            "attention, a local length that is a multiple of "
+            f"{_SPLASH_BLOCKS[-1]} and, on the TPU, 64-bit types off")
+    _attn_counter().labels(impl=impl).inc()
+    if impl == "splash":
+        return _splash_causal_attention(q, k, v, block,
+                                        interpret=backend != "tpu")
     scale = 1.0 / (D ** 0.5)
     # native-dtype inputs + f32 ACCUMULATION: on bf16 configs the MXU runs
     # bf16 matmuls accumulating in f32 (upcasting the operands instead

@@ -23,9 +23,9 @@ def env_truthy(name: str) -> bool | None:
     applies its own default), else the shared 0/false/off/empty rule.
 
     The ONE parser for the framework's feature toggles
-    (``DISTLEARN_TPU_FUSED``, ``DISTLEARN_TPU_FLASH``, ...) — the fused
-    kernels and the attention dispatch previously each had a copy, which
-    is exactly how the accepted spellings drift apart."""
+    (``DISTLEARN_TPU_FUSED``, ``DISTLEARN_OBS``, ...) — each user once
+    had a copy, which is exactly how the accepted spellings drift
+    apart."""
     value = os.environ.get(name)
     if value is None:
         return None

@@ -39,14 +39,24 @@ def scope_table(hlo_text: str) -> dict[str, str]:
     where ``jax.checkpoint`` computes the forward pass again.
     Instruction names are unique in a module and are the names a device
     trace gives its events, so this table says which part of the model a
-    traced operation belongs to."""
+    traced operation belongs to.
+
+    An instruction's text may run over several lines — a Pallas call that
+    hands the profiler its own ``kernel_metadata`` prints it with line
+    breaks BEFORE its ``metadata={op_name=...}`` — so the ``op_name`` is
+    looked for from the instruction's first line up to the next
+    instruction's."""
     table = {}
+    name = None
     for line in hlo_text.splitlines():
         m = _HLO_INSTRUCTION.match(line)
         if m:
+            name = m.group(1)
+        if name is not None:
             op = _HLO_OP_NAME.search(line)
             if op:
-                table[m.group(1)] = op.group(1)
+                table[name] = op.group(1)
+                name = None
     return table
 
 

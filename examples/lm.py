@@ -48,9 +48,10 @@ def main():
         "seqLayout": ("contig", "sequence shard layout: contig | zigzag "
                       "(zigzag balances the causal ring so masked blocks "
                       "are never computed; needs --seqImpl ring)"),
-        "attnImpl": ("", "single-device attention kernel: '' (env default)"
-                     " | xla | flash | chunked (chunked = causal FLOP skip"
-                     " + saved softmax weights — the measured v5e winner)"),
+        "attnImpl": ("", "force the single-device attention path: '' "
+                     "(chosen from shape, dtype and backend) | xla | splash "
+                     "(blockwise Pallas kernel: causal block skip, no "
+                     "[B,H,L,L] buffer; interpreted off the TPU)"),
         "scanBlocks": (False, "scanned-depth layout: block params stacked,"
                        " depth loop as one lax.scan (program size flat in"
                        " depth; dense models only)"),
@@ -223,15 +224,6 @@ def main():
             log(f"NOTE: --attnImpl {opt.attnImpl} is inert with --sp "
                 f"{opt.sp} > 1 — the ring/all-to-all blockwise path "
                 "takes over (see parallel/sequence.py ring_attention)")
-        elif opt.attnImpl == "chunked":
-            from distlearn_tpu.parallel.sequence import (chunked_engages,
-                                                         resolve_chunk)
-            _L = opt.seqLen // max(1, opt.sp)
-            if not chunked_engages(_L):
-                log(f"NOTE: --attnImpl chunked falls back to xla at "
-                    f"local length {_L} with chunk {resolve_chunk(_L)} "
-                    "(needs L > chunk and L % chunk == 0); use a longer "
-                    "--seqLen or set DISTLEARN_TPU_CHUNK")
         ep_axis = "data" if opt.moeExperts else None
         placed = jax.device_put(
             params, jax.tree_util.tree_map(
@@ -357,6 +349,12 @@ def main():
                 stack.enter_context(trace(opt.profile))
             timer.tick()
             params, loss = step(params, tokens)
+            if i == 1:                    # the step's program is traced now
+                from distlearn_tpu.parallel.sequence import \
+                    attention_paths_traced
+                log(f"single-device attention paths traced "
+                    f"(attn_kernel_total): "
+                    f"{attention_paths_traced() or 'none'}")
             if do_profile and i == prof_stop:
                 jax.block_until_ready(jax.tree_util.tree_leaves(params)[0])
                 timer.reset_window()

@@ -33,13 +33,3 @@ def test_fused_enabled_uses_shared_parser(monkeypatch):
     monkeypatch.setenv("DISTLEARN_TPU_FUSED", "1")
     assert fused_enabled() is True
     assert fused_enabled(override=False) is False   # explicit arg wins
-
-
-def test_flash_enabled_uses_shared_parser(monkeypatch):
-    from distlearn_tpu.parallel.sequence import _flash_enabled
-    monkeypatch.delenv("DISTLEARN_TPU_FLASH", raising=False)
-    assert _flash_enabled(None) is False            # unset defaults off
-    monkeypatch.setenv("DISTLEARN_TPU_FLASH", "on")
-    assert _flash_enabled(None) is True
-    monkeypatch.setenv("DISTLEARN_TPU_FLASH", "off")
-    assert _flash_enabled(None) is False

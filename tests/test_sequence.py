@@ -146,42 +146,6 @@ def test_alltoall_rejects_indivisible_heads():
             q[:, :30], k[:, :30], v[:, :30])
 
 
-def _qkv_long(seed, L=256):
-    rng = np.random.RandomState(seed)
-    mk = lambda: jnp.asarray(rng.randn(B, L, H, D).astype(np.float32))
-    return mk(), mk(), mk()
-
-
-@pytest.mark.skipif(jax.default_backend() != "tpu",
-                    reason="Pallas flash attention is a TPU kernel")
-def test_flash_local_attention_matches_reference():
-    q, k, v = _qkv_long(6)                 # L=256: kernel-block compatible
-    out_f = local_attention(q, k, v, causal=True, flash=True)
-    out_r = local_attention(q, k, v, causal=True, flash=False)
-    np.testing.assert_allclose(np.asarray(out_f), np.asarray(out_r),
-                               rtol=2e-2, atol=2e-2)
-
-
-def test_flash_explicit_request_rejected_when_unsupported(monkeypatch):
-    """flash=True must not be silently ignored: on a non-TPU backend (or
-    incompatible L) it raises instead of materializing the O(L^2) buffer
-    the caller asked to avoid."""
-    monkeypatch.delenv("DISTLEARN_TPU_FLASH", raising=False)
-    q, k, v = _qkv(7)                      # L=32 also violates blocking
-    with pytest.raises(ValueError, match="flash attention needs"):
-        local_attention(q, k, v, causal=True, flash=True)
-
-
-def test_flash_env_fallback_on_unsupported(monkeypatch):
-    """Env-enabled flash falls back to the portable path where the kernel
-    can't run (CPU mesh / L % 128 != 0) — same numbers as flash off."""
-    monkeypatch.setenv("DISTLEARN_TPU_FLASH", "1")
-    q, k, v = _qkv(8)
-    out = local_attention(q, k, v, causal=True)        # flash=None -> env
-    ref = local_attention(q, k, v, causal=True, flash=False)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-
-
 def test_bf16_attention_matches_f32_reference():
     """bf16 operands feed the matmuls natively with f32 accumulation
     (softmax stats stay f32): both the local and the ring path must stay
@@ -207,59 +171,179 @@ def test_bf16_attention_matches_f32_reference():
                                rtol=0.02, atol=0.01)
 
 
-# --- chunked causal attention (parallel/sequence.py chunked_causal_attention)
+# --- the single-device path: chosen from the shape, or forced ---------------
+
+_BF16, _F32 = "bfloat16", "float32"
 
 
-def test_chunked_causal_matches_local():
-    """The chunk-skipped score computation is the same math as the full
-    masked path — forward and gradients (the saved-softmax backward)."""
-    from distlearn_tpu.parallel.sequence import chunked_causal_attention
-    rng = np.random.RandomState(3)
-    mk = lambda: jnp.asarray(rng.randn(2, 64, 4, 16).astype(np.float32))
-    q, k, v = mk(), mk(), mk()
+@pytest.mark.parametrize("causal,L,D,dtype,backend,path", [
+    # the benchmark's shape, chip_smoke's long one, a wider head, float32
+    (True, 1024, 64, _BF16, "tpu", "splash"),
+    (True, 4096, 64, _BF16, "tpu", "splash"),
+    (True, 2048, 128, _BF16, "tpu", "splash"),
+    (True, 1024, 64, _F32, "tpu", "splash"),
+    (True, 1536, 64, _BF16, "tpu", "splash"),
+    # not causal: nothing to skip
+    (False, 1024, 64, _BF16, "tpu", "xla"),
+    (False, 4096, 128, _F32, "tpu", "xla"),
+    # under the shortest length measured to win (the serve engine's prefill
+    # buckets, the CPU tests' toy lengths)
+    (True, 512, 64, _BF16, "tpu", "xla"),
+    (True, 128, 64, _BF16, "tpu", "xla"),
+    (True, 32, 16, _F32, "tpu", "xla"),
+    # ragged: not a multiple of 128, or only of the 128-wide block, which
+    # loses to the full square
+    (True, 1000, 64, _BF16, "tpu", "xla"),
+    (True, 1100, 64, _BF16, "tpu", "xla"),
+    (True, 1152, 64, _BF16, "tpu", "xla"),
+    # ... while a 256-wide block still wins
+    (True, 1280, 64, _BF16, "tpu", "splash"),
+    # a head size the kernel's lanes do not take; a dtype never measured
+    (True, 1024, 16, _BF16, "tpu", "xla"),
+    (True, 1024, 80, _BF16, "tpu", "xla"),
+    (True, 1024, 64, "float16", "tpu", "xla"),
+    # the kernel is a TPU kernel
+    (True, 1024, 64, _BF16, "cpu", "xla"),
+    (True, 4096, 64, _BF16, "gpu", "xla"),
+])
+def test_select_attention_table(causal, L, D, dtype, backend, path):
+    from distlearn_tpu.parallel.sequence import select_attention
+    assert select_attention(causal, L, D, dtype, backend) == path
+    assert select_attention(causal, L, D, jnp.dtype(dtype), backend) == path
 
+
+def test_select_attention_reads_no_environment(monkeypatch):
+    """The retired gates decide nothing: with every one of them set the
+    choice and the numbers are what they are without."""
+    from distlearn_tpu.parallel.sequence import select_attention
+    q, k, v = _qkv(8)
     ref = local_attention(q, k, v, causal=True, impl="xla")
-    got = chunked_causal_attention(q, k, v, chunk=16)
+    for name, value in (("DISTLEARN_TPU_ATTN", "splash"),
+                        ("DISTLEARN_TPU_FLASH", "1"),
+                        ("DISTLEARN_TPU_CHUNK", "16")):
+        monkeypatch.setenv(name, value)
+    assert select_attention(True, 1024, 64, _BF16, "cpu") == "xla"
+    assert select_attention(True, 1024, 64, _BF16, "tpu") == "splash"
+    np.testing.assert_array_equal(
+        np.asarray(local_attention(q, k, v, causal=True)), np.asarray(ref))
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_default_path_here_is_the_full_square_bit_for_bit(causal):
+    q, k, v = _qkv(9)
+    np.testing.assert_array_equal(
+        np.asarray(local_attention(q, k, v, causal=causal)),
+        np.asarray(local_attention(q, k, v, causal=causal, impl="xla")))
+
+
+def _qkv_heads64(L, seed, batch=1, heads=2, head=64):
+    rng = np.random.RandomState(seed)
+    mk = lambda: jnp.asarray(                             # noqa: E731
+        rng.randn(batch, L, heads, head).astype(np.float32))
+    return mk(), mk(), mk()
+
+
+def _loss_and_grads(impl, q, k, v):
+    def loss(a, b, c):
+        out = local_attention(a, b, c, causal=True, impl=impl)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+# 1024 = the shortest length the default engages at (two 512 blocks a side:
+# one block skipped, two on the diagonal, one unmasked); 384 = three 128
+# blocks a side, the narrowest edge a forced call takes; a 64-wide head goes
+# to the kernel sequence-minor, a 128-wide one head-minor
+@pytest.mark.parametrize("L,D", [(384, 64), (1024, 64), (512, 128)])
+def test_splash_matches_full_square_float32(L, D):
+    """The blockwise kernel (Pallas interpret mode here) is the full-square
+    path's math: forward and all three gradients, float32, tight."""
+    q, k, v = _qkv_heads64(L, seed=10, head=D)
+    got = local_attention(q, k, v, causal=True, impl="splash")
+    ref = local_attention(q, k, v, causal=True, impl="xla")
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-6)
-
-    g_ref = jax.grad(lambda a: jnp.sum(
-        local_attention(a, k, v, causal=True, impl="xla") ** 2))(q)
-    g_got = jax.grad(lambda a: jnp.sum(
-        chunked_causal_attention(a, k, v, chunk=16) ** 2))(q)
-    np.testing.assert_allclose(np.asarray(g_got), np.asarray(g_ref),
-                               rtol=2e-4, atol=2e-5)
+    for a, b in zip(_loss_and_grads("splash", q, k, v),
+                    _loss_and_grads("xla", q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=5e-5)
 
 
-def test_chunked_causal_ragged_falls_back():
-    """L not divisible by the chunk (or too short) silently uses the xla
-    path — same numbers either way."""
-    from distlearn_tpu.parallel.sequence import chunked_causal_attention
-    rng = np.random.RandomState(4)
-    mk = lambda: jnp.asarray(rng.randn(1, 24, 2, 8).astype(np.float32))
-    q, k, v = mk(), mk(), mk()
-    got = chunked_causal_attention(q, k, v, chunk=16)
-    ref = local_attention(q, k, v, causal=True, impl="xla")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=1e-6, atol=1e-7)
+def test_splash_bf16_matches_f32_oracle():
+    """bf16 operands, float32 scores and statistics: forward within today's
+    bf16 tolerance of the float32 oracle, gradients as close to it as the
+    full-square path's bf16 gradients are."""
+    q, k, v = _qkv_heads64(1024, seed=11)
+    qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))
+    ref = np.asarray(local_attention(q, k, v, causal=True, impl="xla"))
+    got = local_attention(qb, kb, vb, causal=True, impl="splash")
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(got, np.float32), ref,
+                               rtol=0.05, atol=0.02)
+    g_ref = _loss_and_grads("xla", q, k, v)
+    g_xla = _loss_and_grads("xla", qb, kb, vb)
+    g_got = _loss_and_grads("splash", qb, kb, vb)
+    for r, x, g in zip(g_ref, g_xla, g_got):
+        assert g.dtype == jnp.bfloat16
+        r = np.asarray(r)
+        err_xla = np.abs(np.asarray(x, np.float32) - r).max()
+        err_got = np.abs(np.asarray(g, np.float32) - r).max()
+        assert err_got <= max(2 * err_xla, 0.02 * np.abs(r).max())
 
 
-def test_local_attention_impl_validation():
+@pytest.mark.parametrize("causal,L", [(False, 256), (True, 100), (True, 32)])
+def test_forced_splash_raises_where_it_cannot_run(causal, L):
+    """A forced path is never silently swapped for another one."""
+    q = jnp.zeros((1, L, 1, 64))
+    with pytest.raises(ValueError, match="splash attention cannot run"):
+        local_attention(q, q, q, causal=causal, impl="splash")
+
+
+@pytest.mark.parametrize("impl", ["bogus", "flash", "chunked"])
+def test_local_attention_impl_validation(impl):
     q = jnp.zeros((1, 8, 1, 4))
     with pytest.raises(ValueError, match="impl"):
-        local_attention(q, q, q, impl="bogus")
+        local_attention(q, q, q, impl=impl)
 
 
-def test_local_attention_chunked_impl_dispatch():
-    """impl='chunked' on a causal call routes through the chunked path and
-    still matches the oracle (CPU: flash unsupported, chunked is portable)."""
-    rng = np.random.RandomState(5)
-    mk = lambda: jnp.asarray(rng.randn(1, 2048, 2, 8).astype(np.float32))
-    q, k, v = mk(), mk(), mk()
-    got = local_attention(q, k, v, causal=True, impl="chunked")
-    ref = local_attention(q, k, v, causal=True, impl="xla")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=2e-5, atol=2e-6)
+def test_64_bit_types_keep_the_full_square_path_on_the_tpu(monkeypatch):
+    """Mosaic takes no int64 loop counter: with ``jax_enable_x64`` on (as
+    in these tests) a call that would pick the kernel on the TPU keeps the
+    full-square path, and forcing the kernel there raises."""
+    from distlearn_tpu.parallel import sequence
+    assert jax.config.jax_enable_x64
+    monkeypatch.setattr(sequence, "_backend", lambda: "tpu")
+    q, k, v = _qkv_heads64(1024, seed=13)
+    assert sequence.select_attention(True, 1024, 64, q.dtype,
+                                     "tpu") == "splash"
+    np.testing.assert_array_equal(
+        np.asarray(local_attention(q, k, v, causal=True)),
+        np.asarray(local_attention(q, k, v, causal=True, impl="xla")))
+    with pytest.raises(ValueError, match="jax_enable_x64=True"):
+        local_attention(q, k, v, causal=True, impl="splash")
+
+
+def test_attn_kernel_counter_counts_the_resolved_path():
+    """``attn_kernel_total{impl=}`` moves once per traced call, under the
+    path the call resolved to — not once per run of a jitted program."""
+    from distlearn_tpu.parallel.sequence import \
+        attention_paths_traced as calls
+
+    q, k, v = _qkv_heads64(256, seed=12)
+    before = calls()
+    local_attention(q, k, v, causal=True)                  # resolves: xla
+    local_attention(q, k, v, causal=True, impl="splash")
+    jitted = jax.jit(lambda a, b, c: local_attention(a, b, c, causal=False))
+    jitted(q, k, v)
+    jitted(q, k, v)                                        # traced once
+    with pytest.raises(ValueError):
+        local_attention(q, k, v, impl="bogus")             # counts nothing
+    with pytest.raises(ValueError):
+        local_attention(q, k, v, impl="splash")            # not causal: nor
+    after = calls()
+    assert after.get("xla", 0) - before.get("xla", 0) == 2
+    assert after.get("splash", 0) - before.get("splash", 0) == 1
+    assert set(after) <= {"xla", "splash"}
 
 
 # --- zigzag causal ring attention (balanced layout, masked-block skip) ------

@@ -354,6 +354,25 @@ def test_decode_programs_get_the_shared_functions_scopes():
         assert f"/{scope}/" in names, scope
 
 
+def test_scope_table_reads_an_instruction_that_runs_over_lines():
+    """A Pallas call that hands the profiler ``kernel_metadata`` prints it
+    with line breaks before its ``op_name``; an instruction with no
+    ``op_name`` must not borrow its neighbour's."""
+    from distlearn_tpu.utils.profiling import scope_table
+    text = """
+  %copy-done.1 = bf16[8,20,1024,64]{3,2,1,0} copy-done(%copy-start.1)
+  %splash_mha_fwd.7 = (f32[8,512,128]{2,1,0}, bf16[8,20,1024,64]{3,2,1,0}) custom-call(%copy-done.1), custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={
+"xprof_metadata":"{\\"block_q\\": 512}"
+}}, metadata={op_name="jit(step)/jvp()/while/body/checkpoint/attn_core/pallas_call" stack_frame_id=6}, backend_config={}
+  %bitcast.3 = bf16[8,1024,20,64]{3,1,2,0} bitcast(%custom-call)
+  ROOT %fusion.2 = f32[8]{0} fusion(%bitcast.3), kind=kLoop, metadata={op_name="jit(step)/jvp()/mlp/add"}
+"""
+    assert scope_table(text) == {
+        "splash_mha_fwd.7":
+            "jit(step)/jvp()/while/body/checkpoint/attn_core/pallas_call",
+        "fusion.2": "jit(step)/jvp()/mlp/add"}
+
+
 @pytest.fixture(scope="module")
 def v5e_chip():
     """One described (not attached) v5e chip: the TPU compiler runs here
@@ -368,12 +387,22 @@ def v5e_chip():
     return topo.devices[0]
 
 
-def test_tpu_compiler_keeps_every_scope_at_gpt2_large_width(v5e_chip):
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+def test_tpu_compiler_keeps_every_scope_at_gpt2_large_width(
+        v5e_chip, backend, monkeypatch):
     """The benchmark's step at GPT-2-large's widths (1280 x 20 heads, vocab
     50257, 8 x 1024 tokens, bf16, scanned, full remat; depth cut to 2: the
     scan makes the program the same), compiled for the v5e: every declared
     scope survives the TPU compiler's fusion, in every pass it belongs to.
-    Nothing runs; no number of this is a measurement."""
+    Nothing runs; no number of this is a measurement.
+
+    ``local_attention`` picks its path from ``jax.default_backend()``,
+    which here says "cpu" whatever the program is compiled for: ``"tpu"``
+    steers it to what the chip runs (the blockwise kernel: three Mosaic
+    calls under ``attn_core``, one a pass, and no ``[B, H, L, L]`` array),
+    ``"cpu"`` leaves the full-square path short or ragged lengths keep."""
+    from distlearn_tpu.parallel import sequence
+    monkeypatch.setattr(sequence, "_backend", lambda: backend)
     from jax.experimental.compilation_cache import compilation_cache
     from distlearn_tpu.models.core import SCOPES
     from distlearn_tpu.train.lm import build_lm_step
@@ -397,12 +426,31 @@ def test_tpu_compiler_keeps_every_scope_at_gpt2_large_width(v5e_chip):
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        text = build_lm_step(model, mesh, template, lr=0.03).lower(
-            params, tokens).compile().as_text()
+        # conftest turns 64-bit types on; a program for the chip has them
+        # off (with them on local_attention keeps the full-square path:
+        # Mosaic takes no int64 loop counter)
+        with jax.enable_x64(False):
+            text = build_lm_step(model, mesh, template, lr=0.03).lower(
+                params, tokens).compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
         compilation_cache.reset_cache()
-    names = list(scope_table(text).values())
+    table = scope_table(text)
+    names = list(table.values())
+    kernels = {k: v for k, v in table.items() if k.startswith("splash_mha")}
+    square = "[8,20,1024,1024]" in text
+    if backend == "tpu":
+        assert text.count('custom_call_target="tpu_custom_call"') == 3
+        assert not square
+        passes = sorted(
+            ("recompute" if "rematted_computation" in n else
+             "bwd" if "transpose(" in n else "fwd", k.split(".")[0])
+            for k, n in kernels.items() if "/attn_core/" in n)
+        assert passes == [("bwd", "splash_mha_dkv_no_residuals"),
+                          ("fwd", "splash_mha_fwd_residuals"),
+                          ("recompute", "splash_mha_fwd_residuals")]
+    else:
+        assert square and not kernels
 
     def seen(scope, *marks, without=()):
         return any(f"/{scope}/" in n.replace("(", "/").replace(")", "/")
