@@ -15,6 +15,12 @@ models the repo supports, with seeded random weights and synthetic data:
   step at seq 4096; at both lengths the attention ``local_attention`` picks
   by default must be the blockwise kernel (the ``obs`` counter
   ``attn_kernel_total`` says which path each traced call resolved to).
+* ``hybrid_lm`` — the pattern LM (models/hybrid.py) at ITS published widths
+  (4096 wide, 64 query heads over 8 K/V heads of 128, 64 KDA heads of 128,
+  experts 1280 wide, a router of 320, 8 a token; one period of 4 layers, 2
+  experts held, an eighth of an eighth of the vocabulary) through the same
+  build_lm_step at 1 x 2048 tokens, bf16, full remat: the grouped-query
+  layer on the blockwise kernel, no assignment dropped, losses that fall.
 * ``serve`` — examples/lm.py --serve's path at the same width: DecodeEngine
   (8 slots, max_len 1024) behind ServeServer, ServeClients on threads over
   the framed-TCP port: prompts in several prefill buckets, a prefix-cache
@@ -236,6 +242,26 @@ def phase_trainer(*, num_nodes: int, per_node_batch: int = 256,
     return out
 
 
+def _lower_default(step, params, tokens, what):
+    """Lower a step built with no ``attn_impl`` and say which attention
+    ``local_attention`` resolved while it was traced (the ``obs``
+    counter).  On the TPU it must be the blockwise kernel alone, as a
+    Mosaic call."""
+    import jax
+    from distlearn_tpu.parallel.sequence import attention_paths_traced
+    before = attention_paths_traced()
+    lowered = step.lower(params, tokens)
+    traced = {k: v - before.get(k, 0)
+              for k, v in attention_paths_traced().items()
+              if v - before.get(k, 0)}
+    if jax.default_backend() == "tpu":
+        _require(set(traced) == {"splash"},
+                 f"{what}: the blockwise attention did not engage by "
+                 f"default (attn_kernel_total moved by {traced})")
+        _require_mosaic(lowered, f"{what}: blockwise attention")
+    return lowered, traced
+
+
 # -------------------------------------------------------------------- lm --
 
 def phase_lm(*, mesh_shape: tuple[int, int, int] = (1, 1, 1),
@@ -250,7 +276,6 @@ def phase_lm(*, mesh_shape: tuple[int, int, int] = (1, 1, 1),
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from distlearn_tpu.models.transformer import param_specs, transformer_lm
-    from distlearn_tpu.parallel.sequence import attention_paths_traced
     from distlearn_tpu.train.lm import build_lm_step
 
     dp, sp, tp = mesh_shape
@@ -274,29 +299,12 @@ def phase_lm(*, mesh_shape: tuple[int, int, int] = (1, 1, 1),
             .astype(np.int32), NamedSharding(mesh, P("data", "seq")))
         return step, placed, tokens
 
-    def lower_default(step, params, tokens, what):
-        """Lower a step built with no ``attn_impl`` and say which attention
-        ``local_attention`` resolved while it was traced (the ``obs``
-        counter).  On the TPU it must be the blockwise kernel alone, as a
-        Mosaic call."""
-        before = attention_paths_traced()
-        lowered = step.lower(params, tokens)
-        traced = {k: v - before.get(k, 0)
-                  for k, v in attention_paths_traced().items()
-                  if v - before.get(k, 0)}
-        if jax.default_backend() == "tpu":
-            _require(set(traced) == {"splash"},
-                     f"{what}: the blockwise attention did not engage by "
-                     f"default (attn_kernel_total moved by {traced})")
-            _require_mosaic(lowered, f"{what}: blockwise attention")
-        return lowered, traced
-
     step, params, tokens = build(seq, batch)
     _require(_distinct_devices(tokens) == n_dev,
              "tokens do not live on every mesh device")
     _require(_distinct_devices(params["block0"]["wq"]) == n_dev,
              "block0/wq does not live on every mesh device")
-    lowered, traced = lower_default(step, params, tokens, f"seq {seq}")
+    lowered, traced = _lower_default(step, params, tokens, f"seq {seq}")
     out["attn_kernels"] = {str(seq): traced}
     _require(n_dev == 1 or "all-reduce" in lowered.compile().as_text(),
              f"compiled LM step holds no all-reduce on mesh {mesh_shape}")
@@ -321,13 +329,67 @@ def phase_lm(*, mesh_shape: tuple[int, int, int] = (1, 1, 1),
     # long context, one step, default attention again (selective remat =
     # bench.py's long-context recipe)
     lstep, lparams, ltokens = build(long_seq, dp, remat="mlp")
-    _, traced = lower_default(lstep, lparams, ltokens, f"seq {long_seq}")
+    _, traced = _lower_default(lstep, lparams, ltokens,
+                               f"seq {long_seq}")
     out["attn_kernels"][str(long_seq)] = traced
     lparams, loss = lstep(lparams, ltokens)
     _require(np.isfinite(float(loss)), "non-finite long-context loss")
     out.update(long_seq=long_seq, long_loss=round(float(loss), 4))
     del lparams
     return out
+
+
+# ------------------------------------------------------------- hybrid lm --
+
+def phase_hybrid_lm(*, vocab: int = 3072, dim: int = 4096, heads: int = 64,
+                    kv_heads: int = 8, head_dim: int = 128,
+                    kda_heads: int = 64, kda_head_dim: int = 128,
+                    experts: int = 320, held: tuple = (0, 1), top_k: int = 8,
+                    expert_width: int = 1280, seq: int = 2048,
+                    steps: int = 3, lr: float = 0.003,
+                    bf16: bool = True) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import random
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from distlearn_tpu.models import hybrid_lm
+    from distlearn_tpu.train.lm import (build_lm_routing_metrics,
+                                        build_lm_step)
+
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1, 1),
+                ("data", "seq", "model"))
+    model = hybrid_lm(
+        vocab=vocab, dim=dim, layer_types=("gqa", "kda", "kda", "kda"),
+        heads=heads, kv_heads=kv_heads, head_dim=head_dim,
+        kda_heads=kda_heads, kda_head_dim=kda_head_dim,
+        n_routed_experts=experts, held_experts=held, experts_per_tok=top_k,
+        expert_width=expert_width, max_len=seq,
+        compute_dtype=jnp.bfloat16 if bf16 else None, remat="full")
+    params = jax.jit(lambda k: model.init(k)[0])(random.PRNGKey(0))
+    tokens = jax.device_put(
+        np.random.RandomState(0).randint(0, vocab, (1, seq)).astype(np.int32),
+        NamedSharding(mesh, P("data", "seq")))
+    routing = build_lm_routing_metrics(model, mesh, params)(params, tokens)
+    _require(int(routing["dropped"].sum()) == 0,
+             f"the dropless expert layer dropped: {routing['dropped']}")
+    _require(int(routing["assignments"].sum()) > 0,
+             "no token was routed to a held expert")
+    step = build_lm_step(model, mesh, params, lr=lr)
+    lowered, traced = _lower_default(step, params, tokens, "hybrid LM")
+    mosaic = lowered.as_text().count("tpu_custom_call")
+    losses = []
+    for _ in range(steps):
+        params, loss = step(params, tokens)
+        losses.append(float(loss))
+    _require(np.isfinite(losses).all(), f"non-finite hybrid LM loss: {losses}")
+    _require(losses[-1] < losses[0], f"hybrid LM loss did not fall: {losses}")
+    return {"dim": dim, "seq": seq, "held": list(held), "attn_kernels": traced,
+            "mosaic_calls": mosaic, "losses": [round(l, 4) for l in losses],
+            "assignments": routing["assignments"].tolist(),
+            "unheld_frac": [round(float(x), 4)
+                            for x in routing["unheld_frac"]]}
 
 
 # ----------------------------------------------------------------- serve --
@@ -552,6 +614,7 @@ def main() -> int:
     phases = (
         ("trainer", lambda: phase_trainer(num_nodes=n)),
         ("lm", lambda: phase_lm(mesh_shape=lm_mesh, batch=8 * lm_mesh[0])),
+        ("hybrid_lm", phase_hybrid_lm),
         ("serve", phase_serve),
         ("wire_kernels", phase_wire_kernels),
     )

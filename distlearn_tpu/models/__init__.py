@@ -8,6 +8,8 @@ from distlearn_tpu.models.cifar_convnet import cifar_convnet
 from distlearn_tpu.models.resnet import resnet, resnet50
 from distlearn_tpu.models.transformer import (greedy_generate,
                                               transformer_lm)
+from distlearn_tpu.models.hybrid import hybrid_lm
 
 __all__ = ["Model", "loss_fn", "param_count", "mnist_cnn", "cifar_convnet",
-           "resnet", "resnet50", "transformer_lm", "greedy_generate"]
+           "resnet", "resnet50", "transformer_lm", "greedy_generate",
+           "hybrid_lm"]

@@ -25,8 +25,13 @@ PyTree = Any
 #: one of them.  Forward / backward / recomputation are NOT scopes — JAX
 #: marks those itself (``jvp(``, ``transpose(``, ``rematted_computation``).
 #: The one list: trace readers import it, nothing else spells the names.
+#: ``linattn_core`` (a linear-attention layer's convolution, normalisation,
+#: decay and chunked delta rule) and ``moe`` (router, grouping, the held
+#: experts' grouped products, combine) are ``models/hybrid.py``'s; the
+#: dense :func:`~distlearn_tpu.models.transformer.transformer_lm` uses the
+#: list less those two.
 SCOPES = ("embed", "norm", "attn_proj", "attn_core", "mlp", "head_loss",
-          "grad_reduce", "update")
+          "grad_reduce", "update", "linattn_core", "moe")
 
 
 class Model(NamedTuple):

@@ -1,0 +1,309 @@
+"""A decoder-only LM whose layers are a PATTERN: softmax attention with
+grouped queries and an output gate in some, gated delta-rule linear attention
+(KDA) in the others, and in every layer a routed mixture of SwiGLU experts
+beside a shared one.  The second LM constructor beside
+:func:`distlearn_tpu.models.transformer.transformer_lm`; it returns the same
+:class:`~distlearn_tpu.models.core.Model` and its ``apply`` takes the same
+keywords, so ``lm_loss`` and every LM step builder drive it unchanged.
+
+One layer (pre-norm, residual, no bias, no positional term of any kind —
+the causal mask, the convolution and the recurrence carry the order):
+
+    h = x + Mix(rmsnorm(x));        y = h + MoE(rmsnorm(h))
+
+``Mix`` of a ``"gqa"`` layer, ``H`` query heads over ``Hkv`` K/V heads:
+
+    q, k, v = x Wq, x Wk, x Wv;    a = softmax(q k^T / sqrt(D) + causal) v
+    out = (sigmoid(x Wg) * a) Wo                    (elementwise gate)
+
+``Mix`` of a ``"kda"`` layer, per head (``conv`` a causal depthwise
+convolution over time):
+
+    q~, k~, v~ = silu(conv(x Wq)), silu(conv(x Wk)), silu(conv(x Wv))
+    q = l2norm(q~) / sqrt(K);      k = l2norm(k~)
+    g_t = -exp(A_h) softplus((x Wa1) Wa2 + b)       per channel, <= 0
+    beta_t = 2 sigmoid(x w_beta)
+    S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t
+    out = (sigmoid((x Wg1) Wg2) * rmsnorm_head(o)) Wo
+
+``MoE`` (:func:`distlearn_tpu.parallel.ep.moe_held_ffn`): a router over all
+``n_routed_experts``, top-k renormalised, of which THIS model holds
+``held_experts`` and computes their part, plus the shared expert on every
+token.
+
+Arithmetic: parameters in ``dtype`` (float32); the matrix products in
+``compute_dtype``; in float32 regardless: the norms' statistics, the softmax
+of attention (inside the kernel), the KDA decay (softplus, exp, cumulative
+sums), ``beta``, the l2 norms, the triangular inverse and the carried state
+(``ops/delta_rule.py``), the router's scores and its softmax.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Sequence
+
+import jax
+import jax.numpy as jnp
+from jax import lax, random
+
+from distlearn_tpu.models.core import Model
+from distlearn_tpu.models.transformer import _norm_init, _rmsnorm
+from distlearn_tpu.ops.delta_rule import chunked_delta_rule
+from distlearn_tpu.parallel.ep import moe_held_ffn
+from distlearn_tpu.parallel.sequence import local_attention
+
+PyTree = Any
+LAYER_TYPES = ("gqa", "kda")
+
+
+def _dense(key, shape, fan_in, dtype):
+    return random.normal(key, shape, dtype) * (1.0 / math.sqrt(fan_in))
+
+
+def causal_conv(x: jax.Array, w: jax.Array, axis: int = 1) -> jax.Array:
+    """Depthwise causal convolution over time (``axis`` of ``x``):
+    ``y_t = sum_j w[j] x_{t-W+1+j}`` with zeros before the start.  ``w[j]``
+    broadcasts against ``x`` with the time axis taken out of neither: x
+    [B, L, C] with w [W, C], or x [B, H, L, K] with w [W, H, 1, K]."""
+    W, L = w.shape[0], x.shape[axis]
+    pad = jnp.pad(x, [(W - 1, 0) if a == axis else (0, 0)
+                      for a in range(x.ndim)])
+    return sum(lax.slice_in_dim(pad, j, j + L, axis=axis) * w[j]
+               for j in range(W))
+
+
+def _l2norm(x):
+    x = x.astype(jnp.float32)
+    return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+def gqa_apply(blk: PyTree, x: jax.Array, cd, eps: float):
+    """The softmax layer's mixer with its residual."""
+    h = _rmsnorm(blk["ln1"], x, eps)
+    with jax.named_scope("attn_proj"):
+        q = jnp.einsum("ble,ehd->blhd", h, blk["wq"].astype(cd))
+        k = jnp.einsum("ble,ehd->blhd", h, blk["wk"].astype(cd))
+        v = jnp.einsum("ble,ehd->blhd", h, blk["wv"].astype(cd))
+        gate = jnp.einsum("ble,ehd->blhd", h, blk["wg"].astype(cd))
+    with jax.named_scope("attn_core"):
+        att = local_attention(q, k, v, causal=True)
+    with jax.named_scope("attn_proj"):
+        att = jax.nn.sigmoid(gate.astype(jnp.float32)).astype(cd) * att
+        return x + jnp.einsum("blhd,hde->ble", att, blk["wo"].astype(cd))
+
+
+def kda_apply(blk: PyTree, x: jax.Array, cd, eps: float):
+    """The linear-attention layer's mixer with its residual.  Everything
+    between the projections is HEAD-MAJOR ([B, H, L, .]): the projections
+    write that layout directly and the output projection reads it, so the
+    chunked core (whose chunks are blocks of one head's positions) costs no
+    transpose."""
+    H, K = blk["dt_bias"].shape
+    rank = blk["wa1"].shape[1]
+    h = _rmsnorm(blk["ln1"], x, eps)
+    with jax.named_scope("attn_proj"):
+        q = jnp.einsum("ble,ehd->bhld", h, blk["wq"].astype(cd))
+        k = jnp.einsum("ble,ehd->bhld", h, blk["wk"].astype(cd))
+        v = jnp.einsum("ble,ehd->bhld", h, blk["wv"].astype(cd))
+        a = jnp.einsum("blr,rhd->bhld", h @ blk["wa1"].astype(cd),
+                       blk["wa2"].astype(cd).reshape(rank, H, K))
+        gate = jnp.einsum("blr,rhd->bhld", h @ blk["wg1"].astype(cd),
+                          blk["wg2"].astype(cd).reshape(rank, H, K))
+        b_in = jnp.einsum("ble,eh->bhl", h, blk["wb"].astype(cd))
+    with jax.named_scope("linattn_core"):
+        def mix(t, w):              # conv over time and SiLU, head by head
+            return jax.nn.silu(causal_conv(
+                t, w.astype(cd).reshape(-1, H, 1, K), axis=2))
+        q = _l2norm(mix(q, blk["conv_q"])) * (1.0 / math.sqrt(K))
+        k = _l2norm(mix(k, blk["conv_k"]))
+        v = mix(v, blk["conv_v"])
+        g = -jnp.exp(blk["a_log"].astype(jnp.float32))[:, None, None] \
+            * jax.nn.softplus(a.astype(jnp.float32)
+                              + blk["dt_bias"].astype(jnp.float32)[:, None])
+        beta = 2.0 * jax.nn.sigmoid(b_in.astype(jnp.float32))
+        # an undeclared name inside the declared scope: a profile, or a
+        # roofline reader, finds the delta rule's own operations by it
+        with jax.named_scope("delta_rule"):
+            o, _ = chunked_delta_rule(q, k, v, g, beta, compute_dtype=cd)
+    o = _rmsnorm(blk["o_norm"], o, eps)                         # per head
+    with jax.named_scope("attn_proj"):
+        o = jax.nn.sigmoid(gate.astype(jnp.float32)).astype(cd) * o.astype(cd)
+        return x + jnp.einsum("bhld,hde->ble", o,
+                              blk["wo"].astype(cd).reshape(H, K, -1))
+
+
+def moe_apply(blk: PyTree, x: jax.Array, cd, eps: float, held, top_k: int,
+              ep_axis: str | None):
+    """Shared expert + the held experts' part of the routed ones, with the
+    residual; returns ``(y, routing counters)``."""
+    B, L, D = x.shape
+    h = _rmsnorm(blk["ln2"], x, eps)
+    with jax.named_scope("mlp"):
+        shared = (jax.nn.silu(h @ blk["ws_gate"].astype(cd))
+                  * (h @ blk["ws_up"].astype(cd))) @ blk["ws_down"].astype(cd)
+    with jax.named_scope("moe"):
+        routed, aux = moe_held_ffn(
+            h.reshape(B * L, D), blk["router"],
+            (blk["we_gate"], blk["we_up"], blk["we_down"]), held, top_k,
+            compute_dtype=cd, ep_axis=ep_axis)
+        return x + shared + routed.reshape(B, L, D), aux
+
+
+def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
+              heads: int, kv_heads: int, head_dim: int,
+              kda_heads: int, kda_head_dim: int, conv_kernel: int = 4,
+              kda_rank: int | None = None,
+              n_routed_experts: int, held_experts: Sequence[int],
+              experts_per_tok: int, expert_width: int,
+              n_shared_experts: int = 1, eps: float = 1e-5,
+              max_len: int = 2048, dtype=jnp.float32, compute_dtype=None,
+              remat: bool | str = False) -> Model:
+    """Returns a :class:`Model` mapping int tokens [B, L] to next-token
+    logits [B, L, vocab] (untied head).
+
+    ``layer_types``: one of :data:`LAYER_TYPES` per layer — the pattern is
+    data.  ``heads`` / ``kv_heads`` / ``head_dim`` size the softmax layers,
+    ``kda_heads`` / ``kda_head_dim`` (keys and values alike) the
+    linear-attention ones, whose decay and output gates are low-rank
+    through ``kda_rank`` (default: ``kda_head_dim``).
+
+    ``n_routed_experts`` is the router's width; ``held_experts`` names the
+    experts whose weights live HERE (a chip's share of an expert-parallel
+    layer, or ``range(n_routed_experts)`` for all of them): the model
+    computes their part of every layer's result and leaves the rest out —
+    see :func:`distlearn_tpu.parallel.ep.moe_held_ffn`.  The shared expert
+    (``n_shared_experts`` x ``expert_width`` wide) runs on every token.
+
+    ``remat`` (True = ``"full"``) wraps each layer in ``jax.checkpoint``.
+    ``apply``'s ``seq_axis`` / ``tp_axis`` may name mesh axes of size 1 (the
+    LM step builders always pass them); sequence or tensor parallelism of
+    these layers is not written and a larger axis raises.  ``ep_axis`` is
+    handed to the expert layer, which raises until its exchange exists.
+
+    ``apply`` returns the routing counters as its state: ``moe_assignments``
+    [layers, held] (assignments each held expert received), and per layer
+    ``moe_unheld_frac`` and ``moe_dropped`` (always 0: the layer has no
+    capacity to overflow)."""
+    layer_types = tuple(layer_types)
+    if not layer_types or any(t not in LAYER_TYPES for t in layer_types):
+        raise ValueError(f"layer_types must be a non-empty sequence of "
+                         f"{LAYER_TYPES}, got {layer_types!r}")
+    if heads % kv_heads:
+        raise ValueError(f"heads={heads} is not a multiple of "
+                         f"kv_heads={kv_heads}")
+    if isinstance(remat, str) and remat != "full":
+        raise ValueError(f"remat must be False, True or 'full', got {remat!r}")
+    held = tuple(int(e) for e in held_experts)
+    G, F = len(held), expert_width
+    Fs = n_shared_experts * expert_width
+    rank = kda_rank or kda_head_dim
+    H, K = kda_heads, kda_head_dim
+    cd = compute_dtype or dtype
+    depth = len(layer_types)
+
+    def init_layer(key, kind):
+        ks = iter(random.split(key, 24))
+        nk = lambda: next(ks)                                # noqa: E731
+        if kind == "gqa":
+            blk = {
+                "wq": _dense(nk(), (dim, heads, head_dim), dim, dtype),
+                "wk": _dense(nk(), (dim, kv_heads, head_dim), dim, dtype),
+                "wv": _dense(nk(), (dim, kv_heads, head_dim), dim, dtype),
+                "wg": _dense(nk(), (dim, heads, head_dim), dim, dtype),
+                "wo": _dense(nk(), (heads, head_dim, dim), heads * head_dim,
+                             dtype),
+            }
+        else:
+            conv = lambda: random.uniform(                   # noqa: E731
+                nk(), (conv_kernel, H * K), dtype, -1.0, 1.0) \
+                / math.sqrt(conv_kernel)
+            # decay rates and time steps drawn as the published layer
+            # initialises them: A in [1, 16], softplus(b) in [1e-3, 1e-1]
+            dt = jnp.exp(random.uniform(nk(), (H, K), dtype,
+                                        math.log(1e-3), math.log(1e-1)))
+            blk = {
+                "wq": _dense(nk(), (dim, H, K), dim, dtype),
+                "wk": _dense(nk(), (dim, H, K), dim, dtype),
+                "wv": _dense(nk(), (dim, H, K), dim, dtype),
+                "conv_q": conv(), "conv_k": conv(), "conv_v": conv(),
+                "wa1": _dense(nk(), (dim, rank), dim, dtype),
+                "wa2": _dense(nk(), (rank, H * K), rank, dtype),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),   # softplus^-1
+                "a_log": jnp.log(random.uniform(nk(), (H,), dtype, 1.0,
+                                                16.0)),
+                "wb": _dense(nk(), (dim, H), dim, dtype),
+                "wg1": _dense(nk(), (dim, rank), dim, dtype),
+                "wg2": _dense(nk(), (rank, H * K), rank, dtype),
+                "o_norm": _norm_init((K,), dtype),
+                "wo": _dense(nk(), (H * K, dim), H * K, dtype),
+            }
+        blk.update({
+            "ln1": _norm_init((dim,), dtype),
+            "ln2": _norm_init((dim,), dtype),
+            "router": _dense(nk(), (dim, n_routed_experts), dim, dtype),
+            "ws_gate": _dense(nk(), (dim, Fs), dim, dtype),
+            "ws_up": _dense(nk(), (dim, Fs), dim, dtype),
+            "ws_down": _dense(nk(), (Fs, dim), Fs, dtype),
+            "we_gate": _dense(nk(), (G, dim, F), dim, dtype),
+            "we_up": _dense(nk(), (G, dim, F), dim, dtype),
+            "we_down": _dense(nk(), (G, F, dim), F, dtype),
+        })
+        return blk
+
+    def init(key):
+        keys = random.split(key, depth + 2)
+        params = {"embed": _dense(keys[0], (vocab, dim), dim, dtype),
+                  "head": _dense(keys[1], (dim, vocab), dim, dtype),
+                  "out_norm": _norm_init((dim,), dtype)}
+        for i, kind in enumerate(layer_types):
+            params[f"layer{i}"] = init_layer(keys[2 + i], kind)
+        return params, {}
+
+    def apply(params, state, tokens, train=True, rng=None, axis_name=None,
+              bn_weight=None, seq_axis=None, tp_axis=None, ep_axis=None,
+              seq_layout="contig"):
+        for what, axis in (("sequence", seq_axis), ("tensor", tp_axis)):
+            if axis is not None and lax.axis_size(axis) != 1:
+                raise NotImplementedError(
+                    f"hybrid_lm: {what} parallelism over axis {axis!r} of "
+                    f"size {lax.axis_size(axis)} is not written for these "
+                    "layers (the delta rule's state would have to cross "
+                    "the shards); use a size-1 axis")
+        if seq_layout != "contig":
+            raise ValueError("hybrid_lm keeps the sequence contiguous, got "
+                             f"seq_layout={seq_layout!r}")
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens].astype(cd)
+
+        def make_layer(kind):
+            def layer(blk, x):
+                if kind == "gqa":
+                    x = gqa_apply(blk, x, cd, eps)
+                else:
+                    x = kda_apply(blk, x, cd, eps)
+                return moe_apply(blk, x, cd, eps, held, experts_per_tok,
+                                 ep_axis)
+            return jax.checkpoint(layer) if remat else layer
+
+        # one wrapper a kind, reused down the depth (transformer_lm's note:
+        # a fresh checkpoint closure a layer stops XLA sharing the
+        # rematerialised computation)
+        layers = {kind: make_layer(kind) for kind in set(layer_types)}
+        counters = []
+        for i, kind in enumerate(layer_types):
+            x, aux = layers[kind](params[f"layer{i}"], x)
+            counters.append(aux)
+        x = _rmsnorm(params["out_norm"], x, eps)
+        with jax.named_scope("head_loss"):
+            logits = (x @ params["head"].astype(cd)).astype(dtype)
+        state = dict(
+            state,
+            moe_assignments=jnp.stack([c["assignments"] for c in counters]),
+            moe_unheld_frac=jnp.stack([c["unheld_frac"] for c in counters]),
+            moe_dropped=jnp.stack([c["dropped"] for c in counters]))
+        return logits, state
+
+    return Model(init=init, apply=apply, name="hybrid_lm",
+                 input_shape=(max_len,), num_classes=vocab)

@@ -21,6 +21,7 @@ the GShard/Switch pattern expressed TPU-natively:
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable
 
 import jax
@@ -192,3 +193,193 @@ def moe_ffn(expert_fn: Callable, expert_params: PyTree, router_w: jax.Array,
                           tiled=True)                       # [E, C, D]
     y = _combine(combine, home)
     return (y, aux) if return_aux else y
+
+
+# --------------------------------------------------------------------------
+# The share of an expert layer one device holds: dropless, grouped
+# --------------------------------------------------------------------------
+#
+# The layer above gives every device ONE expert and every expert a bucket of
+# fixed capacity; what overflows is dropped.  The layer below is TOLD which
+# experts it holds (``held``: any subset of the router's ``E``), routes over
+# all ``E``, drops nothing, and computes the part of the result its own
+# experts give:
+#
+#     s = softmax(x W_r)  in R^E;   I = top-k(s);   w_i = s_i / sum_{j in I} s_j
+#     y = sum_{i in I and held}  w_i  SwiGLU_i(x)
+#
+# What the experts held elsewhere would add is left out; summed over the
+# shares of every holder it is the whole layer (tests/test_hybrid_lm.py).
+# On one device it runs without an exchange.
+
+#: rows of one grouped product: an expert's assignments are padded to a
+#: multiple of it, so a step costs sum_e ceil(count_e / GROUP_TILE) products.
+#: Of 256 and 512, 256 gave the faster step on the v5e at the load the hybrid
+#: LM's cell sends a held expert (about 200 assignments a step: fewer padded
+#: rows), by 0.5 % on every seed (PERF.md section 6, PR 29)
+GROUP_TILE = 256
+
+
+def route_held(router_w: jax.Array, x: jax.Array, top_k: int, held):
+    """Route ``x`` [N, D] over all ``E`` outputs of ``router_w`` [D, E] and
+    group the assignments that fall on the ``held`` experts by expert.
+
+    Scores, softmax and top-k are float32 at full matmul precision whatever
+    the compute dtype: the choice of experts is discrete, so a rounded score
+    does not give a slightly different output but a different expert.
+
+    Returns ``(plan, slot_w, aux)``.  ``plan = (rows, tile_expert,
+    n_tiles)``: ``rows`` [P] the token of every slot (``N`` marks padding),
+    the slots of one expert contiguous and padded to a multiple of
+    :data:`GROUP_TILE`; ``tile_expert`` [P / GROUP_TILE] the index INTO
+    ``held`` each tile belongs to;
+    ``n_tiles`` how many tiles hold anything.  ``P`` is sized for the worst
+    case (every token on ``min(top_k, len(held))`` held experts), so nothing
+    is ever dropped; the work is that of the tiles in use.  ``slot_w`` [P]
+    float32 are the combine weights (differentiable; 0 on padding).
+    ``aux``: ``assignments`` [len(held)] per held expert, ``unheld_frac``
+    the share of tokens none of whose experts is held, and ``dropped`` (held
+    assignments that found no slot: 0 by construction, counted, not
+    assumed)."""
+    N = x.shape[0]
+    E = router_w.shape[1]
+    tile = GROUP_TILE
+    held = tuple(int(h) for h in held)
+    G = len(held)
+    if not 1 <= top_k <= E or not held or len(set(held)) != G \
+            or min(held) < 0 or max(held) >= E:
+        raise ValueError(f"top_k={top_k} and held={held} do not fit a "
+                         f"router of {E} experts")
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    scores = jax.nn.softmax(logits, axis=-1)
+    topv, topi = lax.top_k(scores, top_k)                   # [N, k]
+    weights = topv / jnp.sum(topv, axis=-1, keepdims=True)
+    lut = jnp.full((E,), -1, jnp.int32).at[jnp.asarray(held)].set(
+        jnp.arange(G, dtype=jnp.int32))
+    local = lut[topi].reshape(N * top_k)                    # index into held
+    onehot = (local[:, None] == jnp.arange(G)[None, :]).astype(jnp.int32)
+    counts = jnp.sum(onehot, axis=0)                        # [G]
+    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - 1) * onehot, axis=1)
+    padded = -(-counts // tile) * tile
+    start = jnp.cumsum(padded) - padded
+    P = -(-(N * min(top_k, G) + G * (tile - 1)) // tile) * tile
+    pos = jnp.where(local >= 0, start[jnp.maximum(local, 0)] + rank, P)
+    token = jnp.arange(N * top_k, dtype=jnp.int32) // top_k
+    rows = jnp.full((P,), N, jnp.int32).at[pos].set(token, mode="drop")
+    slot_w = jnp.zeros((P,), jnp.float32).at[pos].set(
+        weights.reshape(-1), mode="drop")
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(jnp.cumsum(padded), jnp.arange(P // tile) * tile,
+                         side="right"), G - 1).astype(jnp.int32)
+    n_tiles = (jnp.sum(padded) // tile).astype(jnp.int32)
+    aux = {"assignments": counts,
+           "unheld_frac": jnp.mean(jnp.all(
+               local.reshape(N, top_k) < 0, axis=1).astype(jnp.float32)),
+           "dropped": jnp.sum(counts) - jnp.sum(rows < N)}
+    return (rows, tile_expert, n_tiles), slot_w, aux
+
+
+def _tile(plan, slot_w, x, i):
+    rows, tile_expert, _ = plan
+    tile = rows.shape[0] // tile_expert.shape[0]
+    idx = lax.dynamic_slice_in_dim(rows, i * tile, tile)
+    w = lax.dynamic_slice_in_dim(slot_w, i * tile, tile)
+    idx = jnp.minimum(idx, x.shape[0] - 1)      # padding: any row, weight 0
+    return idx, w, x[idx], tile_expert[i]
+
+
+def _expert(weights, e, cd):
+    return [lax.dynamic_index_in_dim(a, e, 0, keepdims=False).astype(cd)
+            for a in weights]
+
+
+def _dot(a, b, eq="ij,jk->ik"):
+    return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def grouped_swiglu(x, wg, wu, wd, slot_w, plan, cd):
+    """``y[n] = sum over the slots p of token n of slot_w[p] *
+    SwiGLU_{e(p)}(x[n])`` — the grouped product of :func:`route_held`'s
+    plan.  x [N, D]; wg, wu [G, D, F]; wd [G, F, D] (float32, cast to ``cd``
+    a tile at a time); returns [N, D] float32.  One loop over the tiles IN
+    USE (a dynamic trip count, so the backward pass is written by hand, as
+    a second such loop that recomputes each tile's hidden layer)."""
+    def body(i, y):
+        idx, w, xe, e = _tile(plan, slot_w, x, i)
+        g, u, d = _expert((wg, wu, wd), e, cd)
+        h = jax.nn.silu(_dot(xe, g)) * _dot(xe, u)
+        return y.at[idx].add(_dot(h.astype(cd), d) * w[:, None])
+    return lax.fori_loop(0, plan[2], body,
+                         jnp.zeros(x.shape, jnp.float32))
+
+
+def _gs_fwd(x, wg, wu, wd, slot_w, plan, cd):
+    return grouped_swiglu(x, wg, wu, wd, slot_w, plan, cd), \
+        (x, wg, wu, wd, slot_w, plan)
+
+
+def _gs_bwd(cd, res, dy):
+    x, wg, wu, wd, slot_w, plan = res
+    tile = plan[0].shape[0] // plan[1].shape[0]
+    dy = dy.astype(cd)
+
+    def body(i, carry):
+        dx, dg, du, dd, dw = carry
+        idx, w, xe, e = _tile(plan, slot_w, x, i)
+        g, u, d = _expert((wg, wu, wd), e, cd)
+        a, b = _dot(xe, g), _dot(xe, u)
+        s = jax.nn.sigmoid(a)
+        h = (a * s * b).astype(cd)
+        dyt = dy[idx]
+        dw = lax.dynamic_update_slice_in_dim(
+            dw, jnp.sum(dyt.astype(jnp.float32) * _dot(h, d), axis=-1),
+            i * tile, 0)
+        dye = (dyt * w[:, None].astype(cd)).astype(cd)
+        dh = _dot(dye, d, "ij,kj->ik")
+        db = (dh * a * s).astype(cd)
+        da = (dh * b * s * (1.0 + a * (1.0 - s))).astype(cd)
+        add = lambda acc, v: acc.at[e].add(v)               # noqa: E731
+        return (dx.at[idx].add(_dot(da, g, "ij,kj->ik")
+                               + _dot(db, u, "ij,kj->ik")),
+                add(dg, _dot(xe, da, "ij,ik->jk")),
+                add(du, _dot(xe, db, "ij,ik->jk")),
+                add(dd, _dot(h, dye, "ij,ik->jk")), dw)
+
+    zeros = lambda a: jnp.zeros(a.shape, jnp.float32)       # noqa: E731
+    dx, dg, du, dd, dw = lax.fori_loop(
+        0, plan[2], body, (zeros(x), zeros(wg), zeros(wu), zeros(wd),
+                           zeros(slot_w)))
+    return (dx.astype(x.dtype), dg.astype(wg.dtype), du.astype(wu.dtype),
+            dd.astype(wd.dtype), dw, None)
+
+
+grouped_swiglu.defvjp(_gs_fwd, _gs_bwd)
+
+
+def moe_held_ffn(x: jax.Array, router_w: jax.Array, experts, held,
+                 top_k: int, *, compute_dtype=None,
+                 ep_axis: str | None = None):
+    """The held experts' part of a routed SwiGLU layer (see the section
+    comment above): ``x`` [N, D], ``router_w`` [D, E], ``experts = (wg, wu,
+    wd)`` stacked over ``len(held)``.  Returns ``(y [N, D] in the compute
+    dtype, aux)`` with :func:`route_held`'s counters.
+
+    ``ep_axis``: the mesh axis over which other devices hold the other
+    experts.  With it the same layer is the expert-parallel one — every
+    device routes its own tokens, the assignments go to the holders and the
+    results come home — but that exchange is NOT written yet for a layer
+    that holds several experts a device, and nothing stands in for it: the
+    call raises.  Without it (one device, or experts replicated) the layer
+    computes its share with no exchange at all."""
+    if ep_axis is not None:
+        raise NotImplementedError(
+            "moe_held_ffn over ep_axis: the dropless exchange of "
+            "assignments between devices that each hold several experts is "
+            "not written; without ep_axis the layer computes the share of "
+            "the experts it is told it holds")
+    cd = compute_dtype or x.dtype
+    plan, slot_w, aux = route_held(router_w, x, top_k, held)
+    y = grouped_swiglu(x.astype(cd), *experts, slot_w, plan, cd)
+    return y.astype(cd), aux

@@ -351,6 +351,7 @@ def _splash_causal_attention(q, k, v, block: int, interpret: bool):
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm)
     B, L, H, D = q.shape
+    Hkv = k.shape[2]
     # A head narrower than the 128 lanes is padded to them wherever it is
     # the minor dimension (``[.., L, 64]`` is stored as ``[.., L, 128]``);
     # sequence-minor q/k/v are not.  Measured at D = 64: 22 ms of a 433 ms
@@ -362,14 +363,27 @@ def _splash_causal_attention(q, k, v, block: int, interpret: bool):
         block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
         use_fused_bwd_kernel=True,
         q_layout=layout, k_layout=layout, v_layout=layout)
-    kernel = sk.make_splash_mha(
-        sm.MultiHeadMask([sm.CausalMask((L, L))] * H), block_sizes=sizes,
-        head_shards=1, q_seq_shards=1, interpret=interpret)
     heads_first = lambda a: a.transpose(0, 2, 1, 3)   # noqa: E731
     # scaled in float32, rounded once (exact at D = 64: the scale is 1/8)
     qs = (q.astype(jnp.float32) * (1.0 / (D ** 0.5))).astype(q.dtype)
-    out = jax.vmap(kernel)(heads_first(qs), heads_first(k), heads_first(v))
-    return heads_first(out).astype(q.dtype)
+    if Hkv == H:
+        kernel = sk.make_splash_mha(
+            sm.MultiHeadMask([sm.CausalMask((L, L))] * H), block_sizes=sizes,
+            head_shards=1, q_seq_shards=1, interpret=interpret)
+        out = jax.vmap(kernel)(heads_first(qs), heads_first(k),
+                               heads_first(v))
+        return heads_first(out).astype(q.dtype)
+    # grouped queries: the H / Hkv query heads that share a K/V head ride
+    # one multi-query call, which reads that head's K and V once for all of
+    # them and sums their dK / dV inside the kernel; the K/V heads are a
+    # second vmapped axis
+    group = H // Hkv
+    kernel = sk.make_splash_mqa(
+        sm.MultiHeadMask([sm.CausalMask((L, L))] * group), block_sizes=sizes,
+        head_shards=1, q_seq_shards=1, interpret=interpret)
+    qg = heads_first(qs).reshape(B, Hkv, group, L, D)
+    out = jax.vmap(jax.vmap(kernel))(qg, heads_first(k), heads_first(v))
+    return heads_first(out.reshape(B, H, L, D)).astype(q.dtype)
 
 
 def local_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -377,7 +391,10 @@ def local_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     impl: str | None = None) -> jax.Array:
     """Single-device attention (same layout as the sharded variants), for
     non-sharded runs and as the per-shard kernel of
-    :func:`alltoall_attention`.  q/k/v: [B, L, H, D].
+    :func:`alltoall_attention`.  q: [B, L, H, D]; k/v: [B, L, Hkv, D] with
+    ``Hkv`` dividing ``H`` (grouped queries: query head ``i`` attends K/V
+    head ``i // (H / Hkv)``; ``Hkv == H`` is ordinary multi-head attention
+    and runs exactly the code it always ran).
 
     ``impl=None`` — what every caller in the package passes — resolves
     through :func:`select_attention`.  Naming an implementation
@@ -392,6 +409,11 @@ def local_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     The resolved path is counted in ``attn_kernel_total{impl=}`` (``obs``):
     once per traced call, not per step — a jitted program is traced once."""
     B, L, H, D = q.shape
+    Hkv = k.shape[2]
+    if H % Hkv or v.shape[2] != Hkv:
+        raise ValueError(f"{H} query heads cannot share {Hkv} K / "
+                         f"{v.shape[2]} V heads: the K/V head count must "
+                         "divide the query head count")
     backend = _backend()
     # with 64-bit types on, the kernel's loop counters trace as int64, which
     # Mosaic refuses (the interpreter, off the TPU, takes them)
@@ -415,6 +437,11 @@ def local_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         return _splash_causal_attention(q, k, v, block,
                                         interpret=backend != "tpu")
     scale = 1.0 / (D ** 0.5)
+    if Hkv != H:
+        # full-square path of grouped queries: each K/V head repeated for
+        # its group (short or ragged lengths and the CPU; the blockwise
+        # kernel never copies them)
+        k, v = (jnp.repeat(a, H // Hkv, axis=2) for a in (k, v))
     # native-dtype inputs + f32 ACCUMULATION: on bf16 configs the MXU runs
     # bf16 matmuls accumulating in f32 (upcasting the operands instead
     # would force f32 matmuls — 8x slower on the systolic array — and f32

@@ -208,6 +208,72 @@ def build_lm_moe_metrics(model: Model, mesh: Mesh, params_template,
         check_vma=False))
 
 
+def build_lm_routing_metrics(model: Model, mesh: Mesh, params_template,
+                             data_axis: str = "data",
+                             seq_axis: str | None = "seq",
+                             tp_axis: str | None = "model",
+                             ep_axis: str | None = None) -> Callable:
+    """``metrics(params, tokens) -> dict`` — :func:`build_lm_moe_metrics`'s
+    sibling for the dropless layer of ``models/hybrid.py`` (forward only,
+    same mesh/sharding contract; run at report cadence).  Per layer, summed
+    over the data/seq axes: ``assignments`` [layers, held] (what each held
+    expert received from this batch), ``unheld_frac`` [layers] (the share of
+    tokens none of whose experts is held here) and ``dropped`` [layers]
+    (held assignments that found no slot — 0, the layer has no capacity).
+    Each call also adds them to the ``obs`` counters
+    ``moe_assignments_total{layer,expert}``, ``moe_tokens_total{layer,held}``
+    and ``moe_dropped_total{layer}``."""
+    from distlearn_tpu import obs
+    pspecs = param_specs(params_template, tp_axis, ep_axis)
+    axes = tuple(a for a in (data_axis, seq_axis) if a is not None)
+
+    def metrics(params, tokens):
+        _, st = model.apply(params, {}, tokens, train=True,
+                            seq_axis=seq_axis, tp_axis=tp_axis,
+                            ep_axis=ep_axis)
+        if "moe_assignments" not in st:
+            raise ValueError("model returned no routing counters — build "
+                             "it with models.hybrid.hybrid_lm")
+        out = {"assignments": st["moe_assignments"],
+               "dropped": st["moe_dropped"]}
+        if axes:
+            out = {k: lax.psum(v, axes) for k, v in out.items()}
+        frac = st["moe_unheld_frac"]
+        out["unheld_frac"] = lax.pmean(frac, axes) if axes else frac
+        return out
+
+    tok_spec = P(data_axis, seq_axis) if seq_axis else P(data_axis)
+    device_fn = jax.jit(shard_map(
+        metrics, mesh=mesh, in_specs=(pspecs, tok_spec),
+        out_specs={"assignments": P(), "dropped": P(), "unheld_frac": P()},
+        check_vma=False))
+
+    assigned = obs.counter(
+        "moe_assignments_total", "assignments a held expert received",
+        labels=("layer", "expert"))
+    seen = obs.counter(
+        "moe_tokens_total", "tokens routed, by whether any of their experts "
+        "is held here", labels=("layer", "held"))
+    dropped = obs.counter(
+        "moe_dropped_total", "held assignments that found no slot",
+        labels=("layer",))
+
+    def counted(params, tokens):
+        out = jax.device_get(device_fn(params, tokens))
+        n_tokens = int(tokens.shape[0] * tokens.shape[1])
+        for layer, row in enumerate(out["assignments"]):
+            for expert, n in enumerate(row):
+                assigned.labels(layer=str(layer), expert=str(expert)).inc(
+                    int(n))
+            unheld = round(float(out["unheld_frac"][layer]) * n_tokens)
+            seen.labels(layer=str(layer), held="no").inc(unheld)
+            seen.labels(layer=str(layer), held="yes").inc(n_tokens - unheld)
+            dropped.labels(layer=str(layer)).inc(int(out["dropped"][layer]))
+        return out
+
+    return counted
+
+
 def stack_blocks(params, depth: int):
     """Split a :func:`transformer_lm` param pytree into
     ``(shared, stacked_blocks)``: the embed/pos/out_norm leaves, and the

@@ -11,6 +11,8 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
+import numpy as np  # noqa: E402
+
 import chip_smoke  # noqa: E402
 
 
@@ -34,6 +36,16 @@ def test_lm_phase_toy(devices):
     assert res["attn_kernels"] == {"64": {"xla": 2}, "256": {"xla": 2}}
     assert abs(res["loss_xla"] - res["losses"][0]) < 1e-3
     assert res["long_loss"] > 0
+
+
+def test_hybrid_lm_phase_toy():
+    res = chip_smoke.phase_hybrid_lm(
+        vocab=97, dim=32, heads=4, kv_heads=2, head_dim=8, kda_heads=2,
+        kda_head_dim=8, experts=8, held=(1, 6), top_k=2, expert_width=16,
+        seq=128, steps=3, lr=0.05, bf16=False)
+    assert res["losses"][-1] < res["losses"][0]
+    assert res["attn_kernels"] == {"xla": 1} and res["mosaic_calls"] == 0
+    assert np.shape(res["assignments"]) == (4, 2)
 
 
 def test_serve_phase_toy():

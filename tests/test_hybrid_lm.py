@@ -1,0 +1,230 @@
+"""The pattern LM (``models/hybrid.py``) and the expert layer that is told
+which experts it holds (``parallel/ep.py``), at toy size on the CPU mesh:
+the SHARE test (every holder's part plus the shared expert once is the whole
+layer), no assignment ever dropped, the grouped product's hand-written
+backward pass, the routing counters, the LM step builders driving the model
+unchanged, and what is refused until it is written."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from distlearn_tpu import obs
+from distlearn_tpu.models import hybrid_lm
+from distlearn_tpu.models.hybrid import causal_conv, moe_apply
+from distlearn_tpu.models.transformer import lm_loss
+from distlearn_tpu.parallel import ep
+from distlearn_tpu.parallel.ep import (grouped_swiglu, moe_held_ffn,
+                                       route_held)
+from distlearn_tpu.train import build_lm_routing_metrics, build_lm_step
+
+N, D, F, E, K = 96, 16, 24, 16, 4
+
+
+def _layer(seed=0, held=4):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    n = lambda i, *s: jax.random.normal(ks[i], s, jnp.float32)  # noqa: E731
+    return (n(0, N, D), n(1, D, E), n(2, held, D, F) / 4, n(3, held, D, F) / 4,
+            n(4, held, F, D) / 5)
+
+
+def _masked_loop(x, router, wg, wu, wd, held):
+    """The routed part by a loop over the held experts with masks."""
+    s = jax.nn.softmax(x @ router, axis=-1)
+    top, chosen = jax.lax.top_k(s, K)
+    w = top / top.sum(-1, keepdims=True)
+    y = 0.0
+    for j, e in enumerate(held):
+        w_e = jnp.sum(jnp.where(chosen == e, w, 0.0), -1, keepdims=True)
+        y = y + w_e * ((jax.nn.silu(x @ wg[j]) * (x @ wu[j])) @ wd[j])
+    return y
+
+
+@pytest.mark.parametrize("tile", [8, 32])
+def test_grouped_product_and_its_backward_pass_are_the_masked_loop(
+        monkeypatch, tile):
+    # the layer's one tile height is a module constant; small here so that
+    # an expert's assignments span several tiles
+    monkeypatch.setattr(ep, "GROUP_TILE", tile)
+    held = (3, 5, 6, 11)
+    args = _layer()
+
+    def system(x, router, wg, wu, wd):
+        return moe_held_ffn(x, router, (wg, wu, wd), held, K)[0]
+
+    np.testing.assert_allclose(system(*args), _masked_loop(*args, held),
+                               rtol=1e-5, atol=1e-6)
+    c = jnp.cos(jnp.arange(N * D, dtype=jnp.float32).reshape(N, D))
+    got = jax.grad(lambda *a: jnp.sum(system(*a) * c),
+                   argnums=tuple(range(5)))(*args)
+    want = jax.grad(lambda *a: jnp.sum(_masked_loop(*a, held) * c),
+                    argnums=tuple(range(5)))(*args)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+def test_the_shares_of_all_holders_add_up_to_the_whole_layer():
+    """16 experts over 4 holders of 4: each holder's routed part, with the
+    shared expert counted ONCE, is the uncut layer (every expert held by one
+    caller) — what ties a chip's share to the model."""
+    ks = jax.random.split(jax.random.PRNGKey(1), 10)
+    n = lambda i, *s: jax.random.normal(ks[i], s, jnp.float32)  # noqa: E731
+    blk = {"ln2": {"scale": 1.0 + 0.1 * n(0, D)}, "router": n(1, D, E),
+           "ws_gate": n(2, D, F) / 4, "ws_up": n(3, D, F) / 4,
+           "ws_down": n(4, F, D) / 5, "we_gate": n(5, E, D, F) / 4,
+           "we_up": n(6, E, D, F) / 4, "we_down": n(7, E, F, D) / 5}
+    x = n(8, 2, N // 2, D)
+    whole, aux = moe_apply(blk, x, jnp.float32, 1e-5, tuple(range(E)), K,
+                           None)
+    assert int(aux["assignments"].sum()) == N * K
+    assert float(aux["unheld_frac"]) == 0.0
+    shared_only = dict(blk, **{k: jnp.zeros_like(blk[k][:1]) for k in
+                               ("we_gate", "we_up", "we_down")})
+    total = moe_apply(shared_only, x, jnp.float32, 1e-5, (0,), K, None)[0]
+    seen = 0
+    for holder in range(4):
+        held = tuple(range(4 * holder, 4 * holder + 4))
+        part = dict(blk, **{k: blk[k][4 * holder:4 * holder + 4] for k in
+                            ("we_gate", "we_up", "we_down")},
+                    ws_down=jnp.zeros_like(blk["ws_down"]))
+        y, a = moe_apply(part, x, jnp.float32, 1e-5, held, K, None)
+        total = total + (y - x)             # the routed part alone
+        seen += int(a["assignments"].sum())
+        assert int(a["dropped"]) == 0
+    assert seen == N * K                    # every assignment has a holder
+    np.testing.assert_allclose(total, whole, rtol=1e-5, atol=1e-5)
+
+
+def test_nothing_is_dropped_when_the_router_collapses(monkeypatch):
+    """A router forced onto one held expert: all N tokens land on it (a
+    capacity bucket would drop most of them), every one is computed."""
+    monkeypatch.setattr(ep, "GROUP_TILE", 8)
+    x, _, wg, wu, wd = _layer()
+    held = (3, 5, 6, 11)
+    router = jnp.zeros((D, E)).at[:, 5].set(1.0)
+    x = jnp.abs(x) + 0.1                    # positive scores for expert 5
+    plan, slot_w, aux = route_held(router, x, K, held)
+    assert int(aux["assignments"][1]) == N and int(aux["dropped"]) == 0
+    assert int(plan[2]) >= N // 8
+    y = moe_held_ffn(x, router, (wg, wu, wd), held, K)[0]
+    np.testing.assert_allclose(y, _masked_loop(x, router, wg, wu, wd, held),
+                               rtol=1e-5, atol=1e-6)
+    assert bool(jnp.all(jnp.abs(y).sum(-1) > 0))    # no token lost
+
+
+def test_held_layer_refuses_what_is_not_written():
+    x, router, wg, wu, wd = _layer()
+    with pytest.raises(NotImplementedError, match="exchange"):
+        moe_held_ffn(x, router, (wg, wu, wd), (0, 1, 2, 3), K,
+                     ep_axis="data")
+    for bad in ((0, 0, 1, 2), (0, 1, 2, E), ()):
+        with pytest.raises(ValueError, match="do not fit"):
+            route_held(router, x, K, bad)
+    with pytest.raises(ValueError, match="do not fit"):
+        route_held(router, x, E + 1, (0, 1, 2, 3))
+    assert grouped_swiglu.__name__ == "grouped_swiglu"
+
+
+def test_causal_conv_sees_no_future():
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, 12, 5), jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(3), (4, 5), jnp.float32)
+    y = causal_conv(x, w)
+    want = sum(w[j] * jnp.pad(x, ((0, 0), (3, 0), (0, 0)))[:, j:j + 12]
+               for j in range(4))
+    np.testing.assert_allclose(y, want, rtol=1e-6)
+    bumped = causal_conv(x.at[:, 7].add(1.0), w)
+    np.testing.assert_array_equal(np.asarray(bumped[:, :7]),
+                                  np.asarray(y[:, :7]))
+
+
+# ------------------------------------------------------------- the model --
+
+def _toy(**kw):
+    kw = dict(dict(vocab=97, dim=32, layer_types=("gqa", "kda", "kda", "kda"),
+                   heads=4, kv_heads=2, head_dim=8, kda_heads=4,
+                   kda_head_dim=8, n_routed_experts=16,
+                   held_experts=(1, 5, 6, 11), experts_per_tok=4,
+                   expert_width=24, max_len=64), **kw)
+    return hybrid_lm(**kw)
+
+
+def _mesh(shape=(2, 1, 1)):
+    n = int(np.prod(shape))
+    return Mesh(np.array(jax.devices()[:n]).reshape(shape),
+                ("data", "seq", "model"))
+
+
+def _tokens(mesh, b=4, L=64):
+    return jax.device_put(
+        jax.random.randint(jax.random.PRNGKey(1), (b, L), 0, 97, jnp.int32),
+        NamedSharding(mesh, P("data", "seq")))
+
+
+@pytest.mark.parametrize("remat", [False, "full"])
+def test_build_lm_step_drives_the_hybrid_model_unchanged(remat):
+    """Same builder, same call: two data-parallel steps are the gradient
+    steps of ``lm_loss`` on the whole batch, and the loss falls."""
+    mesh, model = _mesh(), _toy(remat=remat)
+    params, _ = model.init(jax.random.PRNGKey(0))
+    tokens = _tokens(mesh)
+    step = build_lm_step(model, mesh, params, lr=0.05, donate=False)
+    want = jax.tree_util.tree_map(
+        lambda p, g: p - 0.05 * g, params,
+        jax.grad(lambda p: lm_loss(model, p, tokens))(params))
+    got, loss = step(params, tokens)
+    assert float(loss) == pytest.approx(float(lm_loss(model, params, tokens)),
+                                        rel=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-6)
+    _, after = step(got, tokens)
+    assert float(after) < float(loss)
+
+
+def test_routing_metrics_count_into_obs():
+    mesh, model = _mesh(), _toy()
+    params, _ = model.init(jax.random.PRNGKey(0))
+    tokens = _tokens(mesh)
+    metrics = build_lm_routing_metrics(model, mesh, params)
+    out = metrics(params, tokens)
+    assert out["assignments"].shape == (4, 4)
+    assert (out["dropped"] == 0).all()
+    assert ((0 <= out["unheld_frac"]) & (out["unheld_frac"] < 1)).all()
+    # every token has 4 experts of 16; the held 4 get their share of them
+    assert 0 < out["assignments"].sum() < 4 * 4 * 64 * 4
+    family = obs.counter("moe_assignments_total", labels=("layer", "expert"))
+    if family is not obs.NULL:
+        before = sum(s["value"] for s in family.sample())
+        metrics(params, tokens)
+        assert sum(s["value"] for s in family.sample()) - before \
+            == out["assignments"].sum()
+    from distlearn_tpu.models import transformer_lm
+    dense = transformer_lm(vocab=97, dim=32, depth=1, heads=4, max_len=64)
+    dparams, _ = dense.init(jax.random.PRNGKey(0))
+    with pytest.raises(ValueError, match="no routing counters"):
+        build_lm_routing_metrics(dense, mesh, dparams)(dparams, tokens)
+
+
+@pytest.mark.parametrize("shape,what", [((1, 2, 1), "sequence"),
+                                        ((1, 1, 2), "tensor")])
+def test_sequence_and_tensor_axes_must_be_of_size_one(shape, what):
+    mesh, model = _mesh(shape), _toy()
+    params, _ = model.init(jax.random.PRNGKey(0))
+    step = build_lm_step(model, mesh, params, lr=0.05, donate=False)
+    with pytest.raises(NotImplementedError, match=what):
+        step(params, _tokens(mesh, b=2))
+
+
+def test_constructor_refuses_what_it_cannot_build():
+    with pytest.raises(ValueError, match="layer_types"):
+        _toy(layer_types=("gqa", "mamba"))
+    with pytest.raises(ValueError, match="kv_heads"):
+        _toy(kv_heads=3)
+    with pytest.raises(ValueError, match="remat"):
+        _toy(remat="mlp")
+    mesh, model = _mesh((1, 1, 1)), _toy()
+    params, _ = model.init(jax.random.PRNGKey(0))
+    with pytest.raises(NotImplementedError, match="exchange"):
+        model.apply(params, {}, _tokens(mesh, b=1), ep_axis="data")

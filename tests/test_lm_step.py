@@ -489,15 +489,40 @@ def _scoped_lm(**kw):
                          **kw), params, tokens
 
 
-@pytest.fixture(scope="module")
-def scoped_op_names():
-    """``op_name`` of every instruction of the toy step's optimized HLO."""
+def _scoped_hybrid():
+    """The pattern LM at toy size on the same mesh, fully rematerialised: one
+    softmax layer, two linear-attention ones, 2 of 8 experts held."""
+    from distlearn_tpu.models import hybrid_lm
+    mesh = Mesh(np.array(jax.devices()[:2]).reshape(2, 1, 1),
+                ("data", "seq", "model"))
+    model = hybrid_lm(vocab=97, dim=32, layer_types=("gqa", "kda", "kda"),
+                      heads=4, kv_heads=2, head_dim=8, kda_heads=2,
+                      kda_head_dim=8, n_routed_experts=8, held_experts=(1, 6),
+                      experts_per_tok=2, expert_width=16,
+                      max_len=32, remat="full")
+    params, _ = model.init(jax.random.PRNGKey(0))
+    tokens = jax.device_put(
+        jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, 97, jnp.int32),
+        NamedSharding(mesh, P("data", "seq")))
+    return build_lm_step(model, mesh, params, lr=0.1,
+                         donate=False), params, tokens
+
+
+#: the scopes of ``models.core.SCOPES`` only the pattern LM uses
+_HYBRID_ONLY = {"linattn_core", "moe"}
+
+
+@pytest.fixture(scope="module", params=["dense", "hybrid"])
+def scoped_op_names(request):
+    """``(model kind, op_name of every instruction of the toy step's
+    optimized HLO)``, for the dense LM and for the pattern LM."""
     from distlearn_tpu.utils.profiling import scope_table
-    step, params, tokens = _scoped_lm()
+    build = _scoped_lm if request.param == "dense" else _scoped_hybrid
+    step, params, tokens = build()
     step(params, tokens)
     table = scope_table(step.hlo_text())
     assert table
-    return list(table.values())
+    return request.param, list(table.values())
 
 
 def _has_scope(op_name, scope):
@@ -517,8 +542,11 @@ _IN_PHASE = {
 def test_every_block_scope_shows_in_every_pass(scoped_op_names, phase):
     """Forward / recompute / backward are JAX's own marks; the declared
     scopes ride inside each of them."""
-    names = [n for n in scoped_op_names if _IN_PHASE[phase](n)]
+    kind, op_names = scoped_op_names
+    names = [n for n in op_names if _IN_PHASE[phase](n)]
     wanted = {"norm", "attn_proj", "attn_core", "mlp"}
+    if kind == "hybrid":
+        wanted |= _HYBRID_ONLY
     if phase != "recompute":        # embedding and head sit outside the scan
         wanted |= {"embed", "head_loss"}
     missing = {s for s in wanted if not any(_has_scope(n, s) for n in names)}
@@ -527,14 +555,16 @@ def test_every_block_scope_shows_in_every_pass(scoped_op_names, phase):
 
 def test_update_and_grad_reduce_sit_outside_the_passes(scoped_op_names):
     from distlearn_tpu.models.core import SCOPES
+    kind, op_names = scoped_op_names
     for scope in ("update", "grad_reduce"):
-        names = [n for n in scoped_op_names if _has_scope(n, scope)]
+        names = [n for n in op_names if _has_scope(n, scope)]
         assert names, scope
         assert not any("jvp(" in n or "transpose(" in n for n in names), scope
-    # the declared list is what the program uses: nothing else, nothing less
+    # the declared list is what the programs use: nothing else, nothing
+    # less — the dense model all of it but the pattern LM's two names
     used = {s for s in SCOPES
-            if any(_has_scope(n, s) for n in scoped_op_names)}
-    assert used == set(SCOPES)
+            if any(_has_scope(n, s) for n in op_names)}
+    assert used == set(SCOPES) - (_HYBRID_ONLY if kind == "dense" else set())
 
 
 def test_scopes_change_no_number(monkeypatch):

@@ -183,6 +183,12 @@ _BF16, _F32 = "bfloat16", "float32"
     (True, 2048, 128, _BF16, "tpu", "splash"),
     (True, 1024, 64, _F32, "tpu", "splash"),
     (True, 1536, 64, _BF16, "tpu", "splash"),
+    # the hybrid LM's softmax layer: 64 query heads over 8 K/V heads of 128
+    # at 8192 positions (the head counts are not the selector's business:
+    # grouped queries ride the same kernel, test_grouped_queries_* below)
+    (True, 8192, 128, _BF16, "tpu", "splash"),
+    (True, 8192, 128, _F32, "tpu", "splash"),
+    (True, 8192, 128, _BF16, "cpu", "xla"),
     # not causal: nothing to skip
     (False, 1024, 64, _BF16, "tpu", "xla"),
     (False, 4096, 128, _F32, "tpu", "xla"),
@@ -289,6 +295,43 @@ def test_splash_bf16_matches_f32_oracle():
         err_xla = np.abs(np.asarray(x, np.float32) - r).max()
         err_got = np.abs(np.asarray(g, np.float32) - r).max()
         assert err_got <= max(2 * err_xla, 0.02 * np.abs(r).max())
+
+
+def _gqa_qkv(L, D, heads, kv_heads, seed):
+    rng = np.random.RandomState(seed)
+    mk = lambda h: jnp.asarray(                           # noqa: E731
+        rng.randn(1, L, h, D).astype(np.float32))
+    return mk(heads), mk(kv_heads), mk(kv_heads)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_grouped_queries_full_square_is_the_repeated_heads(causal):
+    """Query head i attends K/V head i // (H / Hkv): the full-square path
+    with 2 K/V heads is bitwise the multi-head path on K/V repeated."""
+    q, k, v = _gqa_qkv(48, 16, 6, 2, seed=12)
+    got = local_attention(q, k, v, causal=causal)
+    rep = lambda a: jnp.repeat(a, 3, axis=2)              # noqa: E731
+    np.testing.assert_array_equal(
+        np.asarray(got),
+        np.asarray(local_attention(q, rep(k), rep(v), causal=causal)))
+    with pytest.raises(ValueError, match="must divide"):
+        local_attention(q, k[:, :, :1].repeat(4, axis=2), v, causal=causal)
+
+
+def test_grouped_queries_splash_matches_full_square_float32():
+    """The blockwise kernel's multi-query call a K/V head (Pallas interpret
+    mode here) against the full square: forward and the three gradients,
+    dK and dV summed over the group, at the hybrid LM's head size."""
+    q, k, v = _gqa_qkv(256, 128, 4, 2, seed=13)
+    got = local_attention(q, k, v, causal=True, impl="splash")
+    ref = local_attention(q, k, v, causal=True, impl="xla")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-5, atol=2e-6)
+    for a, b in zip(_loss_and_grads("splash", q, k, v),
+                    _loss_and_grads("xla", q, k, v)):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=5e-5)
 
 
 @pytest.mark.parametrize("causal,L", [(False, 256), (True, 100), (True, 32)])

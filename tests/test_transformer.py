@@ -387,14 +387,22 @@ def v5e_chip():
     return topo.devices[0]
 
 
-@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+@pytest.mark.parametrize("kind,backend", [("dense", "tpu"), ("dense", "cpu"),
+                                          ("hybrid", "tpu")])
 def test_tpu_compiler_keeps_every_scope_at_gpt2_large_width(
-        v5e_chip, backend, monkeypatch):
+        v5e_chip, kind, backend, monkeypatch):
     """The benchmark's step at GPT-2-large's widths (1280 x 20 heads, vocab
     50257, 8 x 1024 tokens, bf16, scanned, full remat; depth cut to 2: the
     scan makes the program the same), compiled for the v5e: every declared
     scope survives the TPU compiler's fusion, in every pass it belongs to.
     Nothing runs; no number of this is a measurement.
+
+    ``kind="hybrid"``: the pattern LM's step at ITS published widths (4096,
+    64 query heads over 8 K/V heads of 128, 64 KDA heads of 128, experts
+    1280 wide, a router of 320, 8 a token; one softmax and one
+    linear-attention layer, 2 experts held, 1 x 1024 tokens, a cut of the
+    vocabulary) — the two scopes only it uses survive too, and its softmax
+    layer's grouped queries ride the same three Mosaic calls.
 
     ``local_attention`` picks its path from ``jax.default_backend()``,
     which here says "cpu" whatever the program is compiled for: ``"tpu"``
@@ -409,9 +417,22 @@ def test_tpu_compiler_keeps_every_scope_at_gpt2_large_width(
     from distlearn_tpu.utils.profiling import scope_table
     mesh = Mesh(np.array([v5e_chip]).reshape(1, 1, 1),
                 ("data", "seq", "model"))
-    model = transformer_lm(vocab=50257, dim=1280, depth=2, heads=20,
-                           max_len=1024, compute_dtype=jnp.bfloat16,
-                           scan_blocks=True, remat="full")
+    if kind == "dense":
+        model = transformer_lm(vocab=50257, dim=1280, depth=2, heads=20,
+                               max_len=1024, compute_dtype=jnp.bfloat16,
+                               scan_blocks=True, remat="full")
+        batch, kernel, square = 8, "splash_mha", "[8,20,1024,1024]"
+        mine = set(SCOPES) - {"linattn_core", "moe"}
+    else:
+        from distlearn_tpu.models import hybrid_lm
+        model = hybrid_lm(vocab=4096, dim=4096, layer_types=("gqa", "kda"),
+                          heads=64, kv_heads=8, head_dim=128, kda_heads=64,
+                          kda_head_dim=128, n_routed_experts=320,
+                          held_experts=(0, 1), experts_per_tok=8,
+                          expert_width=1280, max_len=1024,
+                          compute_dtype=jnp.bfloat16, remat="full")
+        batch, kernel, square = 1, "splash_mqa", "[1,64,1024,1024]"
+        mine = set(SCOPES)
     template = jax.eval_shape(lambda k: model.init(k)[0],
                               jax.random.PRNGKey(0))
     params = jax.tree_util.tree_map(
@@ -419,7 +440,8 @@ def test_tpu_compiler_keeps_every_scope_at_gpt2_large_width(
                                           sharding=NamedSharding(mesh, s)),
         template, param_specs(template, "model"))
     tokens = jax.ShapeDtypeStruct(
-        (8, 1024), jnp.int32, sharding=NamedSharding(mesh, P("data", "seq")))
+        (batch, 1024), jnp.int32,
+        sharding=NamedSharding(mesh, P("data", "seq")))
     # a compile for a described chip cannot be read back from the
     # persistent cache: keep it out, and the run silent
     cache_was = jax.config.jax_enable_compilation_cache
@@ -437,18 +459,19 @@ def test_tpu_compiler_keeps_every_scope_at_gpt2_large_width(
         compilation_cache.reset_cache()
     table = scope_table(text)
     names = list(table.values())
-    kernels = {k: v for k, v in table.items() if k.startswith("splash_mha")}
-    square = "[8,20,1024,1024]" in text
+    kernels = {k: v for k, v in table.items() if k.startswith(kernel)}
+    square = square in text
     if backend == "tpu":
         assert text.count('custom_call_target="tpu_custom_call"') == 3
         assert not square
         passes = sorted(
             ("recompute" if "rematted_computation" in n else
              "bwd" if "transpose(" in n else "fwd", k.split(".")[0])
-            for k, n in kernels.items() if "/attn_core/" in n)
-        assert passes == [("bwd", "splash_mha_dkv_no_residuals"),
-                          ("fwd", "splash_mha_fwd_residuals"),
-                          ("recompute", "splash_mha_fwd_residuals")]
+            for k, n in kernels.items()
+            if "/attn_core/" in n.replace("(", "/").replace(")", "/"))
+        assert passes == [("bwd", f"{kernel}_dkv_no_residuals"),
+                          ("fwd", f"{kernel}_fwd_residuals"),
+                          ("recompute", f"{kernel}_fwd_residuals")]
     else:
         assert square and not kernels
 
@@ -457,7 +480,7 @@ def test_tpu_compiler_keeps_every_scope_at_gpt2_large_width(
                    and all(m in n for m in marks)
                    and not any(w in n for w in without) for n in names)
 
-    for scope in ("norm", "attn_proj", "attn_core", "mlp"):
+    for scope in mine - {"embed", "head_loss", "update", "grad_reduce"}:
         assert seen(scope, "jvp(", without=("transpose(",)), scope
         assert seen(scope, "rematted_computation"), scope
         assert seen(scope, "transpose(",
@@ -466,5 +489,6 @@ def test_tpu_compiler_keeps_every_scope_at_gpt2_large_width(
         assert seen(scope, "jvp("), scope
     assert seen("update")
     # grad_reduce is all collectives and a scaling by 1/dp = 1: on one chip
-    # the compiler folds it away, so seven of the eight names remain
-    assert {s for s in SCOPES if seen(s)} >= set(SCOPES) - {"grad_reduce"}
+    # the compiler folds it away, so all the model's names but that remain
+    # — and the dense model shows none of the pattern LM's two
+    assert mine - {"grad_reduce"} <= {s for s in SCOPES if seen(s)} <= mine
