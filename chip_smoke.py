@@ -20,7 +20,8 @@ models the repo supports, with seeded random weights and synthetic data:
   experts 1280 wide, a router of 320, 8 a token; one period of 4 layers, 2
   experts held, an eighth of an eighth of the vocabulary) through the same
   build_lm_step at 1 x 2048 tokens, bf16, full remat: the grouped-query
-  layer on the blockwise kernel, no assignment dropped, losses that fall.
+  layer on the blockwise kernel (two Mosaic calls: the recomputation runs
+  none), no assignment dropped, losses that fall.
 * ``serve`` — examples/lm.py --serve's path at the same width: DecodeEngine
   (8 slots, max_len 1024) behind ServeServer, ServeClients on threads over
   the framed-TCP port: prompts in several prefill buckets, a prefix-cache
@@ -379,6 +380,13 @@ def phase_hybrid_lm(*, vocab: int = 3072, dim: int = 4096, heads: int = 64,
     step = build_lm_step(model, mesh, params, lr=lr)
     lowered, traced = _lower_default(step, params, tokens, "hybrid LM")
     mosaic = lowered.as_text().count("tpu_custom_call")
+    # one softmax layer, rematerialised: its checkpoint keeps the kernel's
+    # output and log-sum-exp, so the step holds the forward and the
+    # backward kernel and no third call in the recomputation
+    _require(mosaic == 2 or jax.default_backend() != "tpu",
+             f"hybrid LM: {mosaic} Mosaic calls in the rematerialised step, "
+             "expected 2 (forward and backward kernel of its one softmax "
+             "layer)")
     losses = []
     for _ in range(steps):
         params, loss = step(params, tokens)

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from distlearn_tpu.models import nn
+from distlearn_tpu.parallel.sequence import ATTN_RESIDUALS
 
 PyTree = Any
 
@@ -32,6 +33,26 @@ PyTree = Any
 #: list less those two.
 SCOPES = ("embed", "norm", "attn_proj", "attn_core", "mlp", "head_loss",
           "grad_reduce", "update", "linattn_core", "moe")
+
+
+def checkpoint_block(fn: Callable) -> Callable:
+    """``fn`` as one rematerialised block — what ``remat="full"`` and the
+    pipeline builders' ``remat=True`` mean, in the one place that says it.
+
+    The checkpoint keeps the block's input and, where the blockwise
+    attention kernel ran inside it, the two results that kernel names
+    (:data:`~distlearn_tpu.parallel.sequence.ATTN_RESIDUALS`: its output,
+    ``[tokens, dim]`` in the compute dtype like the input, and its float32
+    log-sum-exp ``[B, H, L]``); everything else is computed again in the
+    backward pass.  Without them the backward pass would run the whole
+    forward kernel a second time only to hand its own backward call those
+    two arrays (27.5 ms of a 412.6 ms step at GPT-2-large's sizes: PERF.md
+    section 6, PR 30).  A block on the full-square path names nothing, so
+    its checkpoint holds the input alone, as a bare ``jax.checkpoint``
+    does: what is kept follows from the path the attention call took."""
+    return jax.checkpoint(
+        fn, policy=jax.checkpoint_policies.save_only_these_names(
+            ATTN_RESIDUALS))
 
 
 class Model(NamedTuple):

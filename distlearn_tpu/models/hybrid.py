@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax, random
 
-from distlearn_tpu.models.core import Model
+from distlearn_tpu.models.core import Model, checkpoint_block
 from distlearn_tpu.models.transformer import _norm_init, _rmsnorm
 from distlearn_tpu.ops.delta_rule import chunked_delta_rule
 from distlearn_tpu.parallel.ep import moe_held_ffn
@@ -176,7 +176,15 @@ def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
     see :func:`distlearn_tpu.parallel.ep.moe_held_ffn`.  The shared expert
     (``n_shared_experts`` x ``expert_width`` wide) runs on every token.
 
-    ``remat`` (True = ``"full"``) wraps each layer in ``jax.checkpoint``.
+    ``remat`` (True = ``"full"``) makes each layer one checkpoint
+    (:func:`distlearn_tpu.models.core.checkpoint_block`): its activations
+    are recomputed in the backward pass.  The checkpoint holds the layer's
+    input and, in a softmax layer whose attention runs on the blockwise
+    kernel, that kernel's output (``[B, L, heads * head_dim]`` in the
+    compute dtype) and float32 log-sum-exp (``[B, heads, L]``), which the
+    kernel's backward call reads: the forward kernel runs once a layer, at
+    the price of one more array of the input's size order held a softmax
+    layer.  A linear-attention layer holds its input alone.
     ``apply``'s ``seq_axis`` / ``tp_axis`` may name mesh axes of size 1 (the
     LM step builders always pass them); sequence or tensor parallelism of
     these layers is not written and a larger axis raises.  ``ep_axis`` is
@@ -285,7 +293,7 @@ def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
                     x = kda_apply(blk, x, cd, eps)
                 return moe_apply(blk, x, cd, eps, held, experts_per_tok,
                                  ep_axis)
-            return jax.checkpoint(layer) if remat else layer
+            return checkpoint_block(layer) if remat else layer
 
         # one wrapper a kind, reused down the depth (transformer_lm's note:
         # a fresh checkpoint closure a layer stops XLA sharing the

@@ -30,7 +30,7 @@ from jax import lax, random
 
 from jax.sharding import PartitionSpec as P
 
-from distlearn_tpu.models.core import Model
+from distlearn_tpu.models.core import Model, checkpoint_block
 from distlearn_tpu.parallel.sequence import (alltoall_attention,
                                              local_attention, ring_attention)
 from distlearn_tpu.parallel.tp import tp_enter, tp_reduce
@@ -210,11 +210,22 @@ def transformer_lm(vocab: int = 256, dim: int = 128, depth: int = 2,
     :func:`distlearn_tpu.parallel.sequence.ring_attention` for why (and for
     the zigzag layout that does the causal FLOP cut there).
 
-    ``remat=True`` (= ``"full"``) wraps each block in ``jax.checkpoint``:
-    activations are recomputed in the backward pass instead of saved — HBM
-    drops from O(depth * L * dim) to O(L * dim) at ~1/3 extra FLOPs, the
-    standard trade for long-context/deep configs.  ``remat="mlp"`` is the
-    selective middle ground (Megatron-style selective activation
+    ``remat=True`` (= ``"full"``) makes each block one checkpoint
+    (:func:`distlearn_tpu.models.core.checkpoint_block`): its activations
+    are recomputed in the backward pass instead of saved — HBM drops from
+    O(depth * L * dim) to O(L * dim) at ~1/3 extra FLOPs, the standard
+    trade for long-context/deep configs.  A block's checkpoint holds the
+    block's input and, where the blockwise attention kernel runs, that
+    kernel's output and log-sum-exp — the first the size of the input
+    (``[B, L, dim]`` in the compute dtype; twice that on the chip where a
+    64-wide head is padded to the 128 lanes), the second ``[B, H, L]``
+    float32 — because they are all the kernel's backward call lacks:
+    without them the backward pass runs the whole forward kernel again.  At
+    the memory limit that is one more ``[tokens, dim]`` array a layer
+    beside the one already held, the price of every recipe that
+    checkpoints around a flash kernel and not a setting; on the
+    full-square path nothing more than the input is held.  ``remat="mlp"``
+    is the selective middle ground (Megatron-style selective activation
     recomputation): only the FFN half of each block is checkpointed, so
     the attention output AND the blockwise kernel's softmax residuals stay
     saved — the backward pass never re-runs the attention forward, at the
@@ -370,10 +381,10 @@ def transformer_lm(vocab: int = 256, dim: int = 128, depth: int = 2,
                                    moe_top_k=moe_top_k,
                                    return_moe_aux=is_moe,
                                    attn_impl=attn_impl)
-            return jax.checkpoint(block) if remat == "full" else block
+            return checkpoint_block(block) if remat == "full" else block
 
         # ONE wrapper per block kind, reused across the depth loop: a fresh
-        # jax.checkpoint closure per block stops XLA deduplicating the remat
+        # checkpoint closure per block stops XLA deduplicating the remat
         # computation (measured 13% slower on the seq-4096 flash+remat
         # bench); sharing restores it
         blk_dense = make_block(False)

@@ -293,6 +293,16 @@ _SPLASH_MIN_BLOCK = 256
 _SPLASH_MIN_LEN = 1024
 
 
+#: the ``checkpoint_name`` the blockwise kernel gives the two results its
+#: backward call reads besides q, k, v: its output and its log-sum-exp.  A
+#: name is metadata; only a checkpoint whose policy asks for it
+#: (:func:`distlearn_tpu.models.core.checkpoint_block`) keeps them, and then
+#: the backward pass does not run the forward kernel a second time.  The
+#: full-square path names nothing: its residual would be the
+#: ``[B, H, L, L]`` probabilities.
+ATTN_RESIDUALS = "attn_residuals"
+
+
 def _splash_block(L: int) -> int | None:
     """The widest block edge that tiles a length-``L`` sequence, if any."""
     return next((b for b in _SPLASH_BLOCKS if L % b == 0), None)
@@ -345,7 +355,8 @@ def _splash_causal_attention(q, k, v, block: int, interpret: bool):
     """Causal attention through JAX's Pallas ``splash_attention`` kernel
     with a ``CausalMask``: blocks above the diagonal are never visited,
     scores and softmax statistics are float32 and live in VMEM only, the
-    backward pass is the kernel's fused dK/dV/dQ call.  q/k/v:
+    backward pass is the kernel's fused dK/dV/dQ call.  Its output and
+    log-sum-exp carry the name :data:`ATTN_RESIDUALS`.  q/k/v:
     ``[B, L, H, D]``; the kernel wants ``[H, L, D]`` per batch row and an
     already scaled q."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
@@ -369,7 +380,8 @@ def _splash_causal_attention(q, k, v, block: int, interpret: bool):
     if Hkv == H:
         kernel = sk.make_splash_mha(
             sm.MultiHeadMask([sm.CausalMask((L, L))] * H), block_sizes=sizes,
-            head_shards=1, q_seq_shards=1, interpret=interpret)
+            head_shards=1, q_seq_shards=1,
+            residual_checkpoint_name=ATTN_RESIDUALS, interpret=interpret)
         out = jax.vmap(kernel)(heads_first(qs), heads_first(k),
                                heads_first(v))
         return heads_first(out).astype(q.dtype)
@@ -380,7 +392,8 @@ def _splash_causal_attention(q, k, v, block: int, interpret: bool):
     group = H // Hkv
     kernel = sk.make_splash_mqa(
         sm.MultiHeadMask([sm.CausalMask((L, L))] * group), block_sizes=sizes,
-        head_shards=1, q_seq_shards=1, interpret=interpret)
+        head_shards=1, q_seq_shards=1,
+        residual_checkpoint_name=ATTN_RESIDUALS, interpret=interpret)
     qg = heads_first(qs).reshape(B, Hkv, group, L, D)
     out = jax.vmap(jax.vmap(kernel))(qg, heads_first(k), heads_first(v))
     return heads_first(out.reshape(B, H, L, D)).astype(q.dtype)

@@ -15,7 +15,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from jax import shard_map
 
-from distlearn_tpu.models.core import Model
+from distlearn_tpu.models.core import Model, checkpoint_block
 from distlearn_tpu.models.transformer import (_rmsnorm, block_apply, lm_loss,
                                               param_specs,
                                               stack_block_params,
@@ -304,8 +304,10 @@ def build_lm_pp_step(mesh: Mesh, shared_template, stacked_template,
     ``k = depth / n_stages`` transformer blocks per pipeline stage (depth
     must divide evenly; sharding the stacked ``[depth, ...]`` block axis
     over ``pipe`` hands each stage its k contiguous blocks, scanned in
-    order inside the stage fn — ``remat=True`` checkpoints each block so
-    only one block's activations per in-flight microbatch stay live).
+    order inside the stage fn — ``remat=True`` checkpoints each block
+    (:func:`~distlearn_tpu.models.core.checkpoint_block`, as the model
+    constructors' ``remat="full"`` does) so only one block's activations
+    per in-flight microbatch stay live).
     Microbatches stream through the stages via
     :func:`distlearn_tpu.parallel.pp.pipeline_apply`, so the whole GPipe
     schedule — all ticks, forward and backward — is one XLA program, and
@@ -355,7 +357,7 @@ def build_lm_pp_step(mesh: Mesh, shared_template, stacked_template,
 
             one = lambda bp, h: block_apply(bp, h, cd)   # noqa: E731
             if remat:
-                one = jax.checkpoint(one)
+                one = checkpoint_block(one)
 
             def stage(bp_stack, h):
                 h, _ = lax.scan(lambda hh, bp: (one(bp, hh), None),
@@ -463,7 +465,7 @@ def build_lm_pp_1f1b_step(mesh: Mesh, shared_template, stacked_template,
 
         one = lambda bp, h: block_apply(bp, h, cd)   # noqa: E731
         if remat:
-            one = jax.checkpoint(one)
+            one = checkpoint_block(one)
 
         def stage(bp_stack, h):
             h, _ = lax.scan(lambda hh, bp: (one(bp, hh), None), h, bp_stack)

@@ -183,6 +183,85 @@ def test_build_lm_step_drives_the_hybrid_model_unchanged(remat):
     assert float(after) < float(loss)
 
 
+def _remat_toy(monkeypatch, wrap, on_the_kernel=True):
+    """The rematerialised toy at a length and head size the blockwise kernel
+    takes (128, 64).  ``on_the_kernel`` steers its softmax layer onto that
+    kernel as the chip would (interpreted here); ``wrap="bare"`` swaps the
+    constructor's ``checkpoint_block`` for the bare ``jax.checkpoint`` it
+    replaced."""
+    from distlearn_tpu.models import hybrid
+    from distlearn_tpu.parallel import sequence
+    if on_the_kernel:
+        monkeypatch.setattr(sequence, "select_attention",
+                            lambda *a: "splash")
+    if wrap == "bare":
+        monkeypatch.setattr(hybrid, "checkpoint_block", jax.checkpoint)
+    return _toy(head_dim=64, max_len=128, remat="full")
+
+
+@pytest.mark.parametrize("wrap,calls", [("named", 2), ("bare", 3)])
+def test_rematerialised_gqa_layer_runs_the_forward_kernel_once(
+        monkeypatch, wrap, calls):
+    """The grouped-query call of the one softmax layer: forward and backward
+    kernel in the gradient, no third call in the recomputation (the bare
+    ``jax.checkpoint`` had one); the linear-attention layers hold none."""
+    from tests.program_util import pallas_calls
+    model = _remat_toy(monkeypatch, wrap)
+    params = jax.eval_shape(lambda k: model.init(k)[0], jax.random.PRNGKey(0))
+    tokens = jnp.zeros((1, 128), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        jax.grad(lambda p: lm_loss(model, p, tokens)))(params)
+    assert pallas_calls(jaxpr) == calls
+
+
+@pytest.mark.parametrize("on_the_kernel", [True, False],
+                         ids=["splash", "xla"])
+def test_rematerialised_layers_are_bitwise_the_bare_checkpoints(
+        monkeypatch, on_the_kernel):
+    """Loss and parameters after one ``build_lm_step`` are bitwise those of
+    the bare ``jax.checkpoint`` layers, with the softmax layer on the
+    kernel and on the full-square path."""
+    mesh = _mesh()
+    tokens = _tokens(mesh, L=128)
+
+    def one_step(wrap):
+        model = _remat_toy(monkeypatch, wrap, on_the_kernel)
+        params, _ = model.init(jax.random.PRNGKey(0))
+        return build_lm_step(model, mesh, params, lr=0.05,
+                             donate=False)(params, tokens)
+
+    got, loss = one_step("named")
+    want, loss0 = one_step("bare")
+    assert float(loss) == float(loss0)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("kind", ["gqa", "kda"])
+def test_a_layer_with_no_kernel_keeps_its_input_alone(monkeypatch, kind):
+    """On the full-square path, and in a linear-attention layer, nothing
+    carries the kernel's name: the policy finds none, and the rematerialised
+    layer's gradient is the bare checkpoint's program text for text.  (One
+    kind at a time: with two kinds the lowered module repeats some of the
+    expert layer's small private functions — ``_where``, ``cumsum`` — a
+    different number of times under the two checkpoints.)"""
+    from distlearn_tpu.models import hybrid
+    from tests.program_util import program_text
+    tokens = jnp.zeros((2, 64), jnp.int32)
+
+    def lowered():
+        model = _toy(layer_types=(kind,), remat="full")
+        params = jax.eval_shape(lambda k: model.init(k)[0],
+                                jax.random.PRNGKey(0))
+        return jax.jit(jax.grad(
+            lambda p: lm_loss(model, p, tokens))).lower(params)
+
+    named = lowered()
+    monkeypatch.setattr(hybrid, "checkpoint_block", jax.checkpoint)
+    assert program_text(named) == program_text(lowered())
+
+
 def test_routing_metrics_count_into_obs():
     mesh, model = _mesh(), _toy()
     params, _ = model.init(jax.random.PRNGKey(0))

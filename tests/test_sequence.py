@@ -9,6 +9,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distlearn_tpu.parallel.sequence import local_attention, ring_attention
+from tests.program_util import pallas_calls, program_text
 
 B, L, H, D = 2, 32, 4, 16
 
@@ -387,6 +388,90 @@ def test_attn_kernel_counter_counts_the_resolved_path():
     assert after.get("xla", 0) - before.get("xla", 0) == 2
     assert after.get("splash", 0) - before.get("splash", 0) == 1
     assert set(after) <= {"xla", "splash"}
+
+
+# --- what a rematerialised block keeps of the kernel ------------------------
+
+def _attn_block(impl):
+    """Projections, causal attention through ``impl``, output projection and
+    the residual: what a checkpointed block has to compute again."""
+    def block(w, x):
+        q, k, v = (jnp.einsum("bld,dhk->blhk", x, w[n]) for n in "qkv")
+        out = local_attention(q, k, v, causal=True, impl=impl)
+        return x + jnp.einsum("blhk,hkd->bld", out, w["o"])
+    return block
+
+
+def _attn_block_inputs(heads, kv_heads, head=64, dim=32, L=128, seed=14):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    n = lambda i, *s: jax.random.normal(ks[i], s, jnp.float32) / 6  # noqa: E731
+    w = {"q": n(0, dim, heads, head), "k": n(1, dim, kv_heads, head),
+         "v": n(2, dim, kv_heads, head), "o": n(3, heads, head, dim)}
+    return w, 6 * n(4, 1, L, dim)
+
+
+def _block_grad(wrap, impl):
+    block = wrap(_attn_block(impl))
+    return jax.grad(lambda w, x: jnp.sum(block(w, x) ** 2), argnums=(0, 1))
+
+
+def _wrap(name):
+    from distlearn_tpu.models.core import checkpoint_block
+    return {"named": checkpoint_block, "bare": jax.checkpoint,
+            "none": lambda f: f}[name]
+
+
+# the multi-head call, and the multi-query call a K/V head of grouped queries
+@pytest.mark.parametrize("heads,kv_heads", [(2, 2), (4, 2)])
+@pytest.mark.parametrize("wrap,calls", [("named", 2), ("bare", 3),
+                                        ("none", 2)])
+def test_checkpointed_block_runs_the_forward_kernel_once(heads, kv_heads,
+                                                         wrap, calls):
+    """The gradient of a block checkpointed through ``checkpoint_block``
+    holds the forward and the backward kernel, as with no checkpoint at
+    all; a bare ``jax.checkpoint`` holds the forward kernel a second time,
+    run only to hand the backward kernel its output and log-sum-exp."""
+    w, x = _attn_block_inputs(heads, kv_heads)
+    jaxpr = jax.make_jaxpr(_block_grad(_wrap(wrap), "splash"))(w, x)
+    assert pallas_calls(jaxpr) == calls
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(2, 2), (4, 2)])
+def test_kept_residuals_change_no_bit_of_the_gradient(heads, kv_heads):
+    """Same kernels on the same operands, one call fewer: the gradients of
+    the two checkpoints are bitwise equal (Pallas interpret mode here)."""
+    w, x = _attn_block_inputs(heads, kv_heads)
+    named = jax.jit(_block_grad(_wrap("named"), "splash"))(w, x)
+    bare = jax.jit(_block_grad(_wrap("bare"), "splash"))(w, x)
+    for a, b in zip(jax.tree_util.tree_leaves(named),
+                    jax.tree_util.tree_leaves(bare)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(2, 2), (4, 2)])
+def test_full_square_block_keeps_its_input_alone(heads, kv_heads):
+    """The full-square path names nothing (its residual would be the
+    ``[B, H, L, L]`` probabilities): the policy finds no name, and the
+    checkpointed block's gradient is the bare checkpoint's program, text
+    for text — and no kernel is in it."""
+    w, x = _attn_block_inputs(heads, kv_heads)
+    named, bare = (jax.jit(_block_grad(_wrap(n), "xla")).lower(w, x)
+                   for n in ("named", "bare"))
+    assert program_text(named) == program_text(bare)
+    assert pallas_calls(jax.make_jaxpr(
+        _block_grad(_wrap("named"), "xla"))(w, x)) == 0
+
+
+def test_the_name_alone_changes_no_program(monkeypatch):
+    """A ``checkpoint_name`` is metadata: outside a checkpoint that asks for
+    it, the kernel call with the name lowers to the text of the call
+    without one (the call as it was before the name)."""
+    from distlearn_tpu.parallel import sequence
+    w, x = _attn_block_inputs(2, 2)
+    with_name = jax.jit(_block_grad(_wrap("none"), "splash")).lower(w, x)
+    monkeypatch.setattr(sequence, "ATTN_RESIDUALS", None)
+    without = jax.jit(_block_grad(_wrap("none"), "splash")).lower(w, x)
+    assert program_text(with_name) == program_text(without)
 
 
 # --- zigzag causal ring attention (balanced layout, masked-block skip) ------

@@ -111,6 +111,85 @@ def test_remat_matches_no_remat():
                                        rtol=1e-5, atol=1e-7)
 
 
+def _remat_lm(monkeypatch, wrap, **kw):
+    """A toy LM whose blocks the blockwise kernel can be forced on (head 64,
+    length 128), with its tokens.  ``wrap="bare"`` swaps the constructor's
+    ``checkpoint_block`` for the bare ``jax.checkpoint`` it replaced."""
+    from distlearn_tpu.models import transformer
+    if wrap == "bare":
+        monkeypatch.setattr(transformer, "checkpoint_block", jax.checkpoint)
+    lm = transformer_lm(vocab=64, dim=128, depth=2, heads=2, max_len=128,
+                        **kw)
+    toks = jnp.asarray(np.random.RandomState(5).randint(0, 64, (2, 128)),
+                       jnp.int32)
+    return lm, toks
+
+
+@pytest.mark.parametrize("scan", [False, True], ids=["unrolled", "scanned"])
+@pytest.mark.parametrize("wrap,a_layer", [("named", 2), ("bare", 3)])
+def test_full_remat_runs_the_forward_kernel_once_a_layer(monkeypatch, scan,
+                                                         wrap, a_layer):
+    """``remat="full"``: the gradient holds the forward and the backward
+    kernel of each layer (of the scan's one body, when scanned) — the bare
+    ``jax.checkpoint`` held the forward kernel once more, in the
+    recomputation."""
+    from tests.program_util import pallas_calls
+    lm, toks = _remat_lm(monkeypatch, wrap, remat="full", scan_blocks=scan,
+                         attn_impl="splash")
+    params = jax.eval_shape(lambda k: lm.init(k)[0], jax.random.PRNGKey(0))
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: lm_loss(lm, p, toks)))(params)
+    assert pallas_calls(jaxpr) == a_layer * (1 if scan else 2)
+
+
+def _one_step(lm, toks):
+    """Loss, gradients, and the parameters after one ``build_lm_step`` —
+    and the step's lowered program."""
+    from distlearn_tpu.train.lm import build_lm_step
+    mesh = Mesh(np.array(jax.devices()[:2]).reshape(2, 1, 1),
+                ("data", "seq", "model"))
+    params, _ = lm.init(jax.random.PRNGKey(0))
+    tk = jax.device_put(toks, NamedSharding(mesh, P("data", "seq")))
+    step = build_lm_step(lm, mesh, params, lr=0.1, donate=False)
+    grads = jax.jit(jax.grad(lambda p: lm_loss(lm, p, toks)))(params)
+    return step(params, tk), grads, step.lower(params, tk)
+
+
+@pytest.mark.parametrize("scan", [False, True], ids=["unrolled", "scanned"])
+@pytest.mark.parametrize("impl", ["splash", "xla"])
+def test_full_remat_is_bitwise_the_bare_checkpoint(monkeypatch, scan, impl):
+    """What the checkpoint keeps changes no number: loss, gradients and the
+    parameters after one step are bitwise those of the bare
+    ``jax.checkpoint`` block (the kernel in Pallas interpret mode here).
+    On the full-square path the policy finds no name, and the step is the
+    bare checkpoint's program text for text."""
+    from tests.program_util import program_text
+    kw = dict(remat="full", scan_blocks=scan, attn_impl=impl)
+    (got, loss), grads, lowered = _one_step(*_remat_lm(monkeypatch, "named",
+                                                       **kw))
+    (want, loss0), grads0, lowered0 = _one_step(*_remat_lm(monkeypatch,
+                                                           "bare", **kw))
+    assert float(loss) == float(loss0)
+    for tree, tree0 in ((got, want), (grads, grads0)):
+        for a, b in zip(jax.tree_util.tree_leaves(tree),
+                        jax.tree_util.tree_leaves(tree0)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert (program_text(lowered) == program_text(lowered0)) == (impl == "xla")
+
+
+@pytest.mark.parametrize("remat", [False, "mlp"])
+def test_other_remat_modes_lower_to_the_text_they_had(monkeypatch, remat):
+    """No checkpoint around the attention asks for the kernel's name, so
+    ``remat=False`` and ``remat="mlp"`` lower to what they lowered to when
+    the kernel call named nothing."""
+    from distlearn_tpu.parallel import sequence
+    from tests.program_util import program_text
+    kw = dict(remat=remat, attn_impl="splash")
+    *_, lowered = _one_step(*_remat_lm(monkeypatch, "named", **kw))
+    monkeypatch.setattr(sequence, "ATTN_RESIDUALS", None)
+    *_, unnamed = _one_step(*_remat_lm(monkeypatch, "named", **kw))
+    assert program_text(lowered) == program_text(unnamed)
+
+
 def test_remat_mode_validation():
     from distlearn_tpu.models.transformer import transformer_lm
     with pytest.raises(ValueError, match="remat"):
@@ -391,24 +470,28 @@ def v5e_chip():
                                           ("hybrid", "tpu")])
 def test_tpu_compiler_keeps_every_scope_at_gpt2_large_width(
         v5e_chip, kind, backend, monkeypatch):
-    """The benchmark's step at GPT-2-large's widths (1280 x 20 heads, vocab
-    50257, 8 x 1024 tokens, bf16, scanned, full remat; depth cut to 2: the
-    scan makes the program the same), compiled for the v5e: every declared
-    scope survives the TPU compiler's fusion, in every pass it belongs to.
-    Nothing runs; no number of this is a measurement.
+    """The benchmark's step at GPT-2-large's sizes (36 layers x 1280 x 20
+    heads, vocab 50257, 8 x 1024 tokens, bf16, scanned, full remat: the
+    scan makes the program no longer than at depth 2), compiled for the
+    v5e: every declared scope survives the TPU compiler's fusion, in every
+    pass it belongs to.  Nothing runs; no number of this is a measurement.
 
     ``kind="hybrid"``: the pattern LM's step at ITS published widths (4096,
     64 query heads over 8 K/V heads of 128, 64 KDA heads of 128, experts
     1280 wide, a router of 320, 8 a token; one softmax and one
     linear-attention layer, 2 experts held, 1 x 1024 tokens, a cut of the
     vocabulary) — the two scopes only it uses survive too, and its softmax
-    layer's grouped queries ride the same three Mosaic calls.
+    layer's grouped queries ride the same two Mosaic calls.
 
     ``local_attention`` picks its path from ``jax.default_backend()``,
     which here says "cpu" whatever the program is compiled for: ``"tpu"``
-    steers it to what the chip runs (the blockwise kernel: three Mosaic
-    calls under ``attn_core``, one a pass, and no ``[B, H, L, L]`` array),
-    ``"cpu"`` leaves the full-square path short or ragged lengths keep."""
+    steers it to what the chip runs (the blockwise kernel: two Mosaic calls
+    under ``attn_core``, the forward and the backward pass's — the
+    recomputation holds none, because the block's checkpoint keeps the two
+    results the backward call reads, :func:`checkpoint_block` — and no
+    ``[B, H, L, L]`` array), ``"cpu"`` leaves the full-square path short or
+    ragged lengths keep.  What the dense step keeps for that fits the chip:
+    the compiler's own temporary + argument bytes, at the full depth."""
     from distlearn_tpu.parallel import sequence
     monkeypatch.setattr(sequence, "_backend", lambda: backend)
     from jax.experimental.compilation_cache import compilation_cache
@@ -418,7 +501,7 @@ def test_tpu_compiler_keeps_every_scope_at_gpt2_large_width(
     mesh = Mesh(np.array([v5e_chip]).reshape(1, 1, 1),
                 ("data", "seq", "model"))
     if kind == "dense":
-        model = transformer_lm(vocab=50257, dim=1280, depth=2, heads=20,
+        model = transformer_lm(vocab=50257, dim=1280, depth=36, heads=20,
                                max_len=1024, compute_dtype=jnp.bfloat16,
                                scan_blocks=True, remat="full")
         batch, kernel, square = 8, "splash_mha", "[8,20,1024,1024]"
@@ -452,26 +535,33 @@ def test_tpu_compiler_keeps_every_scope_at_gpt2_large_width(
         # off (with them on local_attention keeps the full-square path:
         # Mosaic takes no int64 loop counter)
         with jax.enable_x64(False):
-            text = build_lm_step(model, mesh, template, lr=0.03).lower(
-                params, tokens).compile().as_text()
+            compiled = build_lm_step(model, mesh, template, lr=0.03).lower(
+                params, tokens).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
         compilation_cache.reset_cache()
+    text = compiled.as_text()
     table = scope_table(text)
     names = list(table.values())
     kernels = {k: v for k, v in table.items() if k.startswith(kernel)}
     square = square in text
     if backend == "tpu":
-        assert text.count('custom_call_target="tpu_custom_call"') == 3
+        assert text.count('custom_call_target="tpu_custom_call"') == 2
         assert not square
+        assert not any("rematted_computation" in n for n in kernels.values())
         passes = sorted(
-            ("recompute" if "rematted_computation" in n else
-             "bwd" if "transpose(" in n else "fwd", k.split(".")[0])
+            ("bwd" if "transpose(" in n else "fwd", k.split(".")[0])
             for k, n in kernels.items()
             if "/attn_core/" in n.replace("(", "/").replace(")", "/"))
         assert passes == [("bwd", f"{kernel}_dkv_no_residuals"),
-                          ("fwd", f"{kernel}_fwd_residuals"),
-                          ("recompute", f"{kernel}_fwd_residuals")]
+                          ("fwd", f"{kernel}_fwd_residuals")]
+        if kind == "dense":
+            memory = compiled.memory_analysis()
+            held = memory.temp_size_in_bytes + memory.argument_size_in_bytes
+            assert held < 15.75e9, (
+                f"the dense step holds {held / 1e9:.2f} GB of temporaries "
+                "and arguments by the compiler's count: over the chip's "
+                "15.75 GB")
     else:
         assert square and not kernels
 
