@@ -327,8 +327,8 @@ def phase_lm(*, mesh_shape: tuple[int, int, int] = (1, 1, 1),
                loss_xla=round(float(xloss), 4))
     del params
 
-    # long context, one step, default attention again (selective remat =
-    # bench.py's long-context recipe)
+    # long context, one step, default attention again (selective remat,
+    # which no cell of the benchmark runs)
     lstep, lparams, ltokens = build(long_seq, dp, remat="mlp")
     _, traced = _lower_default(lstep, lparams, ltokens,
                                f"seq {long_seq}")

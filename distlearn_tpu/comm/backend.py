@@ -29,10 +29,13 @@ once, a pod slice of L devices behind one host NIC.
   (``ops.staging``), ONE host TCP leg per host reduces that vector
   across hosts (``Conn.send_packed`` single-iovec frames, optional
   fused int8/fp16 codec), and an in-mesh ``all_gather`` fans the
-  result back over the slice.  Host-leg bytes per host drop by the
-  local device count L versus running L per-device TCP ranks — the
-  classic hierarchical-allreduce bandwidth win (measured:
-  bench.py ``host_sync_bench``, docs/PERF.md).
+  result back over the slice.  A host's NIC then moves ONE node's
+  payload T up and one down an allreduce (2T) whatever the local device
+  count L, where L per-device TCP ranks each move 2T a tree link: at
+  2 hosts x 8 devices x 2 MB, 4.19 MB a host against 92.28 MB (the 22
+  link ends of the busier host's 8 ranks in the base-2 tree).  Pinned
+  by tests/test_backend.py::test_hybrid_host_leg_moves_one_payload_a_host
+  and tests/test_ring.py::test_per_nic_bytes_tree_root_4t_ring_rank_3t.
 
 Value conventions (``stacked_nodes`` tells callers which one a backend
 speaks):
@@ -156,8 +159,8 @@ class HostCollectiveBase:
 
     def nic_bytes(self) -> int:
         """Total TCP payload bytes this handle has moved (in + out over
-        every live link) — the per-NIC traffic number the bench and the
-        ``sync_*`` metrics report (docs/PERF.md)."""
+        every live link) — the per-NIC traffic number the ``sync_*``
+        metrics report."""
         return sum(c.bytes_sent + c.bytes_received for c in self._links())
 
     # -- derived collectives ------------------------------------------------

@@ -6,18 +6,16 @@ but ``~4T`` of traffic through the base-2 root's NIC (two children, payload
 up AND down each) regardless of N.  A ring reduce-scatter + allgather
 (Baidu/NCCL style) puts ``2T*(N-1)/N`` out + the same in through every
 rank's NIC — ``3T`` at N=4, approaching ``2T`` as N grows, vs the root's
-fixed ``4T``.  Measured, not just claimed: at N=4, T=16 MB the bench
-records 67.1 MB through the tree root's NIC vs 50.3 MB through a ring
-rank's (bench.py host_allreduce, ``*_max_nic_bytes``).
+fixed ``4T``.  Counted on the connections' own byte counters, not just
+claimed (tests/test_ring.py::test_per_nic_bytes_tree_root_4t_ring_rank_3t).
 
-WHEN each wins (measured — docs/PERF.md): per-link bandwidth must be the
-bottleneck for the ring's advantage to show in wall clock.  On this
-1-core localhost host both backends push the same TOTAL bytes through one
-shared CPU, so the tree's fewer rounds win or tie (0.86-1.0x observed).
-With every link paced to an emulated 200 MB/s NIC (CPU unsaturated — the
-multi-host regime this backend is FOR), the ring runs **~1.4x faster**
-at N=4, T=16 MB (and the gap widens with N: the root's 4T is fixed while
-its subtree count grows the serialization).  Latency is ``2(N-1)`` hops vs the tree's ``2*log2(N)``,
+WHEN each wins: per-link bandwidth must be the bottleneck for the ring's
+advantage to show in wall clock.  On one localhost both backends push
+the same TOTAL bytes through a shared CPU, so the tree's fewer rounds win
+or tie; the ring is FOR links that are slower than the CPU (the
+multi-host regime; ``Conn.throttle_bps`` emulates one), and the gap
+widens with N: the root's 4T is fixed while its subtree count grows the
+serialization.  Latency is ``2(N-1)`` hops vs the tree's ``2*log2(N)``,
 so for tiny control-plane payloads the tree wins everywhere; the
 framework offers both (``comm.tree.Tree`` for scalars, ``Ring`` for
 bulk), the choice the reference never had.

@@ -121,8 +121,8 @@ class Conn:
 
     ``bytes_sent`` / ``bytes_received`` count payload bytes (frames +
     tensors) — the per-link traffic evidence behind the tree-vs-ring
-    bandwidth analysis (docs/PERF.md).  ``throttle_bps`` (None = off)
-    paces SENDS to that many bytes/second: localhost benches use it to
+    bandwidth analysis (comm/ring.py).  ``throttle_bps`` (None = off)
+    paces SENDS to that many bytes/second: localhost runs use it to
     emulate bandwidth-limited NIC links on a host whose loopback is
     CPU-bound (the regime the ring allreduce is designed for), by
     sleeping out the remainder of each send's wire-time budget."""

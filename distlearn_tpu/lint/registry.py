@@ -142,7 +142,7 @@ def _lm_mixed_family():
     params, _ = model.init(jax.random.PRNGKey(0))
     st = init_lm_mixed_state(params)
     # Default grad_dtype=f32 upcasts bf16 grads BEFORE the psum — the
-    # DL004-clean scheme docs/PERF.md motivates.
+    # DL004-clean scheme.
     step = build_lm_mixed_step(model, mesh, params, lr=0.1)
     tokens = jax.ShapeDtypeStruct((2 * dp, L), "int32")
     return _lint_units([("lm_mixed_step", step, (st, tokens))], mesh)

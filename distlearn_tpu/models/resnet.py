@@ -106,12 +106,10 @@ def resnet(depth: int = 50, num_classes: int = 1000, dtype=jnp.float32,
 
     ``norm="none"`` builds the norm-free SkipInit variant (De & Smith
     2020: zero-init scalar branch gains replace BN's start-as-identity
-    role; convs carry biases): no batch statistics exist at all, so the
-    ~50% of step time the r3 profile attributed to BN channel reductions
-    (docs/PERF.md) is simply absent, and there is no cross-replica
+    role; convs carry biases): no batch statistics exist at all, so BN's
+    channel reductions are simply absent, and there is no cross-replica
     stats sync.  The accuracy trade is the literature's, not re-verified
-    here; the bench reports both variants so the throughput delta is
-    measured, not assumed."""
+    here, and no cell measures either variant on the chip."""
     if depth not in _DEPTHS:
         raise ValueError(f"depth must be one of {sorted(_DEPTHS)}")
     if norm not in ("batch", "none"):

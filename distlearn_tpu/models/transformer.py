@@ -385,8 +385,7 @@ def transformer_lm(vocab: int = 256, dim: int = 128, depth: int = 2,
 
         # ONE wrapper per block kind, reused across the depth loop: a fresh
         # checkpoint closure per block stops XLA deduplicating the remat
-        # computation (measured 13% slower on the seq-4096 flash+remat
-        # bench); sharing restores it
+        # computation; sharing restores it
         blk_dense = make_block(False)
         blk_moe = make_block(True) if moe_experts > 0 else None
 

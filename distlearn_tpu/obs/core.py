@@ -2,9 +2,9 @@
 fixed-bucket histograms.
 
 The reference's entire observability story is ``colorPrint``
-(lua/colorPrint.lua via ``utils/logging.py``); every performance or
-robustness number in docs/PERF.md was recomputed by hand from ad-hoc
-prints or attributes like ``Conn.bytes_sent``.  This module is the
+(lua/colorPrint.lua via ``utils/logging.py``); before this module every
+traffic or robustness number was recomputed by hand from ad-hoc prints
+or attributes like ``Conn.bytes_sent``.  This module is the
 runtime counterpart of the static analyzers (distlint/distcost): the
 framework reports what it actually did — wire bytes per connection,
 handshake latencies, eviction churn, step timing — in one process-global

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from distlearn_tpu.ops import flatten as flatten_lib
-from distlearn_tpu.utils import flags
 from distlearn_tpu.ops.flatten import LANE
 
 PyTree = Any
@@ -36,15 +35,13 @@ PyTree = Any
 def fused_enabled(override: bool | None = None) -> bool:
     """Resolve whether trainers take the fused-kernel path.
 
-    Priority: explicit ``override`` > ``DISTLEARN_TPU_FUSED`` env (0/1) >
-    on-by-default on TPU, off elsewhere (interpret-mode Pallas on CPU is
-    correct but slower than XLA's own fusion, so it is opt-in there)."""
+    The explicit ``override`` when given, else on a TPU and off elsewhere
+    (interpret-mode Pallas on CPU is correct but slower than XLA's own
+    fusion, so it is opt-in there)."""
     if override is not None:
         return bool(override)
-    env = flags.env_truthy("DISTLEARN_TPU_FUSED")
-    if env is not None:
-        return env
     return jax.default_backend() == "tpu"
+
 
 _BLOCK_ROWS = 256  # rows of 128 lanes per grid step (128 KiB f32 per ref)
 

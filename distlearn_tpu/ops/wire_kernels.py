@@ -37,16 +37,13 @@ Backends:
 * **host native (CPU)** — a tiny single-pass SIMD C kernel
   (:mod:`wire_native`), compiled by the system compiler at first use and
   silently absent when there is no compiler.  This is the CPU production
-  route: ~4x lower int8 encode ns/byte than the reference numpy path on
-  the bench host (`bench.py wire_cpu_bench`).
+  route: one pass over a leaf where numpy makes five.
 * **host blocked (CPU fallback)** — a cache-blocked numpy implementation
   working in L2-resident chunks through one reusable thread-local
-  scratch buffer (~2x vs the reference; numpy cannot fuse the 5 ufunc
-  passes any further).  Measured on the 1-core bench host, XLA-CPU is
-  the wrong tool for this op: every ``jit`` call pays a device_put input
-  copy (~2 passes) and its reductions run ~7x slower than numpy's, so
-  the interpret/XLA route *loses* to plain numpy.  docs/PERF.md
-  "zero-copy wire" carries the numbers.
+  scratch buffer (numpy cannot fuse the 5 ufunc passes any further).
+  XLA-CPU is the wrong tool for this op: every ``jit`` call pays a
+  device_put input copy (~2 passes) before its first reduction, which is
+  why the host routes stay in numpy / C and not in the jitted kernels.
 
 Bitwise parity with comm/wire.py's reference codec is load-bearing (the
 tier-1 EASGD trajectory tests assert it at 50 rounds, S=1 and S=4):
@@ -108,7 +105,7 @@ def wirek_enabled(override: bool | None = None) -> bool:
 # ---------------------------------------------------------------------------
 
 #: Elements per chunk — 128k f32 = 512 KB keeps chunk + scratch L2-resident
-#: (bench.py sweep; below 32k the per-call numpy overhead dominates).
+#: (below 32k the per-call numpy overhead dominates).
 _CHUNK = 1 << 17
 
 _scratch = threading.local()

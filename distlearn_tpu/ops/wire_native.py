@@ -2,15 +2,10 @@
 
 The blocked-numpy route in :mod:`wire_kernels` is pass-count-bound: numpy
 cannot fuse ``div -> rint -> cast -> mul -> sub`` into one walk, so the
-int8 encode floor is ~5 separate ufunc passes (~2.1x the reference, not
-the 3x the wire budget targets).  This module closes the gap with a
-~40-line C kernel compiled by the SYSTEM compiler at first use: one
-single pass per leaf computes ``q = rint(d/scale)`` and the
-error-feedback residual ``r = d - q*scale`` together, auto-vectorized
-(the bench host emits 64-byte AVX-512 vectors).  Measured on that host:
-3.3x lower encode ns/byte than the reference numpy path over the CIFAR
-leaf set, 4.1x on the single 13 MB conv kernel (bench.py
-``wire_cpu_bench``; docs/PERF.md carries the table).
+int8 encode floor is ~5 separate ufunc passes.  This module closes the
+gap with a ~40-line C kernel compiled by the SYSTEM compiler at first
+use: one single pass per leaf computes ``q = rint(d/scale)`` and the
+error-feedback residual ``r = d - q*scale`` together, auto-vectorized.
 
 Strictly optional and silently degradable: no compiler, a failed
 compile, a failed load, or ``DISTLEARN_TPU_WIREC=0`` all fall back to

@@ -58,11 +58,10 @@ def pipeline_apply(stage_fn: Callable, stage_params: PyTree, x: jax.Array,
         are accumulated — the rest are masked to zero, so no gradient
         flows from them.
       unroll: forwarded to the tick ``lax.scan``.  ``True`` inlines all
-        ``T = M+S-1`` ticks so XLA fuses and overlaps across tick
-        boundaries — measured ~1.6x on the one-chip GPipe bench
-        (docs/PERF.md) — at the cost of a ~T-times-larger program and a
-        longer compile; off by default (the default wants re-measuring,
-        ROADMAP R4).
+        ``T = M+S-1`` ticks so XLA can fuse and overlap across tick
+        boundaries, at the cost of a ~T-times-larger program and a
+        longer compile; off by default (no pipeline step has been
+        measured on the chip: ROADMAP R5).
 
     Returns:
       Without ``consume_fn``: ``[B, ...]`` outputs of the LAST stage,

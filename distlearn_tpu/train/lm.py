@@ -74,8 +74,6 @@ def build_lm_step(model: Model, mesh: Mesh, params_template, lr: float,
                   tp_axis: str | None = "model",
                   ep_axis: str | None = None, accum_steps: int = 1,
                   moe_balance_weight: float = 0.0,
-                  fused: bool | None = None,
-                  max_bucket_bytes: int | None = None,
                   donate: bool = True,
                   seq_layout: str = "contig") -> Callable:
     """``step(params, tokens) -> (params, loss)``.
@@ -106,22 +104,14 @@ def build_lm_step(model: Model, mesh: Mesh, params_template, lr: float,
     bucket sizes and decides overflow drops per microbatch — training is
     still correct, but not bit-identical to the single-shot step.
 
-    ``fused=True`` routes the SGD update through the Pallas packed-bucket
-    kernel.  DEFAULT OFF for the LM family — measured on the v5e it is a
-    LOSS here (dim 4096: 0.335 vs 0.580 MFU; dim 1024: 0.303 vs 0.341),
-    the opposite of the classifier result (1.43x win): packing a
-    ~800M-param tree into flat buckets costs two multi-GB concatenate
-    passes, while XLA's per-leaf update fusions consume each gradient
-    where it is produced with no extra materialization.  Kept as an
-    option because the crossover favors packing for small trees
-    (docs/PERF.md "fused update" note).  Applies only when every grad
-    leaf's dtype matches its param leaf; falls back per-leaf otherwise.
+    The update is one ``tree_map`` over the leaves and never the packed
+    Pallas kernel of ``ops/fused_update.py``: on a tree of hundreds of
+    millions of parameters, packing gradients and parameters into buckets
+    and unpacking the result costs more passes over memory than the
+    per-leaf update makes (PERF.md section 7a.2).
     """
-    from distlearn_tpu.ops import flatten as flatten_lib
-    from distlearn_tpu.ops import fused_update
     if accum_steps < 1:
         raise ValueError(f"accum_steps must be >= 1, got {accum_steps}")
-    use_fused = bool(fused) if fused is not None else False
     axes = tuple(a for a in (data_axis, seq_axis) if a is not None)
     # expert leaves reduce over every replicated axis EXCEPT the one that
     # shards them — summing across ep_axis would mix different experts
@@ -151,19 +141,10 @@ def build_lm_step(model: Model, mesh: Mesh, params_template, lr: float,
 
         with jax.named_scope("grad_reduce"):
             grads = jax.tree_util.tree_map(reduce_grad, grads, is_ep_leaf)
-        gl = jax.tree_util.tree_leaves(grads)
-        pl = jax.tree_util.tree_leaves(params)
         with jax.named_scope("update"):
-            if use_fused and all(g.dtype == p.dtype
-                                 for g, p in zip(gl, pl)):
-                spec = flatten_lib.make_bucket_spec(grads, max_bucket_bytes)
-                g_flats = flatten_lib.pack_buckets(spec, grads)
-                new_params = fused_update.sgd_update_buckets(
-                    spec, params, g_flats, lr)
-            else:
-                new_params = jax.tree_util.tree_map(
-                    lambda p, g: p - jnp.asarray(lr, p.dtype)
-                    * g.astype(p.dtype), params, grads)
+            new_params = jax.tree_util.tree_map(
+                lambda p, g: p - jnp.asarray(lr, p.dtype)
+                * g.astype(p.dtype), params, grads)
         return new_params, lax.pmean(loss, data_axis)
 
     tok_spec = P(data_axis, seq_axis) if seq_axis else P(data_axis)
@@ -312,9 +293,8 @@ def build_lm_pp_step(mesh: Mesh, shared_template, stacked_template,
     :func:`distlearn_tpu.parallel.pp.pipeline_apply`, so the whole GPipe
     schedule — all ticks, forward and backward — is one XLA program, and
     the microbatch count doubles as the gradient-accumulation lever.
-    ``unroll=True`` inlines the tick scan (measured 1.68x on the one-chip
-    GPipe bench — see pipeline_apply; program size grows ~T-fold, so keep
-    it for small microbatch counts).
+    ``unroll=True`` inlines the tick scan (see pipeline_apply; program
+    size grows ~T-fold, so keep it for small microbatch counts).
 
     Each microbatch's loss share is folded ON the last rank as it emerges
     from the pipeline (``consume_fn``) — only a scalar psum crosses the
@@ -545,11 +525,9 @@ def build_lm_mixed_step(model: Model, mesh: Mesh, params_template, lr: float,
     """:func:`build_lm_step` with bf16 working params + f32 masters:
     ``step(st, tokens) -> (st, loss)`` on :class:`LMMixedState`.
 
-    Motivation (measured, docs/PERF.md): the f32-param step spends ~21%
-    of the dim-4096 step in the f32 ``p - lr*g`` elementwise update and
-    reads 4-byte weights in every matmul even though the MXU computes in
-    bf16 (the convert fuses into the matmul but the HBM read does not
-    shrink).  Storing the working copy in bf16 halves the weight bytes
+    Motivation: the f32-param step reads 4-byte weights in every matmul
+    even though the MXU computes in bf16 (the convert fuses into the
+    matmul but the HBM read does not shrink).  Storing the working copy in bf16 halves the weight bytes
     the three matmul passes pull per step; the f32 master confines f32
     elementwise traffic to the update itself.  Same mesh/sharding
     contract as :func:`build_lm_step` (``params_template`` may be either

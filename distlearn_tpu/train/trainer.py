@@ -90,8 +90,8 @@ class _TimedStep:
     call count.  It keeps the abstract signature of its first call (no
     buffer), so :meth:`hlo_text` can name the program the device ran.
     ``__getattr__`` forwards everything else to the jitted callable so
-    ``.lower()`` consumers — bench.py, the distcost budget gate — see the
-    unwrapped object and compiled HLO stays identical."""
+    ``.lower()`` consumers — the benchmark, the distcost budget gate — see
+    the unwrapped object and compiled HLO stays identical."""
 
     def __init__(self, fn, name: str):
         self._fn = fn

@@ -14,9 +14,9 @@ every entry point:
   directory that moves never hits.
 
 Only ENTRY POINTS call :func:`enable_compile_cache` (the examples'
-``setup_platform``, ``bench.py``, ``chip_smoke.py``).  Library code never
-does: a constructor must not reconfigure process-global JAX state, and
-tests that count compiles must not depend on a cache on disk.
+``setup_platform``, ``chip_smoke.py``, ``benchmarks/run.py``).  Library
+code never does: a constructor must not reconfigure process-global JAX
+state, and tests that count compiles must not depend on a cache on disk.
 """
 
 from __future__ import annotations

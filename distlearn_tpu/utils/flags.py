@@ -23,9 +23,8 @@ def env_truthy(name: str) -> bool | None:
     applies its own default), else the shared 0/false/off/empty rule.
 
     The ONE parser for the framework's feature toggles
-    (``DISTLEARN_TPU_FUSED``, ``DISTLEARN_OBS``, ...) — each user once
-    had a copy, which is exactly how the accepted spellings drift
-    apart."""
+    (``DISTLEARN_OBS``, ``DISTLEARN_TPU_WIREK``, ...) — each user once had
+    a copy, which is exactly how the accepted spellings drift apart."""
     value = os.environ.get(name)
     if value is None:
         return None
@@ -98,7 +97,7 @@ ASYNC_FLAGS = {
     "shards": (1, "server: stripe the center across this many shard "
                   "channels (clients sync stripes in parallel); "
                   "client: 0 opts out of sharded syncs even when the "
-                  "server advertises a stripe plan (see docs/PERF.md)"),
+                  "server advertises a stripe plan"),
 }
 
 OBS_FLAGS = {

@@ -1,7 +1,7 @@
 """Backend pinning helpers.
 
 Every entry point that needs a virtual CPU mesh (tests, the examples
-without ``--tpu``, bench probes, the driver's multichip dryrun) takes the
+without ``--tpu``, the driver's multichip dryrun) takes the
 same two steps, centralized here: replace any
 ``xla_force_host_platform_device_count`` already in ``XLA_FLAGS`` (a
 stale value must not override the caller's count), then pin the platform

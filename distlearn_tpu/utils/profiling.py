@@ -1,6 +1,7 @@
 """Tracing / profiling — the reference has none beyond xlua.progress bars
 (SURVEY.md §5); here: ``jax.profiler`` trace capture plus lightweight
-per-step wall-clock timers suitable for the bench harness.
+per-step wall-clock timers, and the table that names each instruction
+of a step's HLO by scope and pass for the benchmark's trace reducer.
 """
 
 from __future__ import annotations

@@ -49,7 +49,7 @@ if [ -n "$JOIN_AFTER$LEAVE_AFTER" ]; then
 fi
 SERVER_FLAGS=${CONCURRENT:+--concurrent}
 SERVER_FLAGS="$SERVER_FLAGS ${ELASTIC:+--elastic}"
-# SHARDS=N stripes the center across N shard channels (docs/PERF.md);
+# SHARDS=N stripes the center across N shard channels;
 # clients negotiate the plan in the Enter? handshake automatically
 SERVER_FLAGS="$SERVER_FLAGS ${SHARDS:+--shards $SHARDS}"
 # CENTER_CKPT=dir turns on HA checkpointing of the center (+ one final

@@ -18,8 +18,7 @@ never outgrow the 53-bit mantissa)
 the three trajectories are BITWISE identical — the printed digest is
 the same line for every ``--backend`` — while the hybrid host leg
 moves ~numNodes/numHosts-fold fewer TCP bytes than the flat host tree
-(tests/test_backend.py asserts both properties; bench.py
-``host_sync_bench`` measures the byte ratio).
+(tests/test_backend.py asserts both properties).
 
 Run:  python examples/sync_backends.py --backend mesh --numNodes 8
       python examples/sync_backends.py --backend host --numNodes 8

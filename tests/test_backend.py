@@ -240,6 +240,32 @@ def test_hybrid_two_hosts_allreduce_rider_scatter():
     np.testing.assert_array_equal(res[0][0], res[1][0])
 
 
+@pytest.mark.parametrize("local", [2, 4])
+def test_hybrid_host_leg_moves_one_payload_a_host(local):
+    """What the hierarchy is for, as a count: a host's TCP leg carries ONE
+    node's payload T up and one down per allreduce (2T through its NIC),
+    whatever the number of local devices behind it — the flat tree gives
+    each of those devices a rank that moves 2T a link (tests/test_ring.py
+    pins 4T at a base-2 root)."""
+    hosts, t_bytes = 2, 1 << 18
+    port = _port()
+    slices = _disjoint_devices(local)
+
+    def node(rank):
+        b = HybridBackend(rank, hosts, "127.0.0.1", port,
+                          devices=slices[rank])
+        val = np.ones((local, t_bytes // 4), np.float32)
+        before = b.host_leg.nic_bytes()
+        b.all_reduce(val)
+        moved = b.host_leg.nic_bytes() - before
+        b.barrier()
+        b.close()
+        return moved
+
+    for moved in tree_map_spawn(node, hosts, timeout=120):
+        assert 2 * t_bytes <= moved < 2.02 * t_bytes
+
+
 # ------------------------------------------------------------ EASGD parity
 
 _N, _ROUNDS, _ALPHA, _DIM = 4, 24, 0.5, 24

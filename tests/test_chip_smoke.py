@@ -77,13 +77,14 @@ def test_chip_smoke_refuses_the_cpu():
 
 
 def test_imports_initialise_no_backend():
-    """A launcher or client that imports the package (or bench, or the
-    smoke) must not take the chip: no JAX backend comes up on import."""
+    """A launcher or client that imports the package (or the driver's
+    entry module, or the smoke) must not take the chip: no JAX backend
+    comes up on import."""
     code = (
         "import json, sys\n"
         "import distlearn_tpu, distlearn_tpu.serve, distlearn_tpu.train\n"
         "import distlearn_tpu.models, distlearn_tpu.ops\n"
-        "import bench, chip_smoke\n"
+        "import __graft_entry__, chip_smoke\n"
         "from jax._src import xla_bridge\n"
         "print(json.dumps(xla_bridge.backends_are_initialized()))\n")
     env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}

@@ -29,24 +29,6 @@ def test_mnist_parity_line():
     assert rec["train_acc"] > 0.5, rec
 
 
-def test_bench_refuses_to_run_without_a_tpu():
-    """bench.main() on the CPU backend exits non-zero — a CPU number never
-    goes out under the device metric's name — and a device_kind that is
-    not in the peaks table raises instead of silently dropping MFU."""
-    import pytest
-    sys.path.insert(0, os.path.dirname(_EXAMPLES))  # repo root (bench.py)
-    import bench
-
-    with pytest.raises(SystemExit) as exc:
-        bench.main()
-    assert exc.value.code not in (0, None)
-    assert "needs a TPU" in str(exc.value.code)
-
-    assert bench.peak_flops_for("TPU v5 lite") == 197e12
-    with pytest.raises(ValueError, match="no bf16 peak known"):
-        bench.peak_flops_for("TPU v99 imaginary")
-
-
 def test_example_tpu_flag_refuses_the_cpu():
     """``--tpu`` means "use the TPU": with none visible the example exits
     non-zero with a one-line reason instead of training on the CPU."""

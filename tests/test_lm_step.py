@@ -327,8 +327,7 @@ def test_lm_pp_step_k_blocks_per_stage_remat():
 
 
 def test_lm_pp_step_unrolled_ticks_match():
-    """unroll=True (inlined tick scan, the measured-1.68x bench setting)
-    must not change the math."""
+    """unroll=True (inlined tick scan) must not change the math."""
     _pp_vs_sequential(depth=4, n_stages=2, num_microbatches=4, remat=False,
                       unroll=True)
 

@@ -450,8 +450,8 @@ def test_e2e_concurrent_run_jsonl_trail(clean_obs, tmp_path):
     assert hs["p95"] == hs["p95"] and hs["p95"] > 0    # finite, not NaN
     assert doc["spans"]["async_ea.rejoin"]["count"] == 1
     # per-conn wire bytes in the snapshot == the Conn attributes, exactly
-    # (single IO thread per conn; docs/PERF.md's traffic evidence is now
-    # exported, not recomputed by hand)
+    # (single IO thread per conn; the traffic evidence is exported, not
+    # recomputed by hand)
     checked = 0
     for c in conns:
         key = f'transport_bytes_sent_total{{conn="{c.conn_id}"}}'

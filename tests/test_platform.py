@@ -1,6 +1,6 @@
 """utils/platform.py: the flag-replacement helper every entry point leans
 on (a stale pre-set count silently overriding the request was a real bug
-class — bench probes, examples, dryrun)."""
+class — examples, dryrun)."""
 
 import os
 

@@ -132,6 +132,32 @@ def test_ring_matches_tree_bitwise():
         np.testing.assert_array_equal(ring_res[0]["f"], res["f"])
 
 
+def test_per_nic_bytes_tree_root_4t_ring_rank_3t():
+    """The byte counts the ring exists for, read from the connections' own
+    counters at N=4: the base-2 tree's root moves the payload T up and down
+    for each of its two children (4T through one NIC, whatever N), every
+    ring rank moves 2T(N-1)/N out and the same in (3T)."""
+    n, t_bytes = 4, 1 << 20
+    payload = np.ones(t_bytes // 4, np.float32)
+
+    def run(make, port):
+        def node(rank):
+            h = make(rank, n, port)
+            before = h.nic_bytes()
+            h.all_reduce(payload)
+            moved = h.nic_bytes() - before
+            h.close()
+            return moved
+        return tree_map_spawn(node, n)
+
+    tree_moved = run(LocalhostTree, _port())
+    ring_moved = run(LocalhostRing, _port())
+    assert 4 * t_bytes <= tree_moved[0] < 4.01 * t_bytes      # the root
+    assert max(tree_moved) == tree_moved[0]
+    for moved in ring_moved:
+        assert 3 * t_bytes <= moved < 3.01 * t_bytes
+
+
 def test_ring_sgd_reference_invariant():
     """The reference's AllReduceSGD bitwise oracle (test_AllReduceSGD.lua:38)
     over the RING backend: host_algorithms runs on either backend because the

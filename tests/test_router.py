@@ -498,7 +498,11 @@ def test_scenario_replica_kill_every_request_terminal():
     assert report["failures"] == []
     assert (report["completed"] + report["failed_mid_stream"]
             == report["requests"])
-    assert report["retries"] >= 1
+    # the kill was SEEN: a queued request resubmitted, or a stream that
+    # had started ended as a clean mid-stream failure (which of the two
+    # depends on where the kill catches the requests; each branch has a
+    # deterministic test above)
+    assert report["retries"] + report["failed_mid_stream"] >= 1
     assert report["replicas_dispatched"] >= 2
 
 

@@ -186,8 +186,7 @@ def test_sgd_scan_step_matches_per_call_steps():
 def test_sgd_scan_step_uneven_participation_matches_per_call():
     """The scanned step with a [K, num_nodes] participation matrix must
     reproduce K per-call with_contrib steps — the uneven-data-partition
-    semantics (lua/AllReduceSGD.lua:22-27) on the path the headline bench
-    actually measures."""
+    semantics (lua/AllReduceSGD.lua:22-27) on the scanned path."""
     tree = MeshTree(num_nodes=4)
     model = mnist_cnn()
     k = 4

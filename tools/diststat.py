@@ -3,8 +3,8 @@
 or diff two runs.
 
 The obs subsystem (distlearn_tpu/obs/) spills span records and registry
-snapshots to JSONL; this tool turns that trail into the numbers
-docs/PERF.md used to recompute by hand:
+snapshots to JSONL; this tool turns that trail into the numbers that
+used to be recomputed by hand:
 
     python tools/diststat.py summarize run.jsonl [more.jsonl ...]
     python tools/diststat.py summarize run.jsonl --format json
