@@ -30,7 +30,8 @@ from jax import lax, random
 
 from jax.sharding import PartitionSpec as P
 
-from distlearn_tpu.models.core import Model, checkpoint_block
+from distlearn_tpu.models.core import (Model, checkpoint_block,
+                                       scan_reducing)
 from distlearn_tpu.parallel.sequence import (alltoall_attention,
                                              local_attention, ring_attention)
 from distlearn_tpu.parallel.tp import tp_enter, tp_reduce
@@ -238,7 +239,13 @@ def transformer_lm(vocab: int = 256, dim: int = 128, depth: int = 2,
     Identical math to the unrolled layout (tested); convert between
     layouts with :func:`stack_block_params` / :func:`unstack_block_params`.
     Requires a homogeneous dense stack (no MoE blocks — their routed
-    leaves are a different pytree shape).
+    leaves are a different pytree shape).  ``apply(...,
+    grad_reduce_axis=name)`` on a scanned stack makes the loop
+    :func:`distlearn_tpu.models.core.scan_reducing`: the gradient of
+    ``params["blocks"]`` then comes out of the backward pass summed over
+    that mesh axis, layer by layer behind the loop's own work, and its
+    caller must not sum it again (``train/lm.py::build_lm_step`` decides;
+    nothing else passes it).
 
     ``moe_experts=E`` makes every ``moe_every``-th block's FFN a routed
     top-``moe_top_k`` mixture of ``E`` experts (parallel/ep.py; k=1 is
@@ -331,7 +338,7 @@ def transformer_lm(vocab: int = 256, dim: int = 128, depth: int = 2,
 
     def apply(params, state, tokens, train=True, rng=None, axis_name=None,
               bn_weight=None, seq_axis=None, tp_axis=None, ep_axis=None,
-              seq_layout="contig"):
+              seq_layout="contig", grad_reduce_axis=None):
         B, L = tokens.shape
         sa = seq_attn
         if seq_layout not in ("contig", "zigzag"):
@@ -390,7 +397,10 @@ def transformer_lm(vocab: int = 256, dim: int = 128, depth: int = 2,
         blk_moe = make_block(True) if moe_experts > 0 else None
 
         balance = dropped = n_moe = 0
-        if scan_blocks:
+        if scan_blocks and grad_reduce_axis is not None:
+            x = scan_reducing(blk_dense, x, params["blocks"],
+                              grad_reduce_axis)
+        elif scan_blocks:
             x, _ = lax.scan(lambda h, blk: (blk_dense(blk, h), None),
                             x, params["blocks"])
         else:
@@ -641,7 +651,8 @@ def param_specs(params: PyTree, tp_axis: str | None,
 
 def lm_loss(model: Model, params, tokens, seq_axis=None, tp_axis=None,
             ep_axis=None, reduce: bool = True,
-            moe_balance_weight: float = 0.0, seq_layout: str = "contig"):
+            moe_balance_weight: float = 0.0, seq_layout: str = "contig",
+            grad_reduce_axis: str | None = None):
     """Next-token cross-entropy.  With a sequence axis, the final position's
     target lives on the next shard — the shift rides a ppermute so the loss
     is exact across shard boundaries.
@@ -655,10 +666,16 @@ def lm_loss(model: Model, params, tokens, seq_axis=None, tp_axis=None,
 
     ``moe_balance_weight`` adds that multiple of the model's Switch
     load-balancing loss (state output ``moe_balance_loss``) — required for
-    stable MoE training; ignored for dense models."""
+    stable MoE training; ignored for dense models.
+
+    ``grad_reduce_axis`` goes to a scanned :func:`transformer_lm`'s
+    ``apply`` (see there) and to no other model."""
+    reducing = ({} if grad_reduce_axis is None
+                else {"grad_reduce_axis": grad_reduce_axis})
     logits, st = model.apply(params, {}, tokens, train=True,
                              seq_axis=seq_axis, tp_axis=tp_axis,
-                             ep_axis=ep_axis, seq_layout=seq_layout)
+                             ep_axis=ep_axis, seq_layout=seq_layout,
+                             **reducing)
     bal = (moe_balance_weight * st["moe_balance_loss"]
            if moe_balance_weight and isinstance(st, dict)
            and "moe_balance_loss" in st else None)

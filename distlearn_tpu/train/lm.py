@@ -6,6 +6,7 @@ projections) in ONE jitted shard_map program.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable, NamedTuple
 
 import jax
@@ -20,6 +21,8 @@ from distlearn_tpu.models.transformer import (_rmsnorm, block_apply, lm_loss,
                                               param_specs,
                                               stack_block_params,
                                               unstack_block_params)
+from distlearn_tpu import obs
+from distlearn_tpu.parallel.mesh import cut_axis
 from distlearn_tpu.parallel.pp import pipeline_apply
 from distlearn_tpu.train.trainer import (_timed, apply_elastic_round,
                                          local_update)
@@ -28,7 +31,8 @@ from distlearn_tpu.train.trainer import (_timed, apply_elastic_round,
 def lm_local_grads(model: Model, params, tokens, *, seq_axis, tp_axis,
                    ep_axis=None, accum_steps: int = 1,
                    moe_balance_weight: float = 0.0,
-                   seq_layout: str = "contig"):
+                   seq_layout: str = "contig",
+                   grad_reduce_axis: str | None = None):
     """``(local_loss_share, grads)`` of the LM objective on THIS device's
     shard — the gradient machinery shared by every LM step builder
     (:func:`build_lm_step`, ``optim.build_lm_optax_step``).
@@ -37,6 +41,9 @@ def lm_local_grads(model: Model, params, tokens, *, seq_axis, tp_axis,
     transposes to psum under shard_map, so the global psum'd loss must
     not sit inside the differentiated function.  ``accum_steps=k`` scans
     k microbatches and averages — memory lever, same effective batch.
+    ``grad_reduce_axis``: see :func:`lm_loss` (the gradient of a scanned
+    stack then comes back summed over that axis; only
+    :func:`build_lm_step` passes it).
     """
     def local_grad(toks):
         return jax.value_and_grad(
@@ -44,7 +51,8 @@ def lm_local_grads(model: Model, params, tokens, *, seq_axis, tp_axis,
                               tp_axis=tp_axis, ep_axis=ep_axis,
                               reduce=False,
                               moe_balance_weight=moe_balance_weight,
-                              seq_layout=seq_layout)
+                              seq_layout=seq_layout,
+                              grad_reduce_axis=grad_reduce_axis)
             )(params)
 
     if accum_steps == 1:
@@ -69,6 +77,60 @@ def lm_local_grads(model: Model, params, tokens, *, seq_axis, tp_axis,
                 lambda g: g / jnp.asarray(accum_steps, g.dtype), acc_g))
 
 
+#: A scanned layer whose gradient takes fewer bytes on one chip than this
+#: keeps the ``psum`` after the loop.  Set from a sweep on the four chips of
+#: a v5e host (12 layers, 8 x 1024 tokens a chip, the step's time with the
+#: sum inside the loop against the psum after it; my chip run, PR 32): at
+#: 3.2, 7.1 and 12.6 MB a layer the two read the same to 0.9 % either way
+#: (an exchange under 1 MB costs the 100 us it takes to start, whatever its
+#: size), at 28.3 MB the loop wins 1.9 %, at 50.4 MB 2.1 %, at GPT-2-large's
+#: 78.7 MB 3.0 %.
+PIPELINED_LAYER_BYTES = 16 << 20
+
+
+def _local_shape(leaf, spec, mesh: Mesh) -> tuple[int, ...]:
+    """Shape of ``leaf``'s shard on one device of ``mesh`` under ``spec``."""
+    names = tuple(spec) + (None,) * (leaf.ndim - len(spec))
+    return tuple(
+        d // math.prod(mesh.shape[a] for a in jax.tree_util.tree_leaves(name))
+        for d, name in zip(leaf.shape, names))
+
+
+def _local_bytes(leaf, spec, mesh: Mesh) -> int:
+    """Bytes of ``leaf``'s shard on one device of ``mesh`` under ``spec``."""
+    return (math.prod(_local_shape(leaf, spec, mesh))
+            * jnp.dtype(leaf.dtype).itemsize)
+
+
+def _pipelined(mesh: Mesh, template, pspecs, data_axis, seq_axis, ep_axis,
+               accum_steps) -> bool:
+    """Whether the block gradients are summed over ``data_axis`` INSIDE the
+    backward pass (:func:`~distlearn_tpu.models.core.scan_reducing`): the
+    stack is scanned, the data axis (a power of two above 1) is the only
+    axis those gradients are summed over, one backward pass makes them,
+    every leaf of a layer can be cut in as many chunks as the axis has
+    devices, and a layer is large enough for the exchange to be bound by
+    its bytes."""
+    if "blocks" not in template or accum_steps > 1 or ep_axis is not None:
+        return False
+    if seq_axis is not None and mesh.shape[seq_axis] > 1:
+        return False
+    dp = mesh.shape[data_axis]
+    if dp < 2 or dp & (dp - 1):
+        return False
+    leaves = jax.tree_util.tree_leaves(template["blocks"])
+    specs = jax.tree_util.tree_leaves(
+        pspecs["blocks"], is_leaf=lambda s: isinstance(s, P))
+    if len({leaf.dtype for leaf in leaves}) > 1:
+        return False
+    if any(cut_axis(_local_shape(leaf, spec, mesh)[1:], dp) is None
+           for leaf, spec in zip(leaves, specs)):
+        return False
+    layer = sum(_local_bytes(leaf, spec, mesh)
+                for leaf, spec in zip(leaves, specs)) // leaves[0].shape[0]
+    return layer >= PIPELINED_LAYER_BYTES
+
+
 def build_lm_step(model: Model, mesh: Mesh, params_template, lr: float,
                   data_axis: str = "data", seq_axis: str | None = "seq",
                   tp_axis: str | None = "model",
@@ -83,6 +145,17 @@ def build_lm_step(model: Model, mesh: Mesh, params_template, lr: float,
     across data/seq).  Gradients are psum'd over data+seq axes (params are
     replicated there); TP-sharded leaves need no gradient collective — each
     device owns its slice.
+
+    Where a SCANNED stack trains data-parallel (see :func:`_pipelined`: the
+    mesh and the leaf sizes decide, no argument does), the gradients of
+    ``params["blocks"]`` are summed over ``data_axis`` inside the backward
+    loop, each layer's behind the layers after it
+    (:func:`~distlearn_tpu.models.core.scan_reducing`): float32, the same
+    bytes over the links, every replica the same bits.  The psum after the
+    loop then keeps the leaves made outside it (embedding, positions, last
+    norm).  The gauges ``train.grad_reduce.pipelined_bytes{step=lm}`` and
+    ``train.grad_reduce.tail_bytes{step=lm}`` say, from build time, how many
+    bytes a step one chip sums inside the loop and after it.
 
     ``ep_axis`` (MoE models): the mesh axis the expert-stacked leaves are
     sharded over — normally ``data_axis`` itself (EP group == DP group,
@@ -117,14 +190,39 @@ def build_lm_step(model: Model, mesh: Mesh, params_template, lr: float,
     # shards them — summing across ep_axis would mix different experts
     ep_grad_axes = tuple(a for a in axes if a != ep_axis)
     pspecs = param_specs(params_template, tp_axis, ep_axis)
-    is_ep_leaf = jax.tree_util.tree_map(
-        lambda s: ep_axis is not None and ep_axis in s, pspecs)
+    pipelined = _pipelined(mesh, params_template, pspecs, data_axis,
+                           seq_axis, ep_axis, accum_steps)
+    # the axes each leaf's gradient is still to be summed over after the
+    # backward pass
+    grad_axes = jax.tree_util.tree_map(
+        lambda s: ep_grad_axes if ep_axis is not None and ep_axis in s
+        else axes, pspecs)
+    if pipelined:
+        grad_axes["blocks"] = jax.tree_util.tree_map(
+            lambda _: (), params_template["blocks"])
+
+    held = jax.tree_util.tree_map(
+        lambda leaf, s: _local_bytes(leaf, s, mesh), params_template, pspecs)
+    tail = jax.tree_util.tree_map(
+        lambda n, over: n * (math.prod(mesh.shape[a] for a in over) > 1),
+        held, grad_axes)
+    obs.gauge(
+        "train.grad_reduce.pipelined_bytes", "gradient bytes one chip sums "
+        "over the data axis inside the backward loop, a step",
+        labels=("step",)).labels(step="lm").set(
+            sum(jax.tree_util.tree_leaves(held["blocks"])) if pipelined else 0)
+    obs.gauge(
+        "train.grad_reduce.tail_bytes", "gradient bytes one chip hands to "
+        "the psum after the backward pass, a step",
+        labels=("step",)).labels(step="lm").set(
+            sum(jax.tree_util.tree_leaves(tail)))
 
     def step(params, tokens):
         local_loss, grads = lm_local_grads(
             model, params, tokens, seq_axis=seq_axis, tp_axis=tp_axis,
             ep_axis=ep_axis, accum_steps=accum_steps,
-            moe_balance_weight=moe_balance_weight, seq_layout=seq_layout)
+            moe_balance_weight=moe_balance_weight, seq_layout=seq_layout,
+            grad_reduce_axis=data_axis if pipelined else None)
         loss = lax.psum(local_loss, seq_axis) if seq_axis else local_loss
         # Sum partial grads over seq (params replicated there, each shard
         # holds part of the chain) and AVERAGE over data (the global
@@ -133,14 +231,13 @@ def build_lm_step(model: Model, mesh: Mesh, params_template, lr: float,
         # the f/g pattern leaves each slice's gradient exact.
         dp = lax.psum(1, data_axis)
 
-        def reduce_grad(g, is_ep):
-            gaxes = ep_grad_axes if is_ep else axes
-            if gaxes:
-                g = lax.psum(g, gaxes)
+        def reduce_grad(g, over):
+            if over:
+                g = lax.psum(g, over)
             return g / jnp.asarray(dp, g.dtype)
 
         with jax.named_scope("grad_reduce"):
-            grads = jax.tree_util.tree_map(reduce_grad, grads, is_ep_leaf)
+            grads = jax.tree_util.tree_map(reduce_grad, grads, grad_axes)
         with jax.named_scope("update"):
             new_params = jax.tree_util.tree_map(
                 lambda p, g: p - jnp.asarray(lr, p.dtype)

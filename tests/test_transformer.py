@@ -1,6 +1,8 @@
 """Transformer LM: sequence-parallel (ring attention) and tensor-parallel
 outputs must match the single-device model exactly (same full params)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -453,17 +455,56 @@ def test_scope_table_reads_an_instruction_that_runs_over_lines():
 
 
 @pytest.fixture(scope="module")
-def v5e_chip():
-    """One described (not attached) v5e chip: the TPU compiler runs here
-    without the chip.  Inside a fixture, in this one file, so that only
-    the worker that is given this file loads the TPU's library."""
+def v5e_host():
+    """The four described (not attached) chips of a v5e host: the TPU
+    compiler runs here without them.  Inside a fixture, in this one file,
+    so that only the worker that is given this file loads the TPU's
+    library."""
     from jax.experimental import topologies
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:      # noqa: BLE001 — whatever says "not here"
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return topo.devices[0]
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def v5e_chip(v5e_host):
+    return v5e_host[0]
+
+
+def _compiled_lm_step(model, devices, batch):
+    """``build_lm_step``'s program for ``batch`` x 1024 tokens a chip,
+    compiled for the described ``devices`` as a data-parallel mesh."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from distlearn_tpu.train.lm import build_lm_step
+    mesh = Mesh(np.array(devices).reshape(len(devices), 1, 1),
+                ("data", "seq", "model"))
+    template = jax.eval_shape(lambda k: model.init(k)[0],
+                              jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(
+        lambda t, s: jax.ShapeDtypeStruct(t.shape, t.dtype,
+                                          sharding=NamedSharding(mesh, s)),
+        template, param_specs(template, "model"))
+    tokens = jax.ShapeDtypeStruct(
+        (batch * len(devices), 1024), jnp.int32,
+        sharding=NamedSharding(mesh, P("data", "seq")))
+    # a compile for a described chip cannot be read back from the
+    # persistent cache: keep it out, and the run silent
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        # conftest turns 64-bit types on; a program for the chip has them
+        # off (with them on local_attention keeps the full-square path:
+        # Mosaic takes no int64 loop counter)
+        with jax.enable_x64(False):
+            return build_lm_step(model, mesh, template, lr=0.03).lower(
+                params, tokens).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
 
 
 @pytest.mark.parametrize("kind,backend", [("dense", "tpu"), ("dense", "cpu"),
@@ -494,12 +535,8 @@ def test_tpu_compiler_keeps_every_scope_at_gpt2_large_width(
     the compiler's own temporary + argument bytes, at the full depth."""
     from distlearn_tpu.parallel import sequence
     monkeypatch.setattr(sequence, "_backend", lambda: backend)
-    from jax.experimental.compilation_cache import compilation_cache
     from distlearn_tpu.models.core import SCOPES
-    from distlearn_tpu.train.lm import build_lm_step
     from distlearn_tpu.utils.profiling import scope_table
-    mesh = Mesh(np.array([v5e_chip]).reshape(1, 1, 1),
-                ("data", "seq", "model"))
     if kind == "dense":
         model = transformer_lm(vocab=50257, dim=1280, depth=36, heads=20,
                                max_len=1024, compute_dtype=jnp.bfloat16,
@@ -516,30 +553,7 @@ def test_tpu_compiler_keeps_every_scope_at_gpt2_large_width(
                           compute_dtype=jnp.bfloat16, remat="full")
         batch, kernel, square = 1, "splash_mqa", "[1,64,1024,1024]"
         mine = set(SCOPES)
-    template = jax.eval_shape(lambda k: model.init(k)[0],
-                              jax.random.PRNGKey(0))
-    params = jax.tree_util.tree_map(
-        lambda t, s: jax.ShapeDtypeStruct(t.shape, t.dtype,
-                                          sharding=NamedSharding(mesh, s)),
-        template, param_specs(template, "model"))
-    tokens = jax.ShapeDtypeStruct(
-        (batch, 1024), jnp.int32,
-        sharding=NamedSharding(mesh, P("data", "seq")))
-    # a compile for a described chip cannot be read back from the
-    # persistent cache: keep it out, and the run silent
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        # conftest turns 64-bit types on; a program for the chip has them
-        # off (with them on local_attention keeps the full-square path:
-        # Mosaic takes no int64 loop counter)
-        with jax.enable_x64(False):
-            compiled = build_lm_step(model, mesh, template, lr=0.03).lower(
-                params, tokens).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
-        compilation_cache.reset_cache()
+    compiled = _compiled_lm_step(model, [v5e_chip], batch)
     text = compiled.as_text()
     table = scope_table(text)
     names = list(table.values())
@@ -582,3 +596,43 @@ def test_tpu_compiler_keeps_every_scope_at_gpt2_large_width(
     # the compiler folds it away, so all the model's names but that remain
     # — and the dense model shows none of the pattern LM's two
     assert mine - {"grad_reduce"} <= {s for s in SCOPES if seen(s)} <= mine
+
+
+def test_tpu_compiler_hides_the_gradient_sum_behind_the_backward_scan(
+        v5e_host, monkeypatch):
+    """``gpt2-large.train-dp4``'s step (the sizes above on all four
+    described chips, mesh ``[4, 1, 1]``), compiled for the v5e: the
+    backward loop's body starts its ``collective-permute``s BEFORE the
+    layer's backward attention kernel and awaits them AFTER it, so each
+    layer's gradient crosses the links behind the layer that follows; no
+    all-reduce of a ``[36, ...]`` stack is left after the loop; the loop we
+    wrote keeps what ``checkpoint_block`` keeps (two Mosaic calls: the
+    forward kernel is not back in the backward pass); and what the step
+    holds fits the chip.  Nothing runs; no number of this is a
+    measurement."""
+    from distlearn_tpu.parallel import sequence
+    monkeypatch.setattr(sequence, "_backend", lambda: "tpu")
+    model = transformer_lm(vocab=50257, dim=1280, depth=36, heads=20,
+                           max_len=1024, compute_dtype=jnp.bfloat16,
+                           scan_blocks=True, remat="full")
+    compiled = _compiled_lm_step(model, v5e_host, 8)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert not re.search(r"= \(?f32\[36,[^=]*? all-reduce(-start)?\(", text)
+    body, = [c for c in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \([^\n]*\) -> )",
+                                 text)
+             if "splash_mha_dkv_no_residuals" in c
+             and "fused_computation" not in c.split("\n", 1)[0]]
+    kernel = body.index(" custom-call(", body.index(
+        "%splash_mha_dkv_no_residuals"))
+    starts = [m.start() for m in re.finditer(
+        r" collective-permute-start\(", body)]
+    dones = [m.start() for m in re.finditer(
+        r" collective-permute-done\(", body)]
+    # five transfers a layer: the halves and quarters of two layers'
+    # gradients, and one layer's summed quarter to each of the other chips
+    assert len(starts) == len(dones) == 5
+    assert max(starts) < kernel < min(dones)
+    memory = compiled.memory_analysis()
+    held = memory.temp_size_in_bytes + memory.argument_size_in_bytes
+    assert held < 15.75e9, f"{held / 1e9:.2f} GB of temporaries and arguments"
