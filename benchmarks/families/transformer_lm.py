@@ -47,8 +47,7 @@ def train_flops_per_sample(cfg: dict, seq: int) -> float:
     """Operations the forward and backward passes REQUIRE for one sequence
     of ``seq`` tokens (a multiply-add is 2): matrix products and attention
     only, causal attention counted at half the square, backward = 2 x
-    forward, recomputation not counted (the PaLM-appendix count; the shape
-    of bench.py's ``_analytic_lm_train_flops``)."""
+    forward, recomputation not counted (the PaLM-appendix count)."""
     e, f = cfg["n_embd"], cfg.get("n_inner") or 4 * cfg["n_embd"]
     per_token = cfg["n_layer"] * (8 * e * e + 4 * e * f) \
         + 2 * e * cfg["vocab_size"]

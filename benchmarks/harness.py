@@ -228,12 +228,14 @@ class HostProbe:
 class Result:
     """What a kind hands back.  ``end_to_end``: metric name -> value, as
     measured.  ``window``: whatever the per-layer readers of this kind of
-    cell take their numbers from (counts, clocks, snapshots)."""
+    cell take their numbers from (counts, clocks, snapshots).  ``compared``:
+    every number the kind's check compared, ``{name: [value, limit]}``."""
     correct: bool
     attempted: int
     failed: int
     end_to_end: dict
     window: dict = dataclasses.field(default_factory=dict)
+    compared: dict = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -251,6 +253,7 @@ class Run:
     t_process: float            # perf_counter at process start
     setup_s: float | None = None
     setup_meter: dict | None = None
+    closed_s: float = float("inf")      # the window's end, as setup_s
 
     @property
     def family(self):
@@ -278,6 +281,7 @@ class Run:
     def close_window(self) -> dict:
         """Ends the traced window; returns what compiled INSIDE the window
         (all zeros in a sound run)."""
+        self.closed_s = time.perf_counter() - self.t_process
         self.trace.stop(self.devices)
         after = self.meter.snapshot()
         inside = {k: after[k] - self.setup_meter[k] for k in after}
@@ -286,9 +290,15 @@ class Run:
 
 
 def device_record(devices, trace: TraceWindow) -> dict:
+    """The result line's ``device``.  ``memory_peak_bytes`` is the fullest
+    chip's ``peak_bytes_in_use``, which leaves out what a loaded program
+    RESERVES for its temporaries: that counter is logged beside it."""
     d0 = devices[0]
-    peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-               for d in devices)
+    stats = [d.memory_stats() or {} for d in devices]
+    peak = max(s.get("peak_bytes_in_use", 0) for s in stats)
+    log(f"memory: peak_bytes_in_use {peak}, peak_bytes_reserved "
+        f"{max(s.get('peak_bytes_reserved', 0) for s in stats)}, "
+        f"bytes_limit {max(s.get('bytes_limit', 0) for s in stats)}")
     import jax
     rec = {"platform": d0.platform, "kind": d0.device_kind,
            "count": len(jax.devices()), "memory_peak_bytes": int(peak)}

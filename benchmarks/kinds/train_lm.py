@@ -8,8 +8,10 @@ the check's ``loss_tolerance``.
 Correctness, outside the window, on one batch of the cell's own shape (so the
 system compiles ONE step program): the loss before any update and after each
 of ``check_steps`` SGD steps, system against the plain reference applied
-layer by layer.  After the window the loss must be finite and below the
-first.
+layer by layer.  After the window one more call of the step on that same
+batch returns its loss under the trained parameters, which must be finite and
+below its first: one batch compared with itself (the window may end on a
+batch of the ring that no step has seen, whose loss says nothing).
 """
 
 from __future__ import annotations
@@ -83,12 +85,17 @@ def run(run) -> Result:
                           samples_per_call=batch, in_flight=wl["in_flight"])
     inside = run.close_window()
     last = float(stats.pop("last"))
-    ok_after = trained(sys_losses[0], last)
-    log(f"window: {stats['calls']} steps in {stats['elapsed_s']:.3f}s, "
-        f"loss {sys_losses[0]:.4f} -> {last:.4f}")
+    params, after = step(params, batches[0])    # the check's batch, again
+    after = float(after)
+    ok_after = trained(sys_losses[0], after)
+    log(f"window: {stats['calls']} steps in {stats['elapsed_s']:.3f}s, the "
+        f"window's last loss {last:.4f}; the check's batch {sys_losses[0]:.4f}"
+        f" -> {after:.4f}")
+    compared = {f"loss_gap_step{i}": [g, tol] for i, g in enumerate(gaps)}
+    compared["loss_after_minus_first"] = [after - sys_losses[0], 0.0]
     return Result(
         correct=bool(ok_check and ok_after), attempted=stats["calls"],
-        failed=0,
+        failed=0, compared=compared,
         end_to_end={"train_samples_per_s": rate},
         window={**stats, "compiled_inside": inside, "chips": len(devices),
                 "flops_per_sample": fam.train_flops_per_sample(cfg, seq),

@@ -102,6 +102,10 @@ def result_line(man, run, result) -> dict:
         line["breakdown"] = {
             "device_ops": run.trace.reduction["device_ops"],
             "idle_gaps": run.trace.reduction["idle_gaps"]}
+    # every number the check compared beside its limit: last in the line
+    line["compared"] = dict(
+        result.compared,
+        programs_compiled_in_window=[inside.get("programs", 0), 0])
     return line
 
 
@@ -135,7 +139,11 @@ def main(argv=None) -> int:
                                devices=devs[:cell["chips"]], peaks=peaks,
                                meter=meter, t_process=T_PROCESS)
     harness.log(f"compile meter at exit: {json.dumps(meter.snapshot())}")
-    print(json.dumps(result_line(man, run, result)), flush=True)
+    line = result_line(man, run, result)
+    for name, (value, limit) in line["compared"].items():
+        print(f"compared {name}: {value!r} limit {limit!r}", file=sys.stderr)
+    print(f"correct: {line['correct']}", file=sys.stderr, flush=True)
+    print(json.dumps(line), flush=True)
     return 0
 
 

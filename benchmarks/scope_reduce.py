@@ -7,13 +7,14 @@ optimized HLO: JAX's ``op_name``, which carries the ``jax.named_scope`` of
 ``distlearn_tpu.models.core.SCOPES`` and JAX's own marks of the pass).
 
 Pure functions on text and dicts first (tested on the CPU with hand counts),
-then the two that read a run.  A program that has no scopes, no catalog of
+then the ones that read a run.  A program that has no scopes, no catalog of
 step programs or no ``train.dispatch`` span (the parent of the PR that added
 them) gives ``None`` everywhere: a reader then leaves its metric out.
 """
 
 from __future__ import annotations
 
+import functools
 import json
 import re
 import statistics
@@ -47,23 +48,33 @@ def phase_of(op_name: str) -> str:
     return "other"
 
 
-def scope_of(op_name: str, scopes) -> str:
-    """The declared scope among the ``/`` components of ``op_name`` (the
-    innermost, should two nest), seen through JAX's transform wrappers
-    (``transpose(jvp(attn_core))``) but not through ``jit(...)``, which
-    names a function and not a scope; ``UNSCOPED`` if there is none."""
-    found = UNSCOPED
+def components(op_name: str) -> list[str]:
+    """The ``/`` components of ``op_name`` seen through JAX's transform
+    wrappers (``transpose(jvp(attn_core))`` is ``attn_core``) but not
+    through ``jit(...)``, which names a function and not a scope (it reads
+    as the empty string)."""
+    out = []
     for part in op_name.split("/"):
         while (m := _WRAPPED.match(part)):
             if m.group(1) in ("jit", "pjit"):
                 part = ""
                 break
             part = m.group(2)
+        out.append(part)
+    return out
+
+
+def scope_of(op_name: str, scopes) -> str:
+    """The declared scope among the components of ``op_name`` (the
+    innermost, should two nest); ``UNSCOPED`` if there is none."""
+    found = UNSCOPED
+    for part in components(op_name):
         if part in scopes:
             found = part
     return found
 
 
+@functools.lru_cache(maxsize=2)      # a run's readers all ask about one text
 def instructions(hlo_text: str) -> dict:
     """``{instruction name: (opcode, bytes of its array result or None,
     operand names)}`` of every instruction of an HLO module's text."""
@@ -101,20 +112,145 @@ def collective_kind(opcode: str) -> str | None:
     return base if base in COLLECTIVES else None
 
 
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*->.*\{\s*$")
+_CALLEE = re.compile(
+    r"\b(body|condition|calls|to_apply|true_computation|false_computation|"
+    r"branch_computations)=(\{[^}]*\}|%?[\w.\-]+)")
+_TRIPS = re.compile(r'"known_trip_count":\{"n":"(\d+)"\}')
+_SCALAR = re.compile(r"^\s*[su]\d+\[\]\S*\s+constant\((-?\d+)\)")
+_LESS = re.compile(
+    r"\scompare\(%?([\w.\-]+),\s*%?([\w.\-]+)\),\s*direction=LT")
+_INDEX = re.compile(r"\sget-tuple-element\(.*\),\s*index=(\d+)")
+
+
+def _loop_trips(rests: dict, roots: dict, ins: dict, rest: str):
+    """How often a ``while`` (its text after ``=``) runs its body: the
+    compiler's own ``known_trip_count`` where the text has it (the CPU's),
+    else read from the loop itself (the TPU compiler writes none): a
+    condition ``counter < N`` on a counter that starts at a constant ``c``
+    gives N - c — a counter that steps by one, as every ``lax.scan`` and
+    ``fori_loop`` has it.  None where neither can be read."""
+    known = _TRIPS.search(rest)
+    if known:
+        return int(known.group(1))
+    cond = re.search(r"\bcondition=%?([\w.\-]+)", rest)
+    init = re.search(r"\swhile\(%?([\w.\-]+)\)", rest)
+    less = cond and _LESS.search(rests.get(roots.get(cond.group(1)), ""))
+    if not (less and init):
+        return None
+    counter, bound = (rests.get(n, "") for n in less.groups())
+    index, n = _INDEX.search(counter), _SCALAR.match(bound)
+    start = ins.get(init.group(1), ("", None, []))[2]
+    if not (index and n) or int(index.group(1)) >= len(start):
+        return None
+    start = start[int(index.group(1))]
+    for _ in range(4):              # through the copies a compiler puts in
+        if ins.get(start, ("",))[0] not in ("copy", "bitcast", "convert"):
+            break
+        start = ins[start][2][0]
+    c = _SCALAR.match(rests.get(start, ""))
+    return max(0, int(n.group(1)) - int(c.group(1))) if c else None
+
+
+@functools.lru_cache(maxsize=2)
+def structure(hlo_text: str):
+    """How the module's computations hang together: ``(computation of each
+    instruction, {instruction: [(callee computation, times)]}, entry)``.
+    ``times`` is how often one execution of the instruction runs the
+    callee: a ``while``'s trip count for its body (:func:`_loop_trips`;
+    None where it cannot be read), 1 for everything else."""
+    where, rests, roots, entry, comp = {}, {}, {}, None, None
+    for line in hlo_text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            comp = head.group(1)
+            if line.startswith("ENTRY"):
+                entry = comp
+            continue
+        m = _DEF.match(line)
+        if not m or comp is None:
+            continue
+        name, rest = m.groups()
+        where[name], rests[name] = comp, rest
+        if line.lstrip().startswith("ROOT"):
+            roots[comp] = name
+    ins = instructions(hlo_text)
+    calls = {}
+    for name, rest in rests.items():
+        for attr, names in _CALLEE.findall(rest):
+            for callee in re.findall(r"[\w.\-]+", names):
+                times = _loop_trips(rests, roots, ins, rest) \
+                    if attr == "body" else 1
+                calls.setdefault(name, []).append((callee, times))
+    return where, calls, entry
+
+
+def executions(hlo_text: str) -> dict:
+    """``{computation: how often one run of the module executes it}``: the
+    entry once, a ``while`` body its loop's trip count times its loop's own
+    executions, anything else as often as its callers.  A loop whose trip
+    count cannot be read counts ONCE, and is logged."""
+    where, calls, entry = structure(hlo_text)
+    callers: dict[str, list] = {}
+    unknown = []
+    for name, callees in calls.items():
+        for callee, times in callees:
+            if times is None:
+                unknown.append(name)
+                times = 1
+            callers.setdefault(callee, []).append((where[name], times))
+    if unknown:
+        log(f"{len(unknown)} loops with no known_trip_count and no "
+            "condition it can be read from in the step's text, their bodies "
+            f"count once: {unknown[:8]}")
+    done: dict[str, int] = {}
+
+    def runs(comp):
+        if comp not in done:
+            done[comp] = 0          # a cycle cannot be: HLO calls form a DAG
+            done[comp] = 1 if comp == entry else sum(
+                runs(c) * t for c, t in callers.get(comp, ()))
+        return done[comp]
+    return {comp: runs(comp) for comp in set(where.values())}
+
+
 def collective_bytes(hlo_text: str) -> dict:
     """``{collective kind: bytes}`` one chip hands to the collective
-    instructions of the module, each instruction of the text counted once
-    (one inside a ``while`` body too: no cell has one there): the bytes of
-    its operands, sync or ``-start`` form; a ``-done`` hands over nothing
-    new."""
+    instructions of the module in ONE run of it: the bytes of an
+    instruction's operands (sync or ``-start`` form; a ``-done`` hands over
+    nothing new) times the trip count of every ``while`` it sits in
+    (:func:`executions`)."""
     ins = instructions(hlo_text)
+    where, _, _ = structure(hlo_text)
+    runs = executions(hlo_text)
     total: dict[str, int] = {}
-    for opcode, _, operands in ins.values():
+    for name, (opcode, _, operands) in ins.items():
         kind = collective_kind(opcode)
         if kind and not opcode.endswith("-done"):
-            total[kind] = total.get(kind, 0) + sum(
-                ins[o][1] or 0 for o in operands if o in ins)
+            total[kind] = total.get(kind, 0) + runs.get(where.get(name), 1) \
+                * sum(ins[o][1] or 0 for o in operands if o in ins)
     return total
+
+
+def enclosed_by(hlo_text: str, names) -> set:
+    """The instructions that run INSIDE one of ``names``: those of the
+    computations an instruction of ``names`` calls (a ``while``'s body and
+    condition, a call's callee), and of whatever those call."""
+    where, calls, _ = structure(hlo_text)
+    inside_of: dict[str, list] = {}
+    for name, comp in where.items():
+        inside_of.setdefault(comp, []).append(name)
+    out, todo = set(), [c for n in names for c, _ in calls.get(n, ())]
+    seen = set()
+    while todo:
+        comp = todo.pop()
+        if comp in seen:
+            continue
+        seen.add(comp)
+        for name in inside_of.get(comp, ()):
+            out.add(name)
+            todo.extend(c for c, _ in calls.get(name, ()))
+    return out
 
 
 def reduce_ops(ops: dict, table: dict, opcodes: dict, scopes, calls: int):
@@ -218,27 +354,86 @@ def by_phase_and_scope(run, result):
 
 
 def phase_ms(run, result, phase: str):
+    """Milliseconds a step in ``phase``; None where the step has no such
+    pass (a step that recomputes nothing) or nothing can be read."""
     out = by_phase_and_scope(run, result)
-    return None if out is None else 1e3 * sum(out["phases"][phase].values())
+    return out and 1e3 * sum(out["phases"][phase].values()) or None
 
 
 def scope_ms(run, result, *scopes: str):
+    """Milliseconds a step under ``scopes``, all passes together; None where
+    the step has none of them (a dense model has no ``moe``)."""
     out = by_phase_and_scope(run, result)
-    return None if out is None else 1e3 * sum(
-        cell.get(s, 0.0) for cell in out["phases"].values() for s in scopes)
+    return out and 1e3 * sum(
+        cell.get(s, 0.0) for cell in out["phases"].values()
+        for s in scopes) or None
+
+
+def collective_ms(run, result):
+    """Device milliseconds a step in collective instructions (self time,
+    first chip); None where the traced step ran none."""
+    out = by_phase_and_scope(run, result)
+    return out["collective_s"] * 1e3 if out and out["collective_s"] else None
+
+
+def inner_whole_s(run, result, inner: str):
+    """Seconds a step the device spent in the instructions whose
+    ``op_name`` holds the component ``inner`` (a ``jax.named_scope`` inside
+    a declared scope: a kernel's own name), by their WHOLE duration
+    (``ops[key][2]``): an instruction that encloses others so named (a
+    ``while`` over chunks) is taken once and what runs inside it not again.
+    None without a device trace, a program that names its instructions, or
+    any instruction so named."""
+    red, prog = run.trace.reduction, _lm_program()
+    calls = result.window.get("calls")
+    if red is None or prog is None or not calls:
+        return None
+    text = step_hlo(run, result)
+    named = {name for name, op_name in prog[2](text).items()
+             if inner in components(op_name)}
+    named -= enclosed_by(text, named)
+    whole = [v[2] for key, v in red["ops"].items()
+             if key.split(" ", 1)[0] in named]
+    if not whole:
+        return None
+    log(f"{inner}: {len(whole)} traced instructions, whole duration "
+        f"{sum(whole) / calls * 1e3:.3f} ms a step")
+    return sum(whole) / calls
+
+
+def roofline_share(run, result, inner: str, cost):
+    """Per cent of its roofline at which the kernel named ``inner`` ran:
+    the least time the chip could take for one sample's ``cost`` =
+    ``(operations, bytes)`` — the larger of operations over the matrix peak
+    and bytes over the HBM peak — times the samples a chip does a step,
+    over :func:`inner_whole_s`.  None where that is."""
+    whole = inner_whole_s(run, result, inner)
+    if not whole:
+        return None
+    ops, nbytes = cost
+    by = {"operations": ops / run.peaks["bf16_flops_per_s"],
+          "bytes": nbytes / run.peaks["hbm_bytes_per_s"]}
+    bound = max(by, key=by.get)
+    samples = result.window["samples"] / result.window["calls"] \
+        / result.window["chips"]
+    log(f"{inner} roofline: bound by {bound}, least "
+        f"{by[bound] * samples * 1e3:.3f} ms a step of {whole * 1e3:.3f}")
+    return 100.0 * by[bound] * samples / whole
 
 
 def dispatch_spans_ms(run, name: str, **labels):
     """Median ``dur`` in ms of the program's ``name`` spans (with these
-    labels) that STARTED inside the measured window: ``t0`` is on the
-    clock of ``run.t_process`` and ``run.setup_s``.  None if there is
-    none (a program whose spans have no ``t0``, or obs switched off)."""
+    labels) that STARTED inside the measured window (a call the kind makes
+    after it is none of the window's): ``t0`` is on the clock of
+    ``run.t_process``, ``run.setup_s`` and ``run.closed_s``.  None if there
+    is none (a program whose spans have no ``t0``, or obs switched off)."""
     from distlearn_tpu import obs
     if run.setup_s is None:
         return None
     start = run.t_process + run.setup_s
+    end = run.t_process + run.closed_s
     durs = [s["dur"] for s in obs.spans()
-            if s["name"] == name and s.get("t0", -1.0) >= start
+            if s["name"] == name and start <= s.get("t0", -1.0) <= end
             and all(s.get("labels", {}).get(k) == v
                     for k, v in labels.items())]
     return statistics.median(durs) * 1e3 if durs else None

@@ -8,10 +8,8 @@ one, the analytic FLOP counts equal hand counts, and the command itself
 refuses to run without a TPU.  Nothing here is a measurement: no number of
 these runs is recorded anywhere."""
 
-import copy
 import json
 import os
-import re
 import subprocess
 import sys
 import time
@@ -19,36 +17,17 @@ import time
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-BENCH = os.path.join(ROOT, "benchmarks")
-for _p in (ROOT, BENCH):
-    if _p not in sys.path:
-        sys.path.insert(0, _p)
+import jax
+import jax.numpy as jnp
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
+import manifest_rules as rules
+from manifest_rules import BENCH, ROOT, UNIT, bench_run, harness
 
-import harness  # noqa: E402
-import run as bench_run  # noqa: E402
-import trace_reduce  # noqa: E402
+import trace_reduce  # noqa: E402  (benchmarks/ is on the path by now)
 
 MAN = bench_run.manifest()
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
-
-TOY_LM = {"family": "transformer_lm", "vocab_size": 97, "n_positions": 64,
-          "n_embd": 32, "n_layer": 2, "n_head": 4, "n_inner": None}
-TOY = {
-    "train_lm": (TOY_LM, {
-        "kind": "train_lm", "mesh": [2, 1, 1], "global_batch": 4, "seq": 16,
-        "lr": 0.05, "compute_dtype": None, "scan_blocks": True,
-        "remat": "full", "ring_batches": 2, "in_flight": 3, "check_steps": 2,
-        "check_micro": 2, "loss_tolerance": 1e-4, "trace_seconds": 0.3}),
-}
-CELL_OF_KIND = {harness.load_json("workloads", w["name"] + ".json")["kind"]: w
-                for w in MAN["workloads"]}
+CELLS = {w["name"]: w for w in MAN["workloads"]}
+TOY_LM = rules.TOYS["train_lm"]["config"]
 
 
 @pytest.fixture(scope="module")
@@ -56,46 +35,90 @@ def meter():
     return harness.CompileMeter()
 
 
-def _toy_run(kind, meter, trace=0, seconds=0.6):
-    config, workload = copy.deepcopy(TOY[kind])
-    return bench_run.measure_cell(
-        CELL_OF_KIND[kind], config, workload, seed=2**31 + 7,
-        seconds=seconds, trace=trace, devices=jax.devices(), peaks=PEAKS,
-        meter=meter, t_process=time.perf_counter())
+def test_every_kind_has_a_toy_and_every_toy_a_kind():
+    kinds = {f[:-3] for f in os.listdir(os.path.join(BENCH, "kinds"))
+             if not f.startswith("_") and f.endswith(".py")}
+    assert set(rules.TOYS) == kinds >= {rules.kind_of(c) for c in CELLS}
 
 
-def test_every_kind_in_the_manifest_has_a_toy():
-    assert set(TOY) == set(CELL_OF_KIND) == {
-        f[:-3] for f in os.listdir(os.path.join(BENCH, "kinds"))
-        if not f.startswith("_") and f.endswith(".py")}
-
-
-@pytest.mark.parametrize("kind", sorted(TOY))
-def test_kind_runs_end_to_end_and_line_keeps_the_contract(kind, meter):
-    run, result = _toy_run(kind, meter)
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_toy_runs_end_to_end_under_every_cells_name(cell, meter):
+    """The line keeps the contract, and every reader listed for the cell
+    gives what its ``source`` says it must on an untraced CPU run."""
+    run, result = rules.toy_run(CELLS[cell], meter)
     line = json.loads(json.dumps(bench_run.result_line(MAN, run, result)))
-    assert set(line) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(line) == ["correct", "attempted", "failed", "metrics",
+                          "device", "compared"]
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0
-    want = {m["name"] for m in
-            bench_run.cell_metrics(MAN, run.cell["name"], "end_to_end")}
+    want = {m["name"] for m in bench_run.cell_metrics(MAN, cell, "end_to_end")}
     assert set(line["metrics"]) == want and "setup_s" in want
     for m in line["metrics"].values():
         assert m["value"] > 0 and UNIT.match(m["unit"])
     assert set(line["device"]) == {"platform", "kind", "count",
                                    "memory_peak_bytes"}
+    # each number the check compared stands beside its limit, inside it
+    assert line["compared"]["programs_compiled_in_window"] == [0, 0]
+    gaps = [v for k, v in line["compared"].items() if k.startswith("loss_gap")]
+    assert len(gaps) == 3 and all(0 <= g <= tol for g, tol in gaps)
+    after, limit = line["compared"]["loss_after_minus_first"]
+    assert after < limit == 0.0
+    found = rules.readers_keep_the_source_rule(MAN, run, result)
+    assert any(v is not None for v in found.values())
 
 
-@pytest.mark.parametrize("kind", sorted(TOY))
-def test_readers_find_their_numbers_or_nothing(kind, meter):
-    """The readers that need no device trace find their numbers; the ones
-    that need it return nothing rather than a number from elsewhere."""
-    run, result = _toy_run(kind, meter)
-    found = {m["name"]: harness.load_module("layer_metrics", m["name"]).read(
-        run, result) for m in
-        bench_run.cell_metrics(MAN, run.cell["name"], "per_layer")}
-    assert found.pop("device_idle_share.train") is None
-    assert found and all(v is not None and v > 0 for v in found.values()), found
+def test_an_appended_cell_that_lists_every_reader_keeps_every_rule(
+        meter, monkeypatch):
+    """What a later PR does without an edit: a cell of kind ``train_lm``
+    appended to a copy of the manifest, listed by EVERY per-layer reader
+    there is — those of the device trace and of what a dense model does not
+    have among them."""
+    like = min(c for c in CELLS if rules.kind_of(c) == "train_lm"
+               and CELLS[c]["chips"] == 1)
+    name = CELLS[like]["config"] + ".appended-by-a-later-pr"
+    man = rules.with_appended_cell(MAN, like, name)
+    assert [w["name"] for w in man["workloads"]].count(name) == 1
+    listed = {m["name"] for m in bench_run.cell_metrics(man, name, "per_layer")}
+    assert listed == {m["name"] for m in MAN["per_layer"]}
+    load = harness.load_json
+    monkeypatch.setattr(harness, "load_json", lambda *parts: load(
+        *(parts if parts != ("workloads", name + ".json")
+          else ("workloads", like + ".json"))))
+    rules.names_units_and_limits(man)
+    rules.cells_resolve_and_report(man)
+    run, result = rules.toy_run(man["workloads"][-1], meter)
+    found = rules.readers_keep_the_source_rule(man, run, result)
+    assert set(found) == listed
+    by_source = {}
+    for m in man["per_layer"]:
+        by_source.setdefault(m["source"], []).append(found[m["name"]])
+    assert all(v is None for v in by_source["device_trace"])
+    assert all(v is not None for v in by_source["host_clock"])
+    line = bench_run.result_line(man, run, result)
+    assert line["correct"] is True
+
+
+# ------------------------------------------- the check after the window --
+
+def test_window_that_ends_on_an_unseen_batch_is_correct(meter):
+    """A ring longer than the window hands over steps: the window ends on a
+    batch no step has seen, whose loss says nothing about training.  The
+    kind compares the check's batch with itself, after the window."""
+    cell = CELLS[min(CELLS)]
+    run, result = rules.toy_run(cell, meter, seconds=0.05, ring_batches=64,
+                                in_flight=1, lr=0.02)
+    assert 1 < result.window["calls"] < 64       # ends on a batch not seen
+    assert result.correct is True
+    assert result.compared["loss_after_minus_first"][0] < 0
+
+
+def test_a_step_that_does_not_train_is_not_correct(meter):
+    cell = CELLS[min(CELLS)]
+    run, result = rules.toy_run(cell, meter, seconds=0.05, ring_batches=64,
+                                in_flight=1, lr=0.0)
+    assert result.compared["loss_after_minus_first"] == [0.0, 0.0]
+    assert result.correct is False
+    assert bench_run.result_line(MAN, run, result)["correct"] is False
 
 
 # ------------------------------------------------------ the measured loop --
@@ -150,64 +173,11 @@ def test_host_probe_sees_a_garbage_collection():
 # ---------------------------------------------------------- the manifest --
 
 def test_manifest_names_units_and_limits():
-    assert set(MAN) == {"command", "paths", "run_seconds", "configs",
-                        "workloads", "end_to_end", "per_layer"}
-    assert 1 <= MAN["run_seconds"] <= 51
-    names = [e["name"] for g in ("configs", "workloads", "end_to_end",
-                                 "per_layer") for e in MAN[g]]
-    for n in names + [w["traffic"] for w in MAN["workloads"]]:
-        assert NAME.match(n), n
-    for g in ("configs", "workloads"):
-        assert len({e["name"] for e in MAN[g]}) == len(MAN[g])
-    metrics = MAN["end_to_end"] + MAN["per_layer"]
-    assert len({m["name"] for m in metrics}) == len(metrics)
-    for m in metrics:
-        assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
-        assert m["source"] in ("device_trace", "program_span",
-                               "program_counter", "host_clock")
-    for m in MAN["end_to_end"]:
-        assert set(m) <= {"name", "unit", "better", "bound", "source",
-                          "workloads"}
-        assert 0.01 <= m["bound"] <= 0.1
-        assert m["source"] in ("host_clock", "device_trace")
-    for w in MAN["workloads"]:
-        assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert w["chips"] in (1, 4) and 1 <= len(w["why"]) <= 200
-    four = sum(w["chips"] == 4 for w in MAN["workloads"])
-    assert four <= max(1, len(MAN["workloads"]) // 4)
-    pairs = [(w["config"], w["traffic"]) for w in MAN["workloads"]]
-    assert len(set(pairs)) == len(pairs)
-    assert len(json.dumps(MAN)) < 64 * 1024
+    rules.names_units_and_limits(MAN)
 
 
 def test_every_cell_resolves_by_name_and_reports_what_it_must():
-    e2e = {m["name"] for m in MAN["end_to_end"]}
-    used = set()
-    for w in MAN["workloads"]:
-        cell, config, workload = bench_run.resolve(MAN, w["name"])
-        used.add(cell["config"])
-        for folder, name in (("kinds", workload["kind"]),
-                             ("families", config["family"]),
-                             ("reference", config["family"])):
-            assert os.path.isfile(os.path.join(BENCH, folder, name + ".py"))
-        mine = {m["name"] for m in
-                bench_run.cell_metrics(MAN, w["name"], "end_to_end")}
-        assert "setup_s" in mine and len(mine) >= 2
-        layer = bench_run.cell_metrics(MAN, w["name"], "per_layer")
-        assert layer
-        for m in layer:
-            assert set(m) <= {"name", "unit", "better", "source", "layer",
-                              "moves", "workloads"}
-            assert m["moves"] in mine, (w["name"], m["name"])
-            assert hasattr(harness.load_module("layer_metrics", m["name"]),
-                           "read")
-    assert used == {c["name"] for c in MAN["configs"]}
-    assert e2e == {m["name"] for w in MAN["workloads"] for m in
-                   bench_run.cell_metrics(MAN, w["name"], "end_to_end")}
-    for c in MAN["configs"]:
-        assert c["file"].startswith("benchmarks/configs/")
-        assert c["reduced"] == json.load(
-            open(os.path.join(ROOT, c["file"])))["reduced"]
+    rules.cells_resolve_and_report(MAN)
 
 
 def test_gpt2_large_is_at_its_published_sizes():
@@ -222,7 +192,7 @@ def test_gpt2_large_is_at_its_published_sizes():
 
 
 def test_command_refuses_to_run_without_a_tpu():
-    cell = MAN["workloads"][0]["name"]
+    cell = min(CELLS)
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     res = subprocess.run(
         [sys.executable, os.path.join(BENCH, "run.py"), "--workload", cell,
@@ -304,7 +274,7 @@ def test_traced_run_reports_only_what_its_readers_find(meter):
     """On the CPU the trace holds no TPU plane, so a traced run must fail
     loudly rather than report a device number from somewhere else."""
     with pytest.raises(ValueError, match="no device operation"):
-        _toy_run("train_lm", meter, trace=1)
+        rules.toy_run(CELLS[min(CELLS)], meter, trace=1)
 
 
 # ---------------------------------------------- references and FLOP counts --

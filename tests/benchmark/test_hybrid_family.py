@@ -3,30 +3,23 @@
 ``solar-open2-250b.train-8k``), at toy size on the CPU: the system equals
 the plain reference on logits, loss and EVERY gradient leaf; the layer-by-layer reference equals the whole-model one;
 the analytic counts equal hand counts; the configuration is at its published
-widths; the cell's toy twin runs end to end through the kind and lists
-no reader that needs a device trace.  Nothing here is a measurement."""
+widths; the cell's toy twin runs end to end through the kind, and every
+reader the cell lists gives what its ``source`` says.  Nothing here is a
+measurement."""
 
 import copy
 import json
 import os
-import sys
 import time
 
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-BENCH = os.path.join(ROOT, "benchmarks")
-for _p in (ROOT, BENCH):
-    if _p not in sys.path:
-        sys.path.insert(0, _p)
+import jax
+import jax.numpy as jnp
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-
-import harness  # noqa: E402
-import run as bench_run  # noqa: E402
+import manifest_rules as rules
+from manifest_rules import BENCH, bench_run, harness
 
 from distlearn_tpu.models.transformer import lm_loss  # noqa: E402
 
@@ -195,46 +188,65 @@ def test_solar_open2_is_at_its_published_widths():
     assert wl["scan_blocks"] is False and wl["remat"] == "full"
 
 
-def test_cell_is_appended_and_lists_no_device_trace_reader_of_its_own():
-    """New entries go at the END of the manifest's lists, so this cell is
-    the last of its kind: ``test_benchmark.py`` runs the dense toy under its
-    name and demands a number above 0, untraced, from every reader listed
-    for it (``device_idle_share.train`` excepted by name).  A reader of the
-    device trace can therefore not be listed for it: the cell's time by
-    scope and the delta rule's share of its roofline wait for a
-    ``benchmark`` PR (PERF.md section 7d)."""
-    assert [w["name"] for w in MAN["workloads"]][-1] == CELL
-    listed = bench_run.cell_metrics(MAN, CELL, "per_layer")
-    assert {m["name"] for m in listed} == {
-        "compile_s", "dispatch_ms.train", "mfu.train", "step_ms.train",
-        "device_idle_share.train"}
-    assert [m["name"] for m in listed if m["source"] == "device_trace"] == [
-        "device_idle_share.train"]
-    for m in MAN["end_to_end"] + MAN["per_layer"]:
-        if CELL in m.get("workloads", ()):
-            assert m["workloads"][-1] == CELL
+@pytest.fixture(scope="module")
+def toy_cell_run():
+    cell = {w["name"]: w for w in MAN["workloads"]}[CELL]
+    return bench_run.measure_cell(
+        cell, copy.deepcopy(TOY), copy.deepcopy(TOY_WL), seed=2**31 + 7,
+        seconds=0.5, trace=0, devices=jax.devices(), peaks=rules.PEAKS,
+        meter=harness.CompileMeter(), t_process=time.perf_counter())
 
 
-def test_cells_toy_twin_runs_end_to_end_through_the_kind():
+def test_cells_toy_twin_runs_end_to_end_through_the_kind(toy_cell_run):
     """The accepted kind ``train_lm`` takes the new family as data: the toy
     cell is checked against the reference at its own tolerance and trains."""
-    cell = {w["name"]: w for w in MAN["workloads"]}[CELL]
-    run, result = bench_run.measure_cell(
-        cell, copy.deepcopy(TOY), copy.deepcopy(TOY_WL), seed=2**31 + 7,
-        seconds=0.5, trace=0, devices=jax.devices(),
-        peaks={"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11},
-        meter=harness.CompileMeter(), t_process=time.perf_counter())
+    run, result = toy_cell_run
     line = bench_run.result_line(MAN, run, result)
     assert line["correct"] is True and line["attempted"] > 0
     assert set(line["metrics"]) == {"setup_s", "train_samples_per_s"}
     assert result.window["check_gap_max"] < 1e-4
     assert result.window["params"] == FAM.param_count(TOY)
-    found = {m["name"]: harness.load_module("layer_metrics", m["name"]).read(
-        run, result) for m in bench_run.cell_metrics(MAN, CELL, "per_layer")}
-    # untraced: the host-side readers find their numbers, the one reader of
-    # the device trace nothing
-    for name in ("compile_s", "dispatch_ms.train", "mfu.train",
-                 "step_ms.train"):
-        assert found[name] is not None and found[name] > 0, name
-    assert found.pop("device_idle_share.train") is None
-    assert len(found) == 4
+    # untraced: the readers of the host's clock find their numbers, those
+    # of the device trace nothing — the cell's own among them
+    found = rules.readers_keep_the_source_rule(MAN, run, result)
+    assert {"linattn_core_ms.train", "moe_ms.train", "linattn_roofline.train",
+            "fwd_ms.train", "unscoped_share.train"} <= set(found)
+    assert found["mfu.train"] > 0 and found["compile_s"] > 0
+
+
+def test_the_cells_own_readers_find_their_instructions_in_the_toy_step(
+        toy_cell_run, monkeypatch):
+    """The family that feeds them: on the toy step's OWN optimized text,
+    with a made-up trace in which every top-level instruction of the entry
+    computation ran for a millisecond a step, the scope readers find
+    ``linattn_core`` and ``moe``, and the roofline reader the instructions
+    named ``delta_rule`` — a loop over chunks once, its body not again."""
+    import scope_reduce
+    run, result = toy_cell_run
+    text = scope_reduce.step_hlo(run, result)       # untraced: no recompile
+    where, calls, entry = scope_reduce.structure(text)
+    calls_n = result.window["calls"]
+    ops = {f"{n} f32[1]": [1e-3 * calls_n, calls_n, 1e-3 * calls_n]
+           for n, comp in where.items() if comp == entry}
+    window = {k: v for k, v in result.window.items()
+              if k != "scope_reduction"}
+    fake = type(result)(correct=True, attempted=1, failed=0, end_to_end={},
+                        window=window)
+    monkeypatch.setattr(run.trace, "reduction", {"ops": ops})
+    read = lambda m: harness.load_module("layer_metrics", m).read(  # noqa: E731
+        run, fake)
+    assert read("linattn_core_ms.train") > read("moe_ms.train") > 0
+    table = scope_reduce._lm_program()[2](text)
+    named = {n for n, o in table.items()
+             if "delta_rule" in scope_reduce.components(o)}
+    top = {n for n in named - scope_reduce.enclosed_by(text, named)
+           if where[n] == entry}
+    assert top and any(n in calls for n in top)     # loops among them
+    assert any(where[n] != entry for n in named)    # and bodies left out
+    whole = scope_reduce.inner_whole_s(run, fake, "delta_rule")
+    assert whole == pytest.approx(1e-3 * len(top))
+    ops_1, bytes_1 = FAM.delta_rule_cost(TOY, TOY_WL["seq"])
+    least = max(ops_1 / rules.PEAKS["bf16_flops_per_s"],
+                bytes_1 / rules.PEAKS["hbm_bytes_per_s"])
+    assert read("linattn_roofline.train") == pytest.approx(
+        100 * least / whole)

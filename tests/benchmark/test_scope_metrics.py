@@ -1,47 +1,28 @@
 """The scope / pass readers of the benchmark (``scope_reduce.py`` and the
-``layer_metrics`` that call it), at toy size on the CPU: hand counts on a
-hand-written HLO text and a synthetic ``ops`` dict, every device reader
+``layer_metrics`` that call it), at toy size on the CPU: hand counts on two
+hand-written HLO texts and synthetic ``ops`` dicts, every device reader
 silent without a device trace, the two program-side readers finding their
-numbers on an untraced toy run, and the new cell's data.  Nothing here is a
-measurement."""
+numbers on an untraced toy run, and the four-chip cell's data.  Nothing here
+is a measurement."""
 
-import copy
 import json
-import os
-import sys
 import time
 import types
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-BENCH = os.path.join(ROOT, "benchmarks")
-for _p in (ROOT, BENCH):
-    if _p not in sys.path:
-        sys.path.insert(0, _p)
+import jax
 
-import jax  # noqa: E402
+import manifest_rules as rules
+from manifest_rules import bench_run, harness
 
-import harness  # noqa: E402
-import run as bench_run  # noqa: E402
-import scope_reduce  # noqa: E402
+import scope_reduce  # noqa: E402  (benchmarks/ is on the path by now)
 
 from distlearn_tpu.models.core import SCOPES  # noqa: E402
 from distlearn_tpu.utils.profiling import scope_table  # noqa: E402
 
 MAN = bench_run.manifest()
-TOY_LM = {"family": "transformer_lm", "vocab_size": 97, "n_positions": 64,
-          "n_embd": 32, "n_layer": 2, "n_head": 4, "n_inner": None}
-TOY_DP2 = {"kind": "train_lm", "mesh": [2, 1, 1], "global_batch": 4,
-           "seq": 16, "lr": 0.05, "compute_dtype": None, "scan_blocks": True,
-           "remat": "full", "ring_batches": 2, "in_flight": 3,
-           "check_steps": 2, "check_micro": 2, "loss_tolerance": 1e-4,
-           "trace_seconds": 0.3}
 ONE, DP4 = "gpt2-large.train", "gpt2-large.train-dp4"
-DEVICE_READERS = ["fwd_ms.train", "recompute_ms.train", "bwd_ms.train",
-                  "attn_core_ms.train", "update_ms.train",
-                  "unscoped_share.train"]
 
 # a step program in miniature, in the TPU compiler's own spelling: a fused
 # computation (its inner instruction never runs by itself), a while body, an
@@ -95,6 +76,67 @@ OPS = {
     "fusion.8 f32[4,8]": [0.08, 2, 0.08],       # other grad_reduce 0.040
     "fusion.9 f32[4,8]": [0.16, 2, 0.16],       # other update      0.080
 }
+
+
+# a second step in miniature, with what the first has not: a chunked kernel
+# under an inner name (``delta_rule``, inside the scope ``linattn_core``)
+# whose loop over chunks encloses one so-named instruction and a
+# ``collective-permute`` that the loop runs three times, the scope ``moe``,
+# and an all-reduce after the loop
+HLO2 = """\
+HloModule jit_step2, entry_computation_layout={(f32[4,8]{1,0})->f32[4,8]{1,0}}
+
+%fused_computation.1 (p0: f32[4,8]) -> f32[4,8] {
+  %p0 = f32[4,8]{1,0:T(4,128)} parameter(0)
+  ROOT %mul.9 = f32[4,8]{1,0:T(4,128)} multiply(%p0, %p0)
+}
+
+%body.3 (arg.3: (s32[], f32[4,8])) -> (s32[], f32[4,8]) {
+  %arg.3 = (s32[]{:T(128)}, f32[4,8]{1,0:T(4,128)}) parameter(0)
+  %gte.3 = f32[4,8]{1,0:T(4,128)} get-tuple-element(%arg.3), index=1
+  %fusion.11 = f32[4,8]{1,0:T(4,128)} fusion(%gte.3), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(step)/jvp(linattn_core)/delta_rule/while/body/mul"}
+  %collective-permute-start.1 = (f32[4,8]{1,0:T(4,128)}, f32[4,8]{1,0:T(4,128)}) collective-permute-start(%fusion.11), channel_id=3, source_target_pairs={{0,1},{1,0}}, metadata={op_name="jit(step)/transpose(jvp())/while/body/grad_reduce/ppermute"}
+  %collective-permute-done.1 = f32[4,8]{1,0:T(4,128)} collective-permute-done(%collective-permute-start.1)
+  ROOT %tuple.3 = (s32[]{:T(128)}, f32[4,8]{1,0:T(4,128)}) tuple(%gte.0, %collective-permute-done.1)
+}
+
+%cond.3 (arg.4: (s32[], f32[4,8])) -> pred[] {
+  %arg.4 = (s32[]{:T(128)}, f32[4,8]{1,0:T(4,128)}) parameter(0)
+  %constant.3 = s32[]{:T(128)} constant(3)
+  %gte.4 = s32[]{:T(128)} get-tuple-element(%arg.4), index=0
+  ROOT %lt.1 = pred[]{:T(512)} compare(%gte.4, %constant.3), direction=LT, metadata={op_name="jit(step)/jvp(linattn_core)/delta_rule/while/cond/lt"}
+}
+
+ENTRY %main.9 (param.0: f32[4,8]) -> f32[4,8] {
+  %param.0 = f32[4,8]{1,0:T(4,128)} parameter(0)
+  %constant.0 = s32[]{:T(128)} constant(0)
+  %copy.1 = s32[]{:T(128)} copy(%constant.0)
+  %tuple.9 = (s32[]{:T(128)}, f32[4,8]{1,0:T(4,128)}) tuple(%copy.1, %param.0)
+  %fusion.10 = f32[4,8]{1,0:T(4,128)} fusion(%param.0), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(step)/jvp(linattn_core)/conv_general_dilated"}
+  %while.2 = (s32[]{:T(128)}, f32[4,8]{1,0:T(4,128)}) while(%tuple.9), condition=%cond.3, body=%body.3, metadata={op_name="jit(step)/jvp(linattn_core)/delta_rule/while"}, backend_config={"known_trip_count":{"n":"3"},"known_init_step":{"init":"0","step":"1"}}
+  %fusion.12 = f32[4,8]{1,0:T(4,128)} fusion(%param.0), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(step)/transpose(jvp(linattn_core))/delta_rule/dot_general"}
+  %fusion.13 = f32[4,8]{1,0:T(4,128)} fusion(%fusion.12), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(step)/transpose(jvp(moe))/dot_general"}
+  ROOT %all-reduce.2 = f32[4,8]{1,0:T(4,128)} all-reduce(%fusion.13), channel_id=4, replica_groups={{0,1}}, to_apply=%region_1.2
+}
+"""
+
+# two calls of it: the loop's whole duration holds its body's
+OPS2 = {
+    "fusion.10 f32[4,8]": [0.10, 2, 0.10],      # fwd linattn_core  0.050
+    "while.2 s32[]": [0.04, 2, 0.70],           # fwd linattn_core  0.020
+    "fusion.11 f32[4,8]": [0.60, 6, 0.60],      # fwd linattn_core  0.300
+    "collective-permute-start.1 f32[4,8]": [0.02, 6, 0.02],   # coll. 0.010
+    "collective-permute-done.1 f32[4,8]": [0.04, 6, 0.04],    # coll. 0.020
+    "fusion.12 f32[4,8]": [0.20, 2, 0.20],      # bwd linattn_core  0.100
+    "fusion.13 f32[4,8]": [0.30, 2, 0.30],      # bwd moe           0.150
+    "all-reduce.2 f32[4,8]": [0.10, 2, 0.10],   # collective        0.050
+}
+
+# the delta rule's yardstick of the fake family: one sample needs 2e9
+# operations (2 ms at the fake matrix peak) and 1e9 bytes (10 ms at the fake
+# HBM peak: memory-bound); four samples a step against the 0.45 s a step of
+# while.2 (0.70 whole, its body NOT again) and fusion.12 (0.20)
+COST = (2e9, 1e9)
 
 
 @pytest.mark.parametrize("op_name,phase,scope", [
@@ -159,11 +201,47 @@ def test_collective_bytes_count_each_instruction_once():
     assert scope_reduce.collective_kind("fusion") is None
 
 
+@pytest.mark.parametrize("how", ["known_trip_count", "condition", "neither"])
+def test_collective_bytes_count_a_loops_instruction_by_its_trip_count(
+        how, capsys):
+    # the loop hands its f32[4,8] to the collective-permute three times a
+    # run, the all-reduce after it once.  The trip count is the compiler's
+    # own where the text has it (the CPU's), else read from the loop's
+    # condition ``counter < 3`` and its start 0 (the TPU's text has no
+    # known_trip_count); a loop that gives neither counts once, and says so
+    text = HLO2
+    if how != "known_trip_count":
+        text = text.replace('"known_trip_count":{"n":"3"},', "")
+    if how == "neither":
+        text = text.replace("direction=LT", "direction=NE")
+    trips = 1 if how == "neither" else 3
+    runs = scope_reduce.executions(text)
+    assert (runs["main.9"], runs["cond.3"], runs["body.3"]) == (1, 1, trips)
+    assert runs["fused_computation.1"] == 3 + trips
+    assert scope_reduce.collective_bytes(text) == {
+        "collective-permute": trips * 128, "all-reduce": 128}
+    assert ("bodies count once" in capsys.readouterr().out) \
+        == (how == "neither")
+    where, calls, entry = scope_reduce.structure(HLO2)
+    assert entry == "main.9" and where["fusion.11"] == "body.3"
+    assert calls["while.2"] == [("cond.3", 1), ("body.3", 3)]
+    assert scope_reduce.enclosed_by(HLO2, {"while.2"}) >= {
+        "fusion.11", "collective-permute-start.1", "mul.9", "lt.1"}
+    assert "fusion.12" not in scope_reduce.enclosed_by(HLO2, {"while.2"})
+    # a counter that starts at 1 runs the body twice
+    late = text.replace("constant(0)", "constant(1)")
+    if how == "condition":
+        assert scope_reduce.executions(late)["body.3"] == 2
+
+
 # ------------------------------------------------ the readers on a run --
 
 class _Program:
+    def __init__(self, text=HLO):
+        self.text = text
+
     def hlo_text(self):
-        return HLO
+        return self.text
 
 
 def _fake_run(reduction, monkeypatch, program=_Program()):
@@ -176,8 +254,10 @@ def _fake_run(reduction, monkeypatch, program=_Program()):
         lambda: program and (program, SCOPES, scope_table))
     run = types.SimpleNamespace(
         trace=types.SimpleNamespace(reduction=reduction), t_process=0.0,
-        setup_s=0.0)
-    result = types.SimpleNamespace(window={"calls": 2}, end_to_end={})
+        setup_s=0.0, peaks=rules.PEAKS, config={}, workload={"seq": 16},
+        family=types.SimpleNamespace(delta_rule_cost=lambda cfg, seq: COST))
+    result = types.SimpleNamespace(
+        window={"calls": 2, "samples": 8, "chips": 1}, end_to_end={})
     return run, result, cleared
 
 
@@ -185,26 +265,58 @@ def _read(metric, run, result):
     return harness.load_module("layer_metrics", metric).read(run, result)
 
 
-WANT_MS = {"fwd_ms.train": 280.0, "recompute_ms.train": 100.0,
-           "bwd_ms.train": 320.0, "attn_core_ms.train": 300.0,
-           "update_ms.train": 120.0, "unscoped_share.train": 6.0}
+#: what each reader of the device trace reads on the first module's two
+#: calls, and — where that module has nothing for it — on the second's
+WANT = {"fwd_ms.train": 280.0, "recompute_ms.train": 100.0,
+        "bwd_ms.train": 320.0, "attn_core_ms.train": 300.0,
+        "update_ms.train": 120.0, "unscoped_share.train": 6.0,
+        "collective_ms.train": 170.0}
+WANT2 = {"linattn_core_ms.train": 470.0, "moe_ms.train": 150.0,
+         "collective_ms.train": 80.0,
+         "linattn_roofline.train": 100 * 0.010 * 4 / 0.45,
+         "fwd_ms.train": 370.0, "bwd_ms.train": 250.0,
+         "unscoped_share.train": 0.0}
+#: the readers this file has hand counts for; a later PR's reader brings its
+#: hand count in a test file of its own
+DEVICE_READERS = sorted(set(WANT) | set(WANT2))
+
+
+def test_the_hand_counts_are_of_listed_readers_of_the_device_trace():
+    by_name = {m["name"]: m for m in MAN["per_layer"]}
+    assert all(by_name[m]["source"] == "device_trace" for m in DEVICE_READERS)
 
 
 @pytest.mark.parametrize("metric", DEVICE_READERS)
 def test_device_reader_is_silent_without_a_trace_and_right_with_one(
         metric, monkeypatch):
-    run, result, cleared = _fake_run(None, monkeypatch)
-    assert _read(metric, run, result) is None and not cleared
-    run, result, cleared = _fake_run({"ops": OPS}, monkeypatch)
-    assert _read(metric, run, result) == pytest.approx(WANT_MS[metric])
-    # the step is compiled anew for its names once per run, whatever the
-    # number of readers (see scope_reduce.step_hlo)
-    for other in DEVICE_READERS:
-        _read(other, run, result)
-    assert cleared == [1]
-    # a program without the catalog (this PR's parent): nothing, no error
-    run, result, _ = _fake_run({"ops": OPS}, monkeypatch, program=None)
-    assert _read(metric, run, result) is None
+    for text, ops, want in ((HLO, OPS, WANT), (HLO2, OPS2, WANT2)):
+        prog = _Program(text)
+        run, result, cleared = _fake_run(None, monkeypatch, prog)
+        assert _read(metric, run, result) is None and not cleared
+        run, result, cleared = _fake_run({"ops": ops}, monkeypatch, prog)
+        # a module with nothing for the reader: nothing, never a 0
+        assert _read(metric, run, result) == (
+            pytest.approx(want[metric]) if metric in want else None)
+        # the step is compiled anew for its names once per run, whatever
+        # the number of readers (see scope_reduce.step_hlo)
+        for other in DEVICE_READERS:
+            _read(other, run, result)
+        assert cleared == [1]
+        # a program without the catalog: nothing, no error
+        run, result, _ = _fake_run({"ops": ops}, monkeypatch, program=None)
+        assert _read(metric, run, result) is None
+
+
+def test_a_family_without_the_kernel_gives_its_roofline_reader_nothing(
+        monkeypatch):
+    run, result, _ = _fake_run({"ops": OPS2}, monkeypatch, _Program(HLO2))
+    run.family = types.SimpleNamespace()
+    assert _read("linattn_roofline.train", run, result) is None
+    # and a kernel's share is taken over the loop ONCE: counting the body
+    # again (0.60 more over two calls) would read 5.33 %, not 8.89 %
+    run, result, _ = _fake_run({"ops": OPS2}, monkeypatch, _Program(HLO2))
+    assert scope_reduce.inner_whole_s(run, result, "delta_rule") \
+        == pytest.approx(0.45)
 
 
 def test_the_parts_add_up_to_the_busy_time(monkeypatch):
@@ -224,11 +336,8 @@ def toy_run():
     """The new cell's toy twin, untraced, on two virtual CPU devices: mesh
     [2, 1, 1] has a gradient all-reduce."""
     cell = {w["name"]: w for w in MAN["workloads"]}[DP4]
-    return bench_run.measure_cell(
-        cell, copy.deepcopy(TOY_LM), copy.deepcopy(TOY_DP2), seed=2**31 + 11,
-        seconds=0.5, trace=0, devices=jax.devices(),
-        peaks={"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11},
-        meter=harness.CompileMeter(), t_process=time.perf_counter())
+    return rules.toy_run(cell, harness.CompileMeter(), seconds=0.5,
+                         seed=2**31 + 11)
 
 
 def test_program_dispatch_reads_the_programs_own_spans(toy_run):
@@ -241,9 +350,14 @@ def test_program_dispatch_reads_the_programs_own_spans(toy_run):
     assert got <= _read("dispatch_ms.train", run, result)
     inside = [s for s in obs.spans() if s["name"] == "train.dispatch"
               and s["t0"] >= run.t_process + run.setup_s]
-    assert len(inside) == result.window["calls"]
+    # the window's calls, and the one the kind makes after it on the
+    # check's batch, which the reader leaves out
+    assert len(inside) == result.window["calls"] + 1
+    assert sum(s["t0"] <= run.t_process + run.closed_s for s in inside) \
+        == result.window["calls"]
     # a window that starts after every span: nothing to read
-    late = types.SimpleNamespace(t_process=time.perf_counter(), setup_s=0.0)
+    late = types.SimpleNamespace(t_process=time.perf_counter(), setup_s=0.0,
+                                 closed_s=float("inf"))
     assert scope_reduce.dispatch_spans_ms(late, "train.dispatch",
                                           step="lm") is None
 
@@ -294,22 +408,19 @@ def test_dp4_cell_is_the_one_chip_cell_on_four_chips():
     assert workload["global_batch"] == 4 * one["global_batch"]
 
 
-def test_device_trace_readers_are_not_listed_for_the_last_cell_of_a_kind():
-    """``test_benchmark.py`` demands a number > 0 from every reader of the
-    LAST cell of each kind on an untraced CPU run; a reader of the device
-    trace has none there.  Until a ``benchmark`` PR puts that rule by the
-    manifest's ``source``, the order of the cells carries it."""
-    names = [w["name"] for w in MAN["workloads"]]
-    assert names.index(ONE) < names.index(DP4)
-    last_of_kind = {harness.load_json("workloads", n + ".json")["kind"]: n
-                    for n in names}
+def test_sources_of_the_program_side_readers_and_who_lists_what():
+    """Which reader a cell lists follows from what it RUNS, not from where
+    it stands in ``workloads``: every training cell lists the pass / scope
+    readers, every cell that spans chips the readers of collectives."""
     by_name = {m["name"]: m for m in MAN["per_layer"]}
-    for metric in DEVICE_READERS:
-        m = by_name[metric]
-        assert m["source"] == "device_trace"
-        assert m["workloads"] == [ONE]
-        assert not set(m["workloads"]) & set(last_of_kind.values())
-    assert by_name["program_dispatch_ms.train"]["workloads"] == [ONE, DP4]
-    assert by_name["collective_mb.train"]["workloads"] == [DP4]
     assert by_name["collective_mb.train"]["source"] == "program_counter"
     assert by_name["program_dispatch_ms.train"]["source"] == "program_span"
+    cells = {w["name"]: w for w in MAN["workloads"]}
+    train = {c for c in cells if rules.kind_of(c) == "train_lm"}
+    for metric in WANT:
+        if not metric.startswith("collective"):
+            assert set(by_name[metric]["workloads"]) >= train, metric
+    for metric in ("collective_mb.train", "collective_ms.train"):
+        assert by_name[metric]["moves"] == "train_samples_per_s"
+        assert {c for c in train if cells[c]["chips"] == 4} \
+            <= set(by_name[metric]["workloads"])
