@@ -1,20 +1,28 @@
-"""A decoder-only LM whose layers are a PATTERN: softmax attention with
-grouped queries and an output gate in some, gated delta-rule linear attention
-(KDA) in the others, and in every layer a routed mixture of SwiGLU experts
-beside a shared one.  The second LM constructor beside
+"""A decoder-only LM whose layers are a PATTERN (:data:`LAYER_TYPES`, one
+name a layer): softmax attention with grouped queries — with an output gate
+(``"gqa"``), without one over the whole causal triangle (``"full"``), or
+without one over a causal band with rotary positions (``"window"``) — or
+gated delta-rule linear attention (``"kda"``), and in every layer a routed
+mixture of gated-linear-unit experts (SwiGLU or ReGLU), beside a shared
+expert or without one.  The second LM constructor beside
 :func:`distlearn_tpu.models.transformer.transformer_lm`; it returns the same
 :class:`~distlearn_tpu.models.core.Model` and its ``apply`` takes the same
 keywords, so ``lm_loss`` and every LM step builder drive it unchanged.
 
-One layer (pre-norm, residual, no bias, no positional term of any kind —
-the causal mask, the convolution and the recurrence carry the order):
+One layer (pre-norm, residual, no bias; no positional term but the rotation
+of a ``"window"`` layer — elsewhere the causal mask, the convolution and the
+recurrence carry the order):
 
     h = x + Mix(rmsnorm(x));        y = h + MoE(rmsnorm(h))
 
-``Mix`` of a ``"gqa"`` layer, ``H`` query heads over ``Hkv`` K/V heads:
+``Mix`` of a softmax layer, ``H`` query heads over ``Hkv`` K/V heads:
 
-    q, k, v = x Wq, x Wk, x Wv;    a = softmax(q k^T / sqrt(D) + causal) v
-    out = (sigmoid(x Wg) * a) Wo                    (elementwise gate)
+    q, k, v = x Wq, x Wk, x Wv
+    "window":  q, k = rope(q, i), rope(k, i)        (models.transformer.rotary)
+    allowed(i, j) = j <= i   and, "window" only,   i - j < window
+    a = softmax(q k^T / sqrt(D) + mask) v
+    "gqa":  out = (sigmoid(x Wg) * a) Wo            (elementwise gate)
+    "full", "window":  out = a Wo
 
 ``Mix`` of a ``"kda"`` layer, per head (``conv`` a causal depthwise
 convolution over time):
@@ -29,13 +37,15 @@ convolution over time):
 
 ``MoE`` (:func:`distlearn_tpu.parallel.ep.moe_held_ffn`): a router over all
 ``n_routed_experts``, top-k renormalised, of which THIS model holds
-``held_experts`` and computes their part, plus the shared expert on every
-token.
+``held_experts`` and computes their part, plus the shared expert (if the
+model has one) on every token.  The router reads the experts' own input
+``rmsnorm(h)`` or, with ``router_input="layer_input"``, the layer's input
+``x`` as it came in, before the mixer and before any norm.
 
 Arithmetic: parameters in ``dtype`` (float32); the matrix products in
 ``compute_dtype``; in float32 regardless: the norms' statistics, the softmax
-of attention (inside the kernel), the KDA decay (softplus, exp, cumulative
-sums), ``beta``, the l2 norms, the triangular inverse and the carried state
+of attention (inside the kernel), the rotary angles and the rotation, the
+KDA decay (softplus, exp, cumulative sums), ``beta``, the l2 norms, the triangular inverse and the carried state
 (``ops/delta_rule.py``), the router's scores and its softmax.
 """
 
@@ -49,13 +59,16 @@ import jax.numpy as jnp
 from jax import lax, random
 
 from distlearn_tpu.models.core import Model, checkpoint_block
-from distlearn_tpu.models.transformer import _norm_init, _rmsnorm
+from distlearn_tpu.models.transformer import _norm_init, _rmsnorm, rotary
 from distlearn_tpu.ops.delta_rule import chunked_delta_rule
-from distlearn_tpu.parallel.ep import moe_held_ffn
+from distlearn_tpu.parallel.ep import GATE_ACTS, moe_held_ffn
 from distlearn_tpu.parallel.sequence import local_attention
 
 PyTree = Any
-LAYER_TYPES = ("gqa", "kda")
+LAYER_TYPES = ("gqa", "kda", "full", "window")
+#: what the router of a layer may read: the experts' input (the norm after
+#: the mixer) or the layer's own input, un-normed, before the mixer
+ROUTER_INPUTS = ("ffn_norm", "layer_input")
 
 
 def _dense(key, shape, fan_in, dtype):
@@ -79,18 +92,29 @@ def _l2norm(x):
     return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
 
-def gqa_apply(blk: PyTree, x: jax.Array, cd, eps: float):
-    """The softmax layer's mixer with its residual."""
+def gqa_apply(blk: PyTree, x: jax.Array, cd, eps: float,
+              window: int | None = None, rope_theta: float | None = None):
+    """A softmax layer's mixer with its residual: gated where the layer has
+    a ``wg``, q and k rotated where ``rope_theta`` is given, the mask cut to
+    a band where ``window`` is.  The attention call carries the inner name
+    ``attn_window`` or ``attn_full`` inside the declared ``attn_core``."""
     h = _rmsnorm(blk["ln1"], x, eps)
     with jax.named_scope("attn_proj"):
         q = jnp.einsum("ble,ehd->blhd", h, blk["wq"].astype(cd))
         k = jnp.einsum("ble,ehd->blhd", h, blk["wk"].astype(cd))
         v = jnp.einsum("ble,ehd->blhd", h, blk["wv"].astype(cd))
-        gate = jnp.einsum("ble,ehd->blhd", h, blk["wg"].astype(cd))
+        if "wg" in blk:
+            gate = jnp.einsum("ble,ehd->blhd", h, blk["wg"].astype(cd))
+        if rope_theta is not None:
+            pos = jnp.arange(x.shape[1])
+            q, k = rotary(q, pos, rope_theta), rotary(k, pos, rope_theta)
     with jax.named_scope("attn_core"):
-        att = local_attention(q, k, v, causal=True)
+        with jax.named_scope("attn_full" if window is None
+                             else "attn_window"):
+            att = local_attention(q, k, v, causal=True, window=window)
     with jax.named_scope("attn_proj"):
-        att = jax.nn.sigmoid(gate.astype(jnp.float32)).astype(cd) * att
+        if "wg" in blk:
+            att = jax.nn.sigmoid(gate.astype(jnp.float32)).astype(cd) * att
         return x + jnp.einsum("blhd,hde->ble", att, blk["wo"].astype(cd))
 
 
@@ -135,46 +159,65 @@ def kda_apply(blk: PyTree, x: jax.Array, cd, eps: float):
 
 
 def moe_apply(blk: PyTree, x: jax.Array, cd, eps: float, held, top_k: int,
-              ep_axis: str | None):
-    """Shared expert + the held experts' part of the routed ones, with the
-    residual; returns ``(y, routing counters)``."""
+              ep_axis: str | None, route_from: jax.Array | None = None,
+              act: str = "silu"):
+    """The shared expert (where the layer has one) + the held experts' part
+    of the routed ones, with the residual; returns ``(y, routing
+    counters)``.  ``route_from`` [B, L, D]: what the router reads instead of
+    the experts' input; ``act``: the experts' gate activation."""
     B, L, D = x.shape
     h = _rmsnorm(blk["ln2"], x, eps)
-    with jax.named_scope("mlp"):
-        shared = (jax.nn.silu(h @ blk["ws_gate"].astype(cd))
-                  * (h @ blk["ws_up"].astype(cd))) @ blk["ws_down"].astype(cd)
+    if "ws_gate" in blk:
+        with jax.named_scope("mlp"):
+            shared = (GATE_ACTS[act](h @ blk["ws_gate"].astype(cd))
+                      * (h @ blk["ws_up"].astype(cd))) \
+                @ blk["ws_down"].astype(cd)
     with jax.named_scope("moe"):
         routed, aux = moe_held_ffn(
             h.reshape(B * L, D), blk["router"],
             (blk["we_gate"], blk["we_up"], blk["we_down"]), held, top_k,
-            compute_dtype=cd, ep_axis=ep_axis)
-        return x + shared + routed.reshape(B, L, D), aux
+            compute_dtype=cd, ep_axis=ep_axis, act=act,
+            route_from=None if route_from is None
+            else route_from.reshape(B * L, D))
+        if "ws_gate" in blk:
+            x = x + shared
+        return x + routed.reshape(B, L, D), aux
 
 
 def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
               heads: int, kv_heads: int, head_dim: int,
-              kda_heads: int, kda_head_dim: int, conv_kernel: int = 4,
-              kda_rank: int | None = None,
+              kda_heads: int | None = None, kda_head_dim: int | None = None,
+              conv_kernel: int = 4, kda_rank: int | None = None,
+              window: int | None = None, rope_theta: float | None = None,
               n_routed_experts: int, held_experts: Sequence[int],
               experts_per_tok: int, expert_width: int,
-              n_shared_experts: int = 1, eps: float = 1e-5,
+              n_shared_experts: int = 1, expert_act: str = "silu",
+              router_input: str = "ffn_norm", eps: float = 1e-5,
               max_len: int = 2048, dtype=jnp.float32, compute_dtype=None,
               remat: bool | str = False) -> Model:
     """Returns a :class:`Model` mapping int tokens [B, L] to next-token
     logits [B, L, vocab] (untied head).
 
     ``layer_types``: one of :data:`LAYER_TYPES` per layer — the pattern is
-    data.  ``heads`` / ``kv_heads`` / ``head_dim`` size the softmax layers,
-    ``kda_heads`` / ``kda_head_dim`` (keys and values alike) the
-    linear-attention ones, whose decay and output gates are low-rank
-    through ``kda_rank`` (default: ``kda_head_dim``).
+    data.  ``heads`` / ``kv_heads`` / ``head_dim`` size the softmax layers
+    (``"gqa"``: output gate, whole triangle, no positions; ``"full"``: the
+    same without the gate; ``"window"``: no gate, q and k rotated at
+    ``rope_theta`` and the mask cut to the last ``window`` positions —
+    both required by a pattern that has such a layer), ``kda_heads`` /
+    ``kda_head_dim`` (keys and values alike; required by a pattern with a
+    ``"kda"`` layer) the linear-attention ones, whose decay and output
+    gates are low-rank through ``kda_rank`` (default: ``kda_head_dim``).
 
     ``n_routed_experts`` is the router's width; ``held_experts`` names the
     experts whose weights live HERE (a chip's share of an expert-parallel
     layer, or ``range(n_routed_experts)`` for all of them): the model
     computes their part of every layer's result and leaves the rest out —
     see :func:`distlearn_tpu.parallel.ep.moe_held_ffn`.  The shared expert
-    (``n_shared_experts`` x ``expert_width`` wide) runs on every token.
+    (``n_shared_experts`` x ``expert_width`` wide) runs on every token;
+    with ``n_shared_experts=0`` the layers have none and no ``ws_*`` leaf.
+    ``expert_act`` is the experts' gate activation (``"silu"`` | ``"relu"``),
+    ``router_input`` one of :data:`ROUTER_INPUTS`: what every layer's
+    router reads.
 
     ``remat`` (True = ``"full"``) makes each layer one checkpoint
     (:func:`distlearn_tpu.models.core.checkpoint_block`): its activations
@@ -201,6 +244,17 @@ def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
     if heads % kv_heads:
         raise ValueError(f"heads={heads} is not a multiple of "
                          f"kv_heads={kv_heads}")
+    if "kda" in layer_types and not (kda_heads and kda_head_dim):
+        raise ValueError("a 'kda' layer needs kda_heads and kda_head_dim")
+    if "window" in layer_types and not (window and rope_theta):
+        raise ValueError("a 'window' layer needs window and rope_theta, got "
+                         f"window={window!r} rope_theta={rope_theta!r}")
+    if expert_act not in GATE_ACTS:
+        raise ValueError(f"expert_act must be one of {tuple(GATE_ACTS)}, "
+                         f"got {expert_act!r}")
+    if router_input not in ROUTER_INPUTS:
+        raise ValueError(f"router_input must be one of {ROUTER_INPUTS}, "
+                         f"got {router_input!r}")
     if isinstance(remat, str) and remat != "full":
         raise ValueError(f"remat must be False, True or 'full', got {remat!r}")
     held = tuple(int(e) for e in held_experts)
@@ -214,15 +268,16 @@ def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
     def init_layer(key, kind):
         ks = iter(random.split(key, 24))
         nk = lambda: next(ks)                                # noqa: E731
-        if kind == "gqa":
+        if kind != "kda":
             blk = {
                 "wq": _dense(nk(), (dim, heads, head_dim), dim, dtype),
                 "wk": _dense(nk(), (dim, kv_heads, head_dim), dim, dtype),
                 "wv": _dense(nk(), (dim, kv_heads, head_dim), dim, dtype),
-                "wg": _dense(nk(), (dim, heads, head_dim), dim, dtype),
-                "wo": _dense(nk(), (heads, head_dim, dim), heads * head_dim,
-                             dtype),
             }
+            if kind == "gqa":
+                blk["wg"] = _dense(nk(), (dim, heads, head_dim), dim, dtype)
+            blk["wo"] = _dense(nk(), (heads, head_dim, dim), heads * head_dim,
+                               dtype)
         else:
             conv = lambda: random.uniform(                   # noqa: E731
                 nk(), (conv_kernel, H * K), dtype, -1.0, 1.0) \
@@ -250,10 +305,12 @@ def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
         blk.update({
             "ln1": _norm_init((dim,), dtype),
             "ln2": _norm_init((dim,), dtype),
-            "router": _dense(nk(), (dim, n_routed_experts), dim, dtype),
+            "router": _dense(nk(), (dim, n_routed_experts), dim, dtype)})
+        shared = {
             "ws_gate": _dense(nk(), (dim, Fs), dim, dtype),
             "ws_up": _dense(nk(), (dim, Fs), dim, dtype),
-            "ws_down": _dense(nk(), (Fs, dim), Fs, dtype),
+            "ws_down": _dense(nk(), (Fs, dim), Fs, dtype)} if Fs else {}
+        blk.update(shared, **{
             "we_gate": _dense(nk(), (G, dim, F), dim, dtype),
             "we_up": _dense(nk(), (G, dim, F), dim, dtype),
             "we_down": _dense(nk(), (G, F, dim), F, dtype),
@@ -277,8 +334,8 @@ def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
                 raise NotImplementedError(
                     f"hybrid_lm: {what} parallelism over axis {axis!r} of "
                     f"size {lax.axis_size(axis)} is not written for these "
-                    "layers (the delta rule's state would have to cross "
-                    "the shards); use a size-1 axis")
+                    "layers (the delta rule's state and the window's band "
+                    "would have to cross the shards); use a size-1 axis")
         if seq_layout != "contig":
             raise ValueError("hybrid_lm keeps the sequence contiguous, got "
                              f"seq_layout={seq_layout!r}")
@@ -287,12 +344,15 @@ def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
 
         def make_layer(kind):
             def layer(blk, x):
-                if kind == "gqa":
-                    x = gqa_apply(blk, x, cd, eps)
-                else:
+                route_from = x if router_input == "layer_input" else None
+                if kind == "kda":
                     x = kda_apply(blk, x, cd, eps)
+                elif kind == "window":
+                    x = gqa_apply(blk, x, cd, eps, window, rope_theta)
+                else:
+                    x = gqa_apply(blk, x, cd, eps)
                 return moe_apply(blk, x, cd, eps, held, experts_per_tok,
-                                 ep_axis)
+                                 ep_axis, route_from, expert_act)
             return checkpoint_block(layer) if remat else layer
 
         # one wrapper a kind, reused down the depth (transformer_lm's note:

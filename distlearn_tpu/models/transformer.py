@@ -67,6 +67,30 @@ def attn_qkv(blk: PyTree, x: jax.Array, cd, tp_axis: str | None = None):
     return q, k, v
 
 
+def rotary(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """Rotary position embedding of ``x`` [B, L, H, D] at ``positions``
+    [L] over the whole head: the pairs are ``(d, d + D/2)`` ("rotate
+    half"), pair ``d`` turned by ``positions * theta ** (-2 d / D)``.  The
+    angles, their sines and the rotation are float32 whatever ``x`` is (at
+    position 16k a bfloat16 angle is off by whole turns); the result is
+    rounded to ``x``'s dtype once.  Applied to q and k before the attention
+    kernel, the scores depend on ``i - j`` alone.  Callers put it inside
+    their ``attn_proj`` scope; the inner name ``rope`` is what a profile
+    finds it by."""
+    half = x.shape[-1] // 2
+    if x.shape[-1] != 2 * half:
+        raise ValueError(f"rotary needs an even head size, got {x.shape[-1]}")
+    with jax.named_scope("rope"):
+        freq = jnp.float32(theta) ** (
+            jnp.arange(half, dtype=jnp.float32) * (-1.0 / half))
+        angle = positions.astype(jnp.float32)[:, None, None] * freq
+        cos, sin = jnp.cos(angle), jnp.sin(angle)           # [L, 1, D/2]
+        x32 = x.astype(jnp.float32)
+        a, b = x32[..., :half], x32[..., half:]
+        return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                               axis=-1).astype(x.dtype)
+
+
 def attn_out(blk: PyTree, x: jax.Array, att: jax.Array, cd,
              tp_axis: str | None = None) -> jax.Array:
     """Output projection + residual (the other half shared with the

@@ -206,7 +206,9 @@ def moe_ffn(expert_fn: Callable, expert_params: PyTree, router_w: jax.Array,
 # experts give:
 #
 #     s = softmax(x W_r)  in R^E;   I = top-k(s);   w_i = s_i / sum_{j in I} s_j
-#     y = sum_{i in I and held}  w_i  SwiGLU_i(x)
+#     y = sum_{i in I and held}  w_i  GLU_i(x)        (SwiGLU or ReGLU)
+#
+# The router may read another array than the experts do (``route_from``).
 #
 # What the experts held elsewhere would add is left out; summed over the
 # shares of every holder it is the whole layer (tests/test_hybrid_lm.py).
@@ -221,8 +223,10 @@ GROUP_TILE = 256
 
 
 def route_held(router_w: jax.Array, x: jax.Array, top_k: int, held):
-    """Route ``x`` [N, D] over all ``E`` outputs of ``router_w`` [D, E] and
-    group the assignments that fall on the ``held`` experts by expert.
+    """Route ``x`` [N, D] (whatever array the router reads: the experts'
+    input or another of as many rows) over all ``E`` outputs of ``router_w``
+    [D, E] and group the assignments that fall on the ``held`` experts by
+    expert.
 
     Scores, softmax and top-k are float32 at full matmul precision whatever
     the compute dtype: the choice of experts is discrete, so a rounded score
@@ -298,29 +302,38 @@ def _dot(a, b, eq="ij,jk->ik"):
     return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def grouped_swiglu(x, wg, wu, wd, slot_w, plan, cd):
+#: the gate activations of a held expert, by name (a static argument of the
+#: grouped product: its backward pass is written out for each)
+GATE_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def grouped_glu(x, wg, wu, wd, slot_w, plan, cd, act="silu"):
     """``y[n] = sum over the slots p of token n of slot_w[p] *
-    SwiGLU_{e(p)}(x[n])`` — the grouped product of :func:`route_held`'s
-    plan.  x [N, D]; wg, wu [G, D, F]; wd [G, F, D] (float32, cast to ``cd``
-    a tile at a time); returns [N, D] float32.  One loop over the tiles IN
-    USE (a dynamic trip count, so the backward pass is written by hand, as
-    a second such loop that recomputes each tile's hidden layer)."""
+    GLU_{e(p)}(x[n])``, ``GLU(x) = (act(x wg) * (x wu)) wd`` — the grouped
+    product of :func:`route_held`'s plan.  x [N, D]; wg, wu [G, D, F]; wd
+    [G, F, D] (float32, cast to ``cd`` a tile at a time); ``act`` one of
+    :data:`GATE_ACTS` (``"silu"``: SwiGLU, ``"relu"``: ReGLU); returns
+    [N, D] float32.  One loop over the tiles IN USE (a dynamic trip count,
+    so the backward pass is written by hand, as a second such loop that
+    recomputes each tile's hidden layer)."""
+    gate = GATE_ACTS[act]
+
     def body(i, y):
         idx, w, xe, e = _tile(plan, slot_w, x, i)
         g, u, d = _expert((wg, wu, wd), e, cd)
-        h = jax.nn.silu(_dot(xe, g)) * _dot(xe, u)
+        h = gate(_dot(xe, g)) * _dot(xe, u)
         return y.at[idx].add(_dot(h.astype(cd), d) * w[:, None])
     return lax.fori_loop(0, plan[2], body,
                          jnp.zeros(x.shape, jnp.float32))
 
 
-def _gs_fwd(x, wg, wu, wd, slot_w, plan, cd):
-    return grouped_swiglu(x, wg, wu, wd, slot_w, plan, cd), \
+def _gg_fwd(x, wg, wu, wd, slot_w, plan, cd, act):
+    return grouped_glu(x, wg, wu, wd, slot_w, plan, cd, act), \
         (x, wg, wu, wd, slot_w, plan)
 
 
-def _gs_bwd(cd, res, dy):
+def _gg_bwd(cd, act, res, dy):
     x, wg, wu, wd, slot_w, plan = res
     tile = plan[0].shape[0] // plan[1].shape[0]
     dy = dy.astype(cd)
@@ -330,16 +343,25 @@ def _gs_bwd(cd, res, dy):
         idx, w, xe, e = _tile(plan, slot_w, x, i)
         g, u, d = _expert((wg, wu, wd), e, cd)
         a, b = _dot(xe, g), _dot(xe, u)
-        s = jax.nn.sigmoid(a)
-        h = (a * s * b).astype(cd)
+        # the hidden layer again, and (d b, d a) of a cotangent dh of it
+        if act == "silu":
+            s = jax.nn.sigmoid(a)
+            h = (a * s * b).astype(cd)
+            pull = lambda dh: (                             # noqa: E731
+                (dh * a * s).astype(cd),
+                (dh * b * s * (1.0 + a * (1.0 - s))).astype(cd))
+        else:                       # relu: the gate is a where it is positive
+            r = jnp.maximum(a, 0.0)
+            h = (r * b).astype(cd)
+            pull = lambda dh: (                             # noqa: E731
+                (dh * r).astype(cd),
+                jnp.where(a > 0.0, dh * b, 0.0).astype(cd))
         dyt = dy[idx]
         dw = lax.dynamic_update_slice_in_dim(
             dw, jnp.sum(dyt.astype(jnp.float32) * _dot(h, d), axis=-1),
             i * tile, 0)
         dye = (dyt * w[:, None].astype(cd)).astype(cd)
-        dh = _dot(dye, d, "ij,kj->ik")
-        db = (dh * a * s).astype(cd)
-        da = (dh * b * s * (1.0 + a * (1.0 - s))).astype(cd)
+        db, da = pull(_dot(dye, d, "ij,kj->ik"))
         add = lambda acc, v: acc.at[e].add(v)               # noqa: E731
         return (dx.at[idx].add(_dot(da, g, "ij,kj->ik")
                                + _dot(db, u, "ij,kj->ik")),
@@ -355,16 +377,23 @@ def _gs_bwd(cd, res, dy):
             dd.astype(wd.dtype), dw, None)
 
 
-grouped_swiglu.defvjp(_gs_fwd, _gs_bwd)
+grouped_glu.defvjp(_gg_fwd, _gg_bwd)
 
 
 def moe_held_ffn(x: jax.Array, router_w: jax.Array, experts, held,
                  top_k: int, *, compute_dtype=None,
-                 ep_axis: str | None = None):
-    """The held experts' part of a routed SwiGLU layer (see the section
-    comment above): ``x`` [N, D], ``router_w`` [D, E], ``experts = (wg, wu,
-    wd)`` stacked over ``len(held)``.  Returns ``(y [N, D] in the compute
-    dtype, aux)`` with :func:`route_held`'s counters.
+                 ep_axis: str | None = None, route_from: jax.Array | None = None,
+                 act: str = "silu"):
+    """The held experts' part of a routed gated-linear-unit layer (see the
+    section comment above): ``x`` [N, D], ``router_w`` [D, E], ``experts =
+    (wg, wu, wd)`` stacked over ``len(held)``, their gate activation ``act``
+    (:data:`GATE_ACTS`).  Returns ``(y [N, D] in the compute dtype, aux)``
+    with :func:`route_held`'s counters.
+
+    ``route_from`` [N, D]: the array the ROUTER reads where that is not the
+    one the experts read (a layer that scores its experts on its input,
+    before attention, and feeds them the post-attention norm); None routes
+    from ``x``.
 
     ``ep_axis``: the mesh axis over which other devices hold the other
     experts.  With it the same layer is the expert-parallel one — every
@@ -379,7 +408,10 @@ def moe_held_ffn(x: jax.Array, router_w: jax.Array, experts, held,
             "assignments between devices that each hold several experts is "
             "not written; without ep_axis the layer computes the share of "
             "the experts it is told it holds")
+    if act not in GATE_ACTS:
+        raise ValueError(f"act must be one of {tuple(GATE_ACTS)}, got {act!r}")
     cd = compute_dtype or x.dtype
-    plan, slot_w, aux = route_held(router_w, x, top_k, held)
-    y = grouped_swiglu(x.astype(cd), *experts, slot_w, plan, cd)
+    plan, slot_w, aux = route_held(
+        router_w, x if route_from is None else route_from, top_k, held)
+    y = grouped_glu(x.astype(cd), *experts, slot_w, plan, cd, act)
     return y.astype(cd), aux

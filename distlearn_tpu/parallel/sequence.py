@@ -335,10 +335,20 @@ def _attn_counter():
         labels=("impl",))
 
 
-def attention_paths_traced() -> dict[str, int]:
+def _window_counter():
+    return obs.counter(
+        "attn_window_total",
+        "local_attention calls traced whose causal mask is cut to a band "
+        "(window < L), by resolved implementation",
+        labels=("impl",))
+
+
+def attention_paths_traced(windowed: bool = False) -> dict[str, int]:
     """``{impl: local_attention calls traced so far}`` in this process (the
-    ``attn_kernel_total`` counter; empty with ``DISTLEARN_OBS=0``)."""
-    family = _attn_counter()
+    ``attn_kernel_total`` counter; empty with ``DISTLEARN_OBS=0``).
+    ``windowed``: of those, the calls whose mask was a band
+    (``attn_window_total``)."""
+    family = _window_counter() if windowed else _attn_counter()
     if family is obs.NULL:
         return {}
     return {s["labels"]["impl"]: s["value"] for s in family.sample()}
@@ -351,18 +361,23 @@ def _backend() -> str:
     return jax.default_backend()
 
 
-def _splash_causal_attention(q, k, v, block: int, interpret: bool):
+def _splash_causal_attention(q, k, v, block: int, interpret: bool,
+                             window: int | None = None):
     """Causal attention through JAX's Pallas ``splash_attention`` kernel
     with a ``CausalMask``: blocks above the diagonal are never visited,
     scores and softmax statistics are float32 and live in VMEM only, the
-    backward pass is the kernel's fused dK/dV/dQ call.  Its output and
-    log-sum-exp carry the name :data:`ATTN_RESIDUALS`.  q/k/v:
+    backward pass is the kernel's fused dK/dV/dQ call.  With ``window`` the
+    mask is the causal BAND (``LocalMask``: position i sees ``i - window <
+    j <= i``) and the blocks below the band are never visited either.  Its
+    output and log-sum-exp carry the name :data:`ATTN_RESIDUALS`.  q/k/v:
     ``[B, L, H, D]``; the kernel wants ``[H, L, D]`` per batch row and an
     already scaled q."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm)
     B, L, H, D = q.shape
     Hkv = k.shape[2]
+    mask = sm.CausalMask((L, L)) if window is None else sm.LocalMask(
+        (L, L), window_size=(window - 1, 0), offset=0)
     # A head narrower than the 128 lanes is padded to them wherever it is
     # the minor dimension (``[.., L, 64]`` is stored as ``[.., L, 128]``);
     # sequence-minor q/k/v are not.  Measured at D = 64: 22 ms of a 433 ms
@@ -379,7 +394,7 @@ def _splash_causal_attention(q, k, v, block: int, interpret: bool):
     qs = (q.astype(jnp.float32) * (1.0 / (D ** 0.5))).astype(q.dtype)
     if Hkv == H:
         kernel = sk.make_splash_mha(
-            sm.MultiHeadMask([sm.CausalMask((L, L))] * H), block_sizes=sizes,
+            sm.MultiHeadMask([mask] * H), block_sizes=sizes,
             head_shards=1, q_seq_shards=1,
             residual_checkpoint_name=ATTN_RESIDUALS, interpret=interpret)
         out = jax.vmap(kernel)(heads_first(qs), heads_first(k),
@@ -391,7 +406,7 @@ def _splash_causal_attention(q, k, v, block: int, interpret: bool):
     # second vmapped axis
     group = H // Hkv
     kernel = sk.make_splash_mqa(
-        sm.MultiHeadMask([sm.CausalMask((L, L))] * group), block_sizes=sizes,
+        sm.MultiHeadMask([mask] * group), block_sizes=sizes,
         head_shards=1, q_seq_shards=1,
         residual_checkpoint_name=ATTN_RESIDUALS, interpret=interpret)
     qg = heads_first(qs).reshape(B, Hkv, group, L, D)
@@ -401,13 +416,22 @@ def _splash_causal_attention(q, k, v, block: int, interpret: bool):
 
 def local_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False,
-                    impl: str | None = None) -> jax.Array:
+                    impl: str | None = None,
+                    window: int | None = None) -> jax.Array:
     """Single-device attention (same layout as the sharded variants), for
     non-sharded runs and as the per-shard kernel of
     :func:`alltoall_attention`.  q: [B, L, H, D]; k/v: [B, L, Hkv, D] with
     ``Hkv`` dividing ``H`` (grouped queries: query head ``i`` attends K/V
     head ``i // (H / Hkv)``; ``Hkv == H`` is ordinary multi-head attention
     and runs exactly the code it always ran).
+
+    ``window`` (causal attention only) cuts the mask to a band: position
+    ``i`` attends ``j`` with ``j <= i`` and ``i - j < window``.  A window
+    that covers the whole length is no window: the call is the causal one,
+    mask, counter and program.  Both paths take it — the blockwise kernel
+    skips the blocks outside the band, the full-square path masks them —
+    and :func:`select_attention` chooses between them from the call's shape
+    as it does without one.
 
     ``impl=None`` — what every caller in the package passes — resolves
     through :func:`select_attention`.  Naming an implementation
@@ -420,9 +444,16 @@ def local_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     runs the kernel in Pallas interpret mode (slow; the CPU tests).
 
     The resolved path is counted in ``attn_kernel_total{impl=}`` (``obs``):
-    once per traced call, not per step — a jitted program is traced once."""
+    once per traced call, not per step — a jitted program is traced once;
+    a call whose mask is a band also in ``attn_window_total{impl=}``."""
     B, L, H, D = q.shape
     Hkv = k.shape[2]
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(f"window={window} needs causal attention and "
+                             "at least one position to attend")
+        if window >= L:
+            window = None
     if H % Hkv or v.shape[2] != Hkv:
         raise ValueError(f"{H} query heads cannot share {Hkv} K / "
                          f"{v.shape[2]} V heads: the K/V head count must "
@@ -446,9 +477,12 @@ def local_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             "attention, a local length that is a multiple of "
             f"{_SPLASH_BLOCKS[-1]} and, on the TPU, 64-bit types off")
     _attn_counter().labels(impl=impl).inc()
+    if window is not None:
+        _window_counter().labels(impl=impl).inc()
     if impl == "splash":
         return _splash_causal_attention(q, k, v, block,
-                                        interpret=backend != "tpu")
+                                        interpret=backend != "tpu",
+                                        window=window)
     scale = 1.0 / (D ** 0.5)
     if Hkv != H:
         # full-square path of grouped queries: each K/V head repeated for
@@ -463,7 +497,10 @@ def local_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         preferred_element_type=jnp.float32) * scale
     if causal:
         pos = jnp.arange(L)
-        scores = jnp.where(pos[:, None] >= pos[None, :], scores, -jnp.inf)
+        allowed = pos[:, None] >= pos[None, :]
+        if window is not None:
+            allowed &= pos[:, None] - pos[None, :] < window
+        scores = jnp.where(allowed, scores, -jnp.inf)
     w = jax.nn.softmax(scores, axis=-1)   # stays f32 (stable softmax)
     out = jnp.einsum("bhqk,bkhd->bhqd", w.astype(q.dtype), v,
                      preferred_element_type=jnp.float32)

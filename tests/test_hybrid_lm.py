@@ -3,7 +3,10 @@ which experts it holds (``parallel/ep.py``), at toy size on the CPU mesh:
 the SHARE test (every holder's part plus the shared expert once is the whole
 layer), no assignment ever dropped, the grouped product's hand-written
 backward pass, the routing counters, the LM step builders driving the model
-unchanged, and what is refused until it is written."""
+unchanged, and what is refused until it is written; the same for the other
+pattern the constructor builds — ungated softmax layers, full-causal without
+positions among rotary sliding-window ones, ReLU-gated experts with no shared
+expert, the router reading the layer's input."""
 
 import jax
 import jax.numpy as jnp
@@ -13,10 +16,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distlearn_tpu import obs
 from distlearn_tpu.models import hybrid_lm
-from distlearn_tpu.models.hybrid import causal_conv, moe_apply
+from distlearn_tpu.models.hybrid import causal_conv, gqa_apply, moe_apply
 from distlearn_tpu.models.transformer import lm_loss
 from distlearn_tpu.parallel import ep
-from distlearn_tpu.parallel.ep import (grouped_swiglu, moe_held_ffn,
+from distlearn_tpu.parallel.ep import (grouped_glu, moe_held_ffn,
                                        route_held)
 from distlearn_tpu.train import build_lm_routing_metrics, build_lm_step
 
@@ -30,21 +33,27 @@ def _layer(seed=0, held=4):
             n(4, held, F, D) / 5)
 
 
-def _masked_loop(x, router, wg, wu, wd, held):
-    """The routed part by a loop over the held experts with masks."""
-    s = jax.nn.softmax(x @ router, axis=-1)
+_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _masked_loop(x, router, wg, wu, wd, held, act="silu", route_from=None):
+    """The routed part by a loop over the held experts with masks: the plain
+    formula, which autodiff differentiates."""
+    s = jax.nn.softmax((x if route_from is None else route_from) @ router,
+                       axis=-1)
     top, chosen = jax.lax.top_k(s, K)
     w = top / top.sum(-1, keepdims=True)
     y = 0.0
     for j, e in enumerate(held):
         w_e = jnp.sum(jnp.where(chosen == e, w, 0.0), -1, keepdims=True)
-        y = y + w_e * ((jax.nn.silu(x @ wg[j]) * (x @ wu[j])) @ wd[j])
+        y = y + w_e * ((_ACTS[act](x @ wg[j]) * (x @ wu[j])) @ wd[j])
     return y
 
 
+@pytest.mark.parametrize("act", ["silu", "relu"])
 @pytest.mark.parametrize("tile", [8, 32])
 def test_grouped_product_and_its_backward_pass_are_the_masked_loop(
-        monkeypatch, tile):
+        monkeypatch, tile, act):
     # the layer's one tile height is a module constant; small here so that
     # an expert's assignments span several tiles
     monkeypatch.setattr(ep, "GROUP_TILE", tile)
@@ -52,15 +61,49 @@ def test_grouped_product_and_its_backward_pass_are_the_masked_loop(
     args = _layer()
 
     def system(x, router, wg, wu, wd):
-        return moe_held_ffn(x, router, (wg, wu, wd), held, K)[0]
+        return moe_held_ffn(x, router, (wg, wu, wd), held, K, act=act)[0]
 
-    np.testing.assert_allclose(system(*args), _masked_loop(*args, held),
+    np.testing.assert_allclose(system(*args), _masked_loop(*args, held, act),
                                rtol=1e-5, atol=1e-6)
     c = jnp.cos(jnp.arange(N * D, dtype=jnp.float32).reshape(N, D))
     got = jax.grad(lambda *a: jnp.sum(system(*a) * c),
                    argnums=tuple(range(5)))(*args)
-    want = jax.grad(lambda *a: jnp.sum(_masked_loop(*a, held) * c),
+    want = jax.grad(lambda *a: jnp.sum(_masked_loop(*a, held, act) * c),
                     argnums=tuple(range(5)))(*args)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("act", ["silu", "relu"])
+def test_router_reads_another_array_than_the_experts(act):
+    """``route_from``: scores, choice and combine weights come from one
+    array, the experts' products from another; the gradient reaches BOTH —
+    the routing source through the combine weights alone."""
+    held = (3, 5, 6, 11)
+    x, router, wg, wu, wd = _layer()
+    src = jax.random.normal(jax.random.PRNGKey(9), x.shape, jnp.float32)
+
+    def system(x, src, router, wg, wu, wd):
+        return moe_held_ffn(x, router, (wg, wu, wd), held, K, act=act,
+                            route_from=src)
+
+    def plain(x, src, router, wg, wu, wd):
+        return _masked_loop(x, router, wg, wu, wd, held, act, route_from=src)
+
+    args = (x, src, router, wg, wu, wd)
+    y, aux = system(*args)
+    np.testing.assert_allclose(y, plain(*args), rtol=1e-5, atol=1e-6)
+    # the counters are the routing source's: those of route_held on it
+    np.testing.assert_array_equal(
+        aux["assignments"], route_held(router, src, K, held)[2]["assignments"])
+    assert not np.array_equal(
+        aux["assignments"], route_held(router, x, K, held)[2]["assignments"])
+    c = jnp.sin(jnp.arange(N * D, dtype=jnp.float32).reshape(N, D))
+    got = jax.grad(lambda *a: jnp.sum(system(*a)[0] * c),
+                   argnums=tuple(range(6)))(*args)
+    want = jax.grad(lambda *a: jnp.sum(plain(*a) * c),
+                    argnums=tuple(range(6)))(*args)
+    assert float(jnp.abs(want[1]).max()) > 0        # the source has a gradient
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
 
@@ -124,7 +167,9 @@ def test_held_layer_refuses_what_is_not_written():
             route_held(router, x, K, bad)
     with pytest.raises(ValueError, match="do not fit"):
         route_held(router, x, E + 1, (0, 1, 2, 3))
-    assert grouped_swiglu.__name__ == "grouped_swiglu"
+    with pytest.raises(ValueError, match="act must be one of"):
+        moe_held_ffn(x, router, (wg, wu, wd), (0, 1, 2, 3), K, act="gelu")
+    assert grouped_glu.__name__ == "grouped_glu"
 
 
 def test_causal_conv_sees_no_future():
@@ -150,6 +195,22 @@ def _toy(**kw):
     return hybrid_lm(**kw)
 
 
+def _swa_toy(**kw):
+    """The other pattern: [full, window, window, window], no shared expert,
+    ReLU gates, the router on the layer's input; a band of 16 of 64."""
+    kw = dict(dict(vocab=97, dim=32,
+                   layer_types=("full", "window", "window", "window"),
+                   heads=4, kv_heads=2, head_dim=8, window=16,
+                   rope_theta=1.5e6, n_routed_experts=16,
+                   held_experts=(1, 5, 6, 11), experts_per_tok=3,
+                   expert_width=24, n_shared_experts=0, expert_act="relu",
+                   router_input="layer_input", eps=1e-6, max_len=64), **kw)
+    return hybrid_lm(**kw)
+
+
+_TOYS = {"gqa+kda": _toy, "full+window": _swa_toy}
+
+
 def _mesh(shape=(2, 1, 1)):
     n = int(np.prod(shape))
     return Mesh(np.array(jax.devices()[:n]).reshape(shape),
@@ -162,11 +223,12 @@ def _tokens(mesh, b=4, L=64):
         NamedSharding(mesh, P("data", "seq")))
 
 
+@pytest.mark.parametrize("pattern", sorted(_TOYS))
 @pytest.mark.parametrize("remat", [False, "full"])
-def test_build_lm_step_drives_the_hybrid_model_unchanged(remat):
+def test_build_lm_step_drives_the_hybrid_model_unchanged(remat, pattern):
     """Same builder, same call: two data-parallel steps are the gradient
     steps of ``lm_loss`` on the whole batch, and the loss falls."""
-    mesh, model = _mesh(), _toy(remat=remat)
+    mesh, model = _mesh(), _TOYS[pattern](remat=remat)
     params, _ = model.init(jax.random.PRNGKey(0))
     tokens = _tokens(mesh)
     step = build_lm_step(model, mesh, params, lr=0.05, donate=False)
@@ -199,14 +261,27 @@ def _remat_toy(monkeypatch, wrap, on_the_kernel=True):
     return _toy(head_dim=64, max_len=128, remat="full")
 
 
-@pytest.mark.parametrize("wrap,calls", [("named", 2), ("bare", 3)])
+def _remat_swa_toy(monkeypatch, wrap):
+    """One full and one windowed layer (a band of 48 of 128: the kernel's
+    ``LocalMask``) on the blockwise kernel, rematerialised."""
+    _remat_toy(monkeypatch, wrap)
+    return _swa_toy(layer_types=("full", "window"), head_dim=64, window=48,
+                    max_len=128, remat="full")
+
+
+@pytest.mark.parametrize("pattern,wrap,calls", [
+    ("gqa+kda", "named", 2), ("gqa+kda", "bare", 3),
+    ("full+window", "named", 4), ("full+window", "bare", 6)])
 def test_rematerialised_gqa_layer_runs_the_forward_kernel_once(
-        monkeypatch, wrap, calls):
-    """The grouped-query call of the one softmax layer: forward and backward
+        monkeypatch, pattern, wrap, calls):
+    """The grouped-query call of a softmax layer: forward and backward
     kernel in the gradient, no third call in the recomputation (the bare
-    ``jax.checkpoint`` had one); the linear-attention layers hold none."""
+    ``jax.checkpoint`` had one); the linear-attention layers hold none.  A
+    WINDOWED call keeps its residuals under the same name, so it is two
+    calls a layer as the full one."""
     from tests.program_util import pallas_calls
-    model = _remat_toy(monkeypatch, wrap)
+    model = (_remat_toy if pattern == "gqa+kda" else _remat_swa_toy)(
+        monkeypatch, wrap)
     params = jax.eval_shape(lambda k: model.init(k)[0], jax.random.PRNGKey(0))
     tokens = jnp.zeros((1, 128), jnp.int32)
     jaxpr = jax.make_jaxpr(
@@ -238,7 +313,7 @@ def test_rematerialised_layers_are_bitwise_the_bare_checkpoints(
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@pytest.mark.parametrize("kind", ["gqa", "kda"])
+@pytest.mark.parametrize("kind", ["gqa", "kda", "full", "window"])
 def test_a_layer_with_no_kernel_keeps_its_input_alone(monkeypatch, kind):
     """On the full-square path, and in a linear-attention layer, nothing
     carries the kernel's name: the policy finds none, and the rematerialised
@@ -251,7 +326,8 @@ def test_a_layer_with_no_kernel_keeps_its_input_alone(monkeypatch, kind):
     tokens = jnp.zeros((2, 64), jnp.int32)
 
     def lowered():
-        model = _toy(layer_types=(kind,), remat="full")
+        model = _toy(layer_types=(kind,), remat="full", window=16,
+                     rope_theta=1e4)
         params = jax.eval_shape(lambda k: model.init(k)[0],
                                 jax.random.PRNGKey(0))
         return jax.jit(jax.grad(
@@ -262,8 +338,10 @@ def test_a_layer_with_no_kernel_keeps_its_input_alone(monkeypatch, kind):
     assert program_text(named) == program_text(lowered())
 
 
-def test_routing_metrics_count_into_obs():
-    mesh, model = _mesh(), _toy()
+@pytest.mark.parametrize("pattern,top_k", [("gqa+kda", 4),
+                                           ("full+window", 3)])
+def test_routing_metrics_count_into_obs(pattern, top_k):
+    mesh, model = _mesh(), _TOYS[pattern]()
     params, _ = model.init(jax.random.PRNGKey(0))
     tokens = _tokens(mesh)
     metrics = build_lm_routing_metrics(model, mesh, params)
@@ -271,8 +349,8 @@ def test_routing_metrics_count_into_obs():
     assert out["assignments"].shape == (4, 4)
     assert (out["dropped"] == 0).all()
     assert ((0 <= out["unheld_frac"]) & (out["unheld_frac"] < 1)).all()
-    # every token has 4 experts of 16; the held 4 get their share of them
-    assert 0 < out["assignments"].sum() < 4 * 4 * 64 * 4
+    # every token has top_k experts of 16; the held 4 get their share
+    assert 0 < out["assignments"].sum() < 4 * 4 * 64 * top_k
     family = obs.counter("moe_assignments_total", labels=("layer", "expert"))
     if family is not obs.NULL:
         before = sum(s["value"] for s in family.sample())
@@ -296,9 +374,75 @@ def test_sequence_and_tensor_axes_must_be_of_size_one(shape, what):
         step(params, _tokens(mesh, b=2))
 
 
+def test_layers_router_reads_its_input_before_the_mixer():
+    """``router_input="layer_input"``: the counters of a one-layer model are
+    those of routing the EMBEDDING rows (the layer's input, un-normed), and
+    differ from routing the normed post-attention stream, which
+    ``"ffn_norm"`` reads on the same weights."""
+    tokens = jax.random.randint(jax.random.PRNGKey(4), (2, 64), 0, 97)
+    counts = {}
+    for source in ("layer_input", "ffn_norm"):
+        model = _swa_toy(layer_types=("window",), router_input=source)
+        params, _ = model.init(jax.random.PRNGKey(0))
+        counts[source] = model.apply(params, {}, tokens)[1][
+            "moe_assignments"][0]
+    x = params["embed"][tokens].reshape(-1, 32)
+    want = route_held(params["layer0"]["router"], x, 3, (1, 5, 6, 11))[2]
+    np.testing.assert_array_equal(counts["layer_input"], want["assignments"])
+    assert not np.array_equal(counts["layer_input"], counts["ffn_norm"])
+
+
+def test_layer_without_a_shared_expert_builds_no_leaf_for_one():
+    params, _ = _swa_toy().init(jax.random.PRNGKey(0))
+    for i in range(4):
+        assert set(params[f"layer{i}"]) == {
+            "ln1", "ln2", "wq", "wk", "wv", "wo", "router", "we_gate",
+            "we_up", "we_down"}
+    blk = params["layer1"]
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 64, 32), jnp.float32)
+    y, _ = moe_apply(blk, x, jnp.float32, 1e-6, (1, 5, 6, 11), 3, None,
+                     act="relu")
+    h = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6)
+    routed = moe_held_ffn(h.reshape(-1, 32), blk["router"],
+                          (blk["we_gate"], blk["we_up"], blk["we_down"]),
+                          (1, 5, 6, 11), 3, act="relu")[0]
+    np.testing.assert_allclose(y, x + routed.reshape(x.shape), atol=1e-6)
+    # the gated pattern keeps its shared expert and its gate
+    gated, _ = _toy().init(jax.random.PRNGKey(0))
+    assert {"ws_gate", "ws_up", "ws_down", "wg"} <= set(gated["layer0"])
+
+
+def test_windowed_layer_is_the_full_one_where_the_band_covers_the_length():
+    """A softmax layer's mixer with a window of the whole length and no
+    rotation is the full-causal mixer; a shorter band, or the rotation,
+    changes it."""
+    params, _ = _swa_toy().init(jax.random.PRNGKey(0))
+    blk = params["layer1"]
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, 64, 32), jnp.float32)
+    full = gqa_apply(blk, x, jnp.float32, 1e-6)
+    np.testing.assert_array_equal(
+        gqa_apply(blk, x, jnp.float32, 1e-6, window=64), full)
+    band = gqa_apply(blk, x, jnp.float32, 1e-6, window=16)
+    np.testing.assert_allclose(band[:, :16], full[:, :16], atol=1e-6)
+    assert float(jnp.abs(band[:, 16:] - full[:, 16:]).max()) > 1e-3
+    turned = gqa_apply(blk, x, jnp.float32, 1e-6, rope_theta=1.5e6)
+    np.testing.assert_allclose(turned[:, :1], full[:, :1], atol=1e-6)
+    assert float(jnp.abs(turned[:, 1:] - full[:, 1:]).max()) > 1e-3
+
+
 def test_constructor_refuses_what_it_cannot_build():
-    with pytest.raises(ValueError, match="layer_types"):
+    with pytest.raises(ValueError, match="'full', 'window'"):
         _toy(layer_types=("gqa", "mamba"))
+    with pytest.raises(ValueError, match="needs kda_heads"):
+        _toy(kda_heads=None)
+    with pytest.raises(ValueError, match="needs window and rope_theta"):
+        _swa_toy(window=None)
+    with pytest.raises(ValueError, match="needs window and rope_theta"):
+        _swa_toy(rope_theta=None)
+    with pytest.raises(ValueError, match="expert_act"):
+        _swa_toy(expert_act="gelu")
+    with pytest.raises(ValueError, match="router_input"):
+        _swa_toy(router_input="attn_norm")
     with pytest.raises(ValueError, match="kv_heads"):
         _toy(kv_heads=3)
     with pytest.raises(ValueError, match="remat"):

@@ -335,6 +335,123 @@ def test_grouped_queries_splash_matches_full_square_float32():
                                    rtol=2e-4, atol=5e-5)
 
 
+# --- a causal window --------------------------------------------------------
+
+def _band_oracle(q, k, v, window):
+    """Causal attention over the band ``i - window < j <= i`` from the
+    definition: a row's softmax over exactly its allowed keys (numpy,
+    float64), K/V heads repeated for their group."""
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+    B, L, H, D = q.shape
+    k, v = (np.repeat(a, H // k.shape[2], axis=2) for a in (k, v))
+    out = np.zeros_like(q)
+    for i in range(L):
+        lo = max(0, i - window + 1)
+        s = np.einsum("bhd,bkhd->bhk", q[:, i], k[:, lo:i + 1]) / np.sqrt(D)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        out[:, i] = np.einsum("bhk,bkhd->bhd", p, v[:, lo:i + 1])
+    return out
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(2, 2), (4, 2)])
+def test_window_full_square_is_the_band_from_the_definition(heads, kv_heads):
+    q, k, v = _gqa_qkv(40, 16, heads, kv_heads, seed=21)
+    got = local_attention(q, k, v, causal=True, window=7)
+    np.testing.assert_allclose(np.asarray(got), _band_oracle(q, k, v, 7),
+                               rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("impl", ["xla", "splash"])
+def test_window_edge_attends_w_minus_1_back_and_not_w(impl):
+    """``i - j = W - 1`` is attended, ``i - j = W`` is not: a value planted
+    at key j reaches query j + W - 1 and does not reach query j + W.  On
+    the blockwise kernel with a band edge INSIDE a block (W = 100 at block
+    128) and across blocks."""
+    L, W, j = 384, 100, 130
+    q, k, v = _qkv_heads64(L, seed=22)
+    bumped = v.at[:, j].add(1000.0)
+    moved = np.abs(np.asarray(
+        local_attention(q, k, bumped, causal=True, window=W, impl=impl)
+        - local_attention(q, k, v, causal=True, window=W, impl=impl))
+    ).max(axis=(0, 2, 3))                                   # per query
+    assert (moved[:j] == 0).all()                           # causal
+    assert (moved[j:j + W] > 1e-3).all()                    # the band
+    assert moved[j + W - 1] > 1e-3 and (moved[j + W:] == 0).all()
+
+
+@pytest.mark.parametrize("impl", ["xla", "splash"])
+@pytest.mark.parametrize("window", [128, 129, 4096])
+def test_window_that_covers_the_length_is_the_causal_call(impl, window):
+    """``L <= W``: mask, result and program are the causal call's — bit for
+    bit, and the window's counter does not move."""
+    from distlearn_tpu.parallel.sequence import \
+        attention_paths_traced as calls
+    q, k, v = _qkv_heads64(128, seed=23)
+    before = calls(windowed=True)
+    got = local_attention(q, k, v, causal=True, window=window, impl=impl)
+    np.testing.assert_array_equal(
+        np.asarray(got),
+        np.asarray(local_attention(q, k, v, causal=True, impl=impl)))
+    assert calls(windowed=True) == before
+    same = lambda w: jax.jit(lambda a, b, c: local_attention(  # noqa: E731
+        a, b, c, causal=True, window=w, impl=impl)).lower(q, k, v).as_text()
+    assert same(window) == same(None)
+
+
+@pytest.mark.parametrize("heads,kv_heads,L,D,window", [
+    (2, 2, 384, 64, 100),      # MHA, sequence-minor, the edge inside a block
+    (2, 2, 384, 64, 256),      # the edge on a block boundary
+    (4, 2, 256, 128, 72)])     # grouped queries, head-minor
+def test_window_splash_matches_full_square_float32(heads, kv_heads, L, D,
+                                                   window):
+    """The blockwise kernel with the band's ``LocalMask`` (Pallas interpret
+    mode here; blocks below the band never visited) is the full-square
+    path's masked math: forward and the three gradients, float32, tight, at
+    the tolerances of the causal comparison above."""
+    q, k, v = _gqa_qkv(L, D, heads, kv_heads, seed=24)
+
+    def grads(impl):
+        def loss(a, b, c):
+            out = local_attention(a, b, c, causal=True, impl=impl,
+                                  window=window)
+            return jnp.sum(out.astype(jnp.float32) ** 2)
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    got = local_attention(q, k, v, causal=True, impl="splash", window=window)
+    ref = local_attention(q, k, v, causal=True, impl="xla", window=window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(np.asarray(ref),
+                               _band_oracle(q, k, v, window),
+                               rtol=1e-5, atol=2e-6)
+    for a, b in zip(grads("splash"), grads("xla")):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=5e-5)
+
+
+def test_window_is_refused_without_causality_and_counted_with_it():
+    from distlearn_tpu.parallel.sequence import \
+        attention_paths_traced as calls
+    q, k, v = _qkv_heads64(256, seed=25)
+    with pytest.raises(ValueError, match="needs causal attention"):
+        local_attention(q, k, v, causal=False, window=16)
+    with pytest.raises(ValueError, match="needs causal attention"):
+        local_attention(q, k, v, causal=True, window=0)
+    before, all_before = calls(windowed=True), calls()
+    local_attention(q, k, v, causal=True, window=16)         # resolves: xla
+    local_attention(q, k, v, causal=True, window=128, impl="splash")
+    local_attention(q, k, v, causal=True)                    # no window
+    after, all_after = calls(windowed=True), calls()
+    assert after.get("xla", 0) - before.get("xla", 0) == 1
+    assert after.get("splash", 0) - before.get("splash", 0) == 1
+    assert all_after.get("xla", 0) - all_before.get("xla", 0) == 2
+    # the path is chosen from the call's shape as it is without a window
+    from distlearn_tpu.parallel.sequence import select_attention
+    assert select_attention(True, 16384, 128, jnp.bfloat16, "tpu") == "splash"
+
+
 @pytest.mark.parametrize("causal,L", [(False, 256), (True, 100), (True, 32)])
 def test_forced_splash_raises_where_it_cannot_run(causal, L):
     """A forced path is never silently swapped for another one."""

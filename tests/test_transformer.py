@@ -636,3 +636,96 @@ def test_tpu_compiler_hides_the_gradient_sum_behind_the_backward_scan(
     memory = compiled.memory_analysis()
     held = memory.temp_size_in_bytes + memory.argument_size_in_bytes
     assert held < 15.75e9, f"{held / 1e9:.2f} GB of temporaries and arguments"
+
+
+def test_tpu_compiler_takes_the_windowed_kernel_at_its_published_widths(
+        v5e_chip, monkeypatch):
+    """The blockwise kernel with a causal BAND at the widths a windowed
+    layer is trained at (28 query heads over 4 K/V heads of 128, one
+    sequence of 16,384, a band of 4,096, bf16), forward and backward,
+    compiled for the v5e: two Mosaic calls (grouped queries:
+    ``splash_mqa``), no ``[28, 16384, 16384]`` array, and the rotation
+    before it fused by the compiler at that size.  Nothing runs; no number
+    of this is a measurement."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    from distlearn_tpu.models.transformer import rotary
+    from distlearn_tpu.parallel import sequence
+    monkeypatch.setattr(sequence, "_backend", lambda: "tpu")
+    one = SingleDeviceSharding(v5e_chip)
+    q = jax.ShapeDtypeStruct((1, 16384, 28, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16, sharding=one)
+
+    def loss(q, k, v):
+        pos = jnp.arange(q.shape[1])
+        out = sequence.local_attention(
+            rotary(q, pos, 1.5e6), rotary(k, pos, 1.5e6), v, causal=True,
+            window=4096)
+        return jnp.sum(out.astype(jnp.float32))
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+                q, kv, kv).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "splash_mqa_fwd" in text and "splash_mqa_dkv" in text
+    assert "16384,16384" not in text
+
+
+# --- rotary positions -------------------------------------------------------
+
+def test_rotary_is_the_complex_rotation_of_the_half_split_pairs():
+    """Pair ``(d, d + D/2)`` read as the complex number ``x[d] + i x[d +
+    D/2]`` is multiplied by ``exp(i p theta^(-2d/D))``: against that formula
+    written out in numpy's complex128, at positions up to 16k."""
+    from distlearn_tpu.models.transformer import rotary
+    rng = np.random.RandomState(0)
+    x = rng.randn(2, 6, 3, 16).astype(np.float32)
+    pos = np.array([0, 1, 2, 977, 4096, 16383])
+    theta = 1.5e6
+    z = x[..., :8].astype(np.complex128) + 1j * x[..., 8:]
+    turn = np.exp(1j * pos[:, None] * theta ** (-np.arange(8) / 8.0))
+    want = z * turn[None, :, None, :]
+    got = np.asarray(rotary(jnp.asarray(x), jnp.asarray(pos), theta))
+    # float32 angles: position 16383 x frequency 1 is held to 1e-3 rad
+    np.testing.assert_allclose(got[..., :8], want.real, atol=4e-3)
+    np.testing.assert_allclose(got[..., 8:], want.imag, atol=4e-3)
+    np.testing.assert_allclose(got[:, :3, :, :8], want.real[:, :3], atol=1e-6)
+    np.testing.assert_array_equal(got[:, 0], x[:, 0])       # position 0
+    # a rotation: every pair keeps its length
+    np.testing.assert_allclose(got[..., :8] ** 2 + got[..., 8:] ** 2,
+                               x[..., :8] ** 2 + x[..., 8:] ** 2, rtol=1e-5)
+    assert rotary(jnp.asarray(x, jnp.bfloat16), jnp.asarray(pos),
+                  theta).dtype == jnp.bfloat16
+    with pytest.raises(ValueError, match="even head size"):
+        rotary(jnp.zeros((1, 2, 1, 7)), jnp.arange(2), theta)
+
+
+@pytest.mark.parametrize("shift", [1, 37, 4096])
+def test_rotary_scores_depend_on_the_distance_alone(shift):
+    """q and k rotated at positions ``p + shift`` give the scores they give
+    at ``p``: the attention of a rotary layer is invariant to a common shift
+    of the positions."""
+    from distlearn_tpu.models.transformer import rotary
+    rng = np.random.RandomState(1)
+    q, k = (jnp.asarray(rng.randn(1, 24, 2, 32).astype(np.float32))
+            for _ in range(2))
+    pos = jnp.arange(24)
+
+    def scores(p):
+        return jnp.einsum("bqhd,bkhd->bhqk", rotary(q, p, 1e4),
+                          rotary(k, p, 1e4))
+
+    base = scores(pos)
+    np.testing.assert_allclose(np.asarray(scores(pos + shift)),
+                               np.asarray(base), atol=2e-3 if shift > 100
+                               else 2e-4)
+    # and they are NOT the unrotated scores
+    assert float(jnp.abs(base - jnp.einsum("bqhd,bkhd->bhqk", q, k)).max()) \
+        > 0.1
