@@ -243,6 +243,12 @@ def phase_trainer(*, num_nodes: int, per_node_batch: int = 256,
     return out
 
 
+def _moved(before: dict, after: dict) -> dict:
+    """The counters of ``after`` that moved since ``before``, by how much."""
+    return {k: v - before.get(k, 0) for k, v in after.items()
+            if v - before.get(k, 0)}
+
+
 def _lower_default(step, params, tokens, what):
     """Lower a step built with no ``attn_impl`` and say which attention
     ``local_attention`` resolved while it was traced (the ``obs``
@@ -252,9 +258,7 @@ def _lower_default(step, params, tokens, what):
     from distlearn_tpu.parallel.sequence import attention_paths_traced
     before = attention_paths_traced()
     lowered = step.lower(params, tokens)
-    traced = {k: v - before.get(k, 0)
-              for k, v in attention_paths_traced().items()
-              if v - before.get(k, 0)}
+    traced = _moved(before, attention_paths_traced())
     if jax.default_backend() == "tpu":
         _require(set(traced) == {"splash"},
                  f"{what}: the blockwise attention did not engage by "
@@ -377,16 +381,22 @@ def phase_hybrid_lm(*, vocab: int = 3072, dim: int = 4096, heads: int = 64,
              f"the dropless expert layer dropped: {routing['dropped']}")
     _require(int(routing["assignments"].sum()) > 0,
              "no token was routed to a held expert")
+    from distlearn_tpu.parallel.ep import grouped_paths_traced
     step = build_lm_step(model, mesh, params, lr=lr)
+    moe_before = grouped_paths_traced()
     lowered, traced = _lower_default(step, params, tokens, "hybrid LM")
+    moe_traced = _moved(moe_before, grouped_paths_traced())
     mosaic = lowered.as_text().count("tpu_custom_call")
     # one softmax layer, rematerialised: its checkpoint keeps the kernel's
     # output and log-sum-exp, so the step holds the forward and the
-    # backward kernel and no third call in the recomputation
-    _require(mosaic == 2 or jax.default_backend() != "tpu",
+    # backward kernel and no third call in the recomputation; at this load
+    # (2,048 x 8 / 320: 51 rows an expert) the held experts' grouped product
+    # stays the loop, with no kernel of its own (moe_grouped_total)
+    _require((mosaic == 2 and set(moe_traced) == {"xla"})
+             or jax.default_backend() != "tpu",
              f"hybrid LM: {mosaic} Mosaic calls in the rematerialised step, "
              "expected 2 (forward and backward kernel of its one softmax "
-             "layer)")
+             f"layer), grouped products traced as {moe_traced}")
     losses = []
     for _ in range(steps):
         params, loss = step(params, tokens)
@@ -394,7 +404,8 @@ def phase_hybrid_lm(*, vocab: int = 3072, dim: int = 4096, heads: int = 64,
     _require(np.isfinite(losses).all(), f"non-finite hybrid LM loss: {losses}")
     _require(losses[-1] < losses[0], f"hybrid LM loss did not fall: {losses}")
     return {"dim": dim, "seq": seq, "held": list(held), "attn_kernels": traced,
-            "mosaic_calls": mosaic, "losses": [round(l, 4) for l in losses],
+            "moe_grouped": moe_traced, "mosaic_calls": mosaic,
+            "losses": [round(l, 4) for l in losses],
             "assignments": routing["assignments"].tolist(),
             "unheld_frac": [round(float(x), 4)
                             for x in routing["unheld_frac"]]}

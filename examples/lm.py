@@ -355,6 +355,10 @@ def main():
                 log(f"single-device attention paths traced "
                     f"(attn_kernel_total): "
                     f"{attention_paths_traced() or 'none'}")
+                from distlearn_tpu.parallel.ep import grouped_paths_traced
+                log(f"held experts' grouped products traced "
+                    f"(moe_grouped_total): "
+                    f"{grouped_paths_traced() or 'none'}")
             if do_profile and i == prof_stop:
                 jax.block_until_ready(jax.tree_util.tree_leaves(params)[0])
                 timer.reset_window()

@@ -45,6 +45,7 @@ def test_hybrid_lm_phase_toy():
         seq=128, steps=3, lr=0.05, bf16=False)
     assert res["losses"][-1] < res["losses"][0]
     assert res["attn_kernels"] == {"xla": 1} and res["mosaic_calls"] == 0
+    assert set(res["moe_grouped"]) == {"xla"}
     assert np.shape(res["assignments"]) == (4, 2)
 
 

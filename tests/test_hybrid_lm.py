@@ -108,6 +108,147 @@ def test_router_reads_another_array_than_the_experts(act):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
 
 
+#: the kernel path's cases: (chunk rows or None = the whole plan, held
+#: experts, compute dtype).  With tiles of 8 rows the four held experts get
+#: 18 to 34 of the 384 assignments: every expert's rows end inside a tile.
+_KERNEL_CASES = {
+    "the_whole_plan_in_one_chunk": (None, (3, 5, 6, 11), jnp.float32),
+    "a_chunk_boundary_inside_an_expert": (24, (3, 5, 6, 11), jnp.float32),
+    "an_expert_with_no_rows": (64, (3, 5, 16, 11), jnp.float32),
+    "most_of_the_worst_case_unused": (32, (3, 5), jnp.float32),
+    "operands_in_bfloat16": (64, (3, 5, 6, 11), jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("act", ["silu", "relu"])
+@pytest.mark.parametrize("case", list(_KERNEL_CASES))
+def test_kernel_path_is_the_loop(monkeypatch, case, act):
+    """``grouped_glu(impl="gmm")`` — the packed rows through the grouped
+    matmul kernels, in Pallas interpret mode here — against the ``"xla"``
+    loop on one plan: the output and all five gradients."""
+    chunk, held, cd = _KERNEL_CASES[case]
+    monkeypatch.setattr(ep, "GROUP_TILE", 8)
+    x, router, wg, wu, wd = _layer(held=len(held))
+    # a seventeenth expert no token chooses: a constant feature scores it
+    x = x.at[:, 0].set(1.0)
+    router = jnp.concatenate(
+        [router, jnp.zeros((D, 1)).at[0].set(-1e3)], axis=1)
+    plan, slot_w, aux = route_held(router, x, K, held)
+    counts = np.asarray(aux["assignments"])
+    assert (counts[counts > 0] % 8).any()       # rows end inside a tile
+    if case == "an_expert_with_no_rows":
+        assert counts[2] == 0 and counts.sum() > 0
+    if case == "most_of_the_worst_case_unused":
+        assert int(plan[2]) * 8 * 2 < plan[0].shape[0]
+    if chunk is not None:
+        assert int(plan[2]) * 8 > chunk         # a second chunk runs
+    c = jnp.cos(jnp.arange(N * D, dtype=jnp.float32).reshape(N, D))
+
+    def run(impl, chunk):
+        def loss(x, wg, wu, wd, slot_w):
+            y = grouped_glu(x.astype(cd), wg, wu, wd, slot_w, plan, cd, act,
+                            impl, chunk)
+            return jnp.sum(y * c), y
+        return jax.value_and_grad(loss, argnums=tuple(range(5)),
+                                  has_aux=True)(x, wg, wu, wd, slot_w)
+
+    with jax.enable_x64(False):     # as on the chip: Mosaic takes no int64
+        (_, want_y), want = run("xla", None)
+        (_, got_y), got = run("gmm", chunk)
+    tol = dict(rtol=1e-5, atol=1e-5) if cd == jnp.float32 \
+        else dict(rtol=0.05, atol=0.05)
+    np.testing.assert_allclose(got_y, want_y, **tol)
+    for a, b in zip(got, want):
+        assert bool(jnp.isfinite(a).all())
+        np.testing.assert_allclose(a, b, **tol)
+
+
+@pytest.mark.parametrize("act", ["silu", "relu"])
+def test_kernel_path_through_the_layer_with_route_from(monkeypatch, act):
+    """``moe_held_ffn`` on the kernel path (its selection answered for the
+    chip here) with the router reading another array: the loop's output,
+    counters and six gradients."""
+    held = (3, 5, 6, 11)
+    monkeypatch.setattr(ep, "GROUP_TILE", 8)
+    x, router, wg, wu, wd = _layer()
+    src = jax.random.normal(jax.random.PRNGKey(9), x.shape, jnp.float32)
+    c = jnp.sin(jnp.arange(N * D, dtype=jnp.float32).reshape(N, D))
+
+    def run(impl):
+        monkeypatch.setattr(ep, "select_grouped", lambda *a: impl)
+
+        def loss(x, src, router, wg, wu, wd):
+            y, aux = moe_held_ffn(x, router, (wg, wu, wd), held, K, act=act,
+                                  route_from=src)
+            return jnp.sum(y * c), (y, aux)
+        return jax.value_and_grad(loss, argnums=tuple(range(6)),
+                                  has_aux=True)(x, src, router, wg, wu, wd)
+
+    before = ep.grouped_paths_traced()
+    with jax.enable_x64(False):
+        (_, (want_y, want_aux)), want = run("xla")
+        (_, (got_y, got_aux)), got = run("gmm")
+    after = ep.grouped_paths_traced()
+    assert {k: after[k] - before.get(k, 0) for k in after} \
+        == {"xla": 1, "gmm": 1}
+    np.testing.assert_allclose(got_y, want_y, rtol=1e-5, atol=1e-5)
+    for k in want_aux:
+        np.testing.assert_array_equal(got_aux[k], want_aux[k])
+    assert int(got_aux["dropped"]) == 0
+    assert float(jnp.abs(want[1]).max()) > 0        # the source has a gradient
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+_BF16, _F32 = "bfloat16", "float32"
+
+
+@pytest.mark.parametrize("backend,dtype,d,f,rows,path", [
+    # the cell the kernel path was measured on: 16,384 x 6 / 64 rows an expert
+    ("tpu", _BF16, 2560, 768, 1536, "gmm"),
+    ("tpu", _BF16, 4096, 1280, 2048, "gmm"),
+    ("tpu", _BF16, 128, 128, 256, "gmm"),
+    # an expert expected to get less than a tile has its weights used once
+    ("tpu", _BF16, 4096, 1280, 204, "xla"),
+    ("tpu", _BF16, 2560, 768, 255, "xla"),
+    ("tpu", _BF16, 2560, 768, 0, "xla"),
+    # widths the kernel's lanes do not tile; a dtype never measured
+    ("tpu", _BF16, 2560, 760, 1536, "xla"),
+    ("tpu", _BF16, 2500, 768, 1536, "xla"),
+    ("tpu", _BF16, 16, 24, 1536, "xla"),
+    ("tpu", _F32, 2560, 768, 1536, "xla"),
+    ("tpu", "float16", 2560, 768, 1536, "xla"),
+    # the kernel is a TPU kernel
+    ("cpu", _BF16, 2560, 768, 1536, "xla"),
+    ("gpu", _BF16, 2560, 768, 1536, "xla"),
+])
+def test_select_grouped_table(monkeypatch, backend, dtype, d, f, rows, path):
+    for name in ("DISTLEARN_TPU_MOE", "DISTLEARN_TPU_GMM"):
+        monkeypatch.setenv(name, "gmm")         # no environment decides
+    assert ep.select_grouped(backend, dtype, d, f, rows) == path
+    assert ep.select_grouped(backend, jnp.dtype(dtype), d, f, rows) == path
+    assert path in ep.GROUPED_IMPLS
+
+
+def test_moe_grouped_counter_counts_the_resolved_path():
+    """``moe_grouped_total{impl=}`` moves once per traced ``moe_held_ffn``
+    call — not once per run of a jitted program — and on the CPU under
+    ``xla``; a refused call counts nothing."""
+    x, router, wg, wu, wd = _layer()
+    before = ep.grouped_paths_traced()
+    moe_held_ffn(x, router, (wg, wu, wd), (3, 5, 6, 11), K)
+    jitted = jax.jit(lambda x: moe_held_ffn(
+        x, router, (wg, wu, wd), (3, 5, 6, 11), K, act="relu")[0])
+    jitted(x)
+    jitted(x)                                              # traced once
+    with pytest.raises(ValueError):
+        moe_held_ffn(x, router, (wg, wu, wd), (3, 5, 6, 11), K, act="gelu")
+    after = ep.grouped_paths_traced()
+    assert after.get("xla", 0) - before.get("xla", 0) == 2
+    assert after.get("gmm", 0) == before.get("gmm", 0)
+    assert set(after) <= set(ep.GROUPED_IMPLS)
+
+
 def test_the_shares_of_all_holders_add_up_to_the_whole_layer():
     """16 experts over 4 holders of 4: each holder's routed part, with the
     shared expert counted ONCE, is the uncut layer (every expert held by one

@@ -678,6 +678,58 @@ def test_tpu_compiler_takes_the_windowed_kernel_at_its_published_widths(
     assert "16384,16384" not in text
 
 
+@pytest.mark.parametrize("n,d,f,routed,held,top_k,act", [
+    # smallthinker-21b-a3b.train-16k: 16 of 64 ReLU-gated experts, top-6
+    (16384, 2560, 768, 64, 16, 6, "relu"),
+    # solar-open2-250b.train-8k's widths at a load that selects the kernel
+    # path (the cell itself expects 204 rows an expert and keeps the loop)
+    (16384, 4096, 1280, 64, 8, 4, "silu"),
+])
+def test_tpu_compiler_takes_the_grouped_kernels_at_published_widths(
+        v5e_chip, monkeypatch, n, d, f, routed, held, top_k, act):
+    """The held experts' layer on its kernel path (``parallel/ep.py``:
+    packed rows through its grouped-product kernels, the combine through
+    its one-hot product), forward and backward, compiled for the v5e at the
+    widths the pattern cells train: every tile this module chooses from the shapes fits
+    the chip's scoped VMEM (the compiler refuses one that does not), the
+    path is the selection's own, and the program holds the kernels it should
+    — three products and the unpack forward; three products, three weight
+    gradients and the unpack backward (what lies beyond the packed buffer is
+    the loop's, which has no kernel).  Nothing runs; no number of this is a
+    measurement."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    from distlearn_tpu.parallel import ep, sequence
+    monkeypatch.setattr(sequence, "_backend", lambda: "tpu")
+    one = SingleDeviceSharding(v5e_chip)
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    args = (S((n, d), jnp.bfloat16), S((d, routed), jnp.float32),
+            S((held, d, f), jnp.float32), S((held, d, f), jnp.float32),
+            S((held, f, d), jnp.float32))
+
+    def loss(x, router, wg, wu, wd):
+        y, _ = ep.moe_held_ffn(x, router, (wg, wu, wd), tuple(range(held)),
+                               top_k, compute_dtype=jnp.bfloat16, act=act)
+        return jnp.sum(y.astype(jnp.float32))
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    before = ep.grouped_paths_traced()
+    try:
+        with jax.enable_x64(False):
+            text = jax.jit(jax.value_and_grad(
+                loss, argnums=(0, 1, 2, 3, 4))).lower(*args).compile(
+                ).as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    after = ep.grouped_paths_traced()
+    assert after.get("gmm", 0) - before.get("gmm", 0) == 1
+    assert after.get("xla", 0) == before.get("xla", 0)
+    assert text.count('custom_call_target="tpu_custom_call"') == 4 + 7
+
+
 # --- rotary positions -------------------------------------------------------
 
 def test_rotary_is_the_complex_rotation_of_the_half_split_pairs():
