@@ -67,25 +67,42 @@ def attn_qkv(blk: PyTree, x: jax.Array, cd, tp_axis: str | None = None):
     return q, k, v
 
 
-def rotary(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+#: how :func:`rotary` pairs the dimensions of a head
+ROPE_PAIRINGS = ("half", "interleaved")
+
+
+def rotary(x: jax.Array, positions: jax.Array, theta: float,
+           pairing: str = "half") -> jax.Array:
     """Rotary position embedding of ``x`` [B, L, H, D] at ``positions``
-    [L] over the whole head: the pairs are ``(d, d + D/2)`` ("rotate
-    half"), pair ``d`` turned by ``positions * theta ** (-2 d / D)``.  The
-    angles, their sines and the rotation are float32 whatever ``x`` is (at
-    position 16k a bfloat16 angle is off by whole turns); the result is
-    rounded to ``x``'s dtype once.  Applied to q and k before the attention
-    kernel, the scores depend on ``i - j`` alone.  Callers put it inside
-    their ``attn_proj`` scope; the inner name ``rope`` is what a profile
-    finds it by."""
+    [L] over the whole of ``x``'s last axis (a caller that rotates a slice
+    of a head hands that slice): pair ``d`` of the ``D/2`` is turned by
+    ``positions * theta ** (-2 d / D)``.  ``pairing`` (:data:`ROPE_PAIRINGS`)
+    says which two dimensions pair ``d`` is: ``"half"`` — ``(d, d + D/2)``
+    ("rotate half", the default) — or ``"interleaved"`` — ``(2d, 2d + 1)``,
+    neighbours, the result in the same places (a head as complex numbers
+    ``x[2d] + i x[2d+1]``, each times ``exp(i angle_d)``).  The angles,
+    their sines and the rotation are float32 whatever ``x`` is (at position
+    16k a bfloat16 angle is off by whole turns); the result is rounded to
+    ``x``'s dtype once.  Applied to q and k before the attention kernel, the
+    scores depend on ``i - j`` alone.  Callers put it inside their
+    ``attn_proj`` scope; the inner name ``rope`` is what a profile finds it
+    by."""
     half = x.shape[-1] // 2
     if x.shape[-1] != 2 * half:
         raise ValueError(f"rotary needs an even head size, got {x.shape[-1]}")
+    if pairing not in ROPE_PAIRINGS:
+        raise ValueError(f"pairing must be one of {ROPE_PAIRINGS}, got "
+                         f"{pairing!r}")
     with jax.named_scope("rope"):
         freq = jnp.float32(theta) ** (
             jnp.arange(half, dtype=jnp.float32) * (-1.0 / half))
         angle = positions.astype(jnp.float32)[:, None, None] * freq
         cos, sin = jnp.cos(angle), jnp.sin(angle)           # [L, 1, D/2]
         x32 = x.astype(jnp.float32)
+        if pairing == "interleaved":
+            a, b = x32[..., 0::2], x32[..., 1::2]
+            return jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                             axis=-1).reshape(x.shape).astype(x.dtype)
         a, b = x32[..., :half], x32[..., half:]
         return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
                                axis=-1).astype(x.dtype)
@@ -673,6 +690,23 @@ def param_specs(params: PyTree, tp_axis: str | None,
     return jax.tree_util.tree_map_with_path(spec_for, params)
 
 
+def _mtp_loss(state, tokens, seq_axis):
+    """The weighted loss of a multi-token-prediction module whose logits the
+    model's ``state`` carries (see :func:`lm_loss`), or None."""
+    if not isinstance(state, dict) or "mtp_logits" not in state:
+        return None
+    if seq_axis is not None and lax.axis_size(seq_axis) != 1:
+        raise NotImplementedError(
+            "lm_loss: the prediction module's target two ahead would have "
+            f"to cross the shards of axis {seq_axis!r}; keep the sequence "
+            "on one shard")
+    with jax.named_scope("head_loss"):
+        lp = jax.nn.log_softmax(
+            state["mtp_logits"][:, :-2].astype(jnp.float32))
+        nll = -jnp.take_along_axis(lp, tokens[:, 2:, None], -1)[..., 0]
+        return state["mtp_weight"] * nll.mean()
+
+
 def lm_loss(model: Model, params, tokens, seq_axis=None, tp_axis=None,
             ep_axis=None, reduce: bool = True,
             moe_balance_weight: float = 0.0, seq_layout: str = "contig",
@@ -693,7 +727,15 @@ def lm_loss(model: Model, params, tokens, seq_axis=None, tp_axis=None,
     stable MoE training; ignored for dense models.
 
     ``grad_reduce_axis`` goes to a scanned :func:`transformer_lm`'s
-    ``apply`` (see there) and to no other model."""
+    ``apply`` (see there) and to no other model.
+
+    Where the model's state carries the logits of a multi-token-prediction
+    module (``mtp_logits`` [B, L, V] with ``mtp_weight``:
+    :func:`distlearn_tpu.models.hybrid.hybrid_lm` ``mtp_depth=1``, in
+    training), the loss gains ``mtp_weight`` times the mean cross-entropy of
+    ``mtp_logits[:, :-2]`` against ``tokens[:, 2:]`` — position ``i``'s
+    module logits predict the token TWO ahead — under the same scope
+    ``head_loss``.  Such a model keeps the sequence on one shard."""
     reducing = ({} if grad_reduce_axis is None
                 else {"grad_reduce_axis": grad_reduce_axis})
     logits, st = model.apply(params, {}, tokens, train=True,
@@ -703,12 +745,15 @@ def lm_loss(model: Model, params, tokens, seq_axis=None, tp_axis=None,
     bal = (moe_balance_weight * st["moe_balance_loss"]
            if moe_balance_weight and isinstance(st, dict)
            and "moe_balance_loss" in st else None)
+    mtp = _mtp_loss(st, tokens, seq_axis)
     if seq_axis is None:
         targets = tokens[:, 1:]
         with jax.named_scope("head_loss"):
             lp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32))
             nll = -jnp.take_along_axis(lp, targets[..., None], -1)[..., 0]
             loss = nll.mean()
+        if mtp is not None:
+            loss = loss + mtp
         return loss + bal if bal is not None else loss
     n = lax.axis_size(seq_axis)
     my = lax.axis_index(seq_axis)
@@ -748,6 +793,8 @@ def lm_loss(model: Model, params, tokens, seq_axis=None, tp_axis=None,
         # GLOBAL token count (a constant — no gradient flows through it)
         count = lax.psum(jnp.sum(w) * tokens.shape[0], seq_axis)
         local = jnp.sum(nll * w[None, :]) / jnp.maximum(count, 1.0)
+    if mtp is not None:
+        local = local + mtp
     if bal is not None:
         # each shard routes its own tokens: 1/n of the balance term per
         # shard makes the psum'd total the cross-shard mean

@@ -211,7 +211,11 @@ def moe_ffn(expert_fn: Callable, expert_params: PyTree, router_w: jax.Array,
 #     s = softmax(x W_r)  in R^E;   I = top-k(s);   w_i = s_i / sum_{j in I} s_j
 #     y = sum_{i in I and held}  w_i  GLU_i(x)        (SwiGLU or ReGLU)
 #
-# The router may read another array than the experts do (``route_from``).
+# The router may read another array than the experts do (``route_from``),
+# and may score by sigmoid, choose through a per-expert correction bias and
+# scale its weights (``route_held``'s ``score``, ``select_bias``, ``scale``):
+#
+#     s = sigmoid(x W_r);   I = top-k(s + b);   w_i = scale s_i / sum_{j in I} s_j
 #
 # What the experts held elsewhere would add is left out; summed over the
 # shares of every holder it is the whole layer (tests/test_hybrid_lm.py).
@@ -240,14 +244,38 @@ def moe_ffn(expert_fn: Callable, expert_params: PyTree, router_w: jax.Array,
 GROUP_TILE = 256
 
 
-def route_held(router_w: jax.Array, x: jax.Array, top_k: int, held):
+#: how a router turns its logits into the scores of :func:`route_held`
+ROUTER_SCORES = ("softmax", "sigmoid")
+
+
+def _router_counter():
+    return obs.counter(
+        "moe_router_total",
+        "route_held calls traced, by how the router scores its experts",
+        labels=("score",))
+
+
+def route_held(router_w: jax.Array, x: jax.Array, top_k: int, held,
+               score: str = "softmax", select_bias: jax.Array | None = None,
+               scale: float = 1.0):
     """Route ``x`` [N, D] (whatever array the router reads: the experts'
     input or another of as many rows) over all ``E`` outputs of ``router_w``
     [D, E] and group the assignments that fall on the ``held`` experts by
     expert.
 
-    Scores, softmax and top-k are float32 at full matmul precision whatever
-    the compute dtype: the choice of experts is discrete, so a rounded score
+    ``score`` (:data:`ROUTER_SCORES`): the scores ``s`` are the softmax of
+    the logits over the ``E`` experts, or each logit's sigmoid.  The top-k
+    are CHOSEN by ``s + select_bias`` (``select_bias`` [E]: a per-expert
+    correction that steers the choice and nothing else; None: by ``s``) and
+    WEIGHED by the un-biased ``s`` renormalised over the chosen, times
+    ``scale``: ``w_e = scale * s_e / sum over chosen of s`` (the sum of
+    sigmoids with ``1e-20`` added, as that router's published code does).
+    The weights are differentiable through ``s``; the bias enters the discrete
+    choice alone, so its gradient is exactly zero.  The defaults are what
+    the function always did.
+
+    Scores and top-k are float32 at full matmul precision whatever the
+    compute dtype: the choice of experts is discrete, so a rounded score
     does not give a slightly different output but a different expert.
 
     Returns ``(plan, slot_w, aux)``.  ``plan = (rows, tile_expert,
@@ -272,11 +300,26 @@ def route_held(router_w: jax.Array, x: jax.Array, top_k: int, held):
             or min(held) < 0 or max(held) >= E:
         raise ValueError(f"top_k={top_k} and held={held} do not fit a "
                          f"router of {E} experts")
+    if score not in ROUTER_SCORES:
+        raise ValueError(f"score must be one of {ROUTER_SCORES}, got "
+                         f"{score!r}")
+    _router_counter().labels(score=score).inc()
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
-    scores = jax.nn.softmax(logits, axis=-1)
-    topv, topi = lax.top_k(scores, top_k)                   # [N, k]
-    weights = topv / jnp.sum(topv, axis=-1, keepdims=True)
+    scores = (jax.nn.softmax(logits, axis=-1) if score == "softmax"
+              else jax.nn.sigmoid(logits))
+    if select_bias is None:
+        topv, topi = lax.top_k(scores, top_k)               # [N, k]
+    else:
+        _, topi = lax.top_k(scores + lax.stop_gradient(
+            select_bias.astype(jnp.float32)), top_k)
+        topv = jnp.take_along_axis(scores, topi, axis=-1)
+    total = jnp.sum(topv, axis=-1, keepdims=True)
+    if score == "sigmoid":      # every chosen sigmoid may underflow; the
+        total = total + 1e-20   # top of a softmax never does
+    weights = topv / total
+    if scale != 1.0:
+        weights = scale * weights
     lut = jnp.full((E,), -1, jnp.int32).at[jnp.asarray(held)].set(
         jnp.arange(G, dtype=jnp.int32))
     local = lut[topi].reshape(N * top_k)                    # index into held
@@ -769,7 +812,8 @@ grouped_glu.defvjp(_gg_fwd, _gg_bwd)
 def moe_held_ffn(x: jax.Array, router_w: jax.Array, experts, held,
                  top_k: int, *, compute_dtype=None,
                  ep_axis: str | None = None, route_from: jax.Array | None = None,
-                 act: str = "silu"):
+                 act: str = "silu", score: str = "softmax",
+                 select_bias: jax.Array | None = None, scale: float = 1.0):
     """The held experts' part of a routed gated-linear-unit layer (see the
     section comment above): ``x`` [N, D], ``router_w`` [D, E], ``experts =
     (wg, wu, wd)`` stacked over ``len(held)``, their gate activation ``act``
@@ -779,7 +823,9 @@ def moe_held_ffn(x: jax.Array, router_w: jax.Array, experts, held,
     ``route_from`` [N, D]: the array the ROUTER reads where that is not the
     one the experts read (a layer that scores its experts on its input,
     before attention, and feeds them the post-attention norm); None routes
-    from ``x``.
+    from ``x``.  ``score``, ``select_bias``, ``scale``: :func:`route_held`'s
+    (how the router scores, the correction bias of its choice, the factor
+    on its weights).
 
     ``ep_axis``: the mesh axis over which other devices hold the other
     experts.  With it the same layer is the expert-parallel one — every
@@ -805,7 +851,8 @@ def moe_held_ffn(x: jax.Array, router_w: jax.Array, experts, held,
         impl = "xla"        # Mosaic takes no 64-bit counter (local_attention)
     _grouped_counter().labels(impl=impl).inc()
     plan, slot_w, aux = route_held(
-        router_w, x if route_from is None else route_from, top_k, held)
+        router_w, x if route_from is None else route_from, top_k, held,
+        score=score, select_bias=select_bias, scale=scale)
     chunk = _gmm_chunk(expected, G) if impl == "gmm" else None
     y = grouped_glu(x.astype(cd), *experts, slot_w, plan, cd, act, impl,
                     chunk)

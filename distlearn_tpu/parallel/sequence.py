@@ -308,11 +308,39 @@ def _splash_block(L: int) -> int | None:
     return next((b for b in _SPLASH_BLOCKS if L % b == 0), None)
 
 
+#: bytes the kernel's fused backward call may write as its UNREDUCED dq: one
+#: copy of q for every K/V block of that call (``L / block_kv_dkv`` of them,
+#: summed by XLA afterwards).  A quarter of a v5e's memory: at [1, 16384, 32,
+#: 192] the 32 copies of 512-wide blocks are 6.4 GB, which no step has room
+#: for beside its parameters (compiled for the chip: 16.10 of 15.75 GB,
+#: PERF.md section 6, PR 36); every call the package made before fits it at
+#: the block it had (the largest, [1, 16384, 28, 128]: 3.76 GB).
+_DQ_UNREDUCED_MAX = 4 * 2 ** 30
+#: the K/V blocks of that call to widen to, widest first: 4096 is refused by
+#: Mosaic's scoped VMEM at head size 192, and of the rest the wider was the
+#: faster at every width measured on the v5e at [1, 16384, 32, 192 / 128]
+#: (forward + backward 110.1 / 99.5 / 94.7 ms at 512 / 1024 / 2048: fewer
+#: copies to write and to sum; PERF.md section 6, PR 36)
+_DKV_BLOCKS = (2048, 1024)
+
+
+def _dkv_block(block: int, q_bytes: int, L: int) -> int:
+    """The K/V block of the fused backward call: ``block`` where the
+    unreduced dq it makes (``L / block`` copies of q, ``q_bytes`` each) is
+    within :data:`_DQ_UNREDUCED_MAX` — every call but the widest — and else
+    the widest of :data:`_DKV_BLOCKS` that tiles ``L``."""
+    if L // block * q_bytes <= _DQ_UNREDUCED_MAX:
+        return block
+    return next((b for b in _DKV_BLOCKS if b > block and L % b == 0), block)
+
+
 def select_attention(causal: bool, L: int, D: int, dtype,
                      backend: str) -> str:
     """The single-device attention path of a call, decided from what the
-    call itself shows — causality, local length, head size, dtype, backend
-    — and from nothing else (no environment variable, no model name).
+    call itself shows — causality, local length, head size (``D``: that of
+    q and k, the one the scores are taken over; v's may differ and decides
+    nothing), dtype, backend — and from nothing else (no environment
+    variable, no model name).
 
     ``"splash"`` (causal attention blockwise, masked blocks skipped, no
     ``[B, H, L, L]`` array in HBM) where it was measured to win: on the TPU,
@@ -343,12 +371,23 @@ def _window_counter():
         labels=("impl",))
 
 
-def attention_paths_traced(windowed: bool = False) -> dict[str, int]:
+def _latent_counter():
+    return obs.counter(
+        "attn_latent_total",
+        "local_attention calls traced whose v is narrower than its q (the "
+        "scores over one head size, the values of another), by resolved "
+        "implementation", labels=("impl",))
+
+
+def attention_paths_traced(windowed: bool = False,
+                           latent: bool = False) -> dict[str, int]:
     """``{impl: local_attention calls traced so far}`` in this process (the
     ``attn_kernel_total`` counter; empty with ``DISTLEARN_OBS=0``).
     ``windowed``: of those, the calls whose mask was a band
-    (``attn_window_total``)."""
-    family = _window_counter() if windowed else _attn_counter()
+    (``attn_window_total``); ``latent``: those whose v was narrower than
+    their q (``attn_latent_total``)."""
+    family = (_latent_counter() if latent
+              else _window_counter() if windowed else _attn_counter())
     if family is obs.NULL:
         return {}
     return {s["labels"]["impl"]: s["value"] for s in family.sample()}
@@ -371,7 +410,8 @@ def _splash_causal_attention(q, k, v, block: int, interpret: bool,
     j <= i``) and the blocks below the band are never visited either.  Its
     output and log-sum-exp carry the name :data:`ATTN_RESIDUALS`.  q/k/v:
     ``[B, L, H, D]``; the kernel wants ``[H, L, D]`` per batch row and an
-    already scaled q."""
+    already scaled q.  v's head size may differ from q's and k's (the
+    kernel's ``head_dim_v``): the output has v's."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm)
     B, L, H, D = q.shape
@@ -382,13 +422,15 @@ def _splash_causal_attention(q, k, v, block: int, interpret: bool,
     # the minor dimension (``[.., L, 64]`` is stored as ``[.., L, 128]``);
     # sequence-minor q/k/v are not.  Measured at D = 64: 22 ms of a 433 ms
     # step, in the projections that write them (PERF.md section 6, PR 27).
-    layout = (sk.QKVLayout.HEAD_DIM_MINOR if D % 128 == 0
-              else sk.QKVLayout.SEQ_MINOR)
+    # Each operand by its OWN minor size: q and k of 192 beside a v of 128
+    # are sequence-minor beside head-minor (PERF.md section 6, PR 36).
+    layout = lambda a: (sk.QKVLayout.HEAD_DIM_MINOR         # noqa: E731
+                        if a.shape[-1] % 128 == 0 else sk.QKVLayout.SEQ_MINOR)
     sizes = sk.BlockSizes(
         block_q=block, block_kv=block, block_kv_compute=block,
-        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
-        use_fused_bwd_kernel=True,
-        q_layout=layout, k_layout=layout, v_layout=layout)
+        block_q_dkv=block, block_kv_dkv=_dkv_block(block, q.nbytes, L),
+        block_kv_dkv_compute=block, use_fused_bwd_kernel=True,
+        q_layout=layout(q), k_layout=layout(k), v_layout=layout(v))
     heads_first = lambda a: a.transpose(0, 2, 1, 3)   # noqa: E731
     # scaled in float32, rounded once (exact at D = 64: the scale is 1/8)
     qs = (q.astype(jnp.float32) * (1.0 / (D ** 0.5))).astype(q.dtype)
@@ -411,7 +453,7 @@ def _splash_causal_attention(q, k, v, block: int, interpret: bool,
         residual_checkpoint_name=ATTN_RESIDUALS, interpret=interpret)
     qg = heads_first(qs).reshape(B, Hkv, group, L, D)
     out = jax.vmap(jax.vmap(kernel))(qg, heads_first(k), heads_first(v))
-    return heads_first(out.reshape(B, H, L, D)).astype(q.dtype)
+    return heads_first(out.reshape(B, H, L, v.shape[-1])).astype(q.dtype)
 
 
 def local_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -420,10 +462,14 @@ def local_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     window: int | None = None) -> jax.Array:
     """Single-device attention (same layout as the sharded variants), for
     non-sharded runs and as the per-shard kernel of
-    :func:`alltoall_attention`.  q: [B, L, H, D]; k/v: [B, L, Hkv, D] with
-    ``Hkv`` dividing ``H`` (grouped queries: query head ``i`` attends K/V
-    head ``i // (H / Hkv)``; ``Hkv == H`` is ordinary multi-head attention
-    and runs exactly the code it always ran).
+    :func:`alltoall_attention`.  q: [B, L, H, D]; k: [B, L, Hkv, D]; v:
+    [B, L, Hkv, Dv] with ``Hkv`` dividing ``H`` (grouped queries: query head
+    ``i`` attends K/V head ``i // (H / Hkv)``; ``Hkv == H`` is ordinary
+    multi-head attention and runs exactly the code it always ran).  q and k
+    share the head size the scores are taken over — the scale is ``1 /
+    sqrt(D)`` — and v may have ANOTHER (latent attention: 192-wide scores
+    over 128-wide values); the result is [B, L, H, Dv].  Equal sizes run
+    the code and the program they always ran.
 
     ``window`` (causal attention only) cuts the mask to a band: position
     ``i`` attends ``j`` with ``j <= i`` and ``i - j < window``.  A window
@@ -445,9 +491,14 @@ def local_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     The resolved path is counted in ``attn_kernel_total{impl=}`` (``obs``):
     once per traced call, not per step — a jitted program is traced once;
-    a call whose mask is a band also in ``attn_window_total{impl=}``."""
+    a call whose mask is a band also in ``attn_window_total{impl=}``, one
+    whose v is narrower than its q also in ``attn_latent_total{impl=}``."""
     B, L, H, D = q.shape
     Hkv = k.shape[2]
+    if k.shape[-1] != D:
+        raise ValueError(f"q and k must share the head size the scores are "
+                         f"taken over, got {D} and {k.shape[-1]} (v's, "
+                         f"{v.shape[-1]}, may differ)")
     if window is not None:
         if not causal or window < 1:
             raise ValueError(f"window={window} needs causal attention and "
@@ -457,7 +508,8 @@ def local_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if H % Hkv or v.shape[2] != Hkv:
         raise ValueError(f"{H} query heads cannot share {Hkv} K / "
                          f"{v.shape[2]} V heads: the K/V head count must "
-                         "divide the query head count")
+                         "divide the query head count (head sizes given: q "
+                         f"{D}, k {k.shape[-1]}, v {v.shape[-1]})")
     backend = _backend()
     # with 64-bit types on, the kernel's loop counters trace as int64, which
     # Mosaic refuses (the interpreter, off the TPU, takes them)
@@ -479,6 +531,8 @@ def local_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     _attn_counter().labels(impl=impl).inc()
     if window is not None:
         _window_counter().labels(impl=impl).inc()
+    if v.shape[-1] < D:
+        _latent_counter().labels(impl=impl).inc()
     if impl == "splash":
         return _splash_causal_attention(q, k, v, block,
                                         interpret=backend != "tpu",

@@ -155,7 +155,10 @@ def build_lm_step(model: Model, mesh: Mesh, params_template, lr: float,
     loop then keeps the leaves made outside it (embedding, positions, last
     norm).  The gauges ``train.grad_reduce.pipelined_bytes{step=lm}`` and
     ``train.grad_reduce.tail_bytes{step=lm}`` say, from build time, how many
-    bytes a step one chip sums inside the loop and after it.
+    bytes a step one chip sums inside the loop and after it; the gauge
+    ``train.mtp.loss_weight{step=lm}`` the weight of a multi-token-prediction
+    module's loss in what the step differentiates (``models/hybrid.py``; 0
+    for a model without one).
 
     ``ep_axis`` (MoE models): the mesh axis the expert-stacked leaves are
     sharded over — normally ``data_axis`` itself (EP group == DP group,
@@ -216,6 +219,11 @@ def build_lm_step(model: Model, mesh: Mesh, params_template, lr: float,
         "the psum after the backward pass, a step",
         labels=("step",)).labels(step="lm").set(
             sum(jax.tree_util.tree_leaves(tail)))
+    obs.gauge(
+        "train.mtp.loss_weight", "weight of the multi-token-prediction "
+        "module's loss in the loss the step differentiates (0: the model "
+        "has no module)", labels=("step",)).labels(step="lm").set(
+            getattr(model.apply, "mtp_weight", 0.0))
 
     def step(params, tokens):
         local_loss, grads = lm_local_grads(

@@ -188,3 +188,122 @@ def test_moe_rejects_wrong_router_shape():
     x_all = jnp.zeros((E, N, D), jnp.float32)
     with pytest.raises(ValueError, match="router_w must be"):
         _moe(mesh, capacity_factor=float(E))(bad, x_all)
+
+
+# --- the held layer's router: softmax or sigmoid, chosen through a bias -----
+
+def _routing(seed=0, n=40, d=8, e=12):
+    rng = np.random.RandomState(seed)
+    return (jnp.asarray(rng.randn(d, e).astype(np.float32)),
+            jnp.asarray(rng.randn(n, d).astype(np.float32)),
+            jnp.asarray(0.3 * rng.randn(e).astype(np.float32)))
+
+
+def _slots(plan, slot_w, held, n):
+    """``{(token, expert): weight}`` of the plan's live slots."""
+    rows, tile_expert, _ = (np.asarray(a) for a in plan)
+    tile = rows.shape[0] // tile_expert.shape[0]
+    w = np.asarray(slot_w)
+    return {(int(t), held[tile_expert[p // tile]]): float(w[p])
+            for p, t in enumerate(rows) if t < n}
+
+
+@pytest.mark.parametrize("scale", [1.0, 2.5])
+def test_sigmoid_router_chooses_by_the_biased_score_and_weighs_by_the_bare(
+        scale):
+    """Written out in numpy: ``s = sigmoid(x W)``; the top-k of ``s + b``
+    are chosen; each weighs ``scale * s_e / (sum over the chosen of s +
+    1e-20)`` — the bias is in the choice and NOT in the weight.  The bias
+    here changes some of the choices and not all."""
+    from distlearn_tpu.parallel.ep import route_held
+    router, x, bias = _routing()
+    held, k = tuple(range(12)), 3
+    plan, slot_w, aux = route_held(router, x, k, held, score="sigmoid",
+                                   select_bias=bias, scale=scale)
+    s = 1.0 / (1.0 + np.exp(-(np.asarray(x, np.float64)
+                              @ np.asarray(router, np.float64))))
+    chosen = np.argsort(-(s + np.asarray(bias, np.float64)), axis=1)[:, :k]
+    want = {}
+    for t, es in enumerate(chosen):
+        for e in es:
+            want[(t, int(e))] = scale * s[t, e] / (s[t, es].sum() + 1e-20)
+    got = _slots(plan, slot_w, held, x.shape[0])
+    assert set(got) == set(want) and int(aux["dropped"]) == 0
+    for key in want:
+        assert got[key] == pytest.approx(want[key], rel=1e-5)
+    unbiased = np.argsort(-s, axis=1)[:, :k]
+    changed = sum(set(a) != set(b) for a, b in zip(chosen, unbiased))
+    assert 0 < changed < x.shape[0]
+
+
+def test_correction_bias_gets_no_gradient_and_the_scores_do():
+    from distlearn_tpu.parallel.ep import route_held
+    router, x, bias = _routing(1)
+
+    def total(router, bias):
+        _, slot_w, _ = route_held(router, x, 3, (0, 4, 7, 9),
+                                  score="sigmoid", select_bias=bias,
+                                  scale=2.5)
+        return jnp.sum(slot_w * jnp.arange(slot_w.shape[0]))
+    d_router, d_bias = jax.grad(total, argnums=(0, 1))(router, bias)
+    assert float(jnp.abs(d_bias).max()) == 0.0
+    assert float(jnp.abs(d_router).max()) > 0.0
+
+
+@pytest.mark.parametrize("score", ["softmax", "sigmoid"])
+def test_router_defaults_are_what_they_were_and_each_scoring_is_counted(
+        score):
+    """No bias, scale 1: the top-k of the scores, renormalised over the
+    chosen — for the softmax exactly the weights the function gave before it
+    took a ``score``; a traced call counts in ``moe_router_total{score=}``."""
+    from distlearn_tpu import obs
+    from distlearn_tpu.parallel.ep import route_held
+    router, x, _ = _routing(2)
+    family = obs.counter("moe_router_total", labels=("score",))
+    count = lambda: sum(s["value"] for s in family.sample()  # noqa: E731
+                        if s["labels"] == {"score": score})
+    before = family is not obs.NULL and count()
+    plan, slot_w, _ = route_held(router, x, 2, tuple(range(12)), score=score)
+    if family is not obs.NULL:
+        assert count() == before + 1
+    logits = np.asarray(x, np.float64) @ np.asarray(router, np.float64)
+    s = np.exp(logits - logits.max(-1, keepdims=True))
+    s = s / s.sum(-1, keepdims=True) if score == "softmax" \
+        else 1.0 / (1.0 + np.exp(-logits))
+    top = np.argsort(-s, axis=1)[:, :2]
+    got = _slots(plan, slot_w, tuple(range(12)), x.shape[0])
+    for t, es in enumerate(top):
+        for e in es:
+            assert got[(t, int(e))] == pytest.approx(
+                s[t, e] / s[t, es].sum(), rel=1e-5)
+    if score == "softmax":
+        same, same_w, _ = route_held(router, x, 2, tuple(range(12)))
+        np.testing.assert_array_equal(np.asarray(same_w), np.asarray(slot_w))
+
+
+def test_sigmoid_layer_is_the_dense_sum_over_the_chosen_and_held():
+    """``moe_held_ffn`` with the sigmoid router against every held expert
+    applied to every token and weighted by hand."""
+    from distlearn_tpu.parallel.ep import moe_held_ffn
+    rng = np.random.RandomState(3)
+    router, x, bias = _routing(3, n=24, d=8, e=12)
+    held, k, f = (1, 4, 10), 3, 6
+    wg, wu = (jnp.asarray(rng.randn(3, 8, f).astype(np.float32) * 0.4)
+              for _ in range(2))
+    wd = jnp.asarray(rng.randn(3, f, 8).astype(np.float32) * 0.4)
+    y, aux = moe_held_ffn(x, router, (wg, wu, wd), held, k, score="sigmoid",
+                          select_bias=bias, scale=2.5)
+    s = jax.nn.sigmoid(x @ router)
+    _, chosen = jax.lax.top_k(s + bias, k)
+    picked = jnp.take_along_axis(s, chosen, -1)
+    w = 2.5 * picked / picked.sum(-1, keepdims=True)
+    want = jnp.zeros_like(x)
+    for g, e in enumerate(held):
+        w_e = jnp.sum(jnp.where(chosen == e, w, 0.0), -1, keepdims=True)
+        want = want + w_e * ((jax.nn.silu(x @ wg[g]) * (x @ wu[g])) @ wd[g])
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=2e-5,
+                               atol=2e-6)
+    assert int(aux["assignments"].sum()) == int(
+        jnp.isin(chosen, jnp.asarray(held)).sum())
+    with pytest.raises(ValueError, match="score must be one of"):
+        moe_held_ffn(x, router, (wg, wu, wd), held, k, score="tanh")

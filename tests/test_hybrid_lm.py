@@ -8,6 +8,8 @@ pattern the constructor builds — ungated softmax layers, full-causal without
 positions among rotary sliding-window ones, ReLU-gated experts with no shared
 expert, the router reading the layer's input."""
 
+import zlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -349,7 +351,23 @@ def _swa_toy(**kw):
     return hybrid_lm(**kw)
 
 
-_TOYS = {"gqa+kda": _toy, "full+window": _swa_toy}
+def _mla_toy(**kw):
+    """The third pattern: latent attention (12-wide scores = 8 un-rotated +
+    4 rotated by neighbouring pairs, 8-wide values, low-rank q and K/V
+    paths), layer 0 dense, then mixtures scored by sigmoid beside a shared
+    expert, and the prediction module after the stack."""
+    kw = dict(dict(vocab=97, dim=32, layer_types=("mla",) * 3, heads=4,
+                   kv_heads=4, head_dim=12, q_lora_rank=24, kv_lora_rank=16,
+                   qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
+                   rope_theta=3.2e7, rope_pairing="interleaved",
+                   dense_layers=1, dense_width=40, n_routed_experts=16,
+                   held_experts=(1, 5, 6, 11), experts_per_tok=3,
+                   expert_width=24, router_score="sigmoid", routed_scale=2.5,
+                   mtp_depth=1, mtp_weight=0.3, eps=1e-6, max_len=64), **kw)
+    return hybrid_lm(**kw)
+
+
+_TOYS = {"gqa+kda": _toy, "full+window": _swa_toy, "mla": _mla_toy}
 
 
 def _mesh(shape=(2, 1, 1)):
@@ -454,7 +472,7 @@ def test_rematerialised_layers_are_bitwise_the_bare_checkpoints(
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@pytest.mark.parametrize("kind", ["gqa", "kda", "full", "window"])
+@pytest.mark.parametrize("kind", ["gqa", "kda", "full", "window", "mla"])
 def test_a_layer_with_no_kernel_keeps_its_input_alone(monkeypatch, kind):
     """On the full-square path, and in a linear-attention layer, nothing
     carries the kernel's name: the policy finds none, and the rematerialised
@@ -468,7 +486,8 @@ def test_a_layer_with_no_kernel_keeps_its_input_alone(monkeypatch, kind):
 
     def lowered():
         model = _toy(layer_types=(kind,), remat="full", window=16,
-                     rope_theta=1e4)
+                     rope_theta=1e4, q_lora_rank=24, kv_lora_rank=16,
+                     qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8)
         params = jax.eval_shape(lambda k: model.init(k)[0],
                                 jax.random.PRNGKey(0))
         return jax.jit(jax.grad(
@@ -592,3 +611,181 @@ def test_constructor_refuses_what_it_cannot_build():
     params, _ = model.init(jax.random.PRNGKey(0))
     with pytest.raises(NotImplementedError, match="exchange"):
         model.apply(params, {}, _tokens(mesh, b=1), ep_axis="data")
+
+
+# ------------------ latent attention, a dense layer, a prediction module --
+
+def _biased(params, key=7):
+    """``params`` with every router's correction bias drawn N(0, 0.1)."""
+    def one(path, leaf):
+        if getattr(path[-1], "key", None) != "router_bias":
+            return leaf
+        return 0.1 * jax.random.normal(
+            jax.random.fold_in(jax.random.PRNGKey(key),
+                               zlib.crc32(str(path).encode())),
+            leaf.shape, leaf.dtype)
+    return jax.tree_util.tree_map_with_path(one, params)
+
+
+def test_mla_model_has_the_leaves_its_equations_name():
+    """The low-rank paths with the norms at their waists and the shared
+    rotated key (one 4-wide head in ``wkv_a``'s last columns); the dense
+    layer has an MLP and NO router, bias or expert leaf; a sigmoid router
+    has its correction bias; the module its own norms, projection and
+    block, and no embedding or head of its own."""
+    params, _ = _mla_toy().init(jax.random.PRNGKey(0))
+    shapes = jax.tree_util.tree_map(lambda a: a.shape, params)
+    mla = {"wq_a": (32, 24), "q_norm": {"scale": (24,)},
+           "wq_b": (24, 4, 12), "wkv_a": (32, 16 + 4),
+           "kv_norm": {"scale": (16,)}, "wkv_b": (16, 4, 8 + 8),
+           "wo": (4, 8, 32), "ln1": {"scale": (32,)}, "ln2": {"scale": (32,)}}
+    mixture = dict(mla, router=(32, 16), router_bias=(16,),
+                   ws_gate=(32, 24), ws_up=(32, 24), ws_down=(24, 32),
+                   we_gate=(4, 32, 24), we_up=(4, 32, 24),
+                   we_down=(4, 24, 32))
+    assert shapes["layer0"] == dict(mla, w_gate=(32, 40), w_up=(32, 40),
+                                    w_down=(40, 32))
+    assert shapes["layer1"] == shapes["layer2"] == mixture
+    assert shapes["mtp"] == {"enorm": {"scale": (32,)},
+                             "hnorm": {"scale": (32,)}, "eh_proj": (64, 32),
+                             "block": mixture, "norm": {"scale": (32,)}}
+    assert set(shapes) == {"embed", "head", "out_norm", "layer0", "layer1",
+                           "layer2", "mtp"}
+    assert float(jnp.abs(params["layer1"]["router_bias"]).max()) == 0.0
+
+
+def test_prediction_module_runs_in_training_alone_and_its_loss_is_weighted():
+    """``train=False``: the main logits, a counter row a MIXTURE layer, no
+    module.  ``train=True``: the module's logits and weight ride the state
+    and its block is one more row; ``lm_loss`` is the main cross-entropy plus
+    0.3 x the mean cross-entropy of the module's logits against the token
+    TWO ahead — with and without a (size-1) sequence axis."""
+    model = _mla_toy()
+    params = _biased(model.init(jax.random.PRNGKey(0))[0])
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0, 97,
+                                jnp.int32)
+    logits, st = model.apply(params, {}, tokens, train=False)
+    assert "mtp_logits" not in st and st["moe_assignments"].shape == (2, 4)
+    trained, st = model.apply(params, {}, tokens, train=True)
+    np.testing.assert_array_equal(trained, logits)
+    assert st["mtp_logits"].shape == (2, 64, 97) and st["mtp_weight"] == 0.3
+    assert st["moe_assignments"].shape == (3, 4)
+    assert int(st["moe_dropped"].sum()) == 0
+    assert model.apply.mtp_weight == 0.3 and _toy().apply.mtp_weight == 0.0
+
+    def ce(lg, ahead):
+        lp = jax.nn.log_softmax(lg[:, :-ahead])
+        return -jnp.take_along_axis(lp, tokens[:, ahead:, None], -1).mean()
+    want = ce(logits, 1) + 0.3 * ce(st["mtp_logits"], 2)
+    assert float(lm_loss(model, params, tokens)) == pytest.approx(
+        float(want), rel=1e-6)
+    mesh = _mesh((1, 1, 1))
+    sharded = jax.jit(jax.shard_map(
+        lambda p, t: lm_loss(model, p, t, seq_axis="seq"), mesh=mesh,
+        in_specs=(P(), P("data", "seq")), out_specs=P(), check_vma=False))
+    assert float(sharded(params, tokens)) == pytest.approx(float(want),
+                                                           rel=1e-6)
+    # the module's loss reaches the module's own leaves and the embedding
+    g = jax.grad(lambda p: lm_loss(model, p, tokens))(params)
+    assert float(jnp.abs(g["mtp"]["eh_proj"]).max()) > 0
+    assert float(jnp.abs(g["mtp"]["block"]["wq_a"]).max()) > 0
+    for blk in (g["layer1"], g["layer2"], g["mtp"]["block"]):
+        assert float(jnp.abs(blk["router_bias"]).max()) == 0.0
+        assert float(jnp.abs(blk["router"]).max()) > 0
+
+
+def test_mla_step_sets_the_modules_gauge_and_counts_its_router_and_calls():
+    from distlearn_tpu.parallel.sequence import attention_paths_traced
+    mesh, model = _mesh((1, 1, 1)), _mla_toy()
+    params, _ = model.init(jax.random.PRNGKey(0))
+    routers = obs.counter("moe_router_total", labels=("score",))
+    count = lambda fam, **lb: sum(                          # noqa: E731
+        s["value"] for s in fam.sample() if s["labels"] == lb)
+    before = (attention_paths_traced(latent=True).get("xla", 0),
+              routers is not obs.NULL and count(routers, score="sigmoid"))
+    step = build_lm_step(model, mesh, params, lr=0.05, donate=False)
+    step(params, _tokens(mesh, b=1))
+    gauge = obs.gauge("train.mtp.loss_weight", labels=("step",))
+    if gauge is not obs.NULL:
+        assert [s["value"] for s in gauge.sample()
+                if s["labels"] == {"step": "lm"}] == [0.3]
+        # three layers and the module's block; the dense layer has no router
+        assert attention_paths_traced(latent=True)["xla"] - before[0] >= 4
+        assert count(routers, score="sigmoid") - before[1] >= 3
+        build_lm_step(_toy(), mesh, _toy().init(jax.random.PRNGKey(0))[0],
+                      lr=0.05)
+        assert [s["value"] for s in gauge.sample()
+                if s["labels"] == {"step": "lm"}] == [0.0]
+
+
+def test_mla_layer_rotates_only_the_last_part_of_a_head_and_shares_one_key():
+    """Against the equations written out in numpy: the first 8 of a head's
+    12 are un-rotated, the last 4 rotated by neighbouring pairs; the rotated
+    key is one head for all four; the scale is 1 / sqrt(12); v is 8 wide."""
+    from distlearn_tpu.models.hybrid import mla_apply
+    model = _mla_toy()
+    blk = model.init(jax.random.PRNGKey(3))[0]["layer0"]
+    x = jax.random.normal(jax.random.PRNGKey(4), (1, 16, 32), jnp.float32)
+    got = mla_apply(blk, x, jnp.float32, 1e-6, 3.2e7, 8, "interleaved")
+    b = jax.tree_util.tree_map(lambda a: np.asarray(a, np.float64), blk)
+    x64 = np.asarray(x, np.float64)[0]
+    norm = lambda a, g: a / np.sqrt((a * a).mean(-1, keepdims=True)  # noqa: E731
+                                    + 1e-6) * g
+
+    def rope(u):                                            # [L, H, 4]
+        z = (u[..., 0::2] + 1j * u[..., 1::2]) * np.exp(
+            1j * np.arange(16)[:, None, None]
+            * 3.2e7 ** (-np.arange(2) / 2.0))
+        out = np.empty_like(u)
+        out[..., 0::2], out[..., 1::2] = z.real, z.imag
+        return out
+    u = norm(x64, b["ln1"]["scale"])
+    q = np.einsum("lr,rhd->lhd", norm(u @ b["wq_a"], b["q_norm"]["scale"]),
+                  b["wq_b"])
+    ckv = u @ b["wkv_a"]
+    kv = np.einsum("lr,rhd->lhd", norm(ckv[:, :16], b["kv_norm"]["scale"]),
+                   b["wkv_b"])
+    kr = rope(ckv[:, None, 16:])
+    q = np.concatenate([q[..., :8], rope(q[..., 8:])], -1)
+    k = np.concatenate([kv[..., :8], np.repeat(kr, 4, axis=1)], -1)
+    s = np.einsum("qhd,khd->hqk", q, k) / np.sqrt(12.0)
+    s = np.where(np.tril(np.ones((16, 16), bool)), s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    a = np.einsum("hqk,khd->qhd", p / p.sum(-1, keepdims=True), kv[..., 8:])
+    want = x64 + np.einsum("qhd,hde->qe", a, b["wo"])
+    np.testing.assert_allclose(np.asarray(got)[0], want, rtol=2e-4, atol=2e-5)
+
+
+def test_mla_routing_metrics_count_the_modules_block_as_one_more_row():
+    mesh, model = _mesh(), _mla_toy()
+    params, _ = model.init(jax.random.PRNGKey(0))
+    out = build_lm_routing_metrics(model, mesh, params)(params, _tokens(mesh))
+    assert out["assignments"].shape == (3, 4)       # layers 1, 2, the module
+    assert (out["dropped"] == 0).all()
+    assert 0 < out["assignments"].sum() < 3 * 4 * 64 * 3
+
+
+@pytest.mark.parametrize("missing", ["q_lora_rank", "kv_lora_rank",
+                                     "qk_nope_head_dim", "qk_rope_head_dim",
+                                     "v_head_dim", "rope_theta"])
+def test_constructor_names_the_size_an_mla_layer_lacks(missing):
+    with pytest.raises(ValueError, match=f"'mla' layer needs {missing} "):
+        _mla_toy(**{missing: None})
+
+
+def test_constructor_refuses_the_new_options_it_cannot_build():
+    with pytest.raises(ValueError, match="rope_pairing"):
+        _mla_toy(rope_pairing="pairs")
+    with pytest.raises(ValueError, match="router_score"):
+        _mla_toy(router_score="tanh")
+    with pytest.raises(ValueError, match="dense_width"):
+        _mla_toy(dense_width=None)
+    with pytest.raises(ValueError, match="dense_layers=4"):
+        _mla_toy(dense_layers=4)
+    with pytest.raises(ValueError, match="mtp_depth"):
+        _mla_toy(mtp_depth=2)
+    mesh, model = _mesh((1, 2, 1)), _mla_toy()
+    params, _ = model.init(jax.random.PRNGKey(0))
+    step = build_lm_step(model, mesh, params, lr=0.05, donate=False)
+    with pytest.raises(NotImplementedError, match="sequence"):
+        step(params, _tokens(mesh, b=2))

@@ -335,6 +335,92 @@ def test_grouped_queries_splash_matches_full_square_float32():
                                    rtol=2e-4, atol=5e-5)
 
 
+# --- scores over one head size, values over another (latent attention) ------
+
+def _latent_qkv(L, dqk, dv, heads, seed):
+    rng = np.random.RandomState(seed)
+    mk = lambda d: jnp.asarray(                           # noqa: E731
+        rng.randn(1, L, heads, d).astype(np.float32))
+    return mk(dqk), mk(dqk), mk(dv)
+
+
+def test_unequal_head_sizes_full_square_is_the_definition():
+    """q and k of one head size, v of another: the scale is 1 / sqrt(q's),
+    the output has v's — against the definition in numpy float64."""
+    q, k, v = _latent_qkv(48, 24, 16, 2, seed=31)
+    got = local_attention(q, k, v, causal=True, impl="xla")
+    assert got.shape == (1, 48, 2, 16)
+    q64, k64, v64 = (np.asarray(a, np.float64) for a in (q, k, v))
+    s = np.einsum("bqhd,bkhd->bhqk", q64, k64) / np.sqrt(24.0)
+    s = np.where(np.tril(np.ones((48, 48), bool)), s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    want = np.einsum("bhqk,bkhd->bqhd", p / p.sum(-1, keepdims=True), v64)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-6)
+
+
+# 192 / 128 are the published sizes (q and k go to the kernel sequence-minor
+# beside a head-minor v: each operand by its own minor size); 96 / 64 the
+# same shape of problem at half the cost, both operands sequence-minor
+@pytest.mark.parametrize("L,dqk,dv", [(256, 192, 128), (384, 96, 64)])
+def test_unequal_head_sizes_splash_matches_full_square_float32(L, dqk, dv):
+    """The blockwise kernel (Pallas interpret mode here) with the library's
+    ``head_dim_v`` against the full square: forward and the three gradients,
+    float32, at the tolerances of the equal-size comparison above."""
+    q, k, v = _latent_qkv(L, dqk, dv, 2, seed=32)
+    got = local_attention(q, k, v, causal=True, impl="splash")
+    ref = local_attention(q, k, v, causal=True, impl="xla")
+    assert got.shape == ref.shape == (1, L, 2, dv)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-5, atol=2e-6)
+    for a, b in zip(_loss_and_grads("splash", q, k, v),
+                    _loss_and_grads("xla", q, k, v)):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=5e-5)
+
+
+def test_unequal_head_sizes_are_checked_and_counted():
+    """k must have q's head size (v's is free); the error for mismatched
+    K / V head COUNTS names the head sizes it was given; a call whose v is
+    narrower than its q is counted in ``attn_latent_total``, an equal-size
+    call is not."""
+    from distlearn_tpu.parallel.sequence import attention_paths_traced
+    q, k, v = _latent_qkv(32, 24, 16, 4, seed=33)
+    with pytest.raises(ValueError, match="q and k must share the head size"):
+        local_attention(q, k[..., :16], v, causal=True)
+    with pytest.raises(ValueError, match=r"head sizes given: q 24, k 24, "
+                                         r"v 16"):
+        local_attention(q, k[:, :, :2], v[:, :, :1], causal=True)
+    before = attention_paths_traced(latent=True).get("xla", 0)
+    local_attention(q, k, v, causal=True)
+    local_attention(q, k, k, causal=True)               # equal sizes
+    assert attention_paths_traced(latent=True).get("xla", 0) == before + 1
+
+
+@pytest.mark.parametrize("dqk,dv,want", [
+    (64, 64, ("SEQ_MINOR",) * 3), (128, 128, ("HEAD_DIM_MINOR",) * 3),
+    (192, 128, ("SEQ_MINOR", "SEQ_MINOR", "HEAD_DIM_MINOR"))])
+def test_kernel_layout_is_each_operands_own(monkeypatch, dqk, dv, want):
+    """The layout the kernel is handed an operand in follows from THAT
+    operand's minor size (a multiple of the 128 lanes: head-minor; else
+    sequence-minor) — for q, k, v of one size the one layout they always
+    had, for 192-wide q and k beside a 128-wide v one each."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk)
+    seen, real = [], sk.make_splash_mha
+
+    def spy(*a, block_sizes, **kw):
+        seen.append(block_sizes)
+        return real(*a, block_sizes=block_sizes, **kw)
+    monkeypatch.setattr(sk, "make_splash_mha", spy)
+    q, k, v = _latent_qkv(128, dqk, dv, 2, seed=34)
+    jax.eval_shape(lambda a, b, c: local_attention(
+        a, b, c, causal=True, impl="splash"), q, k, v)
+    (sizes,) = seen
+    assert tuple(l.name for l in (sizes.q_layout, sizes.k_layout,
+                                  sizes.v_layout)) == want
+
+
 # --- a causal window --------------------------------------------------------
 
 def _band_oracle(q, k, v, window):

@@ -730,6 +730,53 @@ def test_tpu_compiler_takes_the_grouped_kernels_at_published_widths(
     assert text.count('custom_call_target="tpu_custom_call"') == 4 + 7
 
 
+def test_tpu_compiler_takes_the_latent_kernel_at_its_published_widths(
+        v5e_chip, monkeypatch):
+    """The blockwise kernel at the sizes a latent-attention layer is trained
+    at (32 heads, 192-wide q and k beside a 128-wide v, one sequence of
+    16,384, bf16), forward and backward, compiled for the v5e: two Mosaic
+    calls (``splash_mha``), no ``[32, 16384, 16384]`` array, and the fused
+    backward's unreduced dq cut to 8 copies of q by ``_dkv_block`` (at the
+    512-wide block of the other calls it is 32 copies, 6.4 GB, and the whole
+    step does not fit the chip).  Nothing runs; no number of this is a
+    measurement."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    from distlearn_tpu.parallel import sequence
+    monkeypatch.setattr(sequence, "_backend", lambda: "tpu")
+    one = SingleDeviceSharding(v5e_chip)
+    qk = jax.ShapeDtypeStruct((1, 16384, 32, 192), jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16, sharding=one)
+
+    def loss(q, k, v):
+        return jnp.sum(sequence.local_attention(
+            q, k, v, causal=True).astype(jnp.float32))
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+                qk, qk, v).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "splash_mha_fwd" in text and "splash_mha_dkv" in text
+    assert "16384,16384" not in text
+    q_bytes = 16384 * 32 * 192 * 2
+    assert sequence._dkv_block(512, q_bytes, 16384) == 2048
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.5e9
+    # every call the package made before keeps the block it had
+    assert sequence._dkv_block(512, 16384 * 28 * 128 * 2, 16384) == 512
+    assert sequence._dkv_block(512, 8 * 1024 * 20 * 64 * 2, 1024) == 512
+    # the widest that tiles the length, or the block as it was
+    assert sequence._dkv_block(512, 8 * q_bytes, 3 * 1024) == 1024
+    assert sequence._dkv_block(512, 8 * q_bytes, 3 * 512) == 512
+
+
 # --- rotary positions -------------------------------------------------------
 
 def test_rotary_is_the_complex_rotation_of_the_half_split_pairs():
@@ -779,5 +826,64 @@ def test_rotary_scores_depend_on_the_distance_alone(shift):
                                np.asarray(base), atol=2e-3 if shift > 100
                                else 2e-4)
     # and they are NOT the unrotated scores
+    assert float(jnp.abs(base - jnp.einsum("bqhd,bkhd->bhqk", q, k)).max()) \
+        > 0.1
+
+
+def test_rotary_interleaved_is_the_complex_rotation_of_neighbouring_pairs():
+    """``pairing="interleaved"``: the pair ``(2d, 2d + 1)`` read as the
+    complex number ``x[2d] + i x[2d+1]`` is multiplied by ``exp(i p
+    theta^(-2d/D))`` and left in its places: against that formula in
+    numpy's complex128, at theta 32e6 and positions up to 16k; and it is NOT
+    the rotation by halves."""
+    from distlearn_tpu.models.transformer import rotary
+    rng = np.random.RandomState(2)
+    x = rng.randn(2, 6, 3, 16).astype(np.float32)
+    pos = np.array([0, 1, 2, 977, 4096, 16383])
+    theta = 3.2e7
+    z = x[..., 0::2].astype(np.complex128) + 1j * x[..., 1::2]
+    turn = np.exp(1j * pos[:, None] * theta ** (-np.arange(8) / 8.0))
+    want = z * turn[None, :, None, :]
+    got = np.asarray(rotary(jnp.asarray(x), jnp.asarray(pos), theta,
+                            pairing="interleaved"))
+    np.testing.assert_allclose(got[..., 0::2], want.real, atol=4e-3)
+    np.testing.assert_allclose(got[..., 1::2], want.imag, atol=4e-3)
+    np.testing.assert_allclose(got[:, :3, :, 0::2], want.real[:, :3],
+                               atol=1e-6)
+    np.testing.assert_array_equal(got[:, 0], x[:, 0])       # position 0
+    np.testing.assert_allclose(got[..., 0::2] ** 2 + got[..., 1::2] ** 2,
+                               x[..., 0::2] ** 2 + x[..., 1::2] ** 2,
+                               rtol=1e-5)
+    halves = np.asarray(rotary(jnp.asarray(x), jnp.asarray(pos), theta))
+    assert np.abs(halves - got).max() > 0.1
+    # the default is the rotation by halves, argument or none
+    np.testing.assert_array_equal(halves, np.asarray(rotary(
+        jnp.asarray(x), jnp.asarray(pos), theta, pairing="half")))
+    assert rotary(jnp.asarray(x, jnp.bfloat16), jnp.asarray(pos), theta,
+                  "interleaved").dtype == jnp.bfloat16
+    with pytest.raises(ValueError, match="pairing must be one of"):
+        rotary(jnp.asarray(x), jnp.asarray(pos), theta, pairing="pairs")
+
+
+@pytest.mark.parametrize("shift", [1, 37, 4096])
+def test_rotated_slice_of_a_head_keeps_scores_on_the_distance_alone(shift):
+    """A head whose LAST 8 of 24 dimensions are rotated by neighbouring
+    pairs and whose first 16 are not (the latent-attention head): q and k at
+    positions ``p + shift`` give the scores they give at ``p``."""
+    from distlearn_tpu.models.transformer import rotary
+    rng = np.random.RandomState(3)
+    q, k = (jnp.asarray(rng.randn(1, 24, 2, 24).astype(np.float32))
+            for _ in range(2))
+    pos = jnp.arange(24)
+
+    def scores(p):
+        turn = lambda u: jnp.concatenate(                   # noqa: E731
+            [u[..., :16], rotary(u[..., 16:], p, 3.2e7, "interleaved")], -1)
+        return jnp.einsum("bqhd,bkhd->bhqk", turn(q), turn(k))
+
+    base = scores(pos)
+    np.testing.assert_allclose(np.asarray(scores(pos + shift)),
+                               np.asarray(base), atol=2e-3 if shift > 100
+                               else 2e-4)
     assert float(jnp.abs(base - jnp.einsum("bqhd,bkhd->bhqk", q, k)).max()) \
         > 0.1
