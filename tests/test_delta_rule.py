@@ -1,6 +1,8 @@
 """The chunked gated delta rule (``ops/delta_rule.py``) against its
-recurrence, position by position: forward, the hand-written backward pass of
-the scan over the chunks, a non-zero initial state, step sizes above 1
+recurrence, position by position: forward, the hand-written backward passes
+of the per-chunk operands (against autodiff of their plain forward) and of
+the scan over the chunks, the operands' Pallas kernels in interpret mode
+against the ``jnp`` path, a non-zero initial state, step sizes above 1
 (negative eigenvalues), and a decay so strong that any split of an exponent
 into an overflowing factor would show."""
 
@@ -10,17 +12,23 @@ import numpy as np
 import pytest
 
 from distlearn_tpu.ops import delta_rule
-from distlearn_tpu.ops.delta_rule import (_block_inverse, _unit_lower_inverse,
+from distlearn_tpu.ops.delta_rule import (_block_inverse, _chunk_operands,
+                                          _operands, _operands_vjp,
+                                          _unit_lower_inverse,
                                           chunked_delta_rule)
 
 
-def _sizes(monkeypatch, chunk, sub, head_group=delta_rule.HEAD_GROUP):
-    """The op has one chunk length, sub-block and head group (module
-    constants, no argument); the tests set them to walk every branch at a
-    small ``L``."""
+def _sizes(monkeypatch, chunk, sub):
+    """The op has one chunk length and sub-block (module constants, no
+    argument); the tests set them to walk every branch at a small ``L``."""
     monkeypatch.setattr(delta_rule, "CHUNK", chunk)
     monkeypatch.setattr(delta_rule, "SUB", sub)
-    monkeypatch.setattr(delta_rule, "HEAD_GROUP", head_group)
+
+
+def _path(monkeypatch, path):
+    """The op chooses its path from the call (``select_delta_rule``); the
+    tests walk both on the CPU, the kernels in Pallas interpret mode."""
+    monkeypatch.setattr(delta_rule, "select_delta_rule", lambda *a: path)
 
 
 def recurrence(q, k, v, g, beta, S0):
@@ -69,12 +77,13 @@ def test_chunked_forward_is_the_recurrence(monkeypatch, chunk, sub):
         rtol=1e-4, atol=2e-5)
 
 
-@pytest.mark.parametrize("head_group", [1, 4])
-def test_chunked_gradients_are_the_recurrences(monkeypatch, head_group):
+@pytest.mark.parametrize("path", ["xla", "kernel"])
+def test_chunked_gradients_are_the_recurrences(monkeypatch, path):
     """Every input's gradient, the initial state's included, through the
-    hand-written backward pass of the scan (one state a chunk kept, ``U``
-    recomputed) and the rematerialised head groups."""
-    _sizes(monkeypatch, 16, 4, head_group)
+    hand-written backward passes of the scan (one state a chunk kept, ``U``
+    recomputed) and of the per-chunk operands, on both paths."""
+    _sizes(monkeypatch, 16, 4)
+    _path(monkeypatch, path)
     args = _inputs(brutal=False)
 
     def scalar(fn):
@@ -96,10 +105,71 @@ def test_chunked_gradients_are_the_recurrences(monkeypatch, head_group):
                                    err_msg=name)
 
 
-def test_no_exponent_ever_overflows():
+def _chunks(a, chunk):
+    """[B, H, L, X] -> [B, H, N, C, X], what ``_operands`` takes."""
+    return a.reshape(a.shape[:2] + (-1, chunk, a.shape[-1]))
+
+
+COTANGENTS = ("Wv", "Wk", "Qd", "Aqk", "Kd", "gam")
+
+
+@pytest.mark.parametrize("only", COTANGENTS + ("all",))
+@pytest.mark.parametrize("chunk,sub", [(16, 4), (32, 8), (64, 16)])
+def test_operands_pull_back_is_autodiffs(chunk, sub, only):
+    """The written-out pull-back of the per-chunk operands against JAX's
+    own differentiation of their plain forward (``_operands``: no
+    ``custom_vjp`` in it), float32, for each of the six cotangents alone
+    and for all together; step sizes on both sides of 1, decays down to
+    exp(-200)."""
+    q, k, v, g, beta, _ = _inputs()
+    assert float(beta.max()) > 1.0 and float(beta.min()) < 1.0
+    args = [_chunks(a, chunk) for a in (q, k, v, g, beta[..., None])]
+    plain = lambda *a: _operands(*a, sub, jnp.float32)[0]       # noqa: E731
+    ops, pull = jax.vjp(plain, *args)
+    keys = jax.random.split(jax.random.PRNGKey(7), len(ops))
+    cot = [jax.random.normal(key, o.shape, o.dtype) if only in (name, "all")
+           else jnp.zeros_like(o)
+           for key, name, o in zip(keys, COTANGENTS, ops)]
+    want = pull(tuple(cot))
+    _, (X, Akk) = _operands(*args, sub, jnp.float32)
+    got = _operands_vjp(*args, X, Akk, cot, sub, jnp.float32)
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        np.testing.assert_allclose(
+            a, b, rtol=1e-4, atol=2e-6 * max(1.0, float(jnp.abs(b).max())),
+            err_msg=f"d{name} for the cotangent of {only}")
+
+
+@pytest.mark.parametrize("L,chunk,sub", [(256, 32, 8), (192, 32, 8),
+                                         (64, 16, 4)])
+def test_kernels_are_the_jnp_path(L, chunk, sub):
+    """The two Pallas calls (interpret mode here) against the ``jnp`` path
+    of the same ``custom_vjp``: the six operands and all five gradients,
+    where the kernels' block of 128 positions divides the sequence (256),
+    where its last block is partly past the end (192: six chunks in blocks
+    of four) and where the sequence is shorter than one block (64)."""
+    q, k, v, g, beta, _ = _inputs(L=L, H=2, V=16)
+    got, want = [], []
+    for impl, out in (("kernel", got), ("xla", want)):
+        ops, pull = jax.vjp(lambda *a: _chunk_operands(
+            *a, chunk, sub, jnp.float32, impl), q, k, v, g, beta)
+        keys = jax.random.split(jax.random.PRNGKey(11), len(ops))
+        out += ops + pull(tuple(jax.random.normal(key, o.shape, o.dtype)
+                                for key, o in zip(keys, ops)))
+    names = COTANGENTS + tuple("dq dk dv dg dbeta".split())
+    for name, a, b in zip(names, got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        np.testing.assert_allclose(
+            a, b, rtol=2e-4, atol=2e-5 * max(1.0, float(jnp.abs(b).max())),
+            err_msg=name)
+
+
+@pytest.mark.parametrize("path", ["xla", "kernel"])
+def test_no_exponent_ever_overflows(monkeypatch, path):
     """A channel that forgets everything in one step (exp(-200) underflows
     to 0, exp(+200) would be inf): results and gradients stay finite, at
-    the shipped chunk length and sub-block."""
+    the shipped chunk length and sub-block, on both paths."""
+    _path(monkeypatch, path)
     assert (delta_rule.CHUNK, delta_rule.SUB) == (32, 8)
     q, k, v, g, beta, S0 = _inputs()
     val, grads = jax.value_and_grad(
@@ -126,14 +196,18 @@ def test_block_inverse_is_the_inverse(C, sub):
     want = np.linalg.inv(np.eye(C) + np.asarray(a, np.float64))
     np.testing.assert_allclose(_block_inverse(a, sub), want, rtol=2e-3,
                                atol=2e-3 * np.abs(want).max())
-    # the substitution's own backward pass: d(M^-1) = -M^-1 dM M^-1
-    f = lambda m: jnp.sum(_unit_lower_inverse(m)                # noqa: E731
-                          * jnp.arange(m.size, dtype=m.dtype).reshape(m.shape))
+    # the substitution alone, and on the strict triangle its derivative
+    # d(M^-1) = -M^-1 dM M^-1 (the identity the operands' pull-back uses)
     small = a[0, :, :8, :8] * 0.3
-    num = jax.grad(lambda m: jnp.sum(jnp.linalg.inv(jnp.eye(8) + m)
-                                     * jnp.arange(m.size, dtype=m.dtype)
-                                     .reshape(m.shape)))(small)
-    np.testing.assert_allclose(jax.grad(f)(small), jnp.tril(num, -1),
+    np.testing.assert_allclose(
+        _unit_lower_inverse(small),
+        np.linalg.inv(np.eye(8) + np.asarray(small, np.float64)),
+        rtol=1e-5, atol=1e-5)
+    w = jnp.arange(small.size, dtype=small.dtype).reshape(small.shape)
+    x = _unit_lower_inverse(small)
+    want = -jnp.einsum("...ji,...jk,...lk->...il", x, w, x)
+    got = jax.grad(lambda m: jnp.sum(_unit_lower_inverse(m) * w))(small)
+    np.testing.assert_allclose(jnp.tril(got, -1), jnp.tril(want, -1),
                                rtol=1e-3, atol=1e-3)
 
 

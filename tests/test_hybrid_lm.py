@@ -251,6 +251,59 @@ def test_moe_grouped_counter_counts_the_resolved_path():
     assert set(after) <= set(ep.GROUPED_IMPLS)
 
 
+@pytest.mark.parametrize("backend,dtype,k,v,chunk,sub,path", [
+    # the cell the kernels were measured on: 64 heads of 128, chunk 32 / 8
+    ("tpu", _BF16, 128, 128, 32, 8, "kernel"),
+    ("tpu", _BF16, 256, 128, 64, 16, "kernel"),
+    ("tpu", _BF16, 128, 256, 16, 8, "kernel"),
+    # widths the lanes do not tile; a dtype whose products the chip would
+    # round to bfloat16 on the way
+    ("tpu", _BF16, 64, 128, 32, 8, "xla"),
+    ("tpu", _BF16, 128, 96, 32, 8, "xla"),
+    ("tpu", _F32, 128, 128, 32, 8, "xla"),
+    # a sub-block that is not whole vector registers; a chunk that does
+    # not tile the kernels' 128 positions, or cuts them into too many
+    ("tpu", _BF16, 128, 128, 32, 4, "xla"),
+    ("tpu", _BF16, 128, 128, 48, 8, "xla"),
+    ("tpu", _BF16, 128, 128, 256, 8, "xla"),
+    ("tpu", _BF16, 128, 128, 8, 8, "xla"),
+    # off the TPU the kernels would run interpreted
+    ("cpu", _BF16, 128, 128, 32, 8, "xla"),
+    ("gpu", _BF16, 128, 128, 32, 8, "xla"),
+])
+def test_select_delta_rule_table(monkeypatch, backend, dtype, k, v, chunk, sub,
+                                 path):
+    from distlearn_tpu.ops import delta_rule
+    monkeypatch.setenv("DISTLEARN_TPU_DELTA_RULE", "kernel")  # none decides
+    select = delta_rule.select_delta_rule
+    assert select(backend, dtype, k, v, chunk, sub) == path
+    assert select(backend, jnp.dtype(dtype), k, v, chunk, sub) == path
+    assert path in delta_rule.DELTA_RULE_IMPLS
+
+
+def test_delta_rule_counter_counts_the_resolved_path():
+    """``delta_rule_total{impl=}`` moves once per traced
+    ``chunked_delta_rule`` call: a step of three linear-attention layers
+    that share one rematerialised wrapper, traced on the CPU, reads
+    ``xla`` and nothing under ``kernel``; a refused call counts nothing."""
+    from distlearn_tpu.ops import delta_rule
+    mesh, model = _mesh(), _toy(remat="full")
+    params, _ = model.init(jax.random.PRNGKey(0))
+    before = delta_rule.delta_rule_paths_traced()
+    step = build_lm_step(model, mesh, params, lr=0.05, donate=False)
+    step(params, _tokens(mesh))
+    step(params, _tokens(mesh))                            # traced once
+    traced = {k: v - before.get(k, 0)
+              for k, v in delta_rule.delta_rule_paths_traced().items()}
+    assert traced.get("xla", 0) >= 1 and not traced.get("kernel", 0)
+    again = delta_rule.delta_rule_paths_traced()
+    with pytest.raises(ValueError, match="multiple of"):
+        a = jnp.zeros((1, 2, 40, 8))
+        delta_rule.chunked_delta_rule(a, a, a, a, a[..., 0])
+    assert delta_rule.delta_rule_paths_traced() == again
+    assert set(again) <= set(delta_rule.DELTA_RULE_IMPLS)
+
+
 def test_the_shares_of_all_holders_add_up_to_the_whole_layer():
     """16 experts over 4 holders of 4: each holder's routed part, with the
     shared expert counted ONCE, is the uncut layer (every expert held by one

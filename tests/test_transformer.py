@@ -522,7 +522,10 @@ def test_tpu_compiler_keeps_every_scope_at_gpt2_large_width(
     1280 wide, a router of 320, 8 a token; one softmax and one
     linear-attention layer, 2 experts held, 1 x 1024 tokens, a cut of the
     vocabulary) — the two scopes only it uses survive too, and its softmax
-    layer's grouped queries ride the same two Mosaic calls.
+    layer's grouped queries ride the same two Mosaic calls, beside the
+    three of the delta rule's per-chunk operands (``ops/delta_rule.py``:
+    forward, recomputed forward, pull-back), which Mosaic takes at the
+    published head size.
 
     ``local_attention`` picks its path from ``jax.default_backend()``,
     which here says "cpu" whatever the program is compiled for: ``"tpu"``
@@ -560,7 +563,20 @@ def test_tpu_compiler_keeps_every_scope_at_gpt2_large_width(
     kernels = {k: v for k, v in table.items() if k.startswith(kernel)}
     square = square in text
     if backend == "tpu":
-        assert text.count('custom_call_target="tpu_custom_call"') == 2
+        mosaic = re.findall(
+            r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+        assert len([c for c in mosaic if c.startswith(kernel)]) == 2
+        # every other Mosaic call is the delta rule's (the dense model has
+        # none): its per-chunk operands in each pass — forward, recomputed
+        # forward, pull-back — under the layer's scope and the rule's name
+        rule = [table[c].replace("(", "/").replace(")", "/")
+                for c in mosaic if not c.startswith(kernel)]
+        assert len(rule) == (3 if kind == "hybrid" else 0)
+        assert all("/linattn_core/" in n and "/delta_rule/" in n
+                   for n in rule), rule
+        assert sorted("vjp" if "delta_rule_operands_vjp" in n else
+                      "remat" if "rematted_computation" in n else "fwd"
+                      for n in rule) == ["fwd", "remat", "vjp"][:len(rule)]
         assert not square
         assert not any("rematted_computation" in n for n in kernels.values())
         passes = sorted(
@@ -569,13 +585,12 @@ def test_tpu_compiler_keeps_every_scope_at_gpt2_large_width(
             if "/attn_core/" in n.replace("(", "/").replace(")", "/"))
         assert passes == [("bwd", f"{kernel}_dkv_no_residuals"),
                           ("fwd", f"{kernel}_fwd_residuals")]
-        if kind == "dense":
-            memory = compiled.memory_analysis()
-            held = memory.temp_size_in_bytes + memory.argument_size_in_bytes
-            assert held < 15.75e9, (
-                f"the dense step holds {held / 1e9:.2f} GB of temporaries "
-                "and arguments by the compiler's count: over the chip's "
-                "15.75 GB")
+        memory = compiled.memory_analysis()
+        held = memory.temp_size_in_bytes + memory.argument_size_in_bytes
+        assert held < 15.75e9, (
+            f"the {kind} step holds {held / 1e9:.2f} GB of temporaries "
+            "and arguments by the compiler's count: over the chip's "
+            "15.75 GB")
     else:
         assert square and not kernels
 
@@ -636,6 +651,51 @@ def test_tpu_compiler_hides_the_gradient_sum_behind_the_backward_scan(
     memory = compiled.memory_analysis()
     held = memory.temp_size_in_bytes + memory.argument_size_in_bytes
     assert held < 15.75e9, f"{held / 1e9:.2f} GB of temporaries and arguments"
+
+
+@pytest.mark.parametrize("chunk,sub,B,H,L,K,V", [
+    (32, 8, 1, 64, 8192, 128, 128),     # solar-open2-250b.train-8k's call
+    (64, 16, 1, 2, 1024, 256, 128),     # two chunks a block, two lane tiles
+    (16, 8, 2, 3, 416, 128, 256),       # eight chunks a block, the last
+])                                      # block partly past the end
+def test_tpu_compiler_takes_the_delta_rule_kernels(v5e_chip, monkeypatch,
+                                                   chunk, sub, B, H, L, K, V):
+    """The two Pallas calls of the delta rule's per-chunk operands
+    (``ops/delta_rule.py``), forward and pull-back, compiled for the v5e
+    at every kind of shape ``select_delta_rule`` sends them: Mosaic takes
+    the slices, reshapes and products, and the VMEM they need.  Nothing
+    runs; no number of this is a measurement."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    from distlearn_tpu.ops import delta_rule
+    from distlearn_tpu.parallel import sequence
+    monkeypatch.setattr(sequence, "_backend", lambda: "tpu")
+    bf16 = jnp.bfloat16
+    assert delta_rule.select_delta_rule("tpu", bf16, K, V, chunk, sub) \
+        == "kernel"
+    one = SingleDeviceSharding(v5e_chip)
+    arg = lambda X, dt: jax.ShapeDtypeStruct(                   # noqa: E731
+        (B, H, L) + X, dt, sharding=one)
+
+    def loss(q, k, v, g, beta):
+        ops = delta_rule._chunk_operands(q, k, v, g, beta, chunk, sub, bf16,
+                                         "kernel")
+        return sum(jnp.sum(o.astype(jnp.float32)) for o in ops)
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+                arg((K,), bf16), arg((K,), bf16), arg((V,), bf16),
+                arg((K,), jnp.float32), arg((), jnp.float32)
+            ).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "delta_rule_operands_vjp" in text
 
 
 def test_tpu_compiler_takes_the_windowed_kernel_at_its_published_widths(
