@@ -71,49 +71,26 @@ import time
 SERVE_LOGIT_TOL = 0.015
 
 
-class CompileMeter:
-    """Set-up seconds apart from run seconds, from JAX's own events: the
-    trace, lowering and backend-compile durations (a persistent-cache hit
-    reports its retrieval under the last), and cache hits / misses.  One
-    listener for the process — compiles on the serve thread count too."""
-
-    _SETUP = ("/jax/core/compile/jaxpr_trace_duration",
-              "/jax/core/compile/jaxpr_to_mlir_module_duration",
-              "/jax/core/compile/backend_compile_duration")
-
-    def __init__(self):
-        import jax
-        self._lock = threading.Lock()
-        self._total = {"setup_s": 0.0, "programs": 0, "cache_hits": 0,
-                       "cache_misses": 0}
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, name, secs, **_):
-        if name in self._SETUP:
-            with self._lock:
-                self._total["setup_s"] += secs
-                self._total["programs"] += name.endswith("backend_compile_duration")
-
-    def _event(self, name, **_):
-        key = name.rsplit("/", 1)[-1]
-        if key in ("cache_hits", "cache_misses"):
-            with self._lock:
-                self._total[key] += 1
-
-    def measure(self, fn) -> dict:
-        """Run one phase; its result dict plus wall / set-up / run seconds."""
-        with self._lock:
-            before = dict(self._total)
-        t0 = time.perf_counter()
-        res = fn()
-        wall = time.perf_counter() - t0
-        with self._lock:
-            delta = {k: v - before[k] for k, v in self._total.items()}
-        res.update(delta, wall_s=round(wall, 2),
-                   setup_s=round(delta["setup_s"], 2),
-                   run_s=round(wall - delta["setup_s"], 2))
-        return res
+def measure(fn) -> dict:
+    """Run one phase; its result dict plus wall / set-up / run seconds.
+    Set-up is read from the program's own spans and counter
+    (``utils.compile_cache.watch_compiles``, on since ``main`` enabled the
+    cache): the phase's seconds under a ``jit.*`` span, overlaps counted
+    once and compiles on the serve thread too, and its backend compiles by
+    what the persistent cache did.  With ``DISTLEARN_OBS=0``: the wall."""
+    from distlearn_tpu.utils import compile_cache
+    before = compile_cache.compiles()
+    t0 = time.perf_counter()
+    res = fn()
+    wall = time.perf_counter() - t0
+    res["wall_s"] = round(wall, 2)
+    if before:
+        done = {k: v - before[k] for k, v in compile_cache.compiles().items()}
+        setup = compile_cache.jit_seconds(since=t0)
+        res.update(programs=sum(done.values()), cache_hits=done["hit"],
+                   cache_misses=done["miss"], setup_s=round(setup, 2),
+                   run_s=round(wall - setup, 2))
+    return res
 
 
 def _require_mosaic(lowered, what: str) -> int:
@@ -652,10 +629,9 @@ def main() -> int:
         ("serve", phase_serve),
         ("wire_kernels", phase_wire_kernels),
     )
-    meter = CompileMeter()
     t0 = time.perf_counter()
     for name, fn in phases:
-        res = meter.measure(fn)     # a failing phase raises: exit code != 0
+        res = measure(fn)           # a failing phase raises: exit code != 0
         # the allocator's high-water mark since process start
         res["peak_hbm_gb_so_far"] = round(
             (d0.memory_stats() or {}).get("peak_bytes_in_use", 0) / 2**30, 2)

@@ -246,7 +246,9 @@ def set_propagate(on: bool | None):
 
 
 class _Span:
-    __slots__ = ("name", "labels", "_t0", "_ann", "_tc")
+    #: ``dur`` is set when the block is left: a caller that feeds the same
+    #: interval to a histogram reads it there and times nothing twice
+    __slots__ = ("name", "labels", "dur", "_t0", "_ann", "_tc")
 
     def __init__(self, name: str, labels: dict):
         self.name = name
@@ -272,7 +274,7 @@ class _Span:
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dur = time.perf_counter() - self._t0
+        dur = self.dur = time.perf_counter() - self._t0
         if self._ann is not None:
             try:
                 self._ann.__exit__(exc_type, exc, tb)
@@ -298,6 +300,7 @@ class _NullSpan:
     """Shared disabled-path span: no timing, no record, reusable."""
 
     __slots__ = ()
+    dur = None
 
     def __enter__(self):
         return self

@@ -26,7 +26,6 @@ Two families:
 
 from __future__ import annotations
 
-import time
 from typing import Any, Callable, NamedTuple
 
 import jax
@@ -88,7 +87,10 @@ class _TimedStep:
     ENQUEUE the program, which is what the scan/cycle builders exist to
     amortize, not device compute), the same time into a histogram, and a
     call count.  It keeps the abstract signature of its first call (no
-    buffer), so :meth:`hlo_text` can name the program the device ran.
+    buffer), so :meth:`hlo_text` can name the program the device ran, and
+    puts that one call under ``train.first_call{step=}``: what the step
+    costs a fresh process (with ``utils.compile_cache.watch_compiles`` the
+    program's own ``jit.*`` spans lie inside it).
     ``__getattr__`` forwards everything else to the jitted callable so
     ``.lower()`` consumers — the benchmark, the distcost budget gate — see
     the unwrapped object and compiled HLO stays identical."""
@@ -111,11 +113,16 @@ class _TimedStep:
     def __call__(self, *a, **kw):
         if self._sig is None:
             self._sig = _signature((a, kw))
-        t0 = time.perf_counter()
-        with obs.span("train.dispatch", step=self._name):
+            if self._sig is not None:
+                # the call that traces, lowers and fetches or compiles the
+                # program: its jit.* spans lie inside this one
+                with obs.span("train.first_call", step=self._name):
+                    return self(*a, **kw)
+        with obs.span("train.dispatch", step=self._name) as span:
             out = self._fn(*a, **kw)
         self._c.inc()
-        self._h.observe(time.perf_counter() - t0)
+        if span.dur is not None:        # the switch went off after the build
+            self._h.observe(span.dur)
         return out
 
     def hlo_text(self) -> str:

@@ -26,17 +26,21 @@ def setup_platform(num_nodes: int, tpu: bool):
     with ``num_nodes`` virtual host devices (the reference's
     LocalhostTree analogue, SURVEY.md §4).
     """
-    from distlearn_tpu.utils.compile_cache import enable_compile_cache
-    enable_compile_cache()
-    if not tpu:
+    if tpu:
+        import jax
+        platform = jax.devices()[0].platform
+        if platform != "tpu":
+            raise SystemExit(
+                f"--tpu: JAX found no TPU (platform={platform!r}, "
+                f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r})")
+    else:
         from distlearn_tpu.utils.platform import force_cpu
         force_cpu(num_nodes)
-        return
-    import jax
-    platform = jax.devices()[0].platform
-    if platform != "tpu":
-        raise SystemExit(f"--tpu: JAX found no TPU (platform={platform!r}, "
-                         f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r})")
+    # last, not first: its ``process.ready`` mark stands where this process
+    # holds its devices (the pinned CPU's come up at the first query, in
+    # milliseconds); nothing above compiles
+    from distlearn_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
 
 def resolve_num_nodes(requested: int, tpu: bool) -> int:

@@ -359,6 +359,12 @@ def main():
                 log(f"held experts' grouped products traced "
                     f"(moe_grouped_total): "
                     f"{grouped_paths_traced() or 'none'}")
+                from distlearn_tpu.utils.compile_cache import programs
+                log("set-up by program [trace s, lower s, compile s, cache, "
+                    "compiles] (jit.* spans): " + "; ".join(
+                        f"{r['fun']} {r['trace_s']:.2f} {r['lower_s']:.2f} "
+                        f"{r['compile_s']:.2f} {r['cache'] or '-'} "
+                        f"{r['count']}" for r in programs()[:12]))
             if do_profile and i == prof_stop:
                 jax.block_until_ready(jax.tree_util.tree_leaves(params)[0])
                 timer.reset_window()

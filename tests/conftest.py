@@ -26,6 +26,10 @@ jax.config.update("jax_enable_x64", True)
 
 import pytest  # noqa: E402
 
+# tests/benchmark's files are the benchmark's and are not edited: what one
+# of its tests needs repaired besides its own conftest.py comes as a plugin
+pytest_plugins = ["benchmark.manifest_of_its_day"]
+
 
 @pytest.fixture(scope="session")
 def devices():
