@@ -359,13 +359,16 @@ def phase_hybrid_lm(*, vocab: int = 3072, dim: int = 4096, heads: int = 64,
              f"the dropless expert layer dropped: {routing['dropped']}")
     _require(int(routing["assignments"].sum()) > 0,
              "no token was routed to a held expert")
-    from distlearn_tpu.ops.delta_rule import delta_rule_paths_traced
+    from distlearn_tpu.ops.delta_rule import (delta_rule_paths_traced,
+                                              delta_rule_scan_paths_traced)
     from distlearn_tpu.parallel.ep import grouped_paths_traced
     step = build_lm_step(model, mesh, params, lr=lr)
     moe_before, rule_before = grouped_paths_traced(), delta_rule_paths_traced()
+    scan_before = delta_rule_scan_paths_traced()
     lowered, traced = _lower_default(step, params, tokens, "hybrid LM")
     moe_traced = _moved(moe_before, grouped_paths_traced())
     rule_traced = _moved(rule_before, delta_rule_paths_traced())
+    scan_traced = _moved(scan_before, delta_rule_scan_paths_traced())
     kernels = re.findall(r'kernel_name = "([^"]*)"', lowered.as_text())
     mosaic = sum(name.startswith("splash_mqa") for name in kernels)
     rule = sorted(name for name in kernels if name.startswith("delta_rule"))
@@ -376,18 +379,21 @@ def phase_hybrid_lm(*, vocab: int = 3072, dim: int = 4096, heads: int = 64,
     # stays the loop, with no kernel of its own (moe_grouped_total); each of
     # the three delta-rule layers makes its per-chunk operands by a kernel
     # in the forward and the recomputed forward and pulls back by another
-    # (delta_rule_total), and nothing else in the step is a Mosaic call
+    # (delta_rule_total), and scans its chunks the same way
+    # (delta_rule_scan_total), and nothing else in the step is a Mosaic call
     _require((mosaic == 2 and set(moe_traced) == {"xla"}
               and rule == ["delta_rule_operands"] * 6
-              + ["delta_rule_operands_vjp"] * 3
-              and set(rule_traced) == {"kernel"}
+              + ["delta_rule_operands_vjp"] * 3 + ["delta_rule_scan"] * 6
+              + ["delta_rule_scan_vjp"] * 3
+              and set(rule_traced) == set(scan_traced) == {"kernel"}
               and len(kernels) == mosaic + len(rule))
              or jax.default_backend() != "tpu",
              f"hybrid LM: Mosaic calls {kernels} in the rematerialised step, "
              "expected 2 of its one softmax layer (forward and backward "
-             "kernel) and 9 of its three delta-rule layers (two forwards "
-             f"and a pull-back each); grouped products traced as "
-             f"{moe_traced}, the delta rule as {rule_traced}")
+             "kernel) and 18 of its three delta-rule layers (two forwards "
+             "and a pull-back each of the operands and of the scan); grouped "
+             f"products traced as {moe_traced}, the delta rule as "
+             f"{rule_traced}, its scan as {scan_traced}")
     losses = []
     for _ in range(steps):
         params, loss = step(params, tokens)
@@ -396,7 +402,8 @@ def phase_hybrid_lm(*, vocab: int = 3072, dim: int = 4096, heads: int = 64,
     _require(losses[-1] < losses[0], f"hybrid LM loss did not fall: {losses}")
     return {"dim": dim, "seq": seq, "held": list(held), "attn_kernels": traced,
             "moe_grouped": moe_traced, "mosaic_calls": mosaic,
-            "delta_rule": rule_traced, "delta_rule_calls": len(rule),
+            "delta_rule": rule_traced, "delta_rule_scan": scan_traced,
+            "delta_rule_calls": len(rule),
             "losses": [round(l, 4) for l in losses],
             "assignments": routing["assignments"].tolist(),
             "unheld_frac": [round(float(x), 4)

@@ -70,6 +70,22 @@ written straight into the scan's layout; elsewhere ``jnp``, a group of
 writes 2 GB of exponentials or a dozen lane-padded 512 MB slices: the step
 no longer fits the chip), with no checkpoint of its own.
 
+The scan has two evaluations too (:func:`select_delta_scan`, from the same
+things and the chunk count).  On the TPU in bfloat16 two Pallas kernels
+(:func:`_scan_kernel`, :func:`_scan_kernel_vjp`): a grid step carries
+:data:`SCAN_HEADS` heads through :data:`SCAN_CHUNKS` chunks, and each
+head's float32 state stays in VMEM across all of its chunks, so HBM sees
+the operands, O and — only in the forward rule, for the pull-back — the
+state each chunk starts from; the primal, which a rematerialised step's
+forward pass runs, writes O and the last state alone (``optimize_remat``:
+a Pallas call's unused output is never dropped, as a ``lax.scan``'s is).
+Inside a grid step a loop walks the chunks, the heads unrolled under each.
+The sweep of (heads, chunks) a step is at :data:`SCAN_HEADS`: eight heads
+interleaved fill the matrix unit, and a layer's three calls take 10.7 ms
+on the v5e where the ``lax.scan`` and its glue took about 25.  Elsewhere
+the ``lax.scan`` over all heads, a loop whose carry XLA keeps in HBM: the
+CPU's path and the kernels' reference in the tests.
+
 Every exponent is ``<= 0``: a difference ``G_t - G_s`` is never split into
 two factors one of which could overflow.  Pairs inside one sub-block of
 ``sub`` positions are summed channel by channel with their own exponent;
@@ -259,14 +275,37 @@ def _new_values(S, Wv, Wk, cd):
     return Wv - _mm(Wk, S, "hbck,hbkv->hbcv", cd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
-def _carry_state(S0, Wv, Wk, Qd, Aqk, Kd, gam, cd):
-    """The scan: per-chunk operands stacked on axis 0 ([N, H, B, ...]),
-    ``S0`` [H, B, K, V] float32.  Returns ``(O [N, H, B, C, V], S_N)``."""
-    return _carry_fwd(S0, Wv, Wk, Qd, Aqk, Kd, gam, cd)[0]
+def _scan_major(a):
+    """[B, H, N, ...] -> [N, H, B, ...]: the ``lax.scan``'s leading axis."""
+    return jnp.moveaxis(a, 2, 0).swapaxes(1, 2)
 
 
-def _carry_fwd(S0, Wv, Wk, Qd, Aqk, Kd, gam, cd):
+def _head_major(a):
+    """[N, H, B, ...] -> [B, H, N, ...]."""
+    return jnp.moveaxis(a.swapaxes(1, 2), 0, 2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _carry_state(S0, Wv, Wk, Qd, Aqk, Kd, gam, cd, od, impl):
+    """The scan over the chunks, head-major: ``S0`` [B, H, K, V] float32,
+    the per-chunk operands [B, H, N, C, .] and ``gam`` [B, H, N, K].
+    Returns ``(O [B, H, L, V] in od, S_N [B, H, K, V] float32)``.  ``impl``
+    (:func:`select_delta_scan`): ``"kernel"`` the Pallas calls of
+    :func:`_scan_kernel` — this primal, which the forward pass of a
+    rematerialised step runs, the one that writes no kept states
+    (``optimize_remat``); ``"xla"`` a ``lax.scan`` over all heads."""
+    if impl == "kernel":
+        return _scan_kernel(S0, Wv, Wk, Qd, Aqk, Kd, gam, cd, od,
+                            keep=False)
+    return _carry_fwd(S0, Wv, Wk, Qd, Aqk, Kd, gam, cd, od, impl)[0]
+
+
+def _carry_fwd(S0, Wv, Wk, Qd, Aqk, Kd, gam, cd, od, impl):
+    res = (Wv, Wk, Qd, Aqk, Kd, gam)
+    if impl == "kernel":
+        O, S_end, S_starts = _scan_kernel(S0, *res, cd, od, keep=True)
+        return (O, S_end), (S_starts,) + res
+
     def body(S, xs):
         wv, wk, qd, aqk, kd, g = xs
         U = _new_values(S, wv, wk, cd)
@@ -274,13 +313,19 @@ def _carry_fwd(S0, Wv, Wk, Qd, Aqk, Kd, gam, cd):
             + _mm(aqk, U, "hbcs,hbsv->hbcv", cd)
         S_next = g[..., None] * S + _mm(kd, U, "hbck,hbcv->hbkv", cd)
         return S_next, (O, S)
-    S_end, (O, S_starts) = lax.scan(body, S0, (Wv, Wk, Qd, Aqk, Kd, gam))
-    return (O, S_end), (S_starts, Wv, Wk, Qd, Aqk, Kd, gam)
+    S_end, (O, S_starts) = lax.scan(body, jnp.swapaxes(S0, 0, 1),
+                                    tuple(_scan_major(a) for a in res))
+    O = _head_major(O).reshape(S0.shape[:2] + (-1, O.shape[-1]))
+    return (O.astype(od), jnp.swapaxes(S_end, 0, 1)), (S_starts,) + res
 
 
-def _carry_bwd(cd, res, cot):
-    S_starts, Wv, Wk, Qd, Aqk, Kd, gam = res
+def _carry_bwd(cd, od, impl, res, cot):
+    if impl == "kernel":
+        return _scan_kernel_vjp(*res, *cot, cd)
+    S_starts, *ops = res
     dO, dS_end = cot
+    dO = dO.reshape(ops[0].shape).astype(jnp.float32)
+    Wv, Wk, Qd, Aqk, Kd, gam, dO = (_scan_major(a) for a in (*ops, dO))
 
     def body(dS_next, xs):
         S, wv, wk, qd, aqk, kd, g, do = xs
@@ -297,13 +342,13 @@ def _carry_bwd(cd, res, cot):
                  jnp.sum(S * dS_next, axis=-1).astype(g.dtype))
         return dS, grads
 
-    dS0, grads = lax.scan(body, dS_end,
+    dS0, grads = lax.scan(body, jnp.swapaxes(dS_end, 0, 1),
                           (S_starts, Wv, Wk, Qd, Aqk, Kd, gam, dO),
                           reverse=True)
-    return (dS0,) + grads
+    return (jnp.swapaxes(dS0, 0, 1),) + tuple(_head_major(d) for d in grads)
 
 
-_carry_state.defvjp(_carry_fwd, _carry_bwd)
+_carry_state.defvjp(_carry_fwd, _carry_bwd, optimize_remat=True)
 
 
 # ------------------------------------------------ the per-chunk operands --
@@ -592,14 +637,14 @@ def _loaded(refs, i, L):
 
 def _specs(chunk):
     """The BlockSpecs of a grid ``(B, H, L / _ROWS)``: ``seq`` for
-    [B, H, L, X], ``ops`` for the scan's [N, H, B, C, X], ``blk`` for
+    [B, H, L, X], ``ops`` for the scan's [B, H, N, C, X], ``blk`` for
     [B, H, L / _ROWS, ...]."""
     from jax.experimental import pallas as pl
     nb = _ROWS // chunk
     seq = lambda X: pl.BlockSpec(                             # noqa: E731
         (None, None, _ROWS, X), lambda b, h, i: (b, h, i, 0))
     ops = lambda X: pl.BlockSpec(                             # noqa: E731
-        (nb, None, None, chunk, X), lambda b, h, i: (i, h, b, 0, 0))
+        (None, None, nb, chunk, X), lambda b, h, i: (b, h, i, 0, 0))
     blk = lambda r, c: pl.BlockSpec(                          # noqa: E731
         (None, None, None, r, c), lambda b, h, i: (b, h, i, 0, 0))
     return seq, ops, blk
@@ -647,7 +692,7 @@ def _kernel_fwd(q, k, v, g, beta, chunk, sub, cd, interpret):
         x_ref[...], akk_ref[...] = X, Akk
 
     seq, ops, blk = _specs(chunk)
-    scan = lambda X, dt: jax.ShapeDtypeStruct((N, H, B, chunk, X), dt)  # noqa
+    scan = lambda X, dt: jax.ShapeDtypeStruct((B, H, N, chunk, X), dt)  # noqa
     square = jax.ShapeDtypeStruct((B, H, nblk, _ROWS, _ROWS), jnp.float32)
     Wv, Wk, Qd, Aqk, Kd, gam, X, Akk = _pallas(
         kernel, (B, H, nblk),
@@ -660,7 +705,7 @@ def _kernel_fwd(q, k, v, g, beta, chunk, sub, cd, interpret):
          square, square], interpret, "delta_rule_operands")(
              q, k, v, g, _beta_rows(beta, nblk))
     gam = gam[:, :, :, :nb].reshape(B, H, nblk * nb, K)[:, :, :N]
-    return (Wv, Wk, Qd, Aqk, Kd, gam.transpose(2, 1, 0, 3)), (X, Akk)
+    return (Wv, Wk, Qd, Aqk, Kd, gam), (X, Akk)
 
 
 def _kernel_bwd(q, k, v, g, beta, X, Akk, cot, chunk, sub, cd, interpret):
@@ -671,8 +716,8 @@ def _kernel_bwd(q, k, v, g, beta, X, Akk, cot, chunk, sub, cd, interpret):
     V = v.shape[-1]
     N, nb, nblk = L // chunk, _ROWS // chunk, -(-L // _ROWS)
     *cot, dgam = cot
-    # [N, H, B, K] -> [B, H, nblk, _GAM_ROWS, K]: a chunk a row
-    dgam = jnp.pad(dgam.transpose(2, 1, 0, 3).astype(jnp.float32),
+    # [B, H, N, K] -> [B, H, nblk, _GAM_ROWS, K]: a chunk a row
+    dgam = jnp.pad(dgam.astype(jnp.float32),
                    ((0, 0), (0, 0), (0, nblk * nb - N), (0, 0)))
     dgam = jnp.pad(dgam.reshape(B, H, nblk, nb, K),
                    ((0, 0),) * 3 + ((0, _GAM_ROWS - nb), (0, 0)))
@@ -704,6 +749,205 @@ def _kernel_bwd(q, k, v, g, beta, X, Akk, cot, chunk, sub, cd, interpret):
     return (*grads, dbeta.astype(beta.dtype))
 
 
+# ------------------------------------------- the scan as Pallas kernels --
+#
+# A grid ``(B, H / hb, N / nc)``: a step carries ``hb`` heads through ``nc``
+# chunks, the chunk axis sequential ("arbitrary"; the pull-back walks it
+# backwards through its index maps).  Each head's state lives in a VMEM
+# scratch for the whole walk, TRANSPOSED (``T = S^T`` [V, K]): ``gam``, a
+# row over K, then scales it along the lanes, and no product needs more
+# than the transposes the matrix unit's operand load makes.  Inside a step
+# a loop walks the chunks and the heads are unrolled under each, so that
+# their independent chains of small products interleave on the matrix unit
+# (a head alone is a chain of two dependent 32-row products a chunk).  The
+# chunks stay a loop: every process traces and lowers a kernel's body anew,
+# and ``hb x nc`` unrolled bodies a call cost the cell seconds of set-up.
+
+#: heads and chunks a grid step of the scan's kernels carries; swept on the
+#: v5e at [1, 64, 8192, 128] bf16, a layer's three calls (primal, keeping,
+#: pull-back; ms), the chunks a loop: 4 x 16 11.30, 8 x 8 10.66,
+#: 8 x 16 10.62, 8 x 32 10.65, 16 x 8 10.64, 16 x 16 10.66 (the lax.scan
+#: 27.6 without its primal; the chunks unrolled, 1 x 8 22.57, 2 x 8 13.57,
+#: 4 x 8 10.91, 8 x 8 10.55, 16 x 8 10.42): eight heads fill the matrix
+#: unit and the chunks a step do not matter (PERF.md section 6, PR 39).
+#: One value each, no argument.
+SCAN_HEADS = 8
+SCAN_CHUNKS = 8
+
+
+def _scan_block(H, N):
+    """``(hb, nc)`` of a call: heads and chunks a grid step."""
+    return math.gcd(H, SCAN_HEADS), SCAN_CHUNKS if N % SCAN_CHUNKS == 0 else N
+
+
+def _scan_specs(B, H, N, C, V, K, reverse):
+    """The BlockSpecs of the grid ``(B, H / hb, N / nc)`` and ``(hb, nc)``:
+    ``per`` for the operands, O and dO [B, H, N, C, X], ``gams`` for
+    [B, H, N, K], ``kept`` for the kept states [B, H, N, V, K] and
+    ``state`` for a state [B, H, V, K] (the same block over the walk)."""
+    from jax.experimental import pallas as pl
+    hb, nc = _scan_block(H, N)
+    last = N // nc - 1
+    at = (lambda i: last - i) if reverse else (lambda i: i)   # noqa: E731
+    per = lambda X: pl.BlockSpec(                             # noqa: E731
+        (None, hb, nc, C, X), lambda b, h, i: (b, h, at(i), 0, 0))
+    gams = pl.BlockSpec((None, hb, nc, K), lambda b, h, i: (b, h, at(i), 0))
+    kept = pl.BlockSpec((None, hb, nc, V, K),
+                        lambda b, h, i: (b, h, at(i), 0, 0))
+    state = pl.BlockSpec((None, hb, V, K), lambda b, h, i: (b, h, 0, 0))
+    return per, gams, kept, state, (hb, nc)
+
+
+def _vmem_bytes(specs, dtypes):
+    """What a grid step's blocks take in VMEM, double-buffered, with their
+    two minor dimensions padded to whole tiles."""
+    total = 0
+    for spec, dt in zip(specs, dtypes):
+        *lead, r, c = (1 if d is None else d for d in spec.block_shape)
+        total += math.prod(lead) * -(-r // 16) * 16 * -(-c // 128) * 128 \
+            * jnp.dtype(dt).itemsize
+    return 2 * total
+
+
+def _scan_call(kernel, grid, in_specs, args, out_specs, out_shape, name):
+    """One Pallas call of the scan on ``args`` (interpret mode off the
+    TPU), ``in_specs[0]`` a state's: a VMEM scratch for the ``hb`` states
+    [V, K] of a step, and the VMEM its blocks take."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    hb, V, K = in_specs[0].block_shape[1:]
+    vmem = _vmem_bytes(in_specs + out_specs,
+                       [a.dtype for a in args] + [o.dtype for o in out_shape])
+    return pl.pallas_call(
+        kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((hb, V, K), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem + hb * V * K * 4 + (8 << 20)),
+        interpret=sequence._backend() != "tpu", name=name)(*args)
+
+
+def _scan_kernel(S0, Wv, Wk, Qd, Aqk, Kd, gam, cd, od, keep):
+    """:func:`_carry_state`'s forward by one Pallas call (interpret mode off
+    the TPU): ``(O [B, H, L, V] in od, S_N)``, and with ``keep`` the state
+    each chunk starts from, [B, H, N, V, K] (transposed, as it lives)."""
+    from jax.experimental import pallas as pl
+    B, H, N, C, V = Wv.shape
+    K = Wk.shape[-1]
+    per, gams, kept, state, (hb, nc) = _scan_specs(B, H, N, C, V, K, False)
+
+    def kernel(s0_ref, wv_ref, wk_ref, qd_ref, aqk_ref, kd_ref, gam_ref,
+               o_ref, end_ref, *rest):
+        *starts, t_ref = rest
+        i = pl.program_id(2)
+
+        @pl.when(i == 0)
+        def _():
+            t_ref[...] = s0_ref[...]
+
+        def chunk(n, carry):
+            for h in range(hb):
+                T = t_ref[h]
+                if starts:
+                    starts[0][h, n] = T
+                Tc = T.astype(cd)
+                # Wk S and Qd S: one pass over the state
+                P = _dot_cd(jnp.concatenate([wk_ref[h, n], qd_ref[h, n]]),
+                            Tc, ((1,), (1,)), cd)
+                U = (wv_ref[h, n] - P[:C]).astype(cd)
+                O = P[C:] + _dot_cd(aqk_ref[h, n], U, ((1,), (0,)), cd)
+                o_ref[h, n] = O.astype(o_ref.dtype)
+                t_ref[h] = T * gam_ref[h, pl.ds(n, 1), :] \
+                    + _dot_cd(U, kd_ref[h, n], ((0,), (0,)), cd)
+            return carry
+
+        lax.fori_loop(0, nc, chunk, 0)
+
+        @pl.when(i == pl.num_programs(2) - 1)
+        def _():
+            end_ref[...] = t_ref[...]
+
+    f32 = jnp.float32
+    out_specs = [per(V), state] + ([kept] if keep else [])
+    out_shape = [jax.ShapeDtypeStruct((B, H, N, C, V), od),
+                 jax.ShapeDtypeStruct((B, H, V, K), f32)]
+    if keep:
+        out_shape.append(jax.ShapeDtypeStruct((B, H, N, V, K), f32))
+    out = _scan_call(
+        kernel, (B, H // hb, N // nc),
+        [state, per(V), per(K), per(K), per(C), per(K), gams],
+        (jnp.swapaxes(S0, 2, 3), Wv, Wk, Qd, Aqk, Kd, gam), out_specs,
+        out_shape, "delta_rule_scan")
+    return (out[0].reshape(B, H, N * C, V), jnp.swapaxes(out[1], 2, 3)) \
+        + tuple(out[2:])
+
+
+def _scan_kernel_vjp(S_starts, Wv, Wk, Qd, Aqk, Kd, gam, dO, dS_end, cd):
+    """:func:`_carry_state`'s pull-back by one Pallas call, the chunks walked
+    backwards, ``U`` recomputed from the kept state: ``(dS0, dWv, dWk,
+    dQd, dAqk, dKd, dgam)`` in the shapes and dtypes of the primal's
+    arguments."""
+    from jax.experimental import pallas as pl
+    B, H, N, C, V = Wv.shape
+    K = Wk.shape[-1]
+    per, gams, kept, state, (hb, nc) = _scan_specs(B, H, N, C, V, K, True)
+
+    def kernel(end_ref, kept_ref, wv_ref, wk_ref, qd_ref, aqk_ref, kd_ref,
+               gam_ref, do_ref, ds0_ref, dwv_ref, dwk_ref, dqd_ref, daqk_ref,
+               dkd_ref, dgam_ref, e_ref):
+        i = pl.program_id(2)
+
+        @pl.when(i == 0)
+        def _():
+            e_ref[...] = end_ref[...]
+
+        def chunk(j, carry):
+            n = nc - 1 - j
+            for h in range(hb):
+                T, E = kept_ref[h, n], e_ref[h]    # S^T and dS_next^T
+                Tc, Ec = T.astype(cd), E.astype(cd)
+                wk, kd = wk_ref[h, n], kd_ref[h, n]
+                do = do_ref[h, n].astype(cd)
+                U = (wv_ref[h, n] - _dot_cd(wk, Tc, ((1,), (1,)), cd)
+                     ).astype(cd)
+                dU = _dot_cd(aqk_ref[h, n], do, ((0,), (0,)), cd) \
+                    + _dot_cd(kd, Ec, ((1,), (1,)), cd)
+                both = jnp.concatenate([do, dU.astype(cd)])
+                # dS^T = dO^T Qd - dU^T Wk + gam dS_next^T
+                e_ref[h] = E * gam_ref[h, pl.ds(n, 1), :] + _dot_cd(
+                    both, jnp.concatenate([qd_ref[h, n], -wk]),
+                    ((0,), (0,)), cd)
+                dqk = _dot_cd(both, Tc, ((1,), (0,)), cd)  # dQd; -dWk
+                dwv_ref[h, n] = dU.astype(dwv_ref.dtype)
+                dqd_ref[h, n] = dqk[:C].astype(dqd_ref.dtype)
+                dwk_ref[h, n] = (-dqk[C:]).astype(dwk_ref.dtype)
+                daqk_ref[h, n] = _dot_cd(do, U, ((1,), (1,)), cd).astype(
+                    daqk_ref.dtype)
+                dkd_ref[h, n] = _dot_cd(U, Ec, ((1,), (0,)), cd).astype(
+                    dkd_ref.dtype)
+                dgam_ref[h, pl.ds(n, 1), :] = jnp.sum(T * E, axis=0,
+                                                      keepdims=True)
+            return carry
+
+        lax.fori_loop(0, nc, chunk, 0)
+
+        @pl.when(i == pl.num_programs(2) - 1)
+        def _():
+            ds0_ref[...] = e_ref[...]
+
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)   # noqa: E731
+    out = _scan_call(
+        kernel, (B, H // hb, N // nc),
+        [state, kept, per(V), per(K), per(K), per(C), per(K), gams, per(V)],
+        (jnp.swapaxes(dS_end.astype(jnp.float32), 2, 3), S_starts, Wv, Wk,
+         Qd, Aqk, Kd, gam, dO.reshape(B, H, N, C, V)),
+        [state, per(V), per(K), per(K), per(C), per(K), gams],
+        [jax.ShapeDtypeStruct((B, H, V, K), jnp.float32), like(Wv), like(Wk),
+         like(Qd), like(Aqk), like(Kd), like(gam)], "delta_rule_scan_vjp")
+    return (jnp.swapaxes(out[0], 2, 3),) + tuple(out[1:])
+
+
 # ------------------------------------------------------ the path, and both --
 
 #: the implementations of the per-chunk operands (:func:`select_delta_rule`)
@@ -729,6 +973,22 @@ def select_delta_rule(backend: str, dtype, K: int, V: int, chunk: int,
     return "xla"
 
 
+def select_delta_scan(backend: str, dtype, K: int, V: int, chunk: int,
+                      N: int) -> str:
+    """The path of a :func:`chunked_delta_rule` call's scan over its ``N``
+    chunks (:func:`_carry_state`), from what the call shows and nothing
+    else: ``"kernel"`` (the two Pallas calls, each head's state in VMEM
+    across the chunks) on the TPU in bfloat16, at widths the lanes tile
+    (multiples of 128), a chunk of whole bfloat16 tiles (a multiple of 16
+    positions) and a chunk count that :data:`SCAN_CHUNKS` divides or that
+    is smaller; everything else ``"xla"``, the ``lax.scan``."""
+    if (backend == "tpu" and jnp.dtype(dtype) == jnp.dtype(jnp.bfloat16)
+            and K % 128 == 0 and V % 128 == 0 and chunk % 16 == 0
+            and (N % SCAN_CHUNKS == 0 or N < SCAN_CHUNKS)):
+        return "kernel"
+    return "xla"
+
+
 def _path_counter():
     return obs.counter(
         "delta_rule_total",
@@ -736,13 +996,29 @@ def _path_counter():
         "resolved implementation", labels=("impl",))
 
 
-def delta_rule_paths_traced() -> dict[str, int]:
-    """``{impl: chunked_delta_rule calls traced so far}`` in this process
-    (the ``delta_rule_total`` counter; empty with ``DISTLEARN_OBS=0``)."""
-    family = _path_counter()
+def _by_impl(family) -> dict[str, int]:
     if family is obs.NULL:
         return {}
     return {s["labels"]["impl"]: s["value"] for s in family.sample()}
+
+
+def delta_rule_paths_traced() -> dict[str, int]:
+    """``{impl: chunked_delta_rule calls traced so far}`` in this process
+    (the ``delta_rule_total`` counter; empty with ``DISTLEARN_OBS=0``)."""
+    return _by_impl(_path_counter())
+
+
+def _scan_counter():
+    return obs.counter(
+        "delta_rule_scan_total",
+        "chunked_delta_rule calls traced, by the scan over the chunks' "
+        "resolved implementation", labels=("impl",))
+
+
+def delta_rule_scan_paths_traced() -> dict[str, int]:
+    """``{impl: chunked_delta_rule calls traced so far}`` by the scan's path
+    (the ``delta_rule_scan_total`` counter; empty with ``DISTLEARN_OBS=0``)."""
+    return _by_impl(_scan_counter())
 
 
 def _grouped(a, chunk):
@@ -753,15 +1029,15 @@ def _grouped(a, chunk):
         1, 2, 0, 3, 4, 5)
 
 
-def _scan_major(a):
-    """[H / hg, hg, B, N, ...] -> [N, H, B, ...]: the scan's leading axis."""
-    return jnp.moveaxis(a.reshape((-1,) + a.shape[2:]), 2, 0)
+def _ungrouped(a):
+    """[H / hg, hg, B, N, ...] -> [B, H, N, ...]."""
+    return jnp.swapaxes(a.reshape((-1,) + a.shape[2:]), 0, 1)
 
 
 def _group_major(a):
-    """[N, H, B, ...] -> [H / hg, hg, B, N, ...], float32."""
+    """[B, H, N, ...] -> [H / hg, hg, B, N, ...], float32."""
     hg = math.gcd(a.shape[1], HEAD_GROUP)
-    a = jnp.moveaxis(a.astype(jnp.float32), 0, 2)
+    a = jnp.swapaxes(a.astype(jnp.float32), 0, 1)
     return a.reshape((-1, hg) + a.shape[1:])
 
 
@@ -771,7 +1047,7 @@ def _f32(args):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
 def _chunk_operands(q, k, v, g, beta, chunk, sub, cd, impl):
-    """The six operands of :func:`_carry_state` ([N, H, B, ...]; ``Wv`` and
+    """The six operands of :func:`_carry_state` ([B, H, N, ...]; ``Wv`` and
     ``gam`` float32, the others in ``cd``) from head-major q, k, v, g
     [B, H, L, X] and beta [B, H, L], differentiated by hand: the backward
     pass keeps the inputs and the two [C, C] matrices ``X`` and ``Akk`` a
@@ -792,7 +1068,7 @@ def _chunk_operands_fwd(q, k, v, g, beta, chunk, sub, cd, impl):
     ops, kept = lax.map(
         lambda a: _operands(*_f32(a), sub, cd),
         tuple(_grouped(a, chunk) for a in (q, k, v, g, beta[..., None])))
-    Wv, *mid, gam = (_scan_major(a) for a in ops)
+    Wv, *mid, gam = (_ungrouped(a) for a in ops)
     return (Wv, *(a.astype(cd) for a in mid), gam), args + kept
 
 
@@ -827,9 +1103,10 @@ def chunked_delta_rule(q, k, v, g, beta, *, initial_state=None,
     each head's positions: a caller whose projections write [B, H, L, .]
     directly (a product's output layout is free) pays no transpose at all.
 
-    The per-chunk operands are made :data:`HEAD_GROUP` heads at a time
-    (:func:`_chunk_operands`), while the scan over the chunks — a short
-    chain of small products — runs once for all heads."""
+    The per-chunk operands (:func:`_chunk_operands`) and the scan over the
+    chunks (:func:`_carry_state`) each take the path that
+    :func:`select_delta_rule` / :func:`select_delta_scan` give the call,
+    counted under ``delta_rule_total`` / ``delta_rule_scan_total``."""
     B, H, L, K = q.shape
     V = v.shape[-1]
     chunk, sub = CHUNK, SUB
@@ -839,10 +1116,10 @@ def chunked_delta_rule(q, k, v, g, beta, *, initial_state=None,
             f"chunk a power-of-two multiple of sub={sub}")
     cd = compute_dtype or v.dtype
     impl = select_delta_rule(sequence._backend(), cd, K, V, chunk, sub)
+    scan = select_delta_scan(sequence._backend(), cd, K, V, chunk, L // chunk)
     _path_counter().labels(impl=impl).inc()
+    _scan_counter().labels(impl=scan).inc()
     ops = _chunk_operands(q, k, v, g, beta, chunk, sub, cd, impl)
-    S0 = jnp.zeros((H, B, K, V), jnp.float32) if initial_state is None \
-        else jnp.swapaxes(initial_state.astype(jnp.float32), 0, 1)
-    O, S_end = _carry_state(S0, *ops, cd)                    # [N, H, B, C, V]
-    o = O.transpose(2, 1, 0, 3, 4).reshape(B, H, L, V)
-    return o.astype(v.dtype), jnp.swapaxes(S_end, 0, 1)
+    S0 = jnp.zeros((B, H, K, V), jnp.float32) if initial_state is None \
+        else initial_state.astype(jnp.float32)
+    return _carry_state(S0, *ops, cd, v.dtype, scan)

@@ -46,7 +46,8 @@ def test_hybrid_lm_phase_toy():
     assert res["losses"][-1] < res["losses"][0]
     assert res["attn_kernels"] == {"xla": 1} and res["mosaic_calls"] == 0
     assert set(res["moe_grouped"]) == {"xla"}
-    assert set(res["delta_rule"]) == {"xla"} and res["delta_rule_calls"] == 0
+    assert set(res["delta_rule"]) == set(res["delta_rule_scan"]) == {"xla"}
+    assert res["delta_rule_calls"] == 0
     assert np.shape(res["assignments"]) == (4, 2)
 
 

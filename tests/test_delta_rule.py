@@ -1,10 +1,10 @@
 """The chunked gated delta rule (``ops/delta_rule.py``) against its
 recurrence, position by position: forward, the hand-written backward passes
 of the per-chunk operands (against autodiff of their plain forward) and of
-the scan over the chunks, the operands' Pallas kernels in interpret mode
-against the ``jnp`` path, a non-zero initial state, step sizes above 1
-(negative eigenvalues), and a decay so strong that any split of an exponent
-into an overflowing factor would show."""
+the scan over the chunks, the operands' and the scan's Pallas kernels in
+interpret mode against the ``jnp`` / ``lax.scan`` paths, a non-zero initial
+state, step sizes above 1 (negative eigenvalues), and a decay so strong
+that any split of an exponent into an overflowing factor would show."""
 
 import jax
 import jax.numpy as jnp
@@ -12,9 +12,9 @@ import numpy as np
 import pytest
 
 from distlearn_tpu.ops import delta_rule
-from distlearn_tpu.ops.delta_rule import (_block_inverse, _chunk_operands,
-                                          _operands, _operands_vjp,
-                                          _unit_lower_inverse,
+from distlearn_tpu.ops.delta_rule import (_block_inverse, _carry_state,
+                                          _chunk_operands, _operands,
+                                          _operands_vjp, _unit_lower_inverse,
                                           chunked_delta_rule)
 
 
@@ -26,9 +26,12 @@ def _sizes(monkeypatch, chunk, sub):
 
 
 def _path(monkeypatch, path):
-    """The op chooses its path from the call (``select_delta_rule``); the
-    tests walk both on the CPU, the kernels in Pallas interpret mode."""
+    """The op chooses its paths from the call (``select_delta_rule`` for
+    the per-chunk operands, ``select_delta_scan`` for the scan over the
+    chunks); the tests walk both on the CPU, the kernels in Pallas
+    interpret mode."""
     monkeypatch.setattr(delta_rule, "select_delta_rule", lambda *a: path)
+    monkeypatch.setattr(delta_rule, "select_delta_scan", lambda *a: path)
 
 
 def recurrence(q, k, v, g, beta, S0):
@@ -218,3 +221,160 @@ def test_shapes_that_do_not_tile_are_refused(monkeypatch, L, chunk, sub):
     q, k, v, g, beta, _ = _inputs(L=L)
     with pytest.raises(ValueError, match="multiple of"):
         chunked_delta_rule(q, k, v, g, beta)
+
+
+
+# ------------------------------------------------ the scan's two kernels --
+
+def _scan_args(B, H, N, C, K, V, cd):
+    """What ``_chunk_operands`` hands the scan, head-major: a non-zero
+    initial state, the five operands (``Wv`` float32, the others in
+    ``cd``) and decays in (0, 1)."""
+    ks = jax.random.split(jax.random.PRNGKey(5), 7)
+    n = lambda i, *s: jax.random.normal(ks[i], s, jnp.float32)  # noqa: E731
+    return (n(0, B, H, K, V), n(1, B, H, N, C, V),
+            (0.2 * n(2, B, H, N, C, K)).astype(cd),
+            (0.2 * n(3, B, H, N, C, K)).astype(cd),
+            jnp.tril(0.3 * n(4, B, H, N, C, C)).astype(cd),
+            (0.2 * n(5, B, H, N, C, K)).astype(cd),
+            jax.nn.sigmoid(n(6, B, H, N, K) + 2.0))
+
+
+def _blocks(monkeypatch, heads, chunks):
+    """The scan's kernels carry SCAN_HEADS heads through SCAN_CHUNKS chunks
+    a grid step (module constants, no argument); the tests set them small."""
+    monkeypatch.setattr(delta_rule, "SCAN_HEADS", heads)
+    monkeypatch.setattr(delta_rule, "SCAN_CHUNKS", chunks)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("B,H,N,heads,chunks", [
+    (1, 2, 6, 2, 2),        # one head block, three chunk blocks
+    (2, 4, 4, 2, 2),        # two head blocks, two chunk blocks, B = 2
+    (1, 3, 3, 1, 8),        # fewer chunks than a block: one block of three
+])
+def test_scan_kernels_are_the_lax_scan(monkeypatch, dtype, B, H, N, heads,
+                                       chunks):
+    """The scan's two Pallas calls (interpret mode here) against the
+    ``lax.scan`` path of the same ``custom_vjp``: O, the last state and all
+    seven cotangents, from a non-zero initial state, with one head block
+    and several, where the chunk block divides the chunks and where the
+    chunks are fewer than a block."""
+    _blocks(monkeypatch, heads, chunks)
+    cd = jnp.dtype(dtype)
+    args = _scan_args(B, H, N, 16, 16, 8, cd)
+    got, want = [], []
+    for impl, out in (("kernel", got), ("xla", want)):
+        res, pull = jax.vjp(lambda *a: _carry_state(*a, cd, cd, impl), *args)
+        keys = jax.random.split(jax.random.PRNGKey(9), 2)
+        out += list(res) + list(pull(tuple(
+            jax.random.normal(key, r.shape, jnp.float32).astype(r.dtype)
+            for key, r in zip(keys, res))))
+    # bfloat16: the kernel's backward sums dO^T Qd - dU^T Wk in one
+    # accumulation where the scan rounds the two apart, so a carried dS
+    # may round to another bfloat16 in the next chunk's products
+    tol = 1e-5 if dtype == "float32" else 1e-2
+    names = "O S_end dS0 dWv dWk dQd dAqk dKd dgam".split()
+    for name, a, b in zip(names, got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(a, b, rtol=tol,
+                                   atol=tol * float(np.abs(b).max()),
+                                   err_msg=name)
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr``, those of the jaxprs it holds included,
+    each before the ones it holds."""
+    from jax.extend import core
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (list, tuple)) else [p]:
+                if isinstance(sub, core.ClosedJaxpr):
+                    yield from _eqns(sub.jaxpr)
+                elif isinstance(sub, core.Jaxpr):
+                    yield from _eqns(sub)
+
+
+def _pallas_calls(jaxpr):
+    """``(name, outputs, equations in its kernel)`` of every Pallas call in
+    ``jaxpr``, nested ones included."""
+    return [(getattr(e.params["name"], "name", e.params["name"]),
+             len(e.outvars), sum(1 for _ in _eqns(e.params["jaxpr"])))
+            for e in _eqns(jaxpr) if e.primitive.name == "pallas_call"]
+
+
+def test_the_primal_writes_no_kept_states(monkeypatch):
+    """A ``pallas_call`` output is never dropped, so the scan's primal is a
+    kernel with two outputs (O and the last state) and only the
+    ``custom_vjp``'s forward rule writes the state a chunk starts from:
+    the call alone, and the forward pass of a rematerialised gradient
+    (``optimize_remat``), take the first; ``vjp`` and the recomputation the
+    second, and the pull-back its own kernel."""
+    _blocks(monkeypatch, 2, 2)
+    args = _scan_args(1, 2, 4, 16, 16, 8, jnp.float32)
+    scan = lambda *a: _carry_state(*a, jnp.float32, jnp.float32,  # noqa
+                                   "kernel")
+    loss = lambda *a: jnp.sum(scan(*a)[0] ** 2)              # noqa: E731
+    calls = lambda f: [c[:2] for c in _pallas_calls(              # noqa
+        jax.make_jaxpr(f)(*args).jaxpr)]
+    primal = [("delta_rule_scan", 2)]
+    keep = [("delta_rule_scan", 3)]
+    pull = [("delta_rule_scan_vjp", 7)]
+    assert calls(scan) == primal
+    assert calls(lambda *a: jax.vjp(scan, *a)[0]) == keep
+    assert calls(jax.grad(loss)) == keep + pull
+    assert calls(jax.value_and_grad(jax.checkpoint(loss))) \
+        == primal + keep + pull
+
+
+def test_the_chunk_walk_is_a_loop(monkeypatch):
+    """A grid step walks its chunks in a loop, its heads unrolled inside:
+    the kernels' bodies, which every process traces and lowers anew (no
+    compile cache saves that part of set-up), do not grow with
+    SCAN_CHUNKS — unrolled, 8 x 8 bodies a call added seconds to the
+    cell's ``setup_s`` on the chip (PERF.md section 6, PR 39)."""
+    args = _scan_args(1, 2, 8, 16, 16, 8, jnp.float32)
+    grad = jax.grad(lambda *a: jnp.sum(_carry_state(
+        *a, jnp.float32, jnp.float32, "kernel")[0]))
+    sizes = []
+    for chunks in (2, 8):
+        _blocks(monkeypatch, 2, chunks)
+        sizes.append(_pallas_calls(jax.make_jaxpr(grad)(*args).jaxpr))
+    assert [c[:2] for c in sizes[0]] == [("delta_rule_scan", 3),
+                                         ("delta_rule_scan_vjp", 7)]
+    assert sizes[0] == sizes[1]
+
+
+def test_the_scan_counter_counts_each_path(monkeypatch):
+    """``delta_rule_scan_total{impl=}`` moves once per traced
+    ``chunked_delta_rule`` call, under the scan's resolved path."""
+    _sizes(monkeypatch, 16, 4)
+    _blocks(monkeypatch, 2, 2)
+    q, k, v, g, beta, _ = _inputs(L=32, B=1, H=2)
+    for path in ("xla", "kernel"):
+        monkeypatch.setattr(delta_rule, "select_delta_scan",
+                            lambda *a, path=path: path)
+        before = delta_rule.delta_rule_scan_paths_traced()
+        jax.jit(lambda *a: chunked_delta_rule(*a)).lower(q, k, v, g, beta)
+        after = delta_rule.delta_rule_scan_paths_traced()
+        assert {p: n - before.get(p, 0) for p, n in after.items()
+                if n != before.get(p, 0)} == {path: 1}
+    assert set(after) <= set(delta_rule.DELTA_RULE_IMPLS)
+
+
+@pytest.mark.parametrize("backend,dtype,K,V,chunk,N,path", [
+    ("tpu", "bfloat16", 128, 128, 32, 256, "kernel"),   # the cell's call
+    ("tpu", "bfloat16", 256, 128, 16, 8, "kernel"),
+    ("tpu", "bfloat16", 128, 128, 32, 3, "kernel"),     # fewer than a block
+    ("tpu", "bfloat16", 128, 128, 32, 12, "xla"),       # the block does not
+    ("tpu", "bfloat16", 128, 96, 32, 256, "xla"),       # divide; narrow V
+    ("tpu", "bfloat16", 128, 128, 8, 256, "xla"),       # a chunk under a tile
+    ("tpu", "float32", 128, 128, 32, 256, "xla"),
+    ("cpu", "bfloat16", 128, 128, 32, 256, "xla"),
+])
+def test_select_delta_scan_table(backend, dtype, K, V, chunk, N, path):
+    assert delta_rule.SCAN_CHUNKS == 8
+    assert delta_rule.select_delta_scan(backend, jnp.dtype(dtype), K, V,
+                                        chunk, N) == path

@@ -523,9 +523,9 @@ def test_tpu_compiler_keeps_every_scope_at_gpt2_large_width(
     linear-attention layer, 2 experts held, 1 x 1024 tokens, a cut of the
     vocabulary) — the two scopes only it uses survive too, and its softmax
     layer's grouped queries ride the same two Mosaic calls, beside the
-    three of the delta rule's per-chunk operands (``ops/delta_rule.py``:
-    forward, recomputed forward, pull-back), which Mosaic takes at the
-    published head size.
+    three of the delta rule's per-chunk operands and three of its scan over
+    the chunks (``ops/delta_rule.py``: forward, recomputed forward,
+    pull-back each), which Mosaic takes at the published head size.
 
     ``local_attention`` picks its path from ``jax.default_backend()``,
     which here says "cpu" whatever the program is compiled for: ``"tpu"``
@@ -567,16 +567,35 @@ def test_tpu_compiler_keeps_every_scope_at_gpt2_large_width(
             r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
         assert len([c for c in mosaic if c.startswith(kernel)]) == 2
         # every other Mosaic call is the delta rule's (the dense model has
-        # none): its per-chunk operands in each pass — forward, recomputed
-        # forward, pull-back — under the layer's scope and the rule's name
-        rule = [table[c].replace("(", "/").replace(")", "/")
-                for c in mosaic if not c.startswith(kernel)]
-        assert len(rule) == (3 if kind == "hybrid" else 0)
+        # none): its per-chunk operands and its scan over the chunks in
+        # each pass — forward, recomputed forward, pull-back — under the
+        # layer's scope and the rule's name
+        rule = {c: table[c].replace("(", "/").replace(")", "/")
+                for c in mosaic if not c.startswith(kernel)}
         assert all("/linattn_core/" in n and "/delta_rule/" in n
-                   for n in rule), rule
-        assert sorted("vjp" if "delta_rule_operands_vjp" in n else
-                      "remat" if "rematted_computation" in n else "fwd"
-                      for n in rule) == ["fwd", "remat", "vjp"][:len(rule)]
+                   for n in rule.values()), rule
+        calls = sorted(
+            (re.search(r"delta_rule_(operands|scan)(_vjp)?", c).group(),
+             "remat" if "rematted_computation" in n else "")
+            for c, n in rule.items())
+        assert calls == ([] if kind == "dense" else [
+            ("delta_rule_operands", ""), ("delta_rule_operands", "remat"),
+            ("delta_rule_operands_vjp", ""), ("delta_rule_scan", ""),
+            ("delta_rule_scan", "remat"), ("delta_rule_scan_vjp", "")])
+        # the scan's forward pass writes O and the last state, never the
+        # 134 MB of states a chunk starts from ([1, 64, 32, 128, 128]:
+        # only the recomputation, for the pull-back); no loop walks the
+        # chunks and nothing zero-fills the kept states
+        kept = "f32[1,64,32,128,128]"
+        for c, n in rule.items():
+            if c.startswith("delta_rule_scan") and "_vjp" not in c:
+                out = re.search(rf"%{re.escape(c)} = ([^\n]*?) custom-call",
+                                text).group(1)
+                assert (kept in out) == ("rematted_computation" in n), out
+        assert not any("/delta_rule/" in n and n.endswith("/while")
+                       for n in names)
+        assert not re.search(rf"= {re.escape(kept)}[^\n]* broadcast\(",
+                             text)
         assert not square
         assert not any("rematted_computation" in n for n in kernels.values())
         passes = sorted(
@@ -662,9 +681,12 @@ def test_tpu_compiler_takes_the_delta_rule_kernels(v5e_chip, monkeypatch,
                                                    chunk, sub, B, H, L, K, V):
     """The two Pallas calls of the delta rule's per-chunk operands
     (``ops/delta_rule.py``), forward and pull-back, compiled for the v5e
-    at every kind of shape ``select_delta_rule`` sends them: Mosaic takes
-    the slices, reshapes and products, and the VMEM they need.  Nothing
-    runs; no number of this is a measurement."""
+    at every kind of shape ``select_delta_rule`` sends them, and behind
+    them the two of the scan over the chunks where ``select_delta_scan``
+    sends it there (the last shape's 26 chunks are not a multiple of the
+    scan's block: its ``lax.scan``): Mosaic takes the slices, reshapes and
+    products, and the VMEM they need.  Nothing runs; no number of this is
+    a measurement."""
     from jax.experimental.compilation_cache import compilation_cache
     from jax.sharding import SingleDeviceSharding
     from distlearn_tpu.ops import delta_rule
@@ -677,10 +699,14 @@ def test_tpu_compiler_takes_the_delta_rule_kernels(v5e_chip, monkeypatch,
     arg = lambda X, dt: jax.ShapeDtypeStruct(                   # noqa: E731
         (B, H, L) + X, dt, sharding=one)
 
+    scan = delta_rule.select_delta_scan("tpu", bf16, K, V, chunk, L // chunk)
+
     def loss(q, k, v, g, beta):
         ops = delta_rule._chunk_operands(q, k, v, g, beta, chunk, sub, bf16,
                                          "kernel")
-        return sum(jnp.sum(o.astype(jnp.float32)) for o in ops)
+        o, S = delta_rule._carry_state(jnp.ones((B, H, K, V)), *ops, bf16,
+                                       bf16, scan)
+        return jnp.sum(o.astype(jnp.float32)) + jnp.sum(S)
 
     cache_was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -694,8 +720,14 @@ def test_tpu_compiler_takes_the_delta_rule_kernels(v5e_chip, monkeypatch,
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
         compilation_cache.reset_cache()
-    assert text.count('custom_call_target="tpu_custom_call"') == 2
-    assert "delta_rule_operands_vjp" in text
+    kernels = re.findall(r'%[\w\-]*?(delta_rule_(?:operands|scan)(?:_vjp)?)'
+                         r'[\w.\-]* = [^\n]*'
+                         r'custom_call_target="tpu_custom_call"', text)
+    assert sorted(kernels) == ["delta_rule_operands",
+                               "delta_rule_operands_vjp"] + (
+        ["delta_rule_scan", "delta_rule_scan_vjp"] if scan == "kernel"
+        else [])
+    assert (scan == "kernel") == ((chunk, L) != (16, 416))
 
 
 def test_tpu_compiler_takes_the_windowed_kernel_at_its_published_widths(
