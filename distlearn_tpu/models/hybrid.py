@@ -1,7 +1,8 @@
 """A decoder-only LM whose layers are a PATTERN (:data:`LAYER_TYPES`, one
 name a layer): softmax attention with grouped queries — with an output gate
-(``"gqa"``), without one over the whole causal triangle (``"full"``), or
-without one over a causal band with rotary positions (``"window"``) — latent
+(``"gqa"``), over the whole causal triangle (``"full"``), or over a causal
+band with rotary positions (``"window"``), the last two with a gate a head
+or none and the first with rotary positions of its own or none — latent
 attention (``"mla"``: low-rank q and K/V paths, scores over a wider head than
 the values), or gated delta-rule linear attention (``"kda"``); and in every
 layer a routed mixture of gated-linear-unit experts (SwiGLU or ReGLU), beside
@@ -13,20 +14,29 @@ prediction module.  The second LM constructor beside
 keywords, so ``lm_loss`` and every LM step builder drive it unchanged.
 
 One layer (pre-norm, residual, no bias; no positional term but the rotation
-of a ``"window"`` or ``"mla"`` layer — elsewhere the causal mask, the
-convolution and the recurrence carry the order):
+of a ``"window"`` or ``"mla"`` layer and of a rotated ``"full"`` one —
+elsewhere the causal mask, the convolution and the recurrence carry the
+order):
 
     h = x + Mix(rmsnorm(x));        y = h + FFN(rmsnorm(h))
     FFN = MoE, or below ``dense_layers``  (silu(m Wg) * (m Wu)) Wd
 
-``Mix`` of a softmax layer, ``H`` query heads over ``Hkv`` K/V heads:
+``Mix`` of a softmax layer, ``H`` query heads (the layer's own: ``heads``
+may name them layer by layer) over ``Hkv`` K/V heads, ``u`` the normed
+input at position ``i``:
 
-    q, k, v = x Wq, x Wk, x Wv
+    q, k, v = u Wq, u Wk, u Wv
     "window":  q, k = rope(q, i), rope(k, i)        (models.transformer.rotary)
+    "full" with full_rope_theta:   the same at its own theta, over the first
+               R = full_rope_fraction x D of a head (the rest passed through),
+               at YaRN's frequencies with cos and sin x attention_factor
+               where full_rope_yarn is given
     allowed(i, j) = j <= i   and, "window" only,   i - j < window
-    a = softmax(q k^T / sqrt(D) + mask) v
-    "gqa":  out = (sigmoid(x Wg) * a) Wo            (elementwise gate)
-    "full", "window":  out = a Wo
+    a_h = softmax(q_h k_{h // (H/Hkv)}^T / sqrt(D) + mask) v_{h // (H/Hkv)}
+    "gqa":  out = (sigmoid(u Wg) * a) Wo            (elementwise gate, Wg [dim, H, D])
+    "full", "window" with attn_gate="head":
+            a_h <- sigmoid(u Wg)_h a_h;  out = a Wo  (a gate a head, Wg [dim, H])
+    "full", "window" otherwise:  out = a Wo
 
 ``Mix`` of an ``"mla"`` layer, ``H`` heads, ``u`` the normed input at
 position ``i`` (``nope`` + ``rope`` = the head size of the scores, ``dv``
@@ -78,7 +88,7 @@ whose logits and ``mtp_weight`` ride the returned state to
 Arithmetic: parameters in ``dtype`` (float32); the matrix products in
 ``compute_dtype``; in float32 regardless: the norms' statistics, the softmax
 of attention (inside the kernel), the rotary angles and the rotation, the
-KDA decay (softplus, exp, cumulative sums), ``beta``, the l2 norms, the triangular inverse and the carried state
+output gates' sigmoids, the KDA decay (softplus, exp, cumulative sums), ``beta``, the l2 norms, the triangular inverse and the carried state
 (``ops/delta_rule.py``), the router's scores (softmax or sigmoid) and its
 choice.
 """
@@ -86,21 +96,25 @@ choice.
 from __future__ import annotations
 
 import math
-from typing import Any, Sequence
+from typing import Any, Mapping, Sequence
 
 import jax
 import jax.numpy as jnp
 from jax import lax, random
 
+from distlearn_tpu import obs
 from distlearn_tpu.models.core import Model, checkpoint_block
-from distlearn_tpu.models.transformer import (ROPE_PAIRINGS, _norm_init,
-                                              _rmsnorm, rotary)
+from distlearn_tpu.models.transformer import (ROPE_PAIRINGS, Yarn,
+                                              _norm_init, _rmsnorm, rotary)
 from distlearn_tpu.ops.delta_rule import chunked_delta_rule
 from distlearn_tpu.parallel.ep import GATE_ACTS, ROUTER_SCORES, moe_held_ffn
 from distlearn_tpu.parallel.sequence import local_attention
 
 PyTree = Any
 LAYER_TYPES = ("gqa", "kda", "full", "window", "mla")
+#: the output gate a ``"full"`` or ``"window"`` layer may have (a ``"gqa"``
+#: layer's is always one a channel)
+ATTN_GATES = (None, "head")
 #: what the router of a layer may read: the experts' input (the norm after
 #: the mixer) or the layer's own input, un-normed, before the mixer
 ROUTER_INPUTS = ("ffn_norm", "layer_input")
@@ -127,29 +141,64 @@ def _l2norm(x):
     return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
 
+def _gate_counter():
+    return obs.counter(
+        "attn_gate_total", "softmax-layer mixers traced with an output gate, "
+        "by gate: one a channel (element) or one a head (head)",
+        labels=("gate",))
+
+
+def attn_gates_traced() -> dict[str, int]:
+    """``{gate: softmax-layer mixers traced so far with that gate}`` in
+    this process (the ``attn_gate_total`` counter; empty with
+    ``DISTLEARN_OBS=0``)."""
+    family = _gate_counter()
+    if family is obs.NULL:
+        return {}
+    return {s["labels"]["gate"]: s["value"] for s in family.sample()}
+
+
 def gqa_apply(blk: PyTree, x: jax.Array, cd, eps: float,
-              window: int | None = None, rope_theta: float | None = None):
+              window: int | None = None, rope_theta: float | None = None,
+              *, rope_fraction: float = 1.0, yarn: Yarn | None = None):
     """A softmax layer's mixer with its residual: gated where the layer has
-    a ``wg``, q and k rotated where ``rope_theta`` is given, the mask cut to
-    a band where ``window`` is.  The attention call carries the inner name
-    ``attn_window`` or ``attn_full`` inside the declared ``attn_core``."""
+    a ``wg`` — one gate a channel (``[dim, heads, head_dim]``) or one a
+    head (``[dim, heads]``), read from the normed input and applied to the
+    attention's output in float32 — q and k rotated where ``rope_theta`` is
+    given (the first ``rope_fraction`` of a head, at ``yarn``'s frequencies
+    where it is given), the mask cut to a band where ``window`` is.  The
+    attention call carries the inner name ``attn_window`` or ``attn_full``
+    inside the declared ``attn_core``, the gate's projection and product
+    ``attn_gate`` inside ``attn_proj``."""
     h = _rmsnorm(blk["ln1"], x, eps)
+    gate_kind = None if "wg" not in blk else (
+        "head" if blk["wg"].ndim == 2 else "element")
     with jax.named_scope("attn_proj"):
         q = jnp.einsum("ble,ehd->blhd", h, blk["wq"].astype(cd))
         k = jnp.einsum("ble,ehd->blhd", h, blk["wk"].astype(cd))
         v = jnp.einsum("ble,ehd->blhd", h, blk["wv"].astype(cd))
-        if "wg" in blk:
-            gate = jnp.einsum("ble,ehd->blhd", h, blk["wg"].astype(cd))
+        if gate_kind:
+            _gate_counter().labels(gate=gate_kind).inc()
+            with jax.named_scope("attn_gate"):
+                gate = jnp.einsum(
+                    "ble,eh->blh" if gate_kind == "head" else "ble,ehd->blhd",
+                    h, blk["wg"].astype(cd))
         if rope_theta is not None:
             pos = jnp.arange(x.shape[1])
-            q, k = rotary(q, pos, rope_theta), rotary(k, pos, rope_theta)
+            # a whole head at default angles: the call it always was
+            kw = {} if rope_fraction == 1.0 and yarn is None \
+                else {"fraction": rope_fraction, "yarn": yarn}
+            q, k = (rotary(q, pos, rope_theta, **kw),
+                    rotary(k, pos, rope_theta, **kw))
     with jax.named_scope("attn_core"):
         with jax.named_scope("attn_full" if window is None
                              else "attn_window"):
             att = local_attention(q, k, v, causal=True, window=window)
     with jax.named_scope("attn_proj"):
-        if "wg" in blk:
-            att = jax.nn.sigmoid(gate.astype(jnp.float32)).astype(cd) * att
+        if gate_kind:
+            with jax.named_scope("attn_gate"):
+                g = jax.nn.sigmoid(gate.astype(jnp.float32)).astype(cd)
+                att = (g[..., None] if gate_kind == "head" else g) * att
         return x + jnp.einsum("blhd,hde->ble", att, blk["wo"].astype(cd))
 
 
@@ -268,7 +317,7 @@ def moe_apply(blk: PyTree, x: jax.Array, cd, eps: float, held, top_k: int,
 
 
 def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
-              heads: int, kv_heads: int, head_dim: int,
+              heads: int | Sequence[int], kv_heads: int, head_dim: int,
               kda_heads: int | None = None, kda_head_dim: int | None = None,
               conv_kernel: int = 4, kda_rank: int | None = None,
               window: int | None = None, rope_theta: float | None = None,
@@ -284,16 +333,28 @@ def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
               v_head_dim: int | None = None, rope_pairing: str = "half",
               dense_layers: int = 0, dense_width: int | None = None,
               router_score: str = "softmax", routed_scale: float = 1.0,
-              mtp_depth: int = 0, mtp_weight: float = 0.0) -> Model:
+              mtp_depth: int = 0, mtp_weight: float = 0.0,
+              attn_gate: str | None = None,
+              full_rope_theta: float | None = None,
+              full_rope_fraction: float = 1.0,
+              full_rope_yarn: Mapping[str, float] | None = None) -> Model:
     """Returns a :class:`Model` mapping int tokens [B, L] to next-token
     logits [B, L, vocab] (untied head).
 
     ``layer_types``: one of :data:`LAYER_TYPES` per layer — the pattern is
     data.  ``heads`` / ``kv_heads`` / ``head_dim`` size the softmax layers
-    (``"gqa"``: output gate, whole triangle, no positions; ``"full"``: the
-    same without the gate; ``"window"``: no gate, q and k rotated at
+    (``"gqa"``: an elementwise output gate, whole triangle, no positions;
+    ``"full"``: the same without that gate; ``"window"``: q and k rotated at
     ``rope_theta`` and the mask cut to the last ``window`` positions —
-    both required by a pattern that has such a layer), ``kda_heads`` /
+    both required by a pattern that has such a layer); ``heads`` may be a
+    sequence, the query heads of each layer (every entry a multiple of
+    ``kv_heads``, which all layers share).  ``attn_gate="head"``
+    (:data:`ATTN_GATES`) gives every ``"full"`` and ``"window"`` layer a
+    sigmoid gate a head (``wg`` [dim, heads]); ``full_rope_theta`` rotates
+    the q and k of every ``"full"`` layer too, at that theta, over the first
+    ``full_rope_fraction`` of a head and, with ``full_rope_yarn`` (the
+    fields of :class:`~distlearn_tpu.models.transformer.Yarn`), at YaRN's
+    frequencies.  ``kda_heads`` /
     ``kda_head_dim`` (keys and values alike; required by a pattern with a
     ``"kda"`` layer) the linear-attention ones, whose decay and output
     gates are low-rank through ``kda_rank`` (default: ``kda_head_dim``).
@@ -350,9 +411,22 @@ def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
     if not layer_types or any(t not in LAYER_TYPES for t in layer_types):
         raise ValueError(f"layer_types must be a non-empty sequence of "
                          f"{LAYER_TYPES}, got {layer_types!r}")
-    if heads % kv_heads:
+    by_layer = (tuple(heads) if isinstance(heads, Sequence)
+                else (heads,) * len(layer_types))
+    if len(by_layer) != len(layer_types) \
+            or any(n < 1 or n % kv_heads for n in by_layer):
         raise ValueError(f"heads={heads} is not a multiple of "
-                         f"kv_heads={kv_heads}")
+                         f"kv_heads={kv_heads} (one number, or one a layer)")
+    if attn_gate not in ATTN_GATES:
+        raise ValueError(f"attn_gate must be one of {ATTN_GATES}, got "
+                         f"{attn_gate!r}")
+    if (full_rope_yarn is not None or full_rope_fraction != 1.0) \
+            and not full_rope_theta:
+        raise ValueError("full_rope_fraction and full_rope_yarn rotate the "
+                         "'full' layers: they need full_rope_theta")
+    full_rope = {} if full_rope_fraction == 1.0 and full_rope_yarn is None \
+        else {"rope_fraction": full_rope_fraction,
+              "yarn": full_rope_yarn and Yarn(**full_rope_yarn)}
     if "kda" in layer_types and not (kda_heads and kda_head_dim):
         raise ValueError("a 'kda' layer needs kda_heads and kda_head_dim")
     if "window" in layer_types and not (window and rope_theta):
@@ -399,7 +473,7 @@ def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
     router_kw = {} if router_score == "softmax" and routed_scale == 1.0 \
         else {"score": router_score, "scale": routed_scale}
 
-    def init_layer(key, kind, dense=False):
+    def init_layer(key, kind, heads, dense=False):
         ks = iter(random.split(key, 24))
         nk = lambda: next(ks)                                # noqa: E731
         if kind == "mla":
@@ -426,6 +500,8 @@ def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
             }
             if kind == "gqa":
                 blk["wg"] = _dense(nk(), (dim, heads, head_dim), dim, dtype)
+            elif attn_gate == "head":
+                blk["wg"] = _dense(nk(), (dim, heads), dim, dtype)
             blk["wo"] = _dense(nk(), (heads, head_dim, dim), heads * head_dim,
                                dtype)
         else:
@@ -480,7 +556,7 @@ def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
                   "head": _dense(keys[1], (dim, vocab), dim, dtype),
                   "out_norm": _norm_init((dim,), dtype)}
         for i, kind in enumerate(layer_types):
-            params[f"layer{i}"] = init_layer(keys[2 + i], kind,
+            params[f"layer{i}"] = init_layer(keys[2 + i], kind, by_layer[i],
                                              i < dense_layers)
         if mtp_depth:
             k_eh, k_blk = random.split(random.fold_in(key, depth))
@@ -488,7 +564,7 @@ def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
                 "enorm": _norm_init((dim,), dtype),
                 "hnorm": _norm_init((dim,), dtype),
                 "eh_proj": _dense(k_eh, (2 * dim, dim), 2 * dim, dtype),
-                "block": init_layer(k_blk, layer_types[-1]),
+                "block": init_layer(k_blk, layer_types[-1], by_layer[-1]),
                 "norm": _norm_init((dim,), dtype)}
         return params, {}
 
@@ -518,6 +594,9 @@ def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
                                   qk_nope_head_dim, rope_pairing)
                 elif kind == "window":
                     x = gqa_apply(blk, x, cd, eps, window, rope_theta)
+                elif kind == "full" and full_rope_theta:
+                    x = gqa_apply(blk, x, cd, eps, None, full_rope_theta,
+                                  **full_rope)
                 else:
                     x = gqa_apply(blk, x, cd, eps)
                 if dense:

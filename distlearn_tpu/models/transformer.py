@@ -22,14 +22,16 @@ and ``apply`` inserts the one ``psum`` per block that TP requires (after
 from __future__ import annotations
 
 import math
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax, random
 
 from jax.sharding import PartitionSpec as P
 
+from distlearn_tpu import obs
 from distlearn_tpu.models.core import (Model, checkpoint_block,
                                        scan_reducing)
 from distlearn_tpu.parallel.sequence import (alltoall_attention,
@@ -71,41 +73,115 @@ def attn_qkv(blk: PyTree, x: jax.Array, cd, tp_axis: str | None = None):
 ROPE_PAIRINGS = ("half", "interleaved")
 
 
+class Yarn(NamedTuple):
+    """YaRN's rescaling of the rotary angles (Peng et al., "YaRN: Efficient
+    Context Window Extension of Large Language Models", 2023), in the
+    parameters and the convention of the ``transformers`` library's
+    ``_compute_yarn_parameters``: ``factor`` the context's extension over
+    ``original_max_position_embeddings``, ``beta_fast`` / ``beta_slow`` the
+    rotations that bound the ramp between kept and interpolated
+    frequencies, ``attention_factor`` the factor on cos and sin."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float
+    beta_slow: float
+    attention_factor: float
+
+
+def yarn_frequencies(theta: float, dim: int, yarn: Yarn) -> np.ndarray:
+    """The ``dim / 2`` angle frequencies of a rotated part ``dim`` wide
+    under YaRN, float32: pair ``d`` keeps ``f_d = theta ** (-2 d / dim)``
+    below the ramp, turns at ``f_d / factor`` above it, and mixes the two
+    linearly on it::
+
+        c(r)   = dim ln(L0 / (2 pi r)) / (2 ln theta)   L0: the original context
+        low    = max(floor(c(beta_fast)), 0)
+        high   = min(ceil(c(beta_slow)), dim - 1)
+        ramp_d = clip((d - low) / (high - low), 0, 1)
+        freq_d = f_d (1 - ramp_d) + (f_d / factor) ramp_d
+
+    Computed on the host in float64 from the constants alone."""
+    def bound(rotations):
+        return dim * math.log(yarn.original_max_position_embeddings
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(bound(yarn.beta_fast)), 0)
+    high = min(math.ceil(bound(yarn.beta_slow)), dim - 1)
+    d = np.arange(dim // 2, dtype=np.float64)
+    ramp = np.clip((d - low) / max(high - low, 1e-3), 0.0, 1.0)
+    base = float(theta) ** (-2.0 * d / dim)
+    return (base * (1 - ramp) + base / yarn.factor * ramp).astype(np.float32)
+
+
+def _rotary_counter():
+    return obs.counter(
+        "rotary_total", "rotary calls traced, by the scaling of their angles",
+        labels=("scaling",))
+
+
+def rotary_calls_traced() -> dict[str, int]:
+    """``{scaling: rotary calls traced so far}`` in this process (the
+    ``rotary_total`` counter; empty with ``DISTLEARN_OBS=0``)."""
+    family = _rotary_counter()
+    if family is obs.NULL:
+        return {}
+    return {s["labels"]["scaling"]: s["value"] for s in family.sample()}
+
+
 def rotary(x: jax.Array, positions: jax.Array, theta: float,
-           pairing: str = "half") -> jax.Array:
+           pairing: str = "half", fraction: float = 1.0,
+           yarn: Yarn | None = None) -> jax.Array:
     """Rotary position embedding of ``x`` [B, L, H, D] at ``positions``
-    [L] over the whole of ``x``'s last axis (a caller that rotates a slice
-    of a head hands that slice): pair ``d`` of the ``D/2`` is turned by
-    ``positions * theta ** (-2 d / D)``.  ``pairing`` (:data:`ROPE_PAIRINGS`)
-    says which two dimensions pair ``d`` is: ``"half"`` — ``(d, d + D/2)``
+    [L] over the first ``R = fraction x D`` dimensions of ``x``'s last axis
+    (the rest pass through as they are; a caller that rotates the LAST part
+    of a head hands that slice): pair ``d`` of the ``R/2`` is turned by
+    ``positions * theta ** (-2 d / R)``.  ``pairing`` (:data:`ROPE_PAIRINGS`)
+    says which two dimensions pair ``d`` is: ``"half"`` — ``(d, d + R/2)``
     ("rotate half", the default) — or ``"interleaved"`` — ``(2d, 2d + 1)``,
     neighbours, the result in the same places (a head as complex numbers
-    ``x[2d] + i x[2d+1]``, each times ``exp(i angle_d)``).  The angles,
-    their sines and the rotation are float32 whatever ``x`` is (at position
-    16k a bfloat16 angle is off by whole turns); the result is rounded to
-    ``x``'s dtype once.  Applied to q and k before the attention kernel, the
-    scores depend on ``i - j`` alone.  Callers put it inside their
-    ``attn_proj`` scope; the inner name ``rope`` is what a profile finds it
-    by."""
-    half = x.shape[-1] // 2
-    if x.shape[-1] != 2 * half:
-        raise ValueError(f"rotary needs an even head size, got {x.shape[-1]}")
+    ``x[2d] + i x[2d+1]``, each times ``exp(i angle_d)``).  With ``yarn``
+    the frequencies are :func:`yarn_frequencies`' and cos and sin are
+    multiplied by its ``attention_factor`` (the ``transformers``
+    convention: q and k both carry it, so the rotated part's scores carry
+    its square).  The angles, their sines and the rotation are float32
+    whatever ``x`` is (at position 16k a bfloat16 angle is off by whole
+    turns); the result is rounded to ``x``'s dtype once.  Applied to q and
+    k before the attention kernel, the scores depend on ``i - j`` alone.
+    Callers put it inside their ``attn_proj`` scope; the inner name
+    ``rope`` is what a profile finds it by.  Each traced call counts in
+    ``rotary_total{scaling="none"|"yarn"}``."""
+    D = x.shape[-1]
+    R = round(D * fraction)
+    half = R // 2
+    if not 0 < fraction <= 1 or R != D * fraction or R != 2 * half:
+        raise ValueError(f"rotary needs an even head size (rotated part), "
+                         f"got {D} x {fraction}")
     if pairing not in ROPE_PAIRINGS:
         raise ValueError(f"pairing must be one of {ROPE_PAIRINGS}, got "
                          f"{pairing!r}")
+    _rotary_counter().labels(scaling="none" if yarn is None
+                             else "yarn").inc()
     with jax.named_scope("rope"):
-        freq = jnp.float32(theta) ** (
-            jnp.arange(half, dtype=jnp.float32) * (-1.0 / half))
+        if yarn is None:
+            freq = jnp.float32(theta) ** (
+                jnp.arange(half, dtype=jnp.float32) * (-1.0 / half))
+        else:
+            freq = jnp.asarray(yarn_frequencies(theta, R, yarn))
         angle = positions.astype(jnp.float32)[:, None, None] * freq
-        cos, sin = jnp.cos(angle), jnp.sin(angle)           # [L, 1, D/2]
-        x32 = x.astype(jnp.float32)
+        cos, sin = jnp.cos(angle), jnp.sin(angle)           # [L, 1, R/2]
+        if yarn is not None:
+            cos, sin = (t * jnp.float32(yarn.attention_factor)
+                        for t in (cos, sin))
+        x32 = (x if R == D else x[..., :R]).astype(jnp.float32)
         if pairing == "interleaved":
             a, b = x32[..., 0::2], x32[..., 1::2]
-            return jnp.stack([a * cos - b * sin, b * cos + a * sin],
-                             axis=-1).reshape(x.shape).astype(x.dtype)
-        a, b = x32[..., :half], x32[..., half:]
-        return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                               axis=-1).astype(x.dtype)
+            out = jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                            axis=-1).reshape(x32.shape).astype(x.dtype)
+        else:
+            a, b = x32[..., :half], x32[..., half:]
+            out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                                  axis=-1).astype(x.dtype)
+        return out if R == D else jnp.concatenate([out, x[..., R:]], axis=-1)
 
 
 def attn_out(blk: PyTree, x: jax.Array, att: jax.Array, cd,

@@ -842,3 +842,28 @@ def test_constructor_refuses_the_new_options_it_cannot_build():
     step = build_lm_step(model, mesh, params, lr=0.05, donate=False)
     with pytest.raises(NotImplementedError, match="sequence"):
         step(params, _tokens(mesh, b=2))
+
+
+def test_constructor_refuses_what_the_gated_pattern_cannot_build():
+    """Query heads by layer, a gate a head and the full layers' rotation:
+    a head count a layer that is no multiple of the K/V heads, or a list of
+    another length than the pattern, an unknown gate, and a fraction or a
+    YaRN scaling of the full layers' rotation without its theta."""
+    with pytest.raises(ValueError, match="kv_heads"):
+        _swa_toy(heads=(4, 6, 6, 5))
+    with pytest.raises(ValueError, match="kv_heads"):
+        _swa_toy(heads=(4, 6, 6))
+    with pytest.raises(ValueError, match="attn_gate"):
+        _swa_toy(attn_gate="element")
+    with pytest.raises(ValueError, match="need full_rope_theta"):
+        _swa_toy(full_rope_fraction=0.5)
+    with pytest.raises(ValueError, match="need full_rope_theta"):
+        _swa_toy(full_rope_yarn={"factor": 2, "beta_fast": 32, "beta_slow": 1,
+                                 "original_max_position_embeddings": 32,
+                                 "attention_factor": 1.0})
+    model = _swa_toy(heads=(4, 6, 6, 6), attn_gate="head")
+    params, _ = model.init(jax.random.PRNGKey(0))
+    assert [params[f"layer{i}"]["wq"].shape[1] for i in range(4)] \
+        == [4, 6, 6, 6]
+    assert [params[f"layer{i}"]["wg"].shape for i in range(4)] \
+        == [(32, 4), (32, 6), (32, 6), (32, 6)]

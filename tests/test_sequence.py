@@ -488,7 +488,8 @@ def test_window_that_covers_the_length_is_the_causal_call(impl, window):
 @pytest.mark.parametrize("heads,kv_heads,L,D,window", [
     (2, 2, 384, 64, 100),      # MHA, sequence-minor, the edge inside a block
     (2, 2, 384, 64, 256),      # the edge on a block boundary
-    (4, 2, 256, 128, 72)])     # grouped queries, head-minor
+    (4, 2, 256, 128, 72),      # grouped queries, head-minor
+    (18, 2, 384, 128, 128)])   # 9 query heads a K/V head, a band one block
 def test_window_splash_matches_full_square_float32(heads, kv_heads, L, D,
                                                    window):
     """The blockwise kernel with the band's ``LocalMask`` (Pallas interpret

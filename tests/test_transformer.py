@@ -770,6 +770,52 @@ def test_tpu_compiler_takes_the_windowed_kernel_at_its_published_widths(
     assert "16384,16384" not in text
 
 
+@pytest.mark.parametrize("heads,window,yarn", [
+    (72, 512, False),       # a sliding layer: 9 query heads a K/V head
+    (48, None, True)])      # a full layer: half of a head rotated by YaRN
+def test_tpu_compiler_takes_the_gated_pattern_layers_at_published_widths(
+        v5e_chip, monkeypatch, heads, window, yarn):
+    """The blockwise kernel as the two kinds of a per-head-gated pattern are
+    trained (``heads`` query heads over 8 K/V heads of 128, one sequence of
+    16,384, bf16), with the rotation before it, forward and backward,
+    compiled for the v5e: two Mosaic calls (grouped queries:
+    ``splash_mqa``), no ``[heads, 16384, 16384]`` array.  Nothing runs; no
+    number of this is a measurement."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    from distlearn_tpu.models.transformer import Yarn, rotary
+    from distlearn_tpu.parallel import sequence
+    monkeypatch.setattr(sequence, "_backend", lambda: "tpu")
+    one = SingleDeviceSharding(v5e_chip)
+    q = jax.ShapeDtypeStruct((1, 16384, heads, 128), jnp.bfloat16,
+                             sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 16384, 8, 128), jnp.bfloat16, sharding=one)
+    turn = dict(fraction=0.5, yarn=Yarn(128, 8192, 32, 1, 1.4852030263919618)
+                ) if yarn else {}
+    theta = 5e5 if yarn else 1e4
+
+    def loss(q, k, v):
+        pos = jnp.arange(q.shape[1])
+        out = sequence.local_attention(
+            rotary(q, pos, theta, **turn), rotary(k, pos, theta, **turn), v,
+            causal=True, window=window)
+        return jnp.sum(out.astype(jnp.float32))
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+                q, kv, kv).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "splash_mqa_fwd" in text and "splash_mqa_dkv" in text
+    assert "16384,16384" not in text
+
+
 @pytest.mark.parametrize("n,d,f,routed,held,top_k,act", [
     # smallthinker-21b-a3b.train-16k: 16 of 64 ReLU-gated experts, top-6
     (16384, 2560, 768, 64, 16, 6, "relu"),
