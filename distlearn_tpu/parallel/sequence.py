@@ -328,10 +328,38 @@ def _dkv_block(block: int, q_bytes: int, L: int) -> int:
     """The K/V block of the fused backward call: ``block`` where the
     unreduced dq it makes (``L / block`` copies of q, ``q_bytes`` each) is
     within :data:`_DQ_UNREDUCED_MAX` — every call but the widest — and else
-    the widest of :data:`_DKV_BLOCKS` that tiles ``L``."""
+    the widest of :data:`_DKV_BLOCKS` that tiles ``L``.  The fused call runs
+    on the whole grid, the mask's skipped blocks included, and writes a dq
+    block at every grid step (zeros where the mask skips it), so the copies
+    are whole whatever the mask leaves of them."""
     if L // block * q_bytes <= _DQ_UNREDUCED_MAX:
         return block
     return next((b for b in _DKV_BLOCKS if b > block and L % b == 0), block)
+
+
+#: the most K/V blocks a query block may reach, as a share of the call's,
+#: for a band's backward call to take the split form (:func:`_bwd_form`).
+#: Measured on the v5e, the kernel alone, forward + backward at 16k and
+#: block 512 (PERF.md section 6): split against fused -37 % at 2 of 32
+#: blocks reached (72 / 8 heads), -13 % at 9, -2 % at 13, +6 % at 17, +16 %
+#: at 25 (28 / 4 heads): the crossing lies near 14 of 32.
+_SPLIT_MAX_SHARE = 1 / 3
+
+
+def _bwd_form(L: int, block: int, window: int | None) -> str:
+    """The backward pass of a blockwise call, from its length, block and
+    band alone.  ``"split"``: the library's two calls at ``block`` — dq
+    reduced in VMEM over the forward's shrunk grid, dK/dV on a grid shrunk
+    to the blocks the band reaches — where the band reaches at most
+    :data:`_SPLIT_MAX_SHARE` of the call's K/V blocks (at 16k and block 512
+    a band of 512 reaches 2 of 32, one of 4,096 9); ``"fused"`` — one call
+    that writes an unreduced dq (:func:`_dkv_block`) — everywhere else,
+    every call without a band among them."""
+    if window is None:
+        return "fused"
+    # a query block's own K/V block and those of the window - 1 keys before
+    reached = -(-(window - 1) // block) + 1
+    return "split" if reached <= _SPLIT_MAX_SHARE * (L // block) else "fused"
 
 
 def select_attention(causal: bool, L: int, D: int, dtype,
@@ -379,18 +407,30 @@ def _latent_counter():
         "implementation", labels=("impl",))
 
 
-def attention_paths_traced(windowed: bool = False,
-                           latent: bool = False) -> dict[str, int]:
+def _bwd_counter():
+    return obs.counter(
+        "attn_bwd_total",
+        "blockwise attention calls traced, by the form of their backward "
+        "pass (fused: one call, unreduced dq; split: dq and dK/dV calls)",
+        labels=("form",))
+
+
+def attention_paths_traced(windowed: bool = False, latent: bool = False,
+                           backward: bool = False) -> dict[str, int]:
     """``{impl: local_attention calls traced so far}`` in this process (the
     ``attn_kernel_total`` counter; empty with ``DISTLEARN_OBS=0``).
     ``windowed``: of those, the calls whose mask was a band
     (``attn_window_total``); ``latent``: those whose v was narrower than
-    their q (``attn_latent_total``)."""
-    family = (_latent_counter() if latent
-              else _window_counter() if windowed else _attn_counter())
+    their q (``attn_latent_total``); ``backward``: ``{form: blockwise
+    calls}`` by their backward pass, :func:`_bwd_form`'s
+    (``attn_bwd_total``)."""
+    family, label = ((_bwd_counter(), "form") if backward
+                     else (_latent_counter(), "impl") if latent
+                     else (_window_counter(), "impl") if windowed
+                     else (_attn_counter(), "impl"))
     if family is obs.NULL:
         return {}
-    return {s["labels"]["impl"]: s["value"] for s in family.sample()}
+    return {s["labels"][label]: s["value"] for s in family.sample()}
 
 
 def _backend() -> str:
@@ -403,11 +443,18 @@ def _backend() -> str:
 def _splash_causal_attention(q, k, v, block: int, interpret: bool,
                              window: int | None = None):
     """Causal attention through JAX's Pallas ``splash_attention`` kernel
-    with a ``CausalMask``: blocks above the diagonal are never visited,
-    scores and softmax statistics are float32 and live in VMEM only, the
-    backward pass is the kernel's fused dK/dV/dQ call.  With ``window`` the
-    mask is the causal BAND (``LocalMask``: position i sees ``i - window <
-    j <= i``) and the blocks below the band are never visited either.  Its
+    with a ``CausalMask``: blocks above the diagonal are never visited by
+    the forward call, scores and softmax statistics are float32 and live in
+    VMEM only.  With ``window`` the mask is the causal BAND (``LocalMask``:
+    position i sees ``i - window < j <= i``) and the forward call never
+    visits the blocks below the band either.  The backward pass takes the
+    form :func:`_bwd_form` gives (counted in ``attn_bwd_total{form=}``):
+    ``"fused"``, the kernel's one dK/dV/dQ call, whose grid is NOT shrunk
+    to the mask — it steps through every block and writes a dq block at
+    each step, zeros where the mask skips it, one copy of q per K/V block
+    (:func:`_dkv_block`) that XLA sums afterwards; or, for a narrow band,
+    ``"split"``, a dq call that keeps dq reduced in VMEM and a dK/dV call
+    on a grid shrunk to the band, both at ``block``.  Its
     output and log-sum-exp carry the name :data:`ATTN_RESIDUALS`.  q/k/v:
     ``[B, L, H, D]``; the kernel wants ``[H, L, D]`` per batch row and an
     already scaled q.  v's head size may differ from q's and k's (the
@@ -426,10 +473,18 @@ def _splash_causal_attention(q, k, v, block: int, interpret: bool,
     # are sequence-minor beside head-minor (PERF.md section 6, PR 36).
     layout = lambda a: (sk.QKVLayout.HEAD_DIM_MINOR         # noqa: E731
                         if a.shape[-1] % 128 == 0 else sk.QKVLayout.SEQ_MINOR)
+    form = _bwd_form(L, block, window)
+    _bwd_counter().labels(form=form).inc()
+    if form == "split":
+        bwd = dict(block_q_dq=block, block_kv_dq=block, block_q_dkv=block,
+                   block_kv_dkv=block, use_fused_bwd_kernel=False)
+    else:
+        bwd = dict(block_q_dkv=block,
+                   block_kv_dkv=_dkv_block(block, q.nbytes, L),
+                   use_fused_bwd_kernel=True)
     sizes = sk.BlockSizes(
         block_q=block, block_kv=block, block_kv_compute=block,
-        block_q_dkv=block, block_kv_dkv=_dkv_block(block, q.nbytes, L),
-        block_kv_dkv_compute=block, use_fused_bwd_kernel=True,
+        block_kv_dkv_compute=block, **bwd,
         q_layout=layout(q), k_layout=layout(k), v_layout=layout(v))
     heads_first = lambda a: a.transpose(0, 2, 1, 3)   # noqa: E731
     # scaled in float32, rounded once (exact at D = 64: the scale is 1/8)
@@ -492,7 +547,8 @@ def local_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     The resolved path is counted in ``attn_kernel_total{impl=}`` (``obs``):
     once per traced call, not per step — a jitted program is traced once;
     a call whose mask is a band also in ``attn_window_total{impl=}``, one
-    whose v is narrower than its q also in ``attn_latent_total{impl=}``."""
+    whose v is narrower than its q also in ``attn_latent_total{impl=}``; a
+    blockwise call also in ``attn_bwd_total{form=}``."""
     B, L, H, D = q.shape
     Hkv = k.shape[2]
     if k.shape[-1] != D:

@@ -489,13 +489,17 @@ def test_window_that_covers_the_length_is_the_causal_call(impl, window):
     (2, 2, 384, 64, 100),      # MHA, sequence-minor, the edge inside a block
     (2, 2, 384, 64, 256),      # the edge on a block boundary
     (4, 2, 256, 128, 72),      # grouped queries, head-minor
-    (18, 2, 384, 128, 128)])   # 9 query heads a K/V head, a band one block
+    (18, 2, 384, 128, 128),    # 9 query heads a K/V head, a band one block
+    (6, 2, 768, 128, 200)])    # a band narrower than the 256 block: two
+#                                K/V blocks reached a query block
 def test_window_splash_matches_full_square_float32(heads, kv_heads, L, D,
-                                                   window):
+                                                   window, monkeypatch):
     """The blockwise kernel with the band's ``LocalMask`` (Pallas interpret
     mode here; blocks below the band never visited) is the full-square
     path's masked math: forward and the three gradients, float32, tight, at
-    the tolerances of the causal comparison above."""
+    the tolerances of the causal comparison above — through BOTH forms of
+    the backward pass, the fused call and the split dq and dK/dV calls."""
+    from distlearn_tpu.parallel import sequence
     q, k, v = _gqa_qkv(L, D, heads, kv_heads, seed=24)
 
     def grads(impl):
@@ -505,17 +509,24 @@ def test_window_splash_matches_full_square_float32(heads, kv_heads, L, D,
             return jnp.sum(out.astype(jnp.float32) ** 2)
         return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
-    got = local_attention(q, k, v, causal=True, impl="splash", window=window)
     ref = local_attention(q, k, v, causal=True, impl="xla", window=window)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=2e-5, atol=2e-6)
     np.testing.assert_allclose(np.asarray(ref),
                                _band_oracle(q, k, v, window),
                                rtol=1e-5, atol=2e-6)
-    for a, b in zip(grads("splash"), grads("xla")):
-        assert a.shape == b.shape
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-4, atol=5e-5)
+    want = grads("xla")
+    for form in ("fused", "split"):
+        monkeypatch.setattr(sequence, "_bwd_form", lambda *_, f=form: f)
+        before = sequence.attention_paths_traced(backward=True).get(form, 0)
+        got = local_attention(q, k, v, causal=True, impl="splash",
+                              window=window)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-6)
+        for a, b in zip(grads("splash"), want):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=5e-5)
+        assert sequence.attention_paths_traced(
+            backward=True).get(form, 0) - before == 2
 
 
 def test_window_is_refused_without_causality_and_counted_with_it():
@@ -537,6 +548,63 @@ def test_window_is_refused_without_causality_and_counted_with_it():
     # the path is chosen from the call's shape as it is without a window
     from distlearn_tpu.parallel.sequence import select_attention
     assert select_attention(True, 16384, 128, jnp.bfloat16, "tpu") == "splash"
+
+
+_Q = lambda L, H, D=128: L * H * D * 2          # noqa: E731  bf16 q's bytes
+
+
+@pytest.mark.parametrize("L,block,window,q_bytes,form,dkv", [
+    # laguna-s-2.1's sliding layers: a band of 512 reaches 2 of 32 blocks
+    (16384, 512, 512, _Q(16384, 72), "split", None),
+    # smallthinker-21b-a3b's band of 4,096 reaches 9 of 32
+    (16384, 512, 4096, _Q(16384, 28), "split", None),
+    # the rule's edge: 10 of 32 blocks reached split, 11 fused
+    (16384, 512, 4609, _Q(16384, 72), "split", None),
+    (16384, 512, 4610, _Q(16384, 72), "fused", 2048),
+    # measured against each other: split lost at 17 of 32 (a band of 8,192)
+    (16384, 512, 8192, _Q(16384, 28), "fused", 512),
+    # calls without a band: the full layers, the latent layers, dense
+    (16384, 512, None, _Q(16384, 28), "fused", 512),
+    (16384, 512, None, _Q(16384, 48), "fused", 2048),
+    (16384, 512, None, _Q(16384, 32, 192), "fused", 2048),
+    (8192, 512, None, _Q(8192, 64), "fused", 512),
+    (1024, 512, None, 8 * _Q(1024, 20, 64), "fused", 512),
+    # a short call's band reaches too large a share of its blocks
+    (1024, 512, 16, _Q(1024, 20, 64), "fused", 512)])
+def test_backward_form_table(L, block, window, q_bytes, form, dkv):
+    """The backward form of a blockwise call, from its length, block and
+    band alone: split only where the band reaches a third or less of the
+    call's K/V blocks; a fused call keeps the K/V block ``_dkv_block``
+    gives it."""
+    from distlearn_tpu.parallel import sequence
+    assert sequence._bwd_form(L, block, window) == form
+    if dkv is not None:
+        assert sequence._dkv_block(block, q_bytes, L) == dkv
+
+
+def test_backward_form_counter_counts_each_form_once_per_traced_call():
+    """``attn_bwd_total{form=}`` moves once per traced blockwise call, under
+    the form the rule gave it; the full-square path counts nothing there."""
+    from distlearn_tpu.parallel.sequence import \
+        attention_paths_traced as calls
+    q, k, v = _qkv_heads64(17 * 128, seed=26)      # block 128: 17 blocks
+    before = calls(backward=True)
+
+    def traced(window, impl="splash"):
+        return jax.jit(lambda a, b, c: local_attention(
+            a, b, c, causal=True, impl=impl, window=window)).lower(q, k, v)
+    jitted = jax.jit(lambda a, b, c: local_attention(
+        a, b, c, causal=True, impl="splash", window=100))
+    jax.eval_shape(jitted, q, k, v)
+    jax.eval_shape(jitted, q, k, v)                # traced once
+    traced(513)                                    # 5 of 17 blocks: split
+    traced(514)                                    # 6 of 17: fused
+    traced(None)
+    traced(100, impl="xla")
+    after = calls(backward=True)
+    assert after.get("split", 0) - before.get("split", 0) == 2
+    assert after.get("fused", 0) - before.get("fused", 0) == 2
+    assert set(after) <= {"fused", "split"}
 
 
 @pytest.mark.parametrize("causal,L", [(False, 256), (True, 100), (True, 32)])

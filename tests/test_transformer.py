@@ -735,10 +735,12 @@ def test_tpu_compiler_takes_the_windowed_kernel_at_its_published_widths(
     """The blockwise kernel with a causal BAND at the widths a windowed
     layer is trained at (28 query heads over 4 K/V heads of 128, one
     sequence of 16,384, a band of 4,096, bf16), forward and backward,
-    compiled for the v5e: two Mosaic calls (grouped queries:
-    ``splash_mqa``), no ``[28, 16384, 16384]`` array, and the rotation
-    before it fused by the compiler at that size.  Nothing runs; no number
-    of this is a measurement."""
+    compiled for the v5e: the band reaches 9 of 32 K/V blocks, so the
+    backward is split — three Mosaic calls (grouped queries:
+    ``splash_mqa``: forward, dq, dK/dV), no unreduced dq, fewer bytes of
+    temporaries than the fused call's 3.84 GB —, no ``[28, 16384, 16384]``
+    array, and the rotation before it fused by the compiler at that size.
+    Nothing runs; no number of this is a measurement."""
     from jax.experimental.compilation_cache import compilation_cache
     from jax.sharding import SingleDeviceSharding
     from distlearn_tpu.models.transformer import rotary
@@ -760,14 +762,18 @@ def test_tpu_compiler_takes_the_windowed_kernel_at_its_published_widths(
     compilation_cache.reset_cache()
     try:
         with jax.enable_x64(False):
-            text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-                q, kv, kv).compile().as_text()
+            compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+                q, kv, kv).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
         compilation_cache.reset_cache()
-    assert text.count('custom_call_target="tpu_custom_call"') == 2
-    assert "splash_mqa_fwd" in text and "splash_mqa_dkv" in text
+    text = compiled.as_text()
+    assert sequence._bwd_form(16384, 512, 4096) == "split"
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert all(f"splash_mqa_{n}" in text for n in ("fwd", "dq", "dkv"))
     assert "16384,16384" not in text
+    assert not re.findall(r"\[(?:1,)?4,\d+,7,16384,128\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
 @pytest.mark.parametrize("heads,window,yarn", [
@@ -778,9 +784,13 @@ def test_tpu_compiler_takes_the_gated_pattern_layers_at_published_widths(
     """The blockwise kernel as the two kinds of a per-head-gated pattern are
     trained (``heads`` query heads over 8 K/V heads of 128, one sequence of
     16,384, bf16), with the rotation before it, forward and backward,
-    compiled for the v5e: two Mosaic calls (grouped queries:
-    ``splash_mqa``), no ``[heads, 16384, 16384]`` array.  Nothing runs; no
-    number of this is a measurement."""
+    compiled for the v5e (grouped queries: ``splash_mqa``), no ``[heads,
+    16384, 16384]`` array.  The full layer: two Mosaic calls, the fused
+    backward's unreduced dq at 8 copies of q.  The sliding layer's band of
+    512 reaches 2 of 32 K/V blocks, so its backward is split: three calls
+    (forward, dq, dK/dV), no copies axis on q's shape, and fewer bytes of
+    temporaries than the fused call's 2.79 GB.  Nothing runs; no number of
+    this is a measurement."""
     from jax.experimental.compilation_cache import compilation_cache
     from jax.sharding import SingleDeviceSharding
     from distlearn_tpu.models.transformer import Yarn, rotary
@@ -806,14 +816,27 @@ def test_tpu_compiler_takes_the_gated_pattern_layers_at_published_widths(
     compilation_cache.reset_cache()
     try:
         with jax.enable_x64(False):
-            text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-                q, kv, kv).compile().as_text()
+            compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+                q, kv, kv).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
         compilation_cache.reset_cache()
-    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    text = compiled.as_text()
+    split = sequence._bwd_form(16384, 512, window) == "split"
+    assert split == (window is not None)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 + split
     assert "splash_mqa_fwd" in text and "splash_mqa_dkv" in text
+    assert ("splash_mqa_dq" in text) == split
     assert "16384,16384" not in text
+    # the unreduced dq: [(B,) Hkv, K/V blocks, group, L, D]
+    copies = re.findall(rf"\[(?:1,)?8,\d+,{heads // 8},16384,128\]", text)
+    if split:
+        assert not copies
+        assert compiled.memory_analysis().temp_size_in_bytes < 2.3e9
+    else:
+        assert copies
+        assert sequence._dkv_block(512, 16384 * heads * 128 * 2,
+                                   16384) == 2048
 
 
 @pytest.mark.parametrize("n,d,f,routed,held,top_k,act", [
