@@ -32,9 +32,11 @@ PyTree = Any
 #: decay and chunked delta rule) and ``moe`` (router, grouping, the held
 #: experts' grouped products, combine) are ``models/hybrid.py``'s; the
 #: dense :func:`~distlearn_tpu.models.transformer.transformer_lm` uses the
-#: list less those two.
+#: list less those two.  ``mhc`` (a hyper-connection's maps, Sinkhorn,
+#: read-in and write-back of the streams) is the pattern LM's with
+#: ``hc_mult`` alone.
 SCOPES = ("embed", "norm", "attn_proj", "attn_core", "mlp", "head_loss",
-          "grad_reduce", "update", "linattn_core", "moe")
+          "grad_reduce", "update", "linattn_core", "moe", "mhc")
 
 
 def checkpoint_block(fn: Callable) -> Callable:

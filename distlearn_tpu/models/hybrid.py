@@ -4,11 +4,15 @@ name a layer): softmax attention with grouped queries — with an output gate
 band with rotary positions (``"window"``), the last two with a gate a head
 or none and the first with rotary positions of its own or none — latent
 attention (``"mla"``: low-rank q and K/V paths, scores over a wider head than
-the values), or gated delta-rule linear attention (``"kda"``); and in every
+the values), DeepSeek sparse attention over latent attention with no rotary
+part (``"dsa"``: an indexer picks the positions each query attends), or
+gated delta-rule linear attention (``"kda"``); and in every
 layer a routed mixture of gated-linear-unit experts (SwiGLU or ReGLU), beside
 a shared expert or without one — or, in the first ``dense_layers`` layers, a
 dense SwiGLU MLP instead.  After the stack, optionally, a multi-token-
-prediction module.  The second LM constructor beside
+prediction module.  Optionally (``hc_mult``) the residual is ``n``
+streams, every sublayer wrapped in a manifold-constrained hyper-connection.
+The second LM constructor beside
 :func:`distlearn_tpu.models.transformer.transformer_lm`; it returns the same
 :class:`~distlearn_tpu.models.core.Model` and its ``apply`` takes the same
 keywords, so ``lm_loss`` and every LM step builder drive it unchanged.
@@ -20,6 +24,26 @@ order):
 
     h = x + Mix(rmsnorm(x));        y = h + FFN(rmsnorm(h))
     FFN = MoE, or below ``dense_layers``  (silu(m Wg) * (m Wu)) Wd
+    with swiglu_limit = c, in the MLP and every expert:
+          (silu(min(m Wg, c)) * clip(m Wu, -c, c)) Wd
+
+With ``hc_mult`` = n the stream is ``X`` [n, C] a position, and each of the
+two sublayers ``F`` of a layer (the pre-norm mixer or FFN above WITHOUT its
+residual) is wrapped (``hc_mix`` / ``hc_ffn``: ``phi`` [n C, 2n + n^2] =
+[phi_pre | phi_post | phi_res], ``alpha`` [3], ``b_pre``, ``b_post`` [n],
+``b_res`` [n, n]):
+
+    x^    = rmsnorm(vec X)                      over n C, no scale of its own
+    h_pre  = sigmoid(alpha_pre  x^ phi_pre  + b_pre)                 [n]
+    h_post = 2 sigmoid(alpha_post x^ phi_post + b_post)              [n]
+    H_res  = Sinkhorn_I(exp(alpha_res mat(x^ phi_res) + b_res))      [n, n]
+    y      = F(sum_i h_pre_i X_i)
+    X'_i   = sum_j H_res_ij X_j + h_post_i y
+
+``Sinkhorn_I``: ``hc_sinkhorn_iters`` rounds, each dividing every row by its
+sum + ``hc_eps`` and then every column by its sum + ``hc_eps``.  On entry
+every stream is the token's embedding; after the last layer the streams are
+summed, then normed and projected by the head.
 
 ``Mix`` of a softmax layer, ``H`` query heads (the layer's own: ``heads``
 may name them layer by layer) over ``Hkv`` K/V heads, ``u`` the normed
@@ -51,13 +75,33 @@ that of the values):
     a_h = softmax(q_h k_h^T / sqrt(nope + rope) + causal) v_h      [dv]
     out = concat_h(a_h) Wo
 
+``Mix`` of a ``"dsa"`` layer, ``H`` held heads, ``u`` the normed input, no
+rotation anywhere (``dk`` = ``qk_nope_head_dim``, ``dv`` = ``v_head_dim``,
+``J`` x ``dI`` the indexer's heads, ``P`` = ``index_pool``):
+
+    cq = rmsnorm(u Wqa);   q_h = cq Wqb_h                  [q_lora_rank]; [dk]
+    c  = rmsnorm(u Wkva);  k_h,s = c_s Wk_h;  v_h,s = c_s Wv_h    [kv_lora_rank]
+    qI_t,j = cq_t WqI_j;   kI_s = layernorm(u_s WkI);   w_t,j = (u_t Ww)_j / sqrt(J)
+    kbar_b = mean(kI_{P b .. P b + P - 1})
+    I_t,b  = sum_j w_t,j relu(qI_t,j . kbar_b) / sqrt(dI)          b < t // P
+    S_t    = the block t // P + the top min(index_topk / P - 1, t // P)
+             earlier blocks by I_t,.                  (at most index_topk positions)
+    a_h,t  = softmax_{s in S_t, s <= t}(q_h,t . k_h,s / sqrt(dk)) v_h,s
+    out    = sum_h a_h Wo_h
+
+computed in the absorbed form (``q_h Wk_h^T`` against ``c``,
+:mod:`distlearn_tpu.ops.sparse_attention`); the indexer enters the choice
+alone and gets no gradient.
+
 ``Mix`` of a ``"kda"`` layer, per head (``conv`` a causal depthwise
 convolution over time):
 
     q~, k~, v~ = silu(conv(x Wq)), silu(conv(x Wk)), silu(conv(x Wv))
     q = l2norm(q~) / sqrt(K);      k = l2norm(k~)
     g_t = -exp(A_h) softplus((x Wa1) Wa2 + b)       per channel, <= 0
-    beta_t = 2 sigmoid(x w_beta)
+        or, with kda_gate_lower_bound = lb < 0:
+    g_t = lb sigmoid(exp(A_h) ((x Wa1) Wa2 + b))     per channel, in (lb, 0)
+    beta_t = s sigmoid(x w_beta)                     s = kda_beta_scale (2)
     S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T
     o_t = S_t^T q_t
     out = (sigmoid((x Wg1) Wg2) * rmsnorm_head(o)) Wo
@@ -88,9 +132,12 @@ whose logits and ``mtp_weight`` ride the returned state to
 Arithmetic: parameters in ``dtype`` (float32); the matrix products in
 ``compute_dtype``; in float32 regardless: the norms' statistics, the softmax
 of attention (inside the kernel), the rotary angles and the rotation, the
-output gates' sigmoids, the KDA decay (softplus, exp, cumulative sums), ``beta``, the l2 norms, the triangular inverse and the carried state
-(``ops/delta_rule.py``), the router's scores (softmax or sigmoid) and its
-choice.
+output gates' sigmoids, the KDA decay (softplus or bounded sigmoid, exp,
+cumulative sums), ``beta``, the l2 norms, the triangular inverse and the
+carried state (``ops/delta_rule.py``), the router's scores (softmax or
+sigmoid) and its choice, the hyper-connections' maps, Sinkhorn and the
+mixing of the streams (held between sublayers in the compute dtype), the
+indexer (at matmul precision "highest") and the sparse attention's softmax.
 """
 
 from __future__ import annotations
@@ -107,17 +154,25 @@ from distlearn_tpu.models.core import Model, checkpoint_block
 from distlearn_tpu.models.transformer import (ROPE_PAIRINGS, Yarn,
                                               _norm_init, _rmsnorm, rotary)
 from distlearn_tpu.ops.delta_rule import chunked_delta_rule
-from distlearn_tpu.parallel.ep import GATE_ACTS, ROUTER_SCORES, moe_held_ffn
+from distlearn_tpu.ops.sparse_attention import sparse_latent_attention
+from distlearn_tpu.parallel.ep import (GATE_ACTS, ROUTER_SCORES, glu_gate,
+                                      glu_up, moe_held_ffn)
 from distlearn_tpu.parallel.sequence import local_attention
 
 PyTree = Any
-LAYER_TYPES = ("gqa", "kda", "full", "window", "mla")
+LAYER_TYPES = ("gqa", "kda", "full", "window", "mla", "dsa")
 #: the output gate a ``"full"`` or ``"window"`` layer may have (a ``"gqa"``
 #: layer's is always one a channel)
 ATTN_GATES = (None, "head")
 #: what the router of a layer may read: the experts' input (the norm after
 #: the mixer) or the layer's own input, un-normed, before the mixer
 ROUTER_INPUTS = ("ffn_norm", "layer_input")
+#: a hyper-connection's gates as drawn: every ``alpha``, and the diagonal of
+#: ``b_res`` (zeros elsewhere).  Sinkhorn's 20 rounds leave every row of
+#: 100,000 such draws within 2e-5 of 1 and one round visibly short of them
+#: (tests/benchmark/test_mhc_dsa_family.py; the reasons in
+#: benchmarks/configs/glm-5.3-flash.json, ``assumed.hc_init``)
+HC_ALPHA, HC_RES_DIAG = 0.25, 2.0
 
 
 def _dense(key, shape, fan_in, dtype):
@@ -158,11 +213,36 @@ def attn_gates_traced() -> dict[str, int]:
     return {s["labels"]["gate"]: s["value"] for s in family.sample()}
 
 
+def residual(x: jax.Array, scope: str, branch, hc=None):
+    """One sublayer with its residual: ``branch`` (the sublayer's input ->
+    ``(terms, *rest)``, the terms to be summed) added to ``x`` in ``scope``,
+    term by term, or, with ``hc = (params, iters, eps, norm_eps)``, through
+    that hyper-connection (:func:`hc_sublayer`); returns ``(x', *rest)``.
+    Every ``*_apply`` below adds its branch here and nowhere else."""
+    if hc is not None:
+        return hc_sublayer(hc[0], x, branch, *hc[1:])
+    terms, *rest = branch(x)
+    with jax.named_scope(scope):
+        for t in terms:
+            x = x + t.reshape(x.shape)
+    return x, *rest
+
+
 def gqa_apply(blk: PyTree, x: jax.Array, cd, eps: float,
               window: int | None = None, rope_theta: float | None = None,
-              *, rope_fraction: float = 1.0, yarn: Yarn | None = None):
-    """A softmax layer's mixer with its residual: gated where the layer has
-    a ``wg`` — one gate a channel (``[dim, heads, head_dim]``) or one a
+              *, rope_fraction: float = 1.0, yarn: Yarn | None = None,
+              hc=None):
+    """:func:`gqa_branch` with its residual (:func:`residual`)."""
+    return residual(x, "attn_proj", lambda h: ([gqa_branch(
+        blk, h, cd, eps, window, rope_theta, rope_fraction=rope_fraction,
+        yarn=yarn)],), hc)[0]
+
+
+def gqa_branch(blk: PyTree, x: jax.Array, cd, eps: float,
+               window: int | None = None, rope_theta: float | None = None,
+               *, rope_fraction: float = 1.0, yarn: Yarn | None = None):
+    """A softmax layer's mixer, its residual left out: gated where the layer
+    has a ``wg`` — one gate a channel (``[dim, heads, head_dim]``) or one a
     head (``[dim, heads]``), read from the normed input and applied to the
     attention's output in float32 — q and k rotated where ``rope_theta`` is
     given (the first ``rope_fraction`` of a head, at ``yarn``'s frequencies
@@ -199,19 +279,26 @@ def gqa_apply(blk: PyTree, x: jax.Array, cd, eps: float,
             with jax.named_scope("attn_gate"):
                 g = jax.nn.sigmoid(gate.astype(jnp.float32)).astype(cd)
                 att = (g[..., None] if gate_kind == "head" else g) * att
-        return x + jnp.einsum("blhd,hde->ble", att, blk["wo"].astype(cd))
+        return jnp.einsum("blhd,hde->ble", att, blk["wo"].astype(cd))
 
 
 def mla_apply(blk: PyTree, x: jax.Array, cd, eps: float, rope_theta: float,
-              nope: int, pairing: str = "interleaved"):
-    """A latent-attention layer's mixer with its residual (the equations at
-    the top of the module).  q and K/V go through low-rank paths with a norm
-    at the waist; the first ``nope`` dimensions of a head's q and k are
-    un-rotated, the rest rotated (``pairing``), and k's rotated part is ONE
-    head that all heads share, broadcast to them for the kernel; v has a
-    size of its own.  Everything before the attention call carries the inner
-    name ``mla_latent`` inside ``attn_proj``; the call itself ``attn_mla``
-    inside ``attn_core``."""
+              nope: int, pairing: str = "interleaved", *, hc=None):
+    """:func:`mla_branch` with its residual (:func:`residual`)."""
+    return residual(x, "attn_proj", lambda h: ([mla_branch(
+        blk, h, cd, eps, rope_theta, nope, pairing)],), hc)[0]
+
+
+def mla_branch(blk: PyTree, x: jax.Array, cd, eps: float, rope_theta: float,
+               nope: int, pairing: str = "interleaved"):
+    """A latent-attention layer's mixer, its residual left out (the
+    equations at the top of the module).  q and K/V go through low-rank
+    paths with a norm at the waist; the first ``nope`` dimensions of a
+    head's q and k are un-rotated, the rest rotated (``pairing``), and k's
+    rotated part is ONE head that all heads share, broadcast to them for the
+    kernel; v has a size of its own.  Everything before the attention call
+    carries the inner name ``mla_latent`` inside ``attn_proj``; the call
+    itself ``attn_mla`` inside ``attn_core``."""
     h = _rmsnorm(blk["ln1"], x, eps)
     rank = blk["kv_norm"]["scale"].shape[0]
     with jax.named_scope("attn_proj"), jax.named_scope("mla_latent"):
@@ -233,25 +320,46 @@ def mla_apply(blk: PyTree, x: jax.Array, cd, eps: float, rope_theta: float,
     with jax.named_scope("attn_core"), jax.named_scope("attn_mla"):
         att = local_attention(q, k, v, causal=True)
     with jax.named_scope("attn_proj"):
-        return x + jnp.einsum("blhd,hde->ble", att, blk["wo"].astype(cd))
+        return jnp.einsum("blhd,hde->ble", att, blk["wo"].astype(cd))
 
 
-def mlp_apply(blk: PyTree, x: jax.Array, cd, eps: float):
-    """A dense layer's SwiGLU MLP with its residual (a layer below
-    ``dense_layers``: no router, no expert)."""
+def mlp_apply(blk: PyTree, x: jax.Array, cd, eps: float,
+              limit: float | None = None, *, hc=None):
+    """:func:`mlp_branch` with its residual (:func:`residual`)."""
+    return residual(x, "mlp", lambda h: (
+        [mlp_branch(blk, h, cd, eps, limit)],), hc)[0]
+
+
+def mlp_branch(blk: PyTree, x: jax.Array, cd, eps: float,
+               limit: float | None = None):
+    """A dense layer's SwiGLU MLP, its residual left out (a layer below
+    ``dense_layers``: no router, no expert); ``limit``: the clamp of
+    :func:`~distlearn_tpu.parallel.ep.glu_gate`."""
     h = _rmsnorm(blk["ln2"], x, eps)
     with jax.named_scope("mlp"):
-        return x + (jax.nn.silu(h @ blk["w_gate"].astype(cd))
-                    * (h @ blk["w_up"].astype(cd))) \
+        return (glu_gate(h @ blk["w_gate"].astype(cd), "silu", limit)
+                * glu_up(h @ blk["w_up"].astype(cd), limit)) \
             @ blk["w_down"].astype(cd)
 
 
-def kda_apply(blk: PyTree, x: jax.Array, cd, eps: float):
-    """The linear-attention layer's mixer with its residual.  Everything
-    between the projections is HEAD-MAJOR ([B, H, L, .]): the projections
-    write that layout directly and the output projection reads it, so the
-    chunked core (whose chunks are blocks of one head's positions) costs no
-    transpose."""
+def kda_apply(blk: PyTree, x: jax.Array, cd, eps: float,
+              gate_lower_bound: float | None = None, beta_scale: float = 2.0,
+              *, hc=None):
+    """:func:`kda_branch` with its residual (:func:`residual`)."""
+    return residual(x, "attn_proj", lambda h: ([kda_branch(
+        blk, h, cd, eps, gate_lower_bound, beta_scale)],), hc)[0]
+
+
+def kda_branch(blk: PyTree, x: jax.Array, cd, eps: float,
+               gate_lower_bound: float | None = None,
+               beta_scale: float = 2.0):
+    """The linear-attention layer's mixer, its residual left out.
+    Everything between the projections is HEAD-MAJOR ([B, H, L, .]): the
+    projections write that layout directly and the output projection reads
+    it, so the chunked core (whose chunks are blocks of one head's
+    positions) costs no transpose.  ``gate_lower_bound`` (None: the
+    softplus gate) bounds the decay gate to (lb, 0); ``beta_scale`` is the
+    factor on ``beta``'s sigmoid."""
     H, K = blk["dt_bias"].shape
     rank = blk["wa1"].shape[1]
     h = _rmsnorm(blk["ln1"], x, eps)
@@ -271,10 +379,19 @@ def kda_apply(blk: PyTree, x: jax.Array, cd, eps: float):
         q = _l2norm(mix(q, blk["conv_q"])) * (1.0 / math.sqrt(K))
         k = _l2norm(mix(k, blk["conv_k"]))
         v = mix(v, blk["conv_v"])
-        g = -jnp.exp(blk["a_log"].astype(jnp.float32))[:, None, None] \
-            * jax.nn.softplus(a.astype(jnp.float32)
-                              + blk["dt_bias"].astype(jnp.float32)[:, None])
-        beta = 2.0 * jax.nn.sigmoid(b_in.astype(jnp.float32))
+        if gate_lower_bound is None:
+            g = -jnp.exp(blk["a_log"].astype(jnp.float32))[:, None, None] \
+                * jax.nn.softplus(
+                    a.astype(jnp.float32)
+                    + blk["dt_bias"].astype(jnp.float32)[:, None])
+        else:
+            g = gate_lower_bound * jax.nn.sigmoid(
+                jnp.exp(blk["a_log"].astype(jnp.float32))[:, None, None]
+                * (a.astype(jnp.float32)
+                   + blk["dt_bias"].astype(jnp.float32)[:, None]))
+        beta = jax.nn.sigmoid(b_in.astype(jnp.float32))
+        if beta_scale != 1.0:
+            beta = beta_scale * beta
         # an undeclared name inside the declared scope: a profile, or a
         # roofline reader, finds the delta rule's own operations by it
         with jax.named_scope("delta_rule"):
@@ -282,27 +399,97 @@ def kda_apply(blk: PyTree, x: jax.Array, cd, eps: float):
     o = _rmsnorm(blk["o_norm"], o, eps)                         # per head
     with jax.named_scope("attn_proj"):
         o = jax.nn.sigmoid(gate.astype(jnp.float32)).astype(cd) * o.astype(cd)
-        return x + jnp.einsum("bhld,hde->ble", o,
-                              blk["wo"].astype(cd).reshape(H, K, -1))
+        return jnp.einsum("bhld,hde->ble", o,
+                          blk["wo"].astype(cd).reshape(H, K, -1))
+
+
+def dsa_apply(blk: PyTree, x: jax.Array, cd, eps: float, pool: int,
+              topk: int, *, hc=None):
+    """:func:`dsa_branch` with its residual (:func:`residual`)."""
+    return residual(x, "attn_proj", lambda h: ([dsa_branch(
+        blk, h, cd, eps, pool, topk)],), hc)[0]
+
+
+def dsa_branch(blk: PyTree, x: jax.Array, cd, eps: float, pool: int,
+               topk: int):
+    """A sparse-attention layer's mixer, its residual left out (the
+    equations at the top of the module): latent attention with no rotary
+    part over the positions its indexer selects
+    (:func:`~distlearn_tpu.ops.sparse_attention.sparse_latent_attention`),
+    in the absorbed form — every head's query multiplied into the 512-wide
+    latent, the latent read as key and value, the head's value map applied
+    after.  The low-rank paths and the absorption carry the inner name
+    ``mla_latent`` inside ``attn_proj``; the indexer's projections and
+    choice ``dsa_index``, the attention ``attn_dsa``, both inside
+    ``attn_core``."""
+    h = _rmsnorm(blk["ln1"], x, eps)
+    dk = blk["wq_b"].shape[2]
+    J = blk["idx_w"].shape[1]
+    with jax.named_scope("attn_proj"), jax.named_scope("mla_latent"):
+        cq = _rmsnorm(blk["q_norm"], h @ blk["wq_a"].astype(cd), eps)
+        q = jnp.einsum("blr,rhd->blhd", cq, blk["wq_b"].astype(cd))
+        c = _rmsnorm(blk["kv_norm"], h @ blk["wkv_a"].astype(cd), eps)
+        qa = jnp.einsum("blhd,rhd->blhr", q,
+                        blk["wkv_b"][..., :dk].astype(cd))
+    with jax.named_scope("attn_core"):
+        with jax.named_scope("dsa_index"):
+            def f32(a):
+                return lax.stop_gradient(a).astype(jnp.float32)
+            hi = lax.Precision.HIGHEST
+            qi = jnp.einsum("blr,rjd->bljd", f32(cq), f32(blk["idx_wq"]),
+                            precision=hi)
+            ki = _layernorm(blk["idx_k_norm"], jnp.dot(
+                f32(h), f32(blk["idx_wk"]), precision=hi), eps)
+            wi = jnp.dot(f32(h), f32(blk["idx_w"]), precision=hi) \
+                / math.sqrt(J)
+        att = sparse_latent_attention(qa, c, qi, wi, ki, pool=pool,
+                                      topk=topk, scale=1.0 / math.sqrt(dk))
+    with jax.named_scope("attn_proj"):
+        with jax.named_scope("mla_latent"):
+            att = jnp.einsum("blhr,rhd->blhd", att,
+                             blk["wkv_b"][..., dk:].astype(cd))
+        return jnp.einsum("blhd,hde->ble", att, blk["wo"].astype(cd))
+
+
+def _layernorm(p: PyTree, x: jax.Array, eps: float):
+    """LayerNorm with scale and bias (no scope of its own: the indexer's)."""
+    mu = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
+    return (x - mu) * lax.rsqrt(var + eps) * p["scale"] + p["bias"]
 
 
 def moe_apply(blk: PyTree, x: jax.Array, cd, eps: float, held, top_k: int,
               ep_axis: str | None, route_from: jax.Array | None = None,
-              act: str = "silu", score: str = "softmax", scale: float = 1.0):
-    """The shared expert (where the layer has one) + the held experts' part
-    of the routed ones, with the residual; returns ``(y, routing
-    counters)``.  ``route_from`` [B, L, D]: what the router reads instead of
-    the experts' input; ``act``: the experts' gate activation; ``score`` and
-    ``scale``: how the router scores and the factor on its weights
-    (:func:`distlearn_tpu.parallel.ep.route_held`), its choice corrected by
-    the layer's ``router_bias`` where it has one."""
+              act: str = "silu", score: str = "softmax", scale: float = 1.0,
+              limit: float | None = None, *, hc=None):
+    """:func:`moe_branch` with its residual (:func:`residual`), its terms
+    added in order; returns ``(y, routing counters)``."""
+    return residual(x, "moe", lambda h: moe_branch(
+        blk, h, cd, eps, held, top_k, ep_axis, route_from, act, score, scale,
+        limit), hc)
+
+
+def moe_branch(blk: PyTree, x: jax.Array, cd, eps: float, held, top_k: int,
+               ep_axis: str | None, route_from: jax.Array | None = None,
+               act: str = "silu", score: str = "softmax", scale: float = 1.0,
+               limit: float | None = None):
+    """The shared expert (where the layer has one) and the held experts'
+    part of the routed ones, its residual left out; returns ``(terms,
+    routing counters)``, the terms (shared [B, L, D], routed [B L, D]) to be
+    added in that order.  ``route_from`` [B, L, D]: what the router reads
+    instead of the experts' input; ``act`` and ``limit``: the experts' gate
+    activation and clamp (:func:`~distlearn_tpu.parallel.ep.glu_gate`);
+    ``score`` and ``scale``: how the router scores and the factor on its
+    weights (:func:`distlearn_tpu.parallel.ep.route_held`), its choice
+    corrected by the layer's ``router_bias`` where it has one."""
     B, L, D = x.shape
     h = _rmsnorm(blk["ln2"], x, eps)
+    terms = []
     if "ws_gate" in blk:
         with jax.named_scope("mlp"):
-            shared = (GATE_ACTS[act](h @ blk["ws_gate"].astype(cd))
-                      * (h @ blk["ws_up"].astype(cd))) \
-                @ blk["ws_down"].astype(cd)
+            terms.append((glu_gate(h @ blk["ws_gate"].astype(cd), act, limit)
+                          * glu_up(h @ blk["ws_up"].astype(cd), limit))
+                         @ blk["ws_down"].astype(cd))
     with jax.named_scope("moe"):
         routed, aux = moe_held_ffn(
             h.reshape(B * L, D), blk["router"],
@@ -310,10 +497,76 @@ def moe_apply(blk: PyTree, x: jax.Array, cd, eps: float, held, top_k: int,
             compute_dtype=cd, ep_axis=ep_axis, act=act,
             route_from=None if route_from is None
             else route_from.reshape(B * L, D), score=score,
-            select_bias=blk.get("router_bias"), scale=scale)
-        if "ws_gate" in blk:
-            x = x + shared
-        return x + routed.reshape(B, L, D), aux
+            select_bias=blk.get("router_bias"), scale=scale, limit=limit)
+        return terms + [routed], aux
+
+
+def _hc_counter():
+    return obs.counter(
+        "hyper_connections_total", "sublayers traced inside a manifold-"
+        "constrained hyper-connection (the mixer and the FFN of a layer "
+        "count two)")
+
+
+def hyper_connections_traced() -> int:
+    """Sublayers traced so far inside a hyper-connection in this process
+    (the ``hyper_connections_total`` counter; 0 with ``DISTLEARN_OBS=0``)."""
+    family = _hc_counter()
+    if family is obs.NULL:
+        return 0
+    return sum(s["value"] for s in family.sample())
+
+
+def sinkhorn(m: jax.Array, iters: int, eps: float) -> jax.Array:
+    """``iters`` rounds of Sinkhorn-Knopp over the last two axes of the
+    positive ``m``: each round divides every row by its sum + ``eps``, then
+    every column by its sum + ``eps``."""
+    def round_(_, m):
+        m = m / (jnp.sum(m, axis=-1, keepdims=True) + eps)
+        return m / (jnp.sum(m, axis=-2, keepdims=True) + eps)
+    return lax.fori_loop(0, iters, round_, m)
+
+
+def hc_maps(hc: PyTree, X: jax.Array, iters: int, eps: float,
+            norm_eps: float):
+    """``(h_pre [B, L, n], h_post [B, L, n], H_res [B, L, n, n])`` of the
+    streams ``X`` [B, L, n, C], float32 (the equations at the top of the
+    module): the maps read the RMS-normed concatenation of the streams —
+    the norm's scalar applied after the product, so the normed [B, L, n C]
+    is never an array."""
+    B, L, n, C = X.shape
+    v = X.reshape(B, L, n * C).astype(jnp.float32)
+    r = lax.rsqrt(jnp.mean(jnp.square(v), axis=-1, keepdims=True) + norm_eps)
+    z = jnp.dot(v, hc["phi"].astype(jnp.float32),
+                precision=lax.Precision.HIGHEST) * r
+    a = hc["alpha"].astype(jnp.float32)
+    pre = jax.nn.sigmoid(a[0] * z[..., :n] + hc["b_pre"])
+    post = 2.0 * jax.nn.sigmoid(a[1] * z[..., n:2 * n] + hc["b_post"])
+    res = sinkhorn(jnp.exp(a[2] * z[..., 2 * n:].reshape(B, L, n, n)
+                           + hc["b_res"]), iters, eps)
+    return pre, post, res
+
+
+def hc_sublayer(hc: PyTree, X: jax.Array, branch, iters: int, eps: float,
+                norm_eps: float):
+    """One sublayer inside its hyper-connection: ``branch`` (the read-in
+    mix ``sum_i h_pre_i X_i`` [B, L, C] -> its terms, to be summed) and the
+    write-back ``X'_i = sum_j H_res_ij X_j + h_post_i y``; returns ``(X',
+    whatever the branch returned after its terms)``.  The maps, Sinkhorn,
+    read-in and write-back carry the scope ``mhc``; the streams stay in
+    their dtype between sublayers and are mixed in float32."""
+    _hc_counter().inc()
+    n = X.shape[2]
+    with jax.named_scope("mhc"):
+        pre, post, res = hc_maps(hc, X, iters, eps, norm_eps)
+        Xf = X.astype(jnp.float32)
+        x = sum(pre[..., i, None] * Xf[:, :, i] for i in range(n))
+    terms, *rest = branch(x.astype(X.dtype))
+    with jax.named_scope("mhc"):
+        y = sum(t.reshape(x.shape).astype(jnp.float32) for t in terms)
+        out = sum(res[..., j, None] * Xf[:, :, None, j] for j in range(n)) \
+            + post[..., None] * y[:, :, None]
+        return out.astype(X.dtype), *rest
 
 
 def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
@@ -337,7 +590,15 @@ def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
               attn_gate: str | None = None,
               full_rope_theta: float | None = None,
               full_rope_fraction: float = 1.0,
-              full_rope_yarn: Mapping[str, float] | None = None) -> Model:
+              full_rope_yarn: Mapping[str, float] | None = None,
+              index_heads: int | None = None,
+              index_head_dim: int | None = None,
+              index_topk: int | None = None, index_pool: int | None = None,
+              hc_mult: int = 0, hc_sinkhorn_iters: int = 20,
+              hc_eps: float = 1e-6,
+              kda_gate_lower_bound: float | None = None,
+              kda_beta_scale: float = 2.0,
+              swiglu_limit: float | None = None) -> Model:
     """Returns a :class:`Model` mapping int tokens [B, L] to next-token
     logits [B, L, vocab] (untied head).
 
@@ -385,6 +646,29 @@ def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
     more layer ``block`` of the LAST layer's kind with a mixture, ``norm``),
     run in training only; ``mtp_weight`` is the weight ``lm_loss`` gives its
     loss (also ``apply.mtp_weight``, for whoever builds a step).
+
+    A ``"dsa"`` layer (sparse attention over latent attention with no
+    rotary part) takes ``heads`` heads, ``q_lora_rank`` / ``kv_lora_rank``
+    / ``qk_nope_head_dim`` (its score head) / ``v_head_dim`` and its
+    indexer's ``index_heads`` x ``index_head_dim``, the ``index_topk``
+    positions each query keeps and the ``index_pool`` keys a pooled block
+    holds (all required by a pattern that has one); ``qk_rope_head_dim``
+    must be 0 or None there.
+
+    ``hc_mult`` = n > 0 turns the residual into n streams wrapped around
+    every sublayer by a manifold-constrained hyper-connection (the
+    equations at the top of the module; ``params[layer]["hc_mix"]`` and
+    ``["hc_ffn"]``: ``phi`` [n dim, 2n + n^2], ``alpha`` [3], ``b_pre``,
+    ``b_post`` [n], ``b_res`` [n, n]), ``hc_sinkhorn_iters`` rounds of
+    Sinkhorn-Knopp with ``hc_eps``; drawn with every ``alpha`` at
+    :data:`HC_ALPHA`, ``b_res`` :data:`HC_RES_DIAG` on the diagonal and 0
+    elsewhere, ``b_pre`` and ``b_post`` 0.  0 (default): the plain
+    residual, no ``hc_*`` leaf.
+    ``kda_gate_lower_bound`` (None: the softplus gate) and
+    ``kda_beta_scale`` (2.0) are every ``"kda"`` layer's decay gate and
+    factor on ``beta``; ``swiglu_limit`` (None: none) clamps every SwiGLU —
+    the dense MLP, the shared and the routed experts
+    (:func:`~distlearn_tpu.parallel.ep.glu_gate`).
 
     ``remat`` (True = ``"full"``) makes each layer one checkpoint
     (:func:`distlearn_tpu.models.core.checkpoint_block`): its activations
@@ -439,6 +723,20 @@ def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
     if "mla" in layer_types and not all(mla.values()):
         raise ValueError("an 'mla' layer needs " + ", ".join(
             k for k, v in mla.items() if not v) + f" (got {mla!r})")
+    dsa = {"q_lora_rank": q_lora_rank, "kv_lora_rank": kv_lora_rank,
+           "qk_nope_head_dim": qk_nope_head_dim, "v_head_dim": v_head_dim,
+           "index_heads": index_heads, "index_head_dim": index_head_dim,
+           "index_topk": index_topk, "index_pool": index_pool}
+    if "dsa" in layer_types and (not all(dsa.values())
+                                 or qk_rope_head_dim):
+        raise ValueError("a 'dsa' layer needs " + ", ".join(
+            k for k, v in dsa.items() if not v) + " and no rotary part "
+            f"(got {dsa!r}, qk_rope_head_dim={qk_rope_head_dim!r})")
+    if hc_mult < 0 or (hc_mult and (router_input != "ffn_norm"
+                                    or mtp_depth)):
+        raise ValueError(f"hc_mult={hc_mult!r}: 0 (off) or a number of "
+                         "streams, whose routers read the experts' input "
+                         "and which no prediction module follows")
     if rope_pairing not in ROPE_PAIRINGS:
         raise ValueError(f"rope_pairing must be one of {ROPE_PAIRINGS}, got "
                          f"{rope_pairing!r}")
@@ -469,14 +767,45 @@ def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
     cd = compute_dtype or dtype
     depth = len(layer_types)
 
-    # a softmax router's call is the one it always was (no new keyword)
-    router_kw = {} if router_score == "softmax" and routed_scale == 1.0 \
+    # a softmax router's unclamped call is the one it always was (no new
+    # keyword)
+    moe_kw = {} if router_score == "softmax" and routed_scale == 1.0 \
         else {"score": router_score, "scale": routed_scale}
+    if swiglu_limit is not None:
+        moe_kw["limit"] = swiglu_limit
+
+    def init_hc(key):
+        n = hc_mult
+        return {"phi": _dense(key, (n * dim, 2 * n + n * n), n * dim, dtype),
+                "alpha": jnp.full((3,), HC_ALPHA, dtype),
+                "b_pre": jnp.zeros((n,), dtype),
+                "b_post": jnp.zeros((n,), dtype),
+                "b_res": HC_RES_DIAG * jnp.eye(n, dtype=dtype)}
 
     def init_layer(key, kind, heads, dense=False):
         ks = iter(random.split(key, 24))
         nk = lambda: next(ks)                                # noqa: E731
-        if kind == "mla":
+        if kind == "dsa":
+            dk, J, dI = qk_nope_head_dim, index_heads, index_head_dim
+            blk = {
+                "wq_a": _dense(nk(), (dim, q_lora_rank), dim, dtype),
+                "q_norm": _norm_init((q_lora_rank,), dtype),
+                "wq_b": _dense(nk(), (q_lora_rank, heads, dk), q_lora_rank,
+                               dtype),
+                "wkv_a": _dense(nk(), (dim, kv_lora_rank), dim, dtype),
+                "kv_norm": _norm_init((kv_lora_rank,), dtype),
+                "wkv_b": _dense(nk(), (kv_lora_rank, heads, dk + v_head_dim),
+                                kv_lora_rank, dtype),
+                "wo": _dense(nk(), (heads, v_head_dim, dim),
+                             heads * v_head_dim, dtype),
+                "idx_wq": _dense(nk(), (q_lora_rank, J, dI), q_lora_rank,
+                                 dtype),
+                "idx_wk": _dense(nk(), (dim, dI), dim, dtype),
+                "idx_k_norm": {"scale": jnp.ones((dI,), dtype),
+                               "bias": jnp.zeros((dI,), dtype)},
+                "idx_w": _dense(nk(), (dim, J), dim, dtype),
+            }
+        elif kind == "mla":
             qk = qk_nope_head_dim + qk_rope_head_dim
             blk = {
                 "wq_a": _dense(nk(), (dim, q_lora_rank), dim, dtype),
@@ -530,6 +859,8 @@ def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
             }
         blk.update({"ln1": _norm_init((dim,), dtype),
                     "ln2": _norm_init((dim,), dtype)})
+        if hc_mult:
+            blk.update(hc_mix=init_hc(nk()), hc_ffn=init_hc(nk()))
         if dense:
             return dict(blk, **{
                 "w_gate": _dense(nk(), (dim, dense_width), dim, dtype),
@@ -583,26 +914,43 @@ def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
                              f"seq_layout={seq_layout!r}")
         with jax.named_scope("embed"):
             x = params["embed"][tokens].astype(cd)
+        if hc_mult:
+            with jax.named_scope("mhc"):
+                x = jnp.broadcast_to(x[:, :, None],
+                                     x.shape[:2] + (hc_mult, dim))
+
+        def mixer(blk, kind, x, **hc):
+            """The layer's mixer with its residual."""
+            if kind == "kda":
+                return kda_apply(blk, x, cd, eps, kda_gate_lower_bound,
+                                 kda_beta_scale, **hc)
+            if kind == "dsa":
+                return dsa_apply(blk, x, cd, eps, index_pool, index_topk,
+                                 **hc)
+            if kind == "mla":
+                return mla_apply(blk, x, cd, eps, rope_theta,
+                                 qk_nope_head_dim, rope_pairing, **hc)
+            if kind == "window":
+                return gqa_apply(blk, x, cd, eps, window, rope_theta, **hc)
+            if kind == "full" and full_rope_theta:
+                return gqa_apply(blk, x, cd, eps, None, full_rope_theta,
+                                 **full_rope, **hc)
+            return gqa_apply(blk, x, cd, eps, **hc)
 
         def make_layer(kind, dense):
             def layer(blk, x):
                 route_from = x if router_input == "layer_input" else None
-                if kind == "kda":
-                    x = kda_apply(blk, x, cd, eps)
-                elif kind == "mla":
-                    x = mla_apply(blk, x, cd, eps, rope_theta,
-                                  qk_nope_head_dim, rope_pairing)
-                elif kind == "window":
-                    x = gqa_apply(blk, x, cd, eps, window, rope_theta)
-                elif kind == "full" and full_rope_theta:
-                    x = gqa_apply(blk, x, cd, eps, None, full_rope_theta,
-                                  **full_rope)
-                else:
-                    x = gqa_apply(blk, x, cd, eps)
+
+                def hc(name):       # none: each call the one it always was
+                    return {"hc": (blk[name], hc_sinkhorn_iters, hc_eps,
+                                   eps)} if hc_mult else {}
+                x = mixer(blk, kind, x, **hc("hc_mix"))
                 if dense:
-                    return mlp_apply(blk, x, cd, eps), None
+                    return mlp_apply(blk, x, cd, eps, swiglu_limit,
+                                     **hc("hc_ffn")), None
                 return moe_apply(blk, x, cd, eps, held, experts_per_tok,
-                                 ep_axis, route_from, expert_act, **router_kw)
+                                 ep_axis, route_from, expert_act, **moe_kw,
+                                 **hc("hc_ffn"))
             return checkpoint_block(layer) if remat else layer
 
         # one wrapper a kind, reused down the depth (transformer_lm's note:
@@ -617,6 +965,9 @@ def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
         for i, k in enumerate(kinds):
             x, aux = layers[k](params[f"layer{i}"], x)
             counters.append(aux)
+        if hc_mult:
+            with jax.named_scope("mhc"):
+                x = jnp.sum(x.astype(jnp.float32), axis=2).astype(cd)
         x = _rmsnorm(params["out_norm"], x, eps)
         with jax.named_scope("head_loss"):
             logits = (x @ params["head"].astype(cd)).astype(dtype)

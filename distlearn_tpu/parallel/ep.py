@@ -367,6 +367,19 @@ def _dot(a, b, eq="ij,jk->ik"):
 #: grouped product: its backward pass is written out for each)
 GATE_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
+
+def glu_gate(a, act: str = "silu", limit: float | None = None):
+    """The gate half of a gated linear unit, ``act(a)``; with ``limit`` (a
+    clamped SwiGLU, ``act(min(a, limit)) * clip(b, -limit, limit)``) the
+    pre-activation is clamped from above first."""
+    return GATE_ACTS[act](a if limit is None else jnp.minimum(a, limit))
+
+
+def glu_up(b, limit: float | None = None):
+    """The linear half of a gated linear unit: ``b``, clipped to ``[-limit,
+    limit]`` where a limit is given (:func:`glu_gate`)."""
+    return b if limit is None else jnp.clip(b, -limit, limit)
+
 #: the implementations of the grouped product (:func:`select_grouped`)
 GROUPED_IMPLS = ("xla", "gmm")
 
@@ -376,8 +389,16 @@ GROUPED_IMPLS = ("xla", "gmm")
 #: for the chip).  Their row tile is :data:`GROUP_TILE`.
 _GMM_VMEM = 15.5 * 2 ** 20
 #: the fewest assignments an expert is EXPECTED to get (``N top_k / E``) for
-#: the kernel path
-_GMM_MIN_ROWS = GROUP_TILE
+#: the kernel path: 7/8 of a tile.  NOT where the two paths cross: timed
+#: alone on a v5e (forward and backward, 8 experts, D 4096, F 1280 and
+#: 2048), the kernels beat the loop at every load from 128 rows an expert
+#: to 256, by 2-25 % (PERF.md section 6).  Near a tile the seed's routing
+#: decides how many experts spill into a second one, which the loop pays
+#: for (at 228 rows an expert the step's length spread 0.58 % over six
+#: seeds on the loop, 0.31 % on the kernels); below 7/8 the layers of 205
+#: rows an expert (320 experts, 8,192 tokens) stay on the loop, their
+#: move to the kernels not yet measured end to end.
+_GMM_MIN_ROWS = GROUP_TILE * 7 // 8
 
 
 def select_grouped(backend: str, dtype, D: int, F: int,
@@ -415,10 +436,21 @@ def grouped_paths_traced() -> dict[str, int]:
     return {s["labels"]["impl"]: s["value"] for s in family.sample()}
 
 
-def _hidden(a, b, act, cd):
+def _hidden(a, b, act, cd, limit=None):
     """The hidden layer ``act(a) * b`` of pre-activations ``a``, ``b``
     (float32) rounded to ``cd``, and the pull-back ``dh -> (db, da)`` of a
-    float32 cotangent of it, each rounded to ``cd``."""
+    float32 cotangent of it, each rounded to ``cd``; with ``limit`` the
+    clamped unit of :func:`glu_gate`, whose clamps pass no cotangent where
+    they bite."""
+    if limit is not None:
+        h, pull = _hidden(jnp.minimum(a, limit), jnp.clip(b, -limit, limit),
+                          act, cd)
+
+        def clamped(dh):
+            db, da = pull(dh)
+            return (jnp.where(jnp.abs(b) < limit, db, 0).astype(cd),
+                    jnp.where(a < limit, da, 0).astype(cd))
+        return h, clamped
     if act == "silu":
         s = jax.nn.sigmoid(a)
         return (a * s * b).astype(cd), lambda dh: (
@@ -429,23 +461,23 @@ def _hidden(a, b, act, cd):
         (dh * r).astype(cd), jnp.where(a > 0.0, dh * b, 0.0).astype(cd))
 
 
-def _xla_fwd(x, wg, wu, wd, slot_w, plan, cd, act, first=0, y=None):
+def _xla_fwd(x, wg, wu, wd, slot_w, plan, cd, act, first=0, y=None,
+             limit=None):
     """The ``"xla"`` path: one loop over the tiles IN USE (a dynamic trip
     count) from tile ``first`` on, adding into ``y`` (None: zeros): a tile's
     rows gathered, its expert's weights sliced out of the float32 stacks and
     cast, its result scatter-added, every iteration."""
-    gate = GATE_ACTS[act]
-
     def body(i, y):
         idx, w, xe, e = _tile(plan, slot_w, x, i)
         g, u, d = _expert((wg, wu, wd), e, cd)
-        h = gate(_dot(xe, g)) * _dot(xe, u)
+        h = glu_gate(_dot(xe, g), act, limit) * glu_up(_dot(xe, u), limit)
         return y.at[idx].add(_dot(h.astype(cd), d) * w[:, None])
     return lax.fori_loop(first, plan[2], body,
                          jnp.zeros(x.shape, jnp.float32) if y is None else y)
 
 
-def _xla_bwd(x, wg, wu, wd, slot_w, plan, cd, act, dy, first=0, carry=None):
+def _xla_bwd(x, wg, wu, wd, slot_w, plan, cd, act, dy, first=0, carry=None,
+             limit=None):
     """The ``"xla"`` path's backward: a second such loop that recomputes
     each tile's hidden layer, adding into ``carry = (dx, dwg, dwu, dwd,
     dslot_w)`` (None: zeros)."""
@@ -455,7 +487,7 @@ def _xla_bwd(x, wg, wu, wd, slot_w, plan, cd, act, dy, first=0, carry=None):
         dx, dg, du, dd, dw = carry
         idx, w, xe, e = _tile(plan, slot_w, x, i)
         g, u, d = _expert((wg, wu, wd), e, cd)
-        h, pull = _hidden(_dot(xe, g), _dot(xe, u), act, cd)
+        h, pull = _hidden(_dot(xe, g), _dot(xe, u), act, cd, limit)
         dyt = dy[idx]
         dw = lax.dynamic_update_slice_in_dim(
             dw, jnp.sum(dyt.astype(jnp.float32) * _dot(h, d), axis=-1),
@@ -717,20 +749,21 @@ def _packed(plan, slot_w, per):
             jnp.arange(per * tile, dtype=jnp.int32) < n_used * tile)
 
 
-def _gmm_fwd(x, wg, wu, wd, slot_w, plan, cd, act, per):
+def _gmm_fwd(x, wg, wu, wd, slot_w, plan, cd, act, per, limit=None):
     interpret = sequence._backend() != "tpu"
     N = x.shape[0]
     tok, w, tile_expert, n_used, _ = _packed(plan, slot_w, per)
     gmm = functools.partial(_gmm, tile_expert, n_used, interpret=interpret)
     xs = x[jnp.minimum(tok, N - 1)]
-    h = (GATE_ACTS[act](gmm(xs, wg.astype(cd)))
-         * gmm(xs, wu.astype(cd))).astype(cd)
+    h = (glu_gate(gmm(xs, wg.astype(cd)), act, limit)
+         * glu_up(gmm(xs, wu.astype(cd)), limit)).astype(cd)
     o = (gmm(h, wd.astype(cd)) * w[:, None]).astype(cd)
     return _xla_fwd(x, wg, wu, wd, slot_w, plan, cd, act, per,
-                    _sum_by_token(o, tok, N, o.shape[0] // per, interpret))
+                    _sum_by_token(o, tok, N, o.shape[0] // per, interpret),
+                    limit)
 
 
-def _gmm_bwd(x, wg, wu, wd, slot_w, plan, cd, act, dy, per):
+def _gmm_bwd(x, wg, wu, wd, slot_w, plan, cd, act, dy, per, limit=None):
     """As :func:`_xla_bwd` for the packed rows, with two of its sums taken
     the cheaper way round.  ``d slot_w[p] = sum_f h[p, f] * (dy[rows[p]]
     wd^T)[f]`` — the same number as ``sum_d dy[rows[p], d] * (h wd)[p, d]``
@@ -749,7 +782,7 @@ def _gmm_bwd(x, wg, wu, wd, slot_w, plan, cd, act, dy, per):
     xs, dyt = x[idx], dy[idx]
     wgu = jnp.concatenate([wg.astype(cd), wu.astype(cd)], axis=2)
     ab = gmm(xs, wgu)
-    h, pull = _hidden(ab[:, :F], ab[:, F:], act, cd)
+    h, pull = _hidden(ab[:, :F], ab[:, F:], act, cd, limit)
     dhu = gmm(dyt, wd.astype(cd), transpose_rhs=True)
     # rows past the tiles in use come out of the kernels unwritten (and no
     # kernel reads them): a select, where a weight of 0 would not do
@@ -761,17 +794,18 @@ def _gmm_bwd(x, wg, wu, wd, slot_w, plan, cd, act, dy, per):
         _sum_by_token(dxs, tok, N, dxs.shape[0] // per, interpret),
         tgmm(xs, da), tgmm(xs, db),
         tgmm((h * w[:, None]).astype(cd), dyt),
-        jnp.pad(dw, (0, slot_w.shape[0] - dw.shape[0]))))
+        jnp.pad(dw, (0, slot_w.shape[0] - dw.shape[0]))), limit)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
 def grouped_glu(x, wg, wu, wd, slot_w, plan, cd, act="silu", impl="xla",
-                chunk=None):
+                chunk=None, limit=None):
     """``y[n] = sum over the slots p of token n of slot_w[p] *
     GLU_{e(p)}(x[n])``, ``GLU(x) = (act(x wg) * (x wu)) wd`` — the grouped
     product of :func:`route_held`'s plan.  x [N, D]; wg, wu [G, D, F]; wd
     [G, F, D] (float32); ``act`` one of :data:`GATE_ACTS` (``"silu"``:
-    SwiGLU, ``"relu"``: ReGLU); returns [N, D] float32.  Operands in ``cd``,
+    SwiGLU, ``"relu"``: ReGLU), clamped where ``limit`` is given
+    (:func:`glu_gate`); returns [N, D] float32.  Operands in ``cd``,
     sums in float32, the hidden layer rounded to ``cd``, whichever path.
 
     ``impl`` (:data:`GROUPED_IMPLS`; :func:`moe_held_ffn` asks
@@ -784,24 +818,24 @@ def grouped_glu(x, wg, wu, wd, slot_w, plan, cd, act="silu", impl="xla",
     recomputes the hidden layer."""
     if impl == "gmm":
         return _gmm_fwd(x, wg, wu, wd, slot_w, plan, cd, act,
-                        _kernel_tiles(plan, chunk))
-    return _xla_fwd(x, wg, wu, wd, slot_w, plan, cd, act)
+                        _kernel_tiles(plan, chunk), limit)
+    return _xla_fwd(x, wg, wu, wd, slot_w, plan, cd, act, limit=limit)
 
 
-def _gg_fwd(x, wg, wu, wd, slot_w, plan, cd, act, impl, chunk):
-    return grouped_glu(x, wg, wu, wd, slot_w, plan, cd, act, impl, chunk), \
-        (x, wg, wu, wd, slot_w, plan)
+def _gg_fwd(x, wg, wu, wd, slot_w, plan, cd, act, impl, chunk, limit):
+    return grouped_glu(x, wg, wu, wd, slot_w, plan, cd, act, impl, chunk,
+                       limit), (x, wg, wu, wd, slot_w, plan)
 
 
-def _gg_bwd(cd, act, impl, chunk, res, dy):
+def _gg_bwd(cd, act, impl, chunk, limit, res, dy):
     x, wg, wu, wd, slot_w, plan = res
     dy = dy.astype(cd)
     if impl == "gmm":
         dx, dg, du, dd, dw = _gmm_bwd(x, wg, wu, wd, slot_w, plan, cd, act,
-                                      dy, _kernel_tiles(plan, chunk))
+                                      dy, _kernel_tiles(plan, chunk), limit)
     else:
         dx, dg, du, dd, dw = _xla_bwd(x, wg, wu, wd, slot_w, plan, cd, act,
-                                      dy)
+                                      dy, limit=limit)
     return (dx.astype(x.dtype), dg.astype(wg.dtype), du.astype(wu.dtype),
             dd.astype(wd.dtype), dw, None)
 
@@ -813,7 +847,8 @@ def moe_held_ffn(x: jax.Array, router_w: jax.Array, experts, held,
                  top_k: int, *, compute_dtype=None,
                  ep_axis: str | None = None, route_from: jax.Array | None = None,
                  act: str = "silu", score: str = "softmax",
-                 select_bias: jax.Array | None = None, scale: float = 1.0):
+                 select_bias: jax.Array | None = None, scale: float = 1.0,
+                 limit: float | None = None):
     """The held experts' part of a routed gated-linear-unit layer (see the
     section comment above): ``x`` [N, D], ``router_w`` [D, E], ``experts =
     (wg, wu, wd)`` stacked over ``len(held)``, their gate activation ``act``
@@ -825,7 +860,8 @@ def moe_held_ffn(x: jax.Array, router_w: jax.Array, experts, held,
     before attention, and feeds them the post-attention norm); None routes
     from ``x``.  ``score``, ``select_bias``, ``scale``: :func:`route_held`'s
     (how the router scores, the correction bias of its choice, the factor
-    on its weights).
+    on its weights).  ``limit``: the clamp of a clamped SwiGLU
+    (:func:`glu_gate`; None: none).
 
     ``ep_axis``: the mesh axis over which other devices hold the other
     experts.  With it the same layer is the expert-parallel one — every
@@ -855,5 +891,5 @@ def moe_held_ffn(x: jax.Array, router_w: jax.Array, experts, held,
         score=score, select_bias=select_bias, scale=scale)
     chunk = _gmm_chunk(expected, G) if impl == "gmm" else None
     y = grouped_glu(x.astype(cd), *experts, slot_w, plan, cd, act, impl,
-                    chunk)
+                    chunk, limit)
     return y.astype(cd), aux

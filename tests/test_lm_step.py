@@ -507,8 +507,10 @@ def _scoped_hybrid():
                          donate=False), params, tokens
 
 
-#: the scopes of ``models.core.SCOPES`` only the pattern LM uses
+#: the scopes of ``models.core.SCOPES`` only the pattern LM uses, and the
+#: one only a pattern LM with hyper-connections uses (neither toy has them)
 _HYBRID_ONLY = {"linattn_core", "moe"}
+_HC_ONLY = {"mhc"}
 
 
 @pytest.fixture(scope="module", params=["dense", "hybrid"])
@@ -563,7 +565,8 @@ def test_update_and_grad_reduce_sit_outside_the_passes(scoped_op_names):
     # less — the dense model all of it but the pattern LM's two names
     used = {s for s in SCOPES
             if any(_has_scope(n, s) for n in op_names)}
-    assert used == set(SCOPES) - (_HYBRID_ONLY if kind == "dense" else set())
+    assert used == set(SCOPES) - _HC_ONLY \
+        - (_HYBRID_ONLY if kind == "dense" else set())
 
 
 def test_scopes_change_no_number(monkeypatch):

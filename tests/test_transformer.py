@@ -545,7 +545,7 @@ def test_tpu_compiler_keeps_every_scope_at_gpt2_large_width(
                                max_len=1024, compute_dtype=jnp.bfloat16,
                                scan_blocks=True, remat="full")
         batch, kernel, square = 8, "splash_mha", "[8,20,1024,1024]"
-        mine = set(SCOPES) - {"linattn_core", "moe"}
+        mine = set(SCOPES) - {"linattn_core", "moe", "mhc"}
     else:
         from distlearn_tpu.models import hybrid_lm
         model = hybrid_lm(vocab=4096, dim=4096, layer_types=("gqa", "kda"),
@@ -555,7 +555,7 @@ def test_tpu_compiler_keeps_every_scope_at_gpt2_large_width(
                           expert_width=1280, max_len=1024,
                           compute_dtype=jnp.bfloat16, remat="full")
         batch, kernel, square = 1, "splash_mqa", "[1,64,1024,1024]"
-        mine = set(SCOPES)
+        mine = set(SCOPES) - {"mhc"}        # no hyper-connections here
     compiled = _compiled_lm_step(model, [v5e_chip], batch)
     text = compiled.as_text()
     table = scope_table(text)
