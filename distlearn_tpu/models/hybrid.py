@@ -27,11 +27,11 @@ order):
     with swiglu_limit = c, in the MLP and every expert:
           (silu(min(m Wg, c)) * clip(m Wu, -c, c)) Wd
 
-With ``hc_mult`` = n the stream is ``X`` [n, C] a position, and each of the
-two sublayers ``F`` of a layer (the pre-norm mixer or FFN above WITHOUT its
-residual) is wrapped (``hc_mix`` / ``hc_ffn``: ``phi`` [n C, 2n + n^2] =
-[phi_pre | phi_post | phi_res], ``alpha`` [3], ``b_pre``, ``b_post`` [n],
-``b_res`` [n, n]):
+With ``hc_mult`` = n the stream is ``X`` [n, C] a position (held as vec X,
+the n streams side by side), and each of the two sublayers ``F`` of a layer
+(the pre-norm mixer or FFN above WITHOUT its residual) is wrapped
+(``hc_mix`` / ``hc_ffn``: ``phi`` [n C, 2n + n^2] = [phi_pre | phi_post |
+phi_res], ``alpha`` [3], ``b_pre``, ``b_post`` [n], ``b_res`` [n, n]):
 
     x^    = rmsnorm(vec X)                      over n C, no scale of its own
     h_pre  = sigmoid(alpha_pre  x^ phi_pre  + b_pre)                 [n]
@@ -153,8 +153,10 @@ from distlearn_tpu import obs
 from distlearn_tpu.models.core import Model, checkpoint_block
 from distlearn_tpu.models.transformer import (ROPE_PAIRINGS, Yarn,
                                               _norm_init, _rmsnorm, rotary)
+from distlearn_tpu.ops import hyper_connection
 from distlearn_tpu.ops.delta_rule import chunked_delta_rule
 from distlearn_tpu.ops.sparse_attention import sparse_latent_attention
+from distlearn_tpu.parallel import sequence
 from distlearn_tpu.parallel.ep import (GATE_ACTS, ROUTER_SCORES, glu_gate,
                                       glu_up, moe_held_ffn)
 from distlearn_tpu.parallel.sequence import local_attention
@@ -505,16 +507,17 @@ def _hc_counter():
     return obs.counter(
         "hyper_connections_total", "sublayers traced inside a manifold-"
         "constrained hyper-connection (the mixer and the FFN of a layer "
-        "count two)")
+        "count two), by the resolved implementation", labels=("impl",))
 
 
-def hyper_connections_traced() -> int:
-    """Sublayers traced so far inside a hyper-connection in this process
-    (the ``hyper_connections_total`` counter; 0 with ``DISTLEARN_OBS=0``)."""
+def hyper_connections_traced() -> dict[str, int]:
+    """``{impl: sublayers traced so far inside a hyper-connection}`` in
+    this process (the ``hyper_connections_total`` counter; empty with
+    ``DISTLEARN_OBS=0``)."""
     family = _hc_counter()
     if family is obs.NULL:
-        return 0
-    return sum(s["value"] for s in family.sample())
+        return {}
+    return {s["labels"]["impl"]: s["value"] for s in family.sample()}
 
 
 def sinkhorn(m: jax.Array, iters: int, eps: float) -> jax.Array:
@@ -549,15 +552,31 @@ def hc_maps(hc: PyTree, X: jax.Array, iters: int, eps: float,
 
 def hc_sublayer(hc: PyTree, X: jax.Array, branch, iters: int, eps: float,
                 norm_eps: float):
-    """One sublayer inside its hyper-connection: ``branch`` (the read-in
-    mix ``sum_i h_pre_i X_i`` [B, L, C] -> its terms, to be summed) and the
-    write-back ``X'_i = sum_j H_res_ij X_j + h_post_i y``; returns ``(X',
-    whatever the branch returned after its terms)``.  The maps, Sinkhorn,
-    read-in and write-back carry the scope ``mhc``; the streams stay in
-    their dtype between sublayers and are mixed in float32."""
-    _hc_counter().inc()
-    n = X.shape[2]
+    """One sublayer inside its hyper-connection, the n streams held
+    side by side (``X`` [B, L, n C], vec X a position): ``branch`` (the
+    read-in mix ``sum_i h_pre_i X_i`` [B, L, C] -> its terms, to be summed)
+    and the write-back ``X'_i = sum_j H_res_ij X_j + h_post_i y``; returns
+    ``(X' [B, L, n C], whatever the branch returned after its terms)``.  The
+    maps, Sinkhorn, read-in and write-back carry the scope ``mhc``; the
+    streams stay in their dtype between sublayers and are mixed in float32.
+    The path is :func:`~distlearn_tpu.ops.hyper_connection.select_hc`'s
+    (counted under ``hyper_connections_total{impl=}``): ``"kernel"`` the
+    module's Pallas read-in and write-back, ``"xla"`` the ``jnp`` form
+    below."""
+    B, L, N = X.shape
+    n = hc["b_pre"].shape[0]
+    impl = hyper_connection.select_hc(sequence._backend(), X.dtype, n,
+                                      N // n)
+    _hc_counter().labels(impl=impl).inc()
+    if impl == "kernel":
+        with jax.named_scope("mhc"):
+            x, link = hyper_connection.read_in(hc, X, n, iters, eps,
+                                               norm_eps)
+        terms, *rest = branch(x)
+        with jax.named_scope("mhc"):
+            return hyper_connection.write_back(link, terms), *rest
     with jax.named_scope("mhc"):
+        X = X.reshape(B, L, n, N // n)
         pre, post, res = hc_maps(hc, X, iters, eps, norm_eps)
         Xf = X.astype(jnp.float32)
         x = sum(pre[..., i, None] * Xf[:, :, i] for i in range(n))
@@ -566,7 +585,7 @@ def hc_sublayer(hc: PyTree, X: jax.Array, branch, iters: int, eps: float,
         y = sum(t.reshape(x.shape).astype(jnp.float32) for t in terms)
         out = sum(res[..., j, None] * Xf[:, :, None, j] for j in range(n)) \
             + post[..., None] * y[:, :, None]
-        return out.astype(X.dtype), *rest
+        return out.astype(X.dtype).reshape(B, L, N), *rest
 
 
 def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
@@ -914,10 +933,9 @@ def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
                              f"seq_layout={seq_layout!r}")
         with jax.named_scope("embed"):
             x = params["embed"][tokens].astype(cd)
-        if hc_mult:
+        if hc_mult:             # the streams side by side, [B, L, n dim]
             with jax.named_scope("mhc"):
-                x = jnp.broadcast_to(x[:, :, None],
-                                     x.shape[:2] + (hc_mult, dim))
+                x = jnp.concatenate([x] * hc_mult, axis=-1)
 
         def mixer(blk, kind, x, **hc):
             """The layer's mixer with its residual."""
@@ -967,7 +985,8 @@ def hybrid_lm(vocab: int, dim: int, layer_types: Sequence[str], *,
             counters.append(aux)
         if hc_mult:
             with jax.named_scope("mhc"):
-                x = jnp.sum(x.astype(jnp.float32), axis=2).astype(cd)
+                x = jnp.sum(x.reshape(x.shape[:2] + (hc_mult, dim)).astype(
+                    jnp.float32), axis=2).astype(cd)
         x = _rmsnorm(params["out_norm"], x, eps)
         with jax.named_scope("head_loss"):
             logits = (x @ params["head"].astype(cd)).astype(dtype)
