@@ -34,6 +34,8 @@ from jax.sharding import PartitionSpec as P
 from distlearn_tpu import obs
 from distlearn_tpu.models.core import (Model, checkpoint_block,
                                        scan_reducing)
+from distlearn_tpu.ops import cross_entropy
+from distlearn_tpu.parallel import sequence
 from distlearn_tpu.parallel.sequence import (alltoall_attention,
                                              local_attention, ring_attention)
 from distlearn_tpu.parallel.tp import tp_enter, tp_reduce
@@ -766,6 +768,62 @@ def param_specs(params: PyTree, tp_axis: str | None,
     return jax.tree_util.tree_map_with_path(spec_for, params)
 
 
+def _xent_counter():
+    return obs.counter(
+        "xent_total", "next-token cross-entropies traced (a model's head and "
+        "a prediction module's each count one), by the resolved "
+        "implementation", labels=("impl",))
+
+
+def xent_paths_traced() -> dict[str, int]:
+    """``{impl: cross-entropies traced so far}`` in this process (the
+    ``xent_total`` counter; empty with ``DISTLEARN_OBS=0``)."""
+    family = _xent_counter()
+    if family is obs.NULL:
+        return {}
+    return {s["labels"]["impl"]: s["value"] for s in family.sample()}
+
+
+def _next_token_xent(logits, tokens, ahead, targets=None, w=None,
+                     count=None):
+    """The cross-entropy of ``logits`` [B, L, V] against the tokens
+    ``ahead`` positions on, under the scope ``head_loss``, on the path of
+    :func:`~distlearn_tpu.ops.cross_entropy.select_xent` (counted under
+    ``xent_total{impl=}``).
+
+    ``ahead`` 1 or 2: the mean over the ``L - ahead`` positions that have a
+    target in ``tokens`` [B, L] (``targets``, where given, is
+    ``tokens[:, ahead:]``).  ``ahead`` 0: ``targets`` [B, L] are given with
+    the weights ``w`` [L] and ``count()``, the number the weighted sum is
+    divided by.  The ``"xla"`` path is ``log_softmax`` of the logits (sliced
+    to the positions that have a target) and a gather, the program it has
+    always been; ``"fused"`` weighs every position instead of slicing."""
+    impl = cross_entropy.select_xent(sequence._backend())
+    _xent_counter().labels(impl=impl).inc()
+    B, L = tokens.shape
+    with jax.named_scope("head_loss"):
+        if impl == "fused":
+            if ahead:
+                targets = jnp.roll(tokens, -ahead, axis=1)
+                w = (jnp.arange(L) < L - ahead).astype(jnp.float32)
+                total = B * (L - ahead)
+            else:
+                total = jnp.maximum(count(), 1.0)
+            return cross_entropy.weighted_xent(
+                logits, targets, jnp.broadcast_to(w, (B, L))) / total
+        lp = jax.nn.log_softmax(
+            (logits[:, :-ahead] if ahead else logits).astype(jnp.float32))
+        # (``tokens[:, ahead:, None]`` indexes by a gather, where a slice
+        # of ``targets`` does not: each form as its call site wrote it)
+        nll = -jnp.take_along_axis(
+            lp, tokens[:, ahead:, None] if targets is None
+            else targets[..., None], -1)[..., 0]
+        if ahead:
+            return nll.mean()
+        total = count()
+        return jnp.sum(nll * w[None, :]) / jnp.maximum(total, 1.0)
+
+
 def _mtp_loss(state, tokens, seq_axis):
     """The weighted loss of a multi-token-prediction module whose logits the
     model's ``state`` carries (see :func:`lm_loss`), or None."""
@@ -776,11 +834,8 @@ def _mtp_loss(state, tokens, seq_axis):
             "lm_loss: the prediction module's target two ahead would have "
             f"to cross the shards of axis {seq_axis!r}; keep the sequence "
             "on one shard")
-    with jax.named_scope("head_loss"):
-        lp = jax.nn.log_softmax(
-            state["mtp_logits"][:, :-2].astype(jnp.float32))
-        nll = -jnp.take_along_axis(lp, tokens[:, 2:, None], -1)[..., 0]
-        return state["mtp_weight"] * nll.mean()
+    return state["mtp_weight"] * _next_token_xent(state["mtp_logits"],
+                                                  tokens, 2)
 
 
 def lm_loss(model: Model, params, tokens, seq_axis=None, tp_axis=None,
@@ -823,11 +878,7 @@ def lm_loss(model: Model, params, tokens, seq_axis=None, tp_axis=None,
            and "moe_balance_loss" in st else None)
     mtp = _mtp_loss(st, tokens, seq_axis)
     if seq_axis is None:
-        targets = tokens[:, 1:]
-        with jax.named_scope("head_loss"):
-            lp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32))
-            nll = -jnp.take_along_axis(lp, targets[..., None], -1)[..., 0]
-            loss = nll.mean()
+        loss = _next_token_xent(logits, tokens, 1, targets=tokens[:, 1:])
         if mtp is not None:
             loss = loss + mtp
         return loss + bal if bal is not None else loss
@@ -862,13 +913,11 @@ def lm_loss(model: Model, params, tokens, seq_axis=None, tp_axis=None,
         targets = jnp.concatenate([tokens[:, 1:], nxt_first], axis=1)
         pos = my * L + jnp.arange(L)
         w = (pos < n * L - 1).astype(jnp.float32)
-    with jax.named_scope("head_loss"):
-        lp = jax.nn.log_softmax(logits.astype(jnp.float32))
-        nll = -jnp.take_along_axis(lp, targets[..., None], -1)[..., 0]
-        # mask the target-less global last position; normalize by the
-        # GLOBAL token count (a constant — no gradient flows through it)
-        count = lax.psum(jnp.sum(w) * tokens.shape[0], seq_axis)
-        local = jnp.sum(nll * w[None, :]) / jnp.maximum(count, 1.0)
+    # mask the target-less global last position; normalize by the GLOBAL
+    # token count (a constant — no gradient flows through it)
+    local = _next_token_xent(
+        logits, tokens, 0, targets=targets, w=w,
+        count=lambda: lax.psum(jnp.sum(w) * tokens.shape[0], seq_axis))
     if mtp is not None:
         local = local + mtp
     if bal is not None:

@@ -1,6 +1,7 @@
 """Transformer LM: sequence-parallel (ring attention) and tensor-parallel
 outputs must match the single-device model exactly (same full params)."""
 
+import math
 import re
 
 import jax
@@ -936,6 +937,77 @@ def test_tpu_compiler_takes_the_latent_kernel_at_its_published_widths(
     # the widest that tiles the length, or the block as it was
     assert sequence._dkv_block(512, 8 * q_bytes, 3 * 1024) == 1024
     assert sequence._dkv_block(512, 8 * q_bytes, 3 * 512) == 512
+
+
+def _written_arrays(text):
+    """``(name, op, shape)`` of every instruction of a compiled module's text
+    outside a fused computation: the arrays the program writes to memory."""
+    fused = set(re.findall(r" fusion\([^\n]*?calls=(%[\w.\-]+)", text))
+    out = []
+    for comp in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ [^\n]*\{\s*$)", text,
+                         flags=re.M):
+        head = comp.split("\n", 1)[0].split()
+        if not head or head[-1] != "{" or (head[0] != "ENTRY"
+                                           and head[0] in fused):
+            continue
+        out += re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\(",
+                          comp, flags=re.M)
+    return [(name, op, shape) for name, shape, op in out]
+
+
+@pytest.mark.parametrize("ahead", [0, 1, 2])
+def test_tpu_compiler_writes_the_logits_once_in_bf16(v5e_chip, monkeypatch,
+                                                     ahead):
+    """The head and its cross-entropy at ``smallthinker-21b-a3b``'s sizes
+    (16,384 positions, 2,560 wide, a vocabulary of 37,984), forward and
+    both backward products, compiled for the v5e on the ``"fused"`` path,
+    in each form ``lm_loss`` calls it (``ahead`` 0 the sequence-axis form the
+    benchmark's step takes, 1 the head, 2 the prediction module): the one
+    logits-sized array the program writes is the head product's bfloat16
+    output — no float32 copy of it, no log-probabilities, no padded or
+    sliced-into cotangent.  Nothing runs; no number of this is a
+    measurement."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    from distlearn_tpu.models.transformer import _next_token_xent
+    from distlearn_tpu.parallel import sequence
+    monkeypatch.setattr(sequence, "_backend", lambda: "tpu")
+    B, L, D, V = 1, 16384, 2560, 37984
+    one = SingleDeviceSharding(v5e_chip)
+
+    def loss(head, x, tokens):
+        logits = (x @ head.astype(jnp.bfloat16)).astype(jnp.float32)
+        if ahead:
+            return _next_token_xent(logits, tokens, ahead)
+        w = (jnp.arange(L) < L - 1).astype(jnp.float32)
+        return _next_token_xent(logits, tokens, 0,
+                                targets=jnp.roll(tokens, -1, axis=1), w=w,
+                                count=lambda: jnp.sum(w) * B)
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+                jax.ShapeDtypeStruct((D, V), jnp.float32, sharding=one),
+                jax.ShapeDtypeStruct((B, L, D), jnp.bfloat16, sharding=one),
+                jax.ShapeDtypeStruct((B, L), jnp.int32, sharding=one)
+            ).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    logits_sized = [
+        (name, op, shape)
+        for name, op, shape in _written_arrays(compiled.as_text())
+        for dims in re.findall(r"[a-z]\w*\[([\d,]+)\]", shape)
+        if math.prod(int(d) for d in dims.split(",")) == L * V]
+    assert logits_sized, "no logits-sized array found: the parse is stale"
+    assert all("f32[" not in shape and op not in
+               ("pad", "dynamic-update-slice", "concatenate", "copy",
+                "transpose") for _, op, shape in logits_sized), logits_sized
+    assert len({n for n, op, _ in logits_sized
+                if op != "get-tuple-element"}) == 1, logits_sized
 
 
 # --- rotary positions -------------------------------------------------------
